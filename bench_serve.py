@@ -13,7 +13,12 @@ Two load models:
   TTFT distribution (arrival -> first token, queueing included) and
   completed-request goodput at sub/near/at-saturation load points.
 
-Drives the engine DIRECTLY (in-process, the replica's own view).
+Drives the engine DIRECTLY (in-process, the replica's own view), so proxy,
+router and replica cost nothing here; ``chip_smoke.py`` goes through
+``serve.run``, and one benchmark with named cells is ROADMAP Speed item 1.
+
+There is no CPU branch: without a TPU the script prints
+``{"ok": false, ...}`` and exits non-zero.
 """
 
 from __future__ import annotations
@@ -79,25 +84,21 @@ def main() -> None:
 
     from ray_tpu.models.llama import LlamaConfig
     from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.utils.device_report import device_report
 
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
-    if on_tpu:
-        config = LlamaConfig.llama_1b(max_seq_len=2048, attention_impl="flash")
-        # PAGED KV: per-request page commitment instead of slots*max_seq.
-        # 64 slots x <=8 pages(64 rows) ~= 1.5 GB KV pool vs 2.9 GB for 32
-        # dense slots — double the concurrency in half the HBM.
-        num_slots, decode_chunk = 64, 32
-        num_requests, max_tokens = 192, 64
-        prompt_lens = [32, 64, 128, 256]
-        clients = 96
-        paged, page_size, total_pages = True, 64, 64 * 8 + 1
-    else:
-        config = LlamaConfig.tiny(remat=None, attention_impl="reference")
-        num_slots, decode_chunk = 4, 4
-        num_requests, max_tokens = 8, 8
-        prompt_lens = [8, 16]
-        clients = 4
-        paged, page_size, total_pages = True, 16, None
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"bench_serve.py measures a TPU and jax found {platform!r}")
+    config = LlamaConfig.llama_1b(max_seq_len=2048, attention_impl="flash")
+    # PAGED KV: per-request page commitment instead of slots*max_seq.
+    # 64 slots x <=8 pages(64 rows) ~= 1.5 GB KV pool vs 2.9 GB for 32
+    # dense slots: double the concurrency in half the HBM.
+    num_slots, decode_chunk = 64, 32
+    num_requests, max_tokens = 192, 64
+    prompt_lens = [32, 64, 128, 256]
+    clients = 96
+    paged, page_size, total_pages = True, 64, 64 * 8 + 1
 
     engine = LLMEngine(
         config, num_slots=num_slots, decode_chunk=decode_chunk,
@@ -155,6 +156,7 @@ def main() -> None:
     engine.stop()
 
     print(json.dumps({
+        "ok": True,
         "metric": "serve_llm_continuous_batching",
         "value": round(req_s, 2),
         "unit": "req/s",
@@ -172,16 +174,21 @@ def main() -> None:
         "page_size": page_size if paged else None,
         "total_pages": engine.total_pages if paged else None,
         "model_params": config.num_params,
+        "decode_attention": engine.decode_attention,
+        "device": device_report(),
     }))
 
 
 if __name__ == "__main__":
     try:
         main()
-    except Exception as e:  # noqa: BLE001 - always emit a JSON line
+    except Exception as e:  # noqa: BLE001 - one failure line, then fail
+        import traceback
+
+        traceback.print_exc()
         print(json.dumps({
+            "ok": False,
             "metric": "serve_llm_continuous_batching",
-            "value": 0, "unit": "req/s", "vs_baseline": 0.0,
             "error": f"{type(e).__name__}: {e}"[:400],
         }))
-        sys.exit(0)
+        sys.exit(1)

@@ -5,10 +5,13 @@ the object-store arena allocator (plasma_allocator.cc / dlmalloc.cc) and
 the mutable-object channel atomics (experimental_mutable_object_manager.h)
 — behind a C ABI. No pybind11 in the image, so binding is plain ctypes.
 
-The library is built lazily on first import (one `make` shelling out to
-g++, cached next to the sources); if the toolchain is missing the package
-degrades gracefully: ``available()`` returns False and pure-Python
-fallbacks take over (per-object shm segments; RPC-based channels).
+The library is not in the repository: it is built from ``arena.cc`` and
+``channel.cc`` on first use (one ``make`` shelling out to g++, kept next to
+the sources). If that fails, ``available()`` is False and the pure-Python
+data plane takes over (per-object shm segments; RPC-based channels), which
+is a different data plane: the zero-copy block decode needs the arena. So
+the failure is logged once, with the compiler's output, and a caller that
+must not run on the fallback checks ``available()`` (``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -18,6 +21,10 @@ import os
 import subprocess
 import threading
 from typing import Optional
+
+from ray_tpu.utils.logging import get_logger
+
+logger = get_logger("native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "librtpu_native.so")
@@ -38,9 +45,17 @@ def _try_build(force: bool = False) -> bool:
             ["make", "-C", _DIR] + (["-B"] if force else []),
             capture_output=True, text=True, timeout=120,
         )
-        return out.returncode == 0 and os.path.exists(_SO)
-    except Exception:  # noqa: BLE001 - missing make/g++ etc.
+    except (OSError, subprocess.TimeoutExpired) as e:  # no make / g++ hung
+        logger.warning("librtpu_native.so not built (%s: %s); the object "
+                       "store runs on per-object shm segments",
+                       type(e).__name__, e)
         return False
+    if out.returncode != 0 or not os.path.exists(_SO):
+        logger.warning("librtpu_native.so not built (make exited %d); the "
+                       "object store runs on per-object shm segments\n%s",
+                       out.returncode, out.stderr[-2000:])
+        return False
+    return True
 
 
 def _load() -> Optional[ctypes.CDLL]:

@@ -70,7 +70,6 @@ class Cluster:
     def _env(self) -> Dict[str, str]:
         env = dict(os.environ)
         env["RAY_TPU_SESSION_DIR"] = self.session_dir
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # keep subprocess interpreters lean
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

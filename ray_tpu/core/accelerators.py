@@ -27,6 +27,8 @@ TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
 TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
 # test/dev override: pretend this many chips exist
 FAKE_CHIPS_ENV = "RAY_TPU_FAKE_TPU_CHIPS"
+# set by the node agent on a dedicated TPU worker: the chip ids it was leased
+WORKER_CHIPS_ENV = "RAY_TPU_WORKER_CHIPS"
 
 SLICE_LABEL = "ray_tpu.io/slice"
 ACCEL_LABEL = "ray_tpu.io/accelerator"
@@ -35,11 +37,23 @@ WORKER_ID_LABEL = "ray_tpu.io/tpu-worker-id"
 _ACCEL_TYPE_RE = re.compile(r"^v\d+[a-zA-Z]*-\d+$")
 
 
+def jax_pinned_to_cpu() -> bool:
+    """The operator (or the test suite) set ``JAX_PLATFORMS=cpu``: no jax
+    process of this deployment will open a chip. Read from the environment,
+    without importing jax."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
 def detect_num_chips() -> int:
-    """Chips physically attached to this host."""
+    """Chips on this host that a jax process could open. Counted from the
+    device files (no jax import: the caller must stay off the chip), and
+    zero when the operator pinned jax to the CPU with ``JAX_PLATFORMS=cpu``:
+    a chip no process may open is not a schedulable resource."""
     fake = os.environ.get(FAKE_CHIPS_ENV)
     if fake:
         return int(fake)
+    if jax_pinned_to_cpu():
+        return 0
     accel = glob.glob("/dev/accel*")
     if accel:
         return len(accel)
@@ -107,11 +121,18 @@ def node_tpu_resources(num_chips: Optional[int] = None) -> Dict[str, float]:
 
 def visible_chip_env(chip_ids: List[int], total_chips: int) -> Dict[str, str]:
     """Env vars that restrict a process to a chip subset (reference
-    tpu.py:155-195 recipe; see google/jax#14977). Full-host visibility uses
-    the defaults (empty dict = unset everything)."""
+    tpu.py:155-195 recipe; see google/jax#14977). Empty for the whole host:
+    the worker then keeps whatever bounds the host's own environment set."""
     if len(chip_ids) >= total_chips:
         return {}
-    env = {TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chip_ids)}
+    env = {
+        TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chip_ids),
+        # several processes of one host load libtpu at once, each on its own
+        # chips; the node agent, not libtpu's host-wide lockfile, keeps two
+        # of them off the same chip (jax's own multi-process TPU tests set
+        # the same pair)
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
     if len(chip_ids) == 1:
         env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,1,1"
         env[TPU_HOST_BOUNDS_ENV] = "1,1,1"

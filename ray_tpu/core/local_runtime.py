@@ -381,7 +381,11 @@ class LocalRuntime(CoreRuntime):
             # queueing, so floor at 8 for usable parallelism on small hosts.
             num_cpus = max(os.cpu_count() or 1, 8)
         if num_tpus is None:
-            num_tpus = _detect_tpu_chips()
+            # the same detector the node agent uses: device files, no jax
+            # import (the driver may have to stay off the chip)
+            from ray_tpu.core import accelerators
+
+            num_tpus = accelerators.detect_num_chips()
         total = ResourceSet({CPU: num_cpus, **(resources or {})})
         if num_tpus:
             total[TPU] = float(num_tpus)
@@ -1117,20 +1121,6 @@ class LocalRuntime(CoreRuntime):
 class _DepFailed(Exception):
     def __init__(self, error: BaseException):
         self.error = error
-
-
-def _detect_tpu_chips() -> int:
-    """Count TPU chips without forcing a jax import/device init."""
-    import sys
-
-    if "jax" in sys.modules:
-        try:
-            import jax
-
-            return sum(1 for d in jax.devices() if d.platform == "tpu")
-        except Exception:
-            return 0
-    return 0
 
 
 def _flush_profile_local() -> None:

@@ -674,6 +674,14 @@ class WorkerProcess:
 
 def main() -> None:
     setup_component_logging("worker", os.environ.get("RAY_TPU_SESSION_DIR"), also_stderr=True)
+    from ray_tpu.core import accelerators
+
+    if os.environ.get(accelerators.WORKER_CHIPS_ENV):
+        # a dedicated TPU worker exists to compile and run jax programs:
+        # whatever it jits, user code included, goes through the one cache
+        from ray_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     async def run() -> None:
         wp = WorkerProcess()

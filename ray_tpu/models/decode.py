@@ -14,8 +14,8 @@ for serving; here decode is a first-class compiled path):
   length mask.
 - multi-token decode: ``decode_steps`` lax.scans T greedy/temperature steps
   entirely on device, feeding each sampled token into the next step — one
-  host round trip per T tokens (critical on tunneled/remote TPUs where each
-  dispatch costs milliseconds).
+  host round trip per T tokens, so per-program dispatch and the host sync
+  amortize over the chunk.
 - cache buffers are DONATED through jit so XLA updates them in place.
 """
 

@@ -139,16 +139,17 @@ def llama_init(config: LlamaConfig, key) -> Dict[str, Any]:
 
 
 def _attention_dispatch(config: LlamaConfig, rules: ShardingRules, mesh, q, k, v):
-    """Route attention by parallelism layout: with the sequence sharded over
-    a >1-sized cp mesh axis, plain (flash) attention can't see the full
-    sequence — use ring attention (ppermute K/V ring, O(S/cp) memory per
-    device). Otherwise the fused flash path."""
-    seq_axis = rules.lookup("seq") if rules is not None else None
-    if (
-        mesh is not None
-        and isinstance(seq_axis, str)
-        and dict(mesh.shape).get(seq_axis, 1) > 1
-    ):
+    """Route attention by parallelism layout. With the sequence sharded over
+    a >1-sized cp mesh axis, plain attention can't see the full sequence:
+    use ring attention (ppermute K/V ring, O(S/cp) memory per device).
+    Otherwise attention is independent across batch and heads, so under a
+    mesh each device runs it on its own (batch, heads) block inside a
+    shard_map: XLA cannot partition a Mosaic kernel on its own, and a bare
+    flash call in a jit with NamedSharding inputs does not lower."""
+    if mesh is None:
+        return attention(q, k, v, causal=True, impl=config.attention_impl)
+    seq_axis = rules.lookup("seq")
+    if isinstance(seq_axis, str) and dict(mesh.shape).get(seq_axis, 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention_sharded
 
         return ring_attention_sharded(
@@ -156,7 +157,14 @@ def _attention_dispatch(config: LlamaConfig, rules: ShardingRules, mesh, q, k, v
             q_spec=rules.spec(("batch", "seq", "act_heads", "head_dim")),
             kv_spec=rules.spec(("batch", "seq", "act_kv_heads", "head_dim")),
         )
-    return attention(q, k, v, causal=True, impl=config.attention_impl)
+    # seq stays whole inside each block (None), whatever the rules say
+    q_spec = rules.spec(("batch", None, "act_heads", "head_dim"))
+    kv_spec = rules.spec(("batch", None, "act_kv_heads", "head_dim"))
+    local = functools.partial(attention, causal=True, impl=config.attention_impl)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def _layer(

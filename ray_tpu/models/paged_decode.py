@@ -10,9 +10,12 @@ short/long workloads pack 3-8x more concurrent requests into the same HBM
 than the dense slotted cache (models/decode.py).
 
 Decode attention runs the TPU Pallas paged_attention kernel
-(jax.experimental.pallas.ops.tpu.paged_attention) — block-sparse reads of
-exactly the pages a slot owns, no gather materialization. Off-TPU (CPU
-tests) a reference gather path computes the same thing.
+(jax.experimental.pallas.ops.tpu.paged_attention): block-sparse reads of
+exactly the pages a slot owns, no gather materialization. A reference gather
+path computes the same thing for CPU tests and for head dims the kernel does
+not tile. Which of the two a decode program holds is its builder's decision
+(``use_kernel``), made once and visible in the lowered text
+(``tpu_custom_call``); nothing inside the traced function asks the backend.
 
 Layout notes:
 - page_size is a multiple of 8 (TPU sublane) and prefill buckets are
@@ -27,6 +30,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
 
 from ray_tpu.models.decode import _lm_head, _mlp, _project_qkv, sample_token
 from ray_tpu.models.llama import LlamaConfig
@@ -79,17 +83,11 @@ def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
     return out.reshape(b, nh, d).astype(q.dtype)
 
 
-def _paged_attention(q, k_pool, v_pool, table, lengths, scale, config,
-                     pages_per_block: int = 4):
+def _paged_attention(q, k_pool, v_pool, table, lengths, scale,
+                     use_kernel: bool, pages_per_block: int = 4):
     """q: [B, 1, nh, D] -> [B, 1, nh, D]."""
     qs = (q[:, 0] * scale).astype(q.dtype)  # kernel does NOT scale q
-    # the Pallas kernel tiles head_dim onto the 128-lane register file; for
-    # other head dims (tiny test configs) the gather path computes the same
-    if jax.default_backend() == "tpu" and q.shape[-1] % 128 == 0:
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention,
-        )
-
+    if use_kernel:
         out = paged_attention(
             qs.astype(jnp.float32), k_pool, v_pool,
             lengths.astype(jnp.int32), table.astype(jnp.int32),
@@ -158,7 +156,8 @@ def paged_prefill(params, cache: PagedKVCache, tokens, pages, lengths,
 # Decode
 # --------------------------------------------------------------------------- #
 def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
-                     config: LlamaConfig, page_size: int) -> Tuple[jax.Array, PagedKVCache]:
+                     config: LlamaConfig, page_size: int,
+                     use_kernel: bool) -> Tuple[jax.Array, PagedKVCache]:
     """One decode tick. tokens/positions: [B]; table: [B, max_pages].
     positions[b] = cache index the current token writes to; attention spans
     [0, positions[b]] inclusive."""
@@ -189,7 +188,7 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
         ck = jax.lax.dynamic_update_index_in_dim(ck, ck_layer, layer, 0)
         cv = jax.lax.dynamic_update_index_in_dim(cv, cv_layer, layer, 0)
         o = _paged_attention(q, ck_layer, cv_layer, table, lengths, scale,
-                             config)
+                             use_kernel)
         b, t, nh, hd = q.shape
         x = x + o.reshape(b, t, nh * hd) @ lp["wo"]
         x = x + _mlp(config, lp, x)
@@ -204,14 +203,15 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
 
 def paged_decode_steps(params, cache: PagedKVCache, tokens, positions, active,
                        table, key, config: LlamaConfig, num_steps: int,
-                       page_size: int, temperature: float = 0.0):
+                       page_size: int, use_kernel: bool,
+                       temperature: float = 0.0):
     """T decode ticks on device (like decode.decode_steps, paged). The host
     pre-provisions table pages covering positions+T before each chunk."""
 
     def tick(carry, k_):
         toks, pos, cache = carry
         logits, cache = paged_decode_one(params, cache, toks, pos, table,
-                                         config, page_size)
+                                         config, page_size, use_kernel)
         nxt = sample_token(logits, k_, temperature)
         nxt = jnp.where(active, nxt, toks)
         new_pos = jnp.where(active, pos + 1, pos)
@@ -224,11 +224,18 @@ def paged_decode_steps(params, cache: PagedKVCache, tokens, positions, active,
     return sampled.T, last, pos, cache
 
 
+def paged_kernel_fits(config: LlamaConfig) -> bool:
+    """The Pallas kernel tiles head_dim onto the 128-lane register file."""
+    return config.head_dim_ % 128 == 0
+
+
 def make_paged_decode_fn(config: LlamaConfig, num_steps: int, page_size: int,
-                         temperature: float = 0.0):
+                         temperature: float = 0.0, *, use_kernel: bool):
+    """``use_kernel``: Pallas paged attention (a TPU, ``paged_kernel_fits``)
+    or the gather reference. Required: the caller knows what it runs on."""
     fn = functools.partial(paged_decode_steps, config=config,
                            num_steps=num_steps, page_size=page_size,
-                           temperature=temperature)
+                           use_kernel=use_kernel, temperature=temperature)
     return jax.jit(fn, donate_argnums=(1,))
 
 

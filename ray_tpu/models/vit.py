@@ -24,7 +24,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import flash_attention, reference_attention
+from ray_tpu.ops.attention import attention
 from ray_tpu.ops.norms import rms_norm
 
 
@@ -102,14 +102,7 @@ def vit_init(config: ViTConfig, key) -> Dict[str, Any]:
 
 
 def _attention(config: ViTConfig, q, k, v):
-    if config.attention_impl == "reference":
-        return reference_attention(q, k, v, causal=False)
-    if config.attention_impl == "flash":
-        return flash_attention(q, k, v, causal=False)
-    # auto: flash on TPU, reference elsewhere
-    if any(d.platform == "tpu" for d in jax.devices()):
-        return flash_attention(q, k, v, causal=False)
-    return reference_attention(q, k, v, causal=False)
+    return attention(q, k, v, causal=False, impl=config.attention_impl)
 
 
 def _layer(config: ViTConfig, x, lp):

@@ -1,4 +1,5 @@
-"""Attention ops: Pallas TPU flash-attention forward + reference fallback.
+"""Attention ops: Pallas TPU flash attention (forward and backward) and the
+plain reference it is checked against.
 
 Design (see /opt/skills/guides/pallas_guide.md):
 - grid (batch, q_heads, q_blocks); K/V live whole-sequence in VMEM per
@@ -27,11 +28,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-try:  # pragma: no cover - import guard for pallas-less builds
-    from jax.experimental import pallas as pl
-except Exception:  # noqa: BLE001
-    pl = None
+from ray_tpu.utils.logging import get_logger
+
+logger = get_logger("ops.attention")
 
 # Block sizes tuned on v5e (see tools/attn_tune.py): (256, 512) maximizes
 # fwd and fwd+bwd throughput at seq 2048 (43/86 TF/s vs 15/? at 128/128 —
@@ -408,6 +409,11 @@ def flash_attention(
             -1,
         )
         if not causal or pad < 0:
+            # runs at trace time, once per compiled shape
+            logger.warning(
+                "flash_attention: no block padding for q %s / kv %s "
+                "(causal=%s); this call computes the S x S reference instead",
+                q.shape, k.shape, causal)
             return reference_attention(q, k, v, causal, scale)
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -423,17 +429,19 @@ def _round_up(x: int, m: int) -> int:
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl: str = "auto"):
-    """Dispatch: pallas flash on TPU, reference elsewhere.
+    """Dispatch. impl: "flash" | "flash_interpret" | "reference" | "auto".
 
-    impl: "auto" | "flash" | "reference" | "flash_interpret"
+    "flash" is the Pallas kernel and nothing else: where Mosaic cannot
+    compile it the compiler's error surfaces. "auto" resolves once per trace
+    from the default backend, kernel on a TPU and reference elsewhere; it is
+    for code that must also run on a CPU. A measured path names its impl.
     """
+    if impl == "auto":
+        impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return reference_attention(q, k, v, causal, scale)
     if impl == "flash":
         return flash_attention(q, k, v, causal, scale)
     if impl == "flash_interpret":
         return flash_attention(q, k, v, causal, scale, interpret=True)
-    on_tpu = any(d.platform == "tpu" for d in jax.devices()) and pl is not None
-    if on_tpu:
-        return flash_attention(q, k, v, causal, scale)
-    return reference_attention(q, k, v, causal, scale)
+    raise ValueError(f"unknown attention impl {impl!r}")

@@ -107,11 +107,9 @@ def make_mesh(
     """Build a jax.sharding.Mesh.
 
     Uses mesh_utils.create_device_mesh so the logical mesh maps onto the
-    physical ICI torus (neighbor axes get neighbor links); falls back to a
-    plain reshape off-TPU.
+    physical ICI torus (neighbor axes get neighbor links).
     """
     import jax
-    import numpy as np
 
     devs = list(devices) if devices is not None else jax.devices()
     if config is None:
@@ -123,14 +121,14 @@ def make_mesh(
     if config.num_slices > 1:
         return jax.sharding.Mesh(
             _hybrid_mesh_array(config, devs, allow_split_physical_axes), names)
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        arr = mesh_utils.create_device_mesh(
-            shape, devices=devs, allow_split_physical_axes=allow_split_physical_axes
-        )
-    except Exception:
-        arr = np.asarray(devs).reshape(shape)
+    # no reshape fallback: on a TPU a mesh that ignores the ICI layout still
+    # computes, only slower, so a failure to map the topology must surface
+    # (off-TPU create_device_mesh is itself a plain reshape)
+    arr = mesh_utils.create_device_mesh(
+        shape, devices=devs, allow_split_physical_axes=allow_split_physical_axes
+    )
     return jax.sharding.Mesh(arr, names)
 
 
@@ -162,13 +160,9 @@ def _hybrid_mesh_array(config: MeshConfig, devs,
             )
         from jax.experimental import mesh_utils
 
-        try:
-            return mesh_utils.create_hybrid_device_mesh(
-                ici_shape, dcn_shape, devices=devs,
-                allow_split_physical_axes=allow_split_physical_axes)
-        except TypeError:  # older jax without the kwarg
-            return mesh_utils.create_hybrid_device_mesh(
-                ici_shape, dcn_shape, devices=devs)
+        return mesh_utils.create_hybrid_device_mesh(
+            ici_shape, dcn_shape, devices=devs,
+            allow_split_physical_axes=allow_split_physical_axes)
     # virtual slices: contiguous groups (process/device order is already
     # ICI-major under xla_force_host_platform_device_count)
     arr = np.asarray(devs).reshape(

@@ -52,14 +52,7 @@ def pipeline_apply(
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _sm
-
-        shard_map = functools.partial(_sm, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sme
-
-        shard_map = functools.partial(_sme, check_rep=False)
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
 
     pp = mesh.shape[axis_name]
     b = x.shape[0]
@@ -173,14 +166,7 @@ def pipeline_train_step(
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _sm
-
-        shard_map = functools.partial(_sm, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sme
-
-        shard_map = functools.partial(_sme, check_rep=False)
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
 
     if schedule not in ("gpipe", "1f1b"):
         raise ValueError(f"unknown schedule {schedule!r}")

@@ -121,14 +121,7 @@ def ring_attention_sharded(q, k, v, mesh, causal: bool = True,
     forcing replication at the shard_map boundary."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _sm
-
-        wrap = functools.partial(_sm, check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        wrap = functools.partial(_sm, check_rep=False)
+    wrap = functools.partial(jax.shard_map, check_vma=False)
 
     if q_spec is None:
         q_spec = P(None, axis_name, None, None)

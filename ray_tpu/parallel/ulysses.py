@@ -87,14 +87,7 @@ def ulysses_attention_sharded(
     preserved at the boundary instead of forcing replication."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _sm
-
-        wrap = functools.partial(_sm, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sme
-
-        wrap = functools.partial(_sme, check_rep=False)
+    wrap = functools.partial(jax.shard_map, check_vma=False)
 
     if q_spec is None:
         q_spec = P(None, axis_name, None, None)

@@ -22,11 +22,14 @@ from typing import Any, Dict, List, Optional
 import cloudpickle
 
 import ray_tpu
+from ray_tpu import exceptions as exc
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("serve.controller")
 
 CONTROL_LOOP_PERIOD_S = 0.5
+# how long a replica's constructor may run before it counts as hung
+REPLICA_STARTUP_TIMEOUT_S = 600.0
 
 
 class ServeController:
@@ -92,6 +95,8 @@ class ServeController:
                 "init_args": init_args,
                 "target": dep.target_replicas,
                 "replicas": [] if code_changed else (old["replicas"] if old else []),
+                # replica -> monotonic deadline for its constructor
+                "starting": {} if code_changed or not old else old["starting"],
                 "next_replica_idx": old["next_replica_idx"] if old else 0,
                 "last_scale_up": 0.0,
                 "last_scale_down": 0.0,
@@ -230,15 +235,33 @@ class ServeController:
     def _health_check(self, name: str, rec: Dict[str, Any]) -> None:
         dead = []
         for r in list(rec["replicas"]):
+            deadline = rec["starting"].get(r)
             try:
-                ray_tpu.get(r.check_health.remote(), timeout=10)
+                # a constructing replica answers nothing yet: probe it
+                # briefly so the loop keeps serving the other apps
+                ray_tpu.get(r.check_health.remote(),
+                            timeout=10 if deadline is None else 1)
+                rec["starting"].pop(r, None)
+            except exc.GetTimeoutError:
+                # still in its constructor (a TPU replica opens the chip and
+                # loads a model there: tens of seconds), not dead
+                if deadline is None or time.monotonic() > deadline:
+                    dead.append(r)
             except Exception:  # noqa: BLE001
                 dead.append(r)
         if dead:
             with self._lock:
                 for r in dead:
+                    rec["starting"].pop(r, None)
                     if r in rec["replicas"]:
                         rec["replicas"].remove(r)
+            for r in dead:
+                # whatever state it is in, it must give back what it holds
+                # (a leaked TPU replica starves its own replacement)
+                try:
+                    ray_tpu.kill(r)
+                except Exception:  # noqa: BLE001 - already gone
+                    pass
             self._bump_version()
             logger.warning("serve app %s: %d replica(s) failed health check",
                            name, len(dead))
@@ -285,6 +308,8 @@ class ServeController:
                 break
             with self._lock:
                 rec["replicas"].append(replica)
+                rec["starting"][replica] = (
+                    time.monotonic() + REPLICA_STARTUP_TIMEOUT_S)
             changed = True
         if current > target:
             with self._lock:

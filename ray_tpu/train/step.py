@@ -24,6 +24,7 @@ from ray_tpu.parallel.sharding import (
     axes_is_leaf,
     logical_sharding,
 )
+from ray_tpu.utils.compile_cache import enable_compile_cache
 
 
 class TrainState(NamedTuple):
@@ -113,6 +114,7 @@ def make_train_state_factory(
     """Returns init(key) -> sharded TrainState; when a mesh is given, init is
     jitted with sharded out_shardings so parameters are created directly in
     their shards (no host-side full materialization)."""
+    enable_compile_cache()
 
     def init(key) -> TrainState:
         params = llama_init(config, key)
@@ -134,6 +136,7 @@ def make_train_step(
     donate: bool = True,
 ):
     """(state, tokens, targets) -> (state, metrics). tokens/targets: [B, S]."""
+    enable_compile_cache()
 
     def step_fn(state: TrainState, tokens, targets) -> Tuple[TrainState, Dict[str, jax.Array]]:
         def loss_fn(params):

@@ -1,0 +1,169 @@
+"""Compile the main path's programs for a v5e that is described, not attached.
+
+The TPU compiler is installed with jax and compiles for a topology
+description (``jax.experimental.topologies``), so what Mosaic or XLA:TPU
+would refuse on the chip is refused here, on the CPU, in tier-1: a kernel
+that cannot be partitioned, a slice off the tiling, a program over the
+device's memory. Nothing runs, so nothing here is a device number.
+
+Shapes are the ones ``chip_smoke.py`` and the bench scripts use at
+``LlamaConfig.llama_1b`` widths; depth is cut where it only repeats a
+scanned layer.
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from ray_tpu.models import paged_decode as pd
+from ray_tpu.models.llama import LlamaConfig, llama_init
+from ray_tpu.ops.attention import flash_attention
+
+B, S, HQ, HKV, D = 8, 2048, 16, 4, 128
+SLOTS, PAGE, POOL_PAGES, TABLE_PAGES, CHUNK = 64, 64, 64 * 8 + 1, 32, 32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _llama_1b(layers, **kw):
+    return dataclasses.replace(
+        LlamaConfig.llama_1b(max_seq_len=S, **kw), num_layers=layers)
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("passes,kernels", [("forward", 1),
+                                            ("forward_backward", 3)])
+def test_flash_attention_compiles(v5e, passes, kernels):
+    one = SingleDeviceSharding(v5e.devices[0])
+    q = jax.ShapeDtypeStruct((B, S, HQ, D), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((B, S, HKV, D), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    fn = flash_attention if passes == "forward" \
+        else jax.grad(loss, argnums=(0, 1, 2))  # fwd + dq + dk/dv kernels
+    assert _compiled_text(fn, q, kv, kv).count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16])
+def test_library_paged_attention_compiles_at_engine_shapes(v5e, q_dtype):
+    one = SingleDeviceSharding(v5e.devices[0])
+    q = jax.ShapeDtypeStruct((SLOTS, HQ, D), q_dtype, sharding=one)
+    pool = jax.ShapeDtypeStruct((HKV, POOL_PAGES, PAGE, D), jnp.bfloat16,
+                                sharding=one)
+    lengths = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one)
+    table = jax.ShapeDtypeStruct((SLOTS, TABLE_PAGES), jnp.int32, sharding=one)
+    text = _compiled_text(
+        lambda q, k, v, n, t: pd.paged_attention(
+            q, k, v, n, t, pages_per_compute_block=4),
+        q, pool, pool, lengths, table)
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_chunk_holds_the_kernel(v5e):
+    """The engine's decode program, built the way the engine builds it on a
+    TPU (use_kernel=True), without patching what jax thinks the backend is."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    config = _llama_1b(2, attention_impl="flash")
+    params = _on(one, jax.eval_shape(lambda k: llama_init(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: pd.init_paged_cache(config, POOL_PAGES, PAGE)))
+    ints = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one)
+    active = jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one)
+    table = jax.ShapeDtypeStruct((SLOTS, TABLE_PAGES), jnp.int32, sharding=one)
+    key = _on(one, jax.eval_shape(lambda: jax.random.key(0)))
+    decode = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=True)
+    lowered = decode.lower(params, cache, ints, ints, active, table, key)
+    assert "tpu_custom_call" in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    gather = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=False)
+    assert "tpu_custom_call" not in gather.lower(
+        params, cache, ints, ints, active, table, key).as_text()
+
+
+def test_prefill_bucket_compiles(v5e):
+    one = SingleDeviceSharding(v5e.devices[0])
+    bucket = 512
+    config = _llama_1b(2, attention_impl="flash")
+    params = _on(one, jax.eval_shape(lambda k: llama_init(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: pd.init_paged_cache(config, POOL_PAGES, PAGE)))
+    tokens = jax.ShapeDtypeStruct((8, bucket), jnp.int32, sharding=one)
+    pages = jax.ShapeDtypeStruct((8, bucket // PAGE), jnp.int32, sharding=one)
+    lengths = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+    prefill = pd.make_paged_prefill_fn(config, PAGE)
+    text = prefill.lower(params, cache, tokens, pages, lengths).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_fsdp4_train_step_compiles_with_flash(v5e):
+    """Failed to lower before the flash call sat in a shard_map: ``Mosaic
+    kernels cannot be automatically partitioned``."""
+    from ray_tpu.parallel.mesh import MeshConfig, batch_sharding_spec, make_mesh
+    from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES
+    from ray_tpu.train.step import (
+        TrainState, _state_shardings, default_optimizer, make_train_step,
+        state_logical_axes,
+    )
+
+    mesh = make_mesh(MeshConfig(fsdp=4), devices=v5e.devices)
+    config = _llama_1b(2, remat="save_attn", attention_impl="flash")
+    opt = default_optimizer()
+    shardings = _state_shardings(
+        state_logical_axes(config, opt), mesh, DEFAULT_LLM_RULES)
+
+    def init(key):
+        params = llama_init(config, key)
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=opt.init(params))
+
+    state = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        jax.eval_shape(init, jax.random.key(0)), shardings)
+    batch = jax.ShapeDtypeStruct(
+        (B, S), jnp.int32, sharding=NamedSharding(mesh, batch_sharding_spec()))
+    compiled = make_train_step(config, opt, mesh=mesh).lower(
+        state, batch, batch).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "all-gather" in text  # fsdp: weights gathered per layer
+    # each device holds a quarter of the state, not all of it
+    whole = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < 0.3 * whole

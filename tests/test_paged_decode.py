@@ -53,7 +53,7 @@ def _paged_generate(cfg, params, prompt, steps, num_slots=2, total_pages=9):
     first = int(jnp.argmax(logits[0]))
     table = np.zeros((num_slots, 4), np.int32)  # zeros = trash page
     table[0, : len(pages)] = pages
-    dec = pd.make_paged_decode_fn(cfg, steps, PS, 0.0)
+    dec = pd.make_paged_decode_fn(cfg, steps, PS, 0.0, use_kernel=False)
     toks = jnp.zeros((num_slots,), jnp.int32).at[0].set(first)
     pos = jnp.zeros((num_slots,), jnp.int32).at[0].set(len(prompt))
     act = jnp.zeros((num_slots,), bool).at[0].set(True)
