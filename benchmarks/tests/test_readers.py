@@ -1,0 +1,135 @@
+"""Each metric's reader on hand-made observations."""
+import math
+
+import pytest
+
+from benchmarks.harness.loadgen import RequestRecord
+from benchmarks.readers import (
+    engine_step_wall, generator_lag, idle_share, ingress_overhead, input_wait,
+    kernel_roofline, module_time, peak_hbm, report_stall, report_wait,
+    serve_token_rate,
+    slot_occupancy, tpot_percentile, train_mfu, train_token_rate, ttft_percentile)
+
+
+def rec(i, due, first, n, gap, measured=True, error=None, engine_latency=None):
+    r = RequestRecord(i, due, 100, n, measured)
+    r.sent = due + 0.002
+    r.arrivals = [first + k * gap for k in range(n)]
+    r.tokens = list(range(n))
+    r.finished = r.arrivals[-1] + 0.01 if n else due + 1
+    r.error = error
+    r.done = {"latency_s": engine_latency} if engine_latency is not None else None
+    return r
+
+
+@pytest.fixture
+def serve_ctx():
+    records = [rec(i, 10.0 + i, 10.5 + i + 0.01 * i, 11, 0.05 + 0.001 * i,
+                   engine_latency=0.9) for i in range(10)]
+    records.append(rec(99, 5.0, 5.5, 4, 0.05, measured=False))
+    return {"records": records, "t_open": 10.0, "t_close": 21.0}
+
+
+def test_ttft_and_tpot_percentiles(serve_ctx):
+    assert ttft_percentile.read(serve_ctx, {"q": 0.9}) == pytest.approx(0.5 + 0.08)
+    assert tpot_percentile.read(serve_ctx, {"q": 0.9}) == pytest.approx(50 + 8)
+    assert ttft_percentile.read({"records": []}, {"q": 0.9}) is None
+
+
+def test_failed_requests_count_as_missing(serve_ctx):
+    serve_ctx["records"][0].error = "HTTP 500"
+    serve_ctx["records"][1].error = "timeout"
+    assert ttft_percentile.read(serve_ctx, {"q": 0.9}) == math.inf
+    assert ttft_percentile.read(serve_ctx, {"q": 0.5}) < 1.0
+
+
+def test_serve_token_rate_is_read_between_arrivals():
+    r = rec(0, 0.0, 1.0, 5, 1.0)          # tokens at 1, 2, 3, 4, 5
+    ctx = {"records": [r], "t_open": 1.5, "t_close": 4.6}
+    # inside: 2, 3, 4 -> two tokens after the first instant over 2 s
+    assert serve_token_rate.read(ctx, {}) == pytest.approx(1.0)
+
+
+def test_ingress_and_generator_lag(serve_ctx):
+    r0 = serve_ctx["records"][0]
+    expected = 1e3 * ((r0.finished - r0.sent) - 0.9)
+    got = ingress_overhead.read(serve_ctx, {})
+    assert min(1e3 * ((r.finished - r.sent) - 0.9) for r in serve_ctx["records"][:10]) \
+        <= got <= 1e3 * ((serve_ctx["records"][9].finished
+                          - serve_ctx["records"][9].sent) - 0.9)
+    assert expected > 0
+    assert generator_lag.read(serve_ctx, {"q": 0.99}) == pytest.approx(2.0)
+
+
+def test_engine_counters_leave_out_the_traced_seconds():
+    polls = [(t, {"decode_steps": int(100 * t), "active": 10 if t < 5 else 20})
+             for t in [1, 2, 3, 4, 5, 6, 7, 8]]
+    ctx = {"marks": {"open": 0.5, "close": 8.5, "polls": polls,
+                     "trace_call": (3.5, 5.5)}}
+    # segments 1..2 (3 is within 0.6 s of the trace) and 7..8 (6 likewise)
+    assert engine_step_wall.read(ctx, {}) == pytest.approx(10.0)
+    assert slot_occupancy.read(ctx, {}) == pytest.approx(15.0)
+    assert engine_step_wall.read({"marks": {"polls": []}}, {}) is None
+
+
+def test_train_rate_mfu_and_waits():
+    ctx = {"reports": [100.0, 110.0, 120.0, 130.0], "report_tokens": [3e4, 1e5, 1e5, 1e5],
+           "window_open": 100.0, "window_close": 125.0, "chips": 1,
+           "device_report": {"kind": "TPU v5 lite"},
+           "cfg": {"hidden_size": 4096, "intermediate_size": 14336,
+                   "num_attention_heads": 32, "num_key_value_heads": 8,
+                   "head_dim": 128, "vocab_size": 32768, "num_hidden_layers": 4,
+                   "deployment": {"max_seq_len": 4096, "warmup_steps": 1}},
+           "input_waits": [9.0, 0.001, 0.003], "report_waits": [5.0, 0.002, 0.004]}
+    assert train_token_rate.read(ctx, {}) == pytest.approx(1e4)
+    ctx["rate_until"] = 111.0   # a traced run: only the reports before the profiler
+    assert train_token_rate.read(ctx, {}) == pytest.approx(1e4)
+    ctx["chips"] = 4
+    assert train_token_rate.read(ctx, {}) == pytest.approx(2.5e3)
+    ctx["chips"] = 1
+    mfu = train_mfu.read(ctx, {})
+    assert mfu == pytest.approx(100 * 1e4 * 6.44e9 / 197e12, rel=5e-3)
+    assert input_wait.read(ctx, {}) == pytest.approx(2.0)
+    assert report_wait.read(ctx, {}) == pytest.approx(3.0)
+    # a step in the window is 1e5 tokens at 1e4 tokens/s: 10 s. The probe's
+    # three steps, a report after each, took 13.5 s each
+    assert report_stall.read(ctx, {}) is None
+    ctx["tokens_per_step"] = 1e5
+    ctx["probe_reports"] = [130.0, 141.0, 152.0, 170.5]
+    assert report_stall.read(ctx, {}) == pytest.approx(3500.0)
+
+
+def test_trace_readers():
+    trace = {"busy_s": 3.0, "window_s": 4.0,
+             "module_s": {"jit__unknown(1)": 2.4, "jit__unknown(2)": 0.6,
+                          "jit_step_fn(3)": 2.7},
+             "module_count": {"jit__unknown(1)": 8, "jit__unknown(2)": 2,
+                              "jit_step_fn(3)": 3},
+             "module_ops": {"jit__unknown(1)": {"paged_attention.10 = (f32[64": 0.2},
+                            "jit__unknown(2)": {
+                                "closed_call.15 = bf16[8,32,2048,128]{3} "
+                                "custom-call(bf16[8,32,2048,128]{3}": 0.1}},
+             "op_self_s": {"paged_attention.10 = (f32[64,32,1,128]": 0.25},
+             "op_count": {"paged_attention.10 = (f32[64,32,1,128]": 500}}
+    cfg = {"num_attention_heads": 32, "num_key_value_heads": 8, "head_dim": 128,
+           "deployment": {"decode_chunk": 8, "num_slots": 64}}
+    ctx = {"trace": trace, "cfg": cfg, "device_report": {
+        "kind": "TPU v5 lite", "memory_peak_bytes": 7.0e9}}
+    assert idle_share.read(ctx, {}) == pytest.approx(25.0)
+    assert peak_hbm.read(ctx, {}) == pytest.approx(7.0)
+    assert module_time.read(ctx, {"contains": "^paged_attention",
+                                  "steps_key": "decode_chunk"}) == pytest.approx(37.5)
+    assert module_time.read(ctx, {"contains": r"custom-call\(bf16\[8,32,\d+,128\]",
+                                  "without": "^paged_attention"}) == pytest.approx(300.0)
+    assert module_time.read(ctx, {"module": "^jit_step_fn"}) == pytest.approx(900.0)
+    assert module_time.read(ctx, {"contains": "nothing"}) is None
+    # 10,000 live tokens through the traced interval
+    r = rec(0, 0.0, 1.0, 50, 0.1)
+    r.prompt_len = 9975
+    ctx.update(records=[r], marks={"traced": (3.0, 4.0)})
+    live = kernel_roofline._live_tokens(ctx)
+    assert 9975 + 20 <= live <= 9975 + 31
+    share = kernel_roofline.read(ctx, {"pattern": "^paged_attention", "kind": "paged_attn"})
+    need = 500 * (2 * live * 8 * 128 * 2 + 2 * 64 * 32 * 128 * 2)
+    assert share == pytest.approx(100 * need / 819e9 / 0.25)
+    assert kernel_roofline.read({"trace": {}, "cfg": cfg}, {"pattern": "x", "kind": "flash_fwd"}) is None

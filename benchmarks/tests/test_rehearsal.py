@@ -1,0 +1,62 @@
+"""The command end to end at tiny widths on the CPU backend: the same
+control flow as a chip run (cluster, serve.run / TpuTrainer, HTTP streaming,
+the Data feed, the reference check, teardown), refused as a measurement."""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmarks.harness import manifest as mf
+from benchmarks.harness import procs
+
+
+def rehearse(workload, seconds, trace=0):
+    p = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", workload, "--seed",
+         "3000000019", "--seconds", str(seconds), "--trace", str(trace),
+         "--rehearse"], capture_output=True, text=True, cwd=mf.ROOT, timeout=900)
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = p.stdout.strip().splitlines()
+    return json.loads(lines[-1]), lines
+
+
+@pytest.mark.parametrize("workload,seconds", [
+    ("serve_chat", 5), ("serve_longprompt", 5), ("train_4k", 3)])
+def test_rehearsal_runs_and_reports_no_device_metric(workload, seconds):
+    out, lines = rehearse(workload, seconds)
+    assert set(out) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert out["rehearsal"] is True and out["metrics"] == {}
+    assert out["device"]["platform"] == "cpu"
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert any(line.startswith("check ") for line in lines)
+    check = out["observed"]["check"]
+    if workload == "train_4k":
+        assert check["loss_abs_diff"] < 0.02 and check["grad_norm_rel_diff"] < 0.02
+    else:
+        assert check["decisions"] > 0 and check["mean_gap"] < 0.01
+
+
+def test_without_a_chip_there_is_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("RAY_TPU_FAKE_TPU_CHIPS", None)
+    p = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", "train_4k", "--seed",
+         "1", "--seconds", "1", "--trace", "0"],
+        capture_output=True, text=True, cwd=mf.ROOT, env=env, timeout=300)
+    assert p.returncode != 0
+    assert not any(line.startswith("{") for line in p.stdout.splitlines())
+
+
+def test_reaping_finds_marked_processes():
+    token = procs.mark_environment()
+    child = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"],
+                             start_new_session=True)
+    try:
+        assert child.pid in procs.marked_pids(token)
+        assert procs.reap_all(token, grace_s=0.2, limit_s=20) == []
+        assert child.poll() is not None
+    finally:
+        child.kill()
+        os.environ.pop(procs.MARKER, None)
