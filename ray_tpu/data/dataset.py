@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import queue as _queue
 import threading
+from collections import deque
 from typing import Any, Callable, Dict, Iterator, List, Optional, Union
 
 import numpy as np
@@ -33,6 +34,7 @@ from ray_tpu.data.executor import (
     Stage,
     ZipStage,
 )
+from ray_tpu.profiling import span
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("data")
@@ -253,30 +255,10 @@ class Dataset:
     ) -> Iterator[Dict[str, Any]]:
         """Device-side prefetch: batches are transferred to HBM ahead of
         consumption (double-buffering, config.device_prefetch_depth)."""
-        import jax
-
-        from ray_tpu.core.config import config
-
-        host_iter = self.iter_batches(
+        return _device_prefetch(self.iter_batches(
             batch_size=batch_size, batch_format="numpy",
             prefetch_batches=prefetch_batches, drop_last=drop_last,
-        )
-
-        def to_device(batch: Dict[str, np.ndarray]):
-            out = {}
-            for k, v in batch.items():
-                arr = v if dtype is None else v.astype(dtype)
-                out[k] = jax.device_put(arr, sharding) if sharding is not None else jax.device_put(arr)
-            return out
-
-        depth = max(1, config.device_prefetch_depth)
-        buf: "_queue.deque" = __import__("collections").deque()
-        for batch in host_iter:
-            buf.append(to_device(batch))
-            if len(buf) >= depth:
-                yield buf.popleft()
-        while buf:
-            yield buf.popleft()
+        ), sharding, dtype)
 
     def streaming_split(self, n: int, *, equal: bool = True) -> List["DataIterator"]:
         """Split into n per-consumer iterators fed round-robin from one
@@ -491,31 +473,42 @@ class DataIterator:
         """Device-side prefetch on a streaming_split shard — the per-train-
         worker half of the data->train path (reference: DataIterator.
         iter_torch_batches used by Train via DataConfig)."""
-        import jax
-
-        from ray_tpu.core.config import config
-
-        host_iter = self.iter_batches(
+        return _device_prefetch(self.iter_batches(
             batch_size=batch_size, batch_format="numpy",
             prefetch_batches=prefetch_batches, drop_last=drop_last,
-        )
+        ), sharding, dtype)
 
-        def to_device(batch):
-            out = {}
-            for k, v in batch.items():
-                arr = v if dtype is None else v.astype(dtype)
-                out[k] = (jax.device_put(arr, sharding)
-                          if sharding is not None else jax.device_put(arr))
-            return out
 
-        depth = max(1, config.device_prefetch_depth)
-        buf: "_queue.deque" = __import__("collections").deque()
-        for batch in host_iter:
-            buf.append(to_device(batch))
-            if len(buf) >= depth:
-                yield buf.popleft()
-        while buf:
+def _device_prefetch(host_iter: Iterator[Dict[str, np.ndarray]], sharding,
+                     dtype) -> Iterator[Dict[str, Any]]:
+    """Host batches -> device batches, ``config.device_prefetch_depth`` of
+    them transferred ahead of consumption. The wait for the next host batch
+    is the ``data.next_batch`` span of a device trace."""
+    import jax
+
+    from ray_tpu.core.config import config
+
+    def to_device(batch: Dict[str, np.ndarray]):
+        out = {}
+        for k, v in batch.items():
+            arr = v if dtype is None else v.astype(dtype)
+            out[k] = (jax.device_put(arr, sharding)
+                      if sharding is not None else jax.device_put(arr))
+        return out
+
+    depth = max(1, config.device_prefetch_depth)
+    buf: "deque" = deque()
+    host_iter = iter(host_iter)
+    while True:
+        with span("data.next_batch"):
+            batch = next(host_iter, None)
+        if batch is None:
+            break
+        buf.append(to_device(batch))
+        if len(buf) >= depth:
             yield buf.popleft()
+    while buf:
+        yield buf.popleft()
 
 
 def _batch_iterator(refs: Iterator[ObjectRef], batch_size: int, batch_format: str,

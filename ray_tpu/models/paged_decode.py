@@ -232,15 +232,19 @@ def paged_kernel_fits(config: LlamaConfig) -> bool:
 def make_paged_decode_fn(config: LlamaConfig, num_steps: int, page_size: int,
                          temperature: float = 0.0, *, use_kernel: bool):
     """``use_kernel``: Pallas paged attention (a TPU, ``paged_kernel_fits``)
-    or the gather reference. Required: the caller knows what it runs on."""
+    or the gather reference. Required: the caller knows what it runs on.
+    The partial is given the function's name, so a profile's module line
+    reads ``jit_paged_decode_steps`` (a bare partial reads ``jit__unknown``)."""
     fn = functools.partial(paged_decode_steps, config=config,
                            num_steps=num_steps, page_size=page_size,
                            use_kernel=use_kernel, temperature=temperature)
+    fn.__name__ = paged_decode_steps.__name__
     return jax.jit(fn, donate_argnums=(1,))
 
 
 def make_paged_prefill_fn(config: LlamaConfig, page_size: int):
     fn = functools.partial(paged_prefill, config=config, page_size=page_size)
+    fn.__name__ = paged_prefill.__name__  # jit_paged_prefill in a profile
     return jax.jit(fn, donate_argnums=(1,))
 
 

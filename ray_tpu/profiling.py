@@ -16,13 +16,21 @@ cat="user" chrome-trace events next to the task-state spans.
             ...
         with ray_tpu.profile("compute", extra={"batch": 8}):
             ...
+
+``span`` is the one primitive for spans on the DEVICE trace's clock (the
+engine loop, the data feed, ``train.report``): it writes into a running
+``jax.profiler`` trace and nowhere else. ``profile`` enters it too, so user
+spans reach a device trace as well as the dashboard. ``host_events`` counts
+what can stop a host thread from outside: compiles and garbage collections.
 """
 
 from __future__ import annotations
 
+import gc
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 # process-wide buffer: async actor methods record on the event-loop thread
@@ -36,29 +44,104 @@ _lock = threading.Lock()
 _local_runtime_spans: List[Dict[str, Any]] = []
 
 
+_NULL_SPAN = nullcontext()
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is in the process
+
+
+def span(name: str, **ints: int):
+    """A host span in a running ``jax.profiler`` trace (the device trace's
+    clock): ``with span("engine.admit"): ...``. Fixed-string names, integer
+    attributes. With no trace running the annotation records nothing; in a
+    process that never imported jax (the proxy) this is one shared null
+    context and jax stays unimported."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return _NULL_SPAN
+        _annotation = profiler.TraceAnnotation
+    return _annotation(name, **ints)
+
+
+class HostEvents:
+    """Process-wide counts of the two host-side events that stop a thread
+    from outside its own code: XLA compiles (one ``jax.monitoring`` listener
+    on the backend-compile event, which also covers a program fetched from
+    the persistent cache) and garbage collections (one ``gc.callbacks``
+    hook). Cumulative and monotone; read without a lock."""
+
+    GC_LONG_NS = 50_000_000
+
+    def __init__(self) -> None:
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.gc_pause_ns = 0
+        self.gc_pauses_over_50ms = 0
+        self.gc_longest_ns = 0
+        self._gc_started_ns = 0
+
+    def _on_duration(self, event: str, duration_s: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.compile_s += duration_s
+
+    def _on_gc(self, phase: str, _info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._gc_started_ns = time.perf_counter_ns()
+            return
+        pause = time.perf_counter_ns() - self._gc_started_ns
+        self.gc_pause_ns += pause
+        if pause > self.GC_LONG_NS:
+            self.gc_pauses_over_50ms += 1
+        if pause > self.gc_longest_ns:
+            self.gc_longest_ns = pause
+
+
+_host_events: Optional[HostEvents] = None
+
+
+def host_events() -> HostEvents:
+    """The process's ``HostEvents``, hooked in on first use (jax must be
+    imported by then: the caller is about to compile with it)."""
+    global _host_events
+    with _lock:
+        if _host_events is None:
+            import jax.monitoring
+
+            events = HostEvents()
+            jax.monitoring.register_event_duration_secs_listener(
+                events._on_duration)
+            gc.callbacks.append(events._on_gc)
+            _host_events = events
+    return _host_events
+
+
 @contextmanager
 def profile(name: str, extra: Optional[Dict[str, Any]] = None):
-    """Record a named span for the cluster timeline."""
+    """Record a named span for the cluster timeline (and, through ``span``,
+    for a device trace that is running)."""
     start = time.time()
     try:
-        yield
+        with span(str(name)):
+            yield
     finally:
         end = time.time()
-        span: Dict[str, Any] = {"name": str(name), "start": start, "end": end}
+        entry: Dict[str, Any] = {"name": str(name), "start": start, "end": end}
         if extra:
-            span["extra"] = {str(k): v for k, v in extra.items()}
+            entry["extra"] = {str(k): v for k, v in extra.items()}
         try:
             from ray_tpu.core.worker import global_worker
 
             w = global_worker()
             task_id = getattr(w, "current_task_id", None)
             if task_id is not None:
-                span["task_id"] = task_id.hex() if hasattr(task_id, "hex") \
+                entry["task_id"] = task_id.hex() if hasattr(task_id, "hex") \
                     else str(task_id)
         except Exception:  # noqa: BLE001 - outside a runtime
             pass
         with _lock:
-            _spans.append(span)
+            _spans.append(entry)
             del _spans[:-_MAX_PENDING]
 
 

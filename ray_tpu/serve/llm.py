@@ -20,18 +20,46 @@ dicts {"tokens": [...], "max_tokens": N} -> {"tokens": [...], "ttft_s": ...}.
 
 from __future__ import annotations
 
+import bisect
+import os
 import queue
 import threading
 import time
+from collections import deque
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.profiling import host_events, span
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("serve.llm")
+
+PHASES = ("admit", "prefill_dispatch", "decode_dispatch", "device_get", "emit",
+          "retire")
+# queue-wait histogram: 5 buckets a decade from 1 ms to 63 s, one bucket
+# under and one over
+QUEUE_WAIT_EDGES_S = tuple(1e-3 * 10 ** (i / 5) for i in range(25))
+RING_ITERS = 256
+# a ring row: wall start, the six phases in seconds, slots active, requests
+# admitted, requests retired
+RING_COLUMNS = ("start",) + PHASES + ("active", "admitted", "retired")
+SLOW_ITER_FLOOR_S = 1.0
+SLOW_ITER_MEDIANS = 5.0
+SLOW_LOG_EVERY_S = 10.0
+
+
+def _steal_s() -> float:
+    """Seconds since boot in which the hypervisor ran something else on this
+    machine's CPUs (``/proc/stat``, summed over CPUs); 0.0 where it is not
+    told."""
+    try:
+        with open("/proc/stat") as f:
+            return int(f.readline().split()[8]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return 0.0
 
 
 @dataclass
@@ -49,6 +77,10 @@ class GenRequest:
     stream_q: Optional["queue.Queue"] = None
     streamed: int = 0
     cancelled: bool = False
+    # wall instants (one host) of the first token's and the done sentinel's
+    # push, for the done record's hops
+    first_pushed_at: float = 0.0
+    done_pushed_at: float = 0.0
 
 
 class LLMEngine:
@@ -66,7 +98,48 @@ class LLMEngine:
     A step that raises (a kernel the chip's compiler refuses, device OOM)
     fails every in-flight and queued request with that exception and stops
     the engine: the donated cache is gone with the failed program, and a
-    caller should read the compiler's message, not a timeout."""
+    caller should read the compiler's message, not a timeout.
+
+    ``stats()``, field by field. Counters are cumulative and monotone (take
+    differences), written by the loop thread and read without a lock, so one
+    snapshot can be torn by at most the iteration in progress; no container
+    in it grows with requests or time.
+
+    - ``slots``, ``active``, ``queued``: slots there are, slots in use,
+      requests waiting (FIFO + page-pool backlog) now.
+    - ``decode_steps``, ``tokens_generated``, ``uptime_s``,
+      ``decode_attention``: decode ticks dispatched, tokens of retired
+      requests, seconds since construction, the decode attention chosen.
+    - ``iters``, ``iter_ns``: busy iterations of ``_step`` and their time.
+      ``phase_ns``: the same time split into the six phases that partition
+      an iteration: ``admit`` (pull requests, pages, slots),
+      ``prefill_dispatch`` (``_prefill_group``, host side),
+      ``decode_dispatch`` (key split + the decode call), ``device_get`` (the
+      one host sync a chunk), ``emit`` (tokens to requests and streams),
+      ``retire``. ``idle_ns``: iterations with no slot in use (a 10 ms
+      sleep each), in neither ``iters`` nor the ring.
+    - ``admitted``, ``retired``: requests given a slot, requests answered.
+    - ``prefill_calls``, ``prefill_rows_real``, ``prefill_rows_padded``,
+      ``prefill_tokens_real``, ``prefill_tokens_padded``: prefill programs
+      dispatched, their rows holding a request and rows in all, prompt
+      tokens and rows x bucket: what padding to 8 rows x bucket costs.
+    - ``queue_wait_hist``: ``edges_s`` and ``counts`` (one more than
+      edges: under the first edge, between edges, over the last) of
+      admission instant minus submission, one count an admitted request.
+    - ``compiles``, ``compile_s``, ``gc_pause_ns``, ``gc_pauses_over_50ms``,
+      ``gc_longest_s``: the process's ``profiling.host_events()``.
+    - ``ring``: ``columns`` and ``rows`` of the last 256 busy iterations
+      (wall start, phases in seconds, slots active, admitted, retired),
+      oldest first. ``longest_iter_s``: the longest ever.
+    - ``slow_iters``: the last 16 iterations longer than max(1 s, 5 x the
+      ring's median), each with its wall instant, total, longest phase,
+      compile and GC deltas over it, the CPU time the process (``cpu_s``)
+      and the loop thread (``loop_cpu_s``) used in it (near zero: all of it
+      waited, on the device or the kernel; near the total: a thread ran)
+      and ``steal_s`` (seconds, summed over CPUs, the hypervisor gave to
+      others since the engine started), ``queued`` and ``active``; each is
+      also one warning line in the log (at most one every 10 s;
+      ``slow_iters_unlogged`` counts the rest)."""
 
     def __init__(self, config, params=None, *, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, decode_chunk: int = 8,
@@ -143,8 +216,6 @@ class LLMEngine:
         # host-side state
         self._slots: List[Optional[GenRequest]] = [None] * num_slots
         self._pending: "queue.Queue[GenRequest]" = queue.Queue()
-        from collections import deque
-
         # head-of-line holding area for requests the page pool couldn't fit
         self._admit_backlog: "deque[GenRequest]" = deque()
         self._shutdown = False
@@ -154,6 +225,26 @@ class LLMEngine:
         self._steps = 0
         self._tokens_out = 0
         self._started = time.perf_counter()
+        # always-on counters and the flight recorder (see the class docstring)
+        self._host_events = host_events()
+        self._steal0_s = _steal_s()
+        self._iters = 0
+        self._iter_ns = 0
+        self._idle_ns = 0
+        self._phase_ns = [0] * len(PHASES)
+        self._admitted = 0
+        self._retired = 0
+        self._prefill_calls = 0
+        self._prefill_rows_real = 0
+        self._prefill_rows_padded = 0
+        self._prefill_tokens_real = 0
+        self._prefill_tokens_padded = 0
+        self._queue_wait_counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
+        self._ring = np.zeros((RING_ITERS, len(RING_COLUMNS)))
+        self._longest_iter_ns = 0
+        self._slow_iters: "deque[Dict[str, Any]]" = deque(maxlen=16)
+        self._slow_logged_at = -SLOW_LOG_EVERY_S
+        self._slow_unlogged = 0
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
         self._thread.start()
@@ -191,8 +282,19 @@ class LLMEngine:
                         timeout: Optional[float] = None):
         """Streaming generate: yields {"token": t} the moment each token is
         decoded, then a final {"done": True, "ttft_s", "latency_s",
-        "num_tokens"} record. Abandoning the generator cancels the request
-        (its slot retires at the next decode step)."""
+        "num_tokens", "hops"} record. Abandoning the generator cancels the
+        request (its slot retires at the next decode step).
+
+        ``hops``: wall instants (``time.time()``, one host) of this request,
+        stamped once a request and for its first token and the done
+        sentinel only: ``engine_enter`` (here), ``first_push`` / ``done_push``
+        (the loop thread), ``first_pickup`` / ``done_pickup`` (this
+        generator), with what ``serve.replica.current_request_hops()`` holds
+        of the way in; the proxy adds ``first_recv``, ``done_recv``,
+        ``first_write``, ``done_write``."""
+        from ray_tpu.serve.replica import current_request_hops
+
+        hops = dict(current_request_hops() or {}, engine_enter=time.time())
         if len(tokens) + max_tokens > self.max_seq:
             raise ValueError(
                 f"prompt {len(tokens)} + max_tokens {max_tokens} exceeds "
@@ -203,28 +305,63 @@ class LLMEngine:
         req.stream_q = queue.Queue()
         self._submit(req)
         try:
-            while True:
-                tok = req.stream_q.get(timeout=timeout)
-                if tok is None:
-                    break
+            tok = req.stream_q.get(timeout=timeout)
+            hops["first_pickup"] = time.time()
+            while tok is not None:
                 yield {"token": tok}
+                tok = req.stream_q.get(timeout=timeout)
+            hops["done_pickup"] = time.time()
             result = req.future.result(timeout=5.0)
+            hops["first_push"] = req.first_pushed_at
+            hops["done_push"] = req.done_pushed_at
             yield {"done": True, "ttft_s": result["ttft_s"],
                    "latency_s": result["latency_s"],
-                   "num_tokens": len(result["tokens"])}
+                   "num_tokens": len(result["tokens"]), "hops": hops}
         finally:
             req.cancelled = True  # no-op if already finished
 
     def stats(self) -> Dict[str, Any]:
+        """Counters and the flight recorder; the class docstring has every
+        field. No lock is taken and nothing here grows."""
+        host = self._host_events
+        n = self._iters
+        ring = self._ring if n >= RING_ITERS else self._ring[:n]
         return {
             "slots": self.num_slots,
             "active": sum(r is not None for r in self._slots),
-            "queued": self._pending.qsize() + len(self._admit_backlog),
+            "queued": self._queued(),
             "decode_steps": self._steps,
             "decode_attention": self.decode_attention,
             "tokens_generated": self._tokens_out,
             "uptime_s": time.perf_counter() - self._started,
+            "iters": n,
+            "iter_ns": self._iter_ns,
+            "idle_ns": self._idle_ns,
+            "phase_ns": dict(zip(PHASES, self._phase_ns)),
+            "admitted": self._admitted,
+            "retired": self._retired,
+            "prefill_calls": self._prefill_calls,
+            "prefill_rows_real": self._prefill_rows_real,
+            "prefill_rows_padded": self._prefill_rows_padded,
+            "prefill_tokens_real": self._prefill_tokens_real,
+            "prefill_tokens_padded": self._prefill_tokens_padded,
+            "queue_wait_hist": {"edges_s": list(QUEUE_WAIT_EDGES_S),
+                                "counts": list(self._queue_wait_counts)},
+            "compiles": host.compiles,
+            "compile_s": host.compile_s,
+            "gc_pause_ns": host.gc_pause_ns,
+            "gc_pauses_over_50ms": host.gc_pauses_over_50ms,
+            "gc_longest_s": host.gc_longest_ns / 1e9,
+            "ring": {"columns": list(RING_COLUMNS),
+                     "rows": np.roll(ring, -(n % len(ring)), axis=0).tolist()
+                     if n else []},
+            "longest_iter_s": self._longest_iter_ns / 1e9,
+            "slow_iters": list(self._slow_iters),
+            "slow_iters_unlogged": self._slow_unlogged,
         }
+
+    def _queued(self) -> int:
+        return self._pending.qsize() + len(self._admit_backlog)
 
     def decode_program_text(self) -> str:
         """The decode program lowered (not compiled) at the engine's shapes.
@@ -261,24 +398,27 @@ class LLMEngine:
             bucket = min(bucket, self.pages_per_slot * self.page_size)
         return bucket
 
-    def _admit(self) -> None:
+    def _admit(self) -> List[tuple]:
         """Prefill waiting requests into free slots WITHOUT a host sync: the
         first sampled token stays on device and is fetched together with the
         next decode chunk (one host sync per loop iteration, however many
-        requests were admitted)."""
+        requests were admitted). Returns the paged mode's prefill groups for
+        ``_step`` to dispatch; the dense mode prefills here, one request a
+        program, and returns none."""
         if self.paged:
-            self._admit_paged_batched()
-            return
+            return self._admit_paged_batched()
         jnp = self._jnp
+        now = time.perf_counter()
         while True:
             try:
                 free = self._slots.index(None)
             except ValueError:
-                return
+                return []
             try:
                 req = self._pending.get_nowait()
             except queue.Empty:
-                return
+                return []
+            self._count_admitted(req, now)
             n = len(req.tokens)
             bucket = self._bucket_for(n)
             assert bucket >= n, (bucket, n)
@@ -288,6 +428,7 @@ class LLMEngine:
             # that raises must find it there (_fail_all)
             req.slot = free
             self._slots[free] = req
+            self._count_prefill(1, 1, n, bucket)
             logits, self.cache = self._prefill(
                 self.params, self.cache, jnp.asarray(padded),
                 jnp.int32(free), jnp.int32(min(n, bucket)),
@@ -298,12 +439,28 @@ class LLMEngine:
             self._positions = self._positions.at[free].set(n)
             self._active = self._active.at[free].set(True)
 
-    def _admit_paged_batched(self) -> None:
-        """Pull every admissible request, group by prefill bucket, and run
-        ONE batched prefill program per group. Every group pads to a FIXED
-        batch size (min(8, num_slots)), which keeps ONE compile per bucket;
-        what the padding rows cost on the chip has not been measured."""
-        jnp = self._jnp
+    def _count_admitted(self, req: GenRequest, now: float) -> None:
+        self._admitted += 1
+        self._queue_wait_counts[bisect.bisect_right(
+            QUEUE_WAIT_EDGES_S, now - req.submitted_at)] += 1
+
+    def _count_prefill(self, rows_real: int, rows_padded: int,
+                       tokens_real: int, bucket: int) -> None:
+        self._prefill_calls += 1
+        self._prefill_rows_real += rows_real
+        self._prefill_rows_padded += rows_padded
+        self._prefill_tokens_real += tokens_real
+        self._prefill_tokens_padded += rows_padded * bucket
+
+    def _admit_paged_batched(self) -> List[tuple]:
+        """Pull every admissible request and group by prefill bucket: ONE
+        batched prefill program per group, as (chunk, bucket, size) for
+        ``_prefill_group``. Every group pads to a FIXED batch size
+        (min(8, num_slots)), which keeps ONE compile per bucket; what the
+        padding costs is counted where the program is dispatched
+        (``prefill_rows_*`` / ``prefill_tokens_*`` of ``stats()``; the
+        benchmark's ``prefill_padding_share``)."""
+        now = time.perf_counter()
         free_slots = [i for i, r in enumerate(self._slots) if r is None]
         admitted: List[tuple] = []  # (req, slot, pages, bucket)
         while free_slots:
@@ -337,16 +494,15 @@ class LLMEngine:
             req.slot = slot
             self._slots[slot] = req
             self._slot_pages[slot] = pages
+            self._count_admitted(req, now)
             admitted.append((req, slot, pages, bucket))
-        if not admitted:
-            return
         by_bucket: Dict[int, List[tuple]] = {}
         for item in admitted:
             by_bucket.setdefault(item[3], []).append(item)
         size = min(8, self.num_slots)
-        for bucket, group in by_bucket.items():
-            for i in range(0, len(group), size):
-                self._prefill_group(group[i:i + size], bucket, size)
+        return [(group[i:i + size], bucket, size)
+                for bucket, group in by_bucket.items()
+                for i in range(0, len(group), size)]
 
     def _prefill_group(self, chunk: List[tuple], bucket: int, size: int) -> None:
         """One batched prefill program for `chunk` (padded to `size` rows;
@@ -356,11 +512,14 @@ class LLMEngine:
         tokens = np.zeros((size, bucket), np.int32)
         page_arr = np.zeros((size, n_pages), np.int32)  # pad rows -> trash
         lengths = np.ones((size,), np.int32)
+        tokens_real = 0
         for row, (req, slot, pages, _b) in enumerate(chunk):
             n = len(req.tokens)
             tokens[row, :n] = req.tokens
             page_arr[row] = pages[:n_pages]
             lengths[row] = min(n, bucket)
+            tokens_real += n
+        self._count_prefill(len(chunk), size, tokens_real, bucket)
         logits, self.cache = self._prefill(
             self.params, self.cache, jnp.asarray(tokens),
             jnp.asarray(page_arr), jnp.asarray(lengths),
@@ -412,8 +571,10 @@ class LLMEngine:
         if req.eos_token is not None and req.eos_token in req.out_tokens:
             req.out_tokens = req.out_tokens[: req.out_tokens.index(req.eos_token) + 1]
         self._tokens_out += len(req.out_tokens)
+        self._retired += 1
         self._push_stream(req)
         if req.stream_q is not None:
+            req.done_pushed_at = time.time()
             req.stream_q.put(None)  # end-of-stream sentinel
         req.future.set_result({
             "tokens": req.out_tokens,
@@ -454,52 +615,150 @@ class LLMEngine:
             self._fail_all(e)
 
     def _step(self) -> None:
+        """One iteration, in six phases that partition it (``PHASES``): each
+        is a span on the device trace's clock and, at the end, one integer
+        add into ``phase_ns``."""
         jax = self._jax
-        self._admit()
+        clock = time.perf_counter_ns
+        host = self._host_events
+        compiles0, gc_ns0 = host.compiles, host.gc_pause_ns
+        admitted0, retired0 = self._admitted, self._retired
+        started_wall = time.time()
+        cpu0, loop_cpu0 = time.process_time_ns(), time.thread_time_ns()
+        t0 = clock()
+        with span("engine.admit"):
+            groups = self._admit()
+        t1 = clock()
+        for chunk, bucket, size in groups:
+            with span("engine.prefill_dispatch", bucket=bucket,
+                      rows_real=len(chunk), rows_padded=size):
+                self._prefill_group(chunk, bucket, size)
+        t2 = clock()
         if not any(r is not None for r in self._slots):
             time.sleep(0.01)  # idle: poll for work (_admit drains FIFO)
+            self._idle_ns += clock() - t0
             return
-        self._key, sub = jax.random.split(self._key)
-        if self.paged:
-            sampled, last, self._positions, self.cache = self._decode(
-                self.params, self.cache, self._tokens,
-                self._positions, self._active, self._table, sub,
-            )
-        else:
-            sampled, last, self._positions, self.cache = self._decode(
-                self.params, self.cache, self._tokens,
-                self._positions, self._active, sub,
-            )
-        self._tokens = last
-        self._steps += self.decode_chunk
-        # ONE host sync per chunk: chunk tokens + any pending first
-        # tokens from this round's prefills
-        firsts = {slot: req.pending_first
-                  for slot, req in enumerate(self._slots)
-                  if req is not None and req.pending_first is not None}
-        host_tokens, host_firsts = jax.device_get((sampled, firsts))
-        now = time.perf_counter()
+        with span("engine.decode_dispatch"):
+            self._key, sub = jax.random.split(self._key)
+            if self.paged:
+                sampled, last, self._positions, self.cache = self._decode(
+                    self.params, self.cache, self._tokens,
+                    self._positions, self._active, self._table, sub,
+                )
+            else:
+                sampled, last, self._positions, self.cache = self._decode(
+                    self.params, self.cache, self._tokens,
+                    self._positions, self._active, sub,
+                )
+            self._tokens = last
+            self._steps += self.decode_chunk
+            # ONE host sync per chunk: chunk tokens + any pending first
+            # tokens from this round's prefills
+            firsts = {slot: req.pending_first
+                      for slot, req in enumerate(self._slots)
+                      if req is not None and req.pending_first is not None}
+        t3 = clock()
+        with span("engine.device_get"):
+            host_tokens, host_firsts = jax.device_get((sampled, firsts))
+        t4 = clock()
+        now = t4 / 1e9  # perf_counter's clock, as submitted_at
+        now_wall = time.time()
+        active = self._admitted - retired0  # every admitted request retires
+        retire_ns = self._emit(host_tokens, host_firsts, now, now_wall)
+        t5 = clock()
+        # a slot retires inside the emit loop, where its last token is
+        # pushed; the phases still partition the iteration
+        self._record_iter(started_wall, (t0, t1, t2, t3, t4, t5 - retire_ns, t5),
+                          active, self._admitted - admitted0,
+                          self._retired - retired0,
+                          host.compiles - compiles0, host.gc_pause_ns - gc_ns0,
+                          time.process_time_ns() - cpu0,
+                          time.thread_time_ns() - loop_cpu0)
+
+    def _emit(self, host_tokens, host_firsts, now: float, now_wall: float) -> int:
+        """Tokens to requests and streams; a finished slot retires where its
+        last token is pushed. Spans ``engine.emit`` and ``engine.retire``
+        alternate and do not nest, so a device gap is named by the one the
+        host was in. Returns the nanoseconds spent retiring."""
+        clock = time.perf_counter_ns
+        retire_ns = 0
+        emitting = span("engine.emit")
+        emitting.__enter__()
         for slot, first in host_firsts.items():
             req = self._slots[slot]
             if req is None:
                 continue
             req.pending_first = None
             req.ttft_s = now - req.submitted_at
+            req.first_pushed_at = now_wall
             req.out_tokens.append(int(first))
             self._push_stream(req)  # first token streams immediately
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
+            if not self._finished(req):
+                for t in host_tokens[slot]:
+                    req.out_tokens.append(int(t))
+                    if self._finished(req):
+                        break
+                self._push_stream(req)
             if self._finished(req):
-                self._retire(slot)
-                continue
-            for t in host_tokens[slot]:
-                req.out_tokens.append(int(t))
-                if self._finished(req):
-                    break
-            self._push_stream(req)
-            if self._finished(req):
-                self._retire(slot)
+                emitting.__exit__(None, None, None)
+                t0 = clock()
+                with span("engine.retire"):
+                    self._retire(slot)
+                retire_ns += clock() - t0
+                emitting = span("engine.emit")
+                emitting.__enter__()
+        emitting.__exit__(None, None, None)
+        return retire_ns
+
+    def _record_iter(self, started_wall: float, t: tuple, active: int,
+                     admitted: int, retired: int, compiles: int,
+                     gc_ns: int, cpu_ns: int, loop_cpu_ns: int) -> None:
+        """Constant work an iteration: the counters, one ring row, and the
+        slow-iteration check (the ring's median and ``/proc/stat`` are read
+        only for an iteration over the 1 s floor)."""
+        total = t[6] - t[0]
+        phases = [b - a for a, b in zip(t, t[1:])]
+        acc = self._phase_ns
+        for i, ns in enumerate(phases):
+            acc[i] += ns
+        self._iter_ns += total
+        self._ring[self._iters % RING_ITERS] = (
+            started_wall, *(ns / 1e9 for ns in phases), active, admitted, retired)
+        self._iters += 1
+        if total > self._longest_iter_ns:
+            self._longest_iter_ns = total
+        if total <= SLOW_ITER_FLOOR_S * 1e9:
+            return
+        rows = self._ring[:min(self._iters, RING_ITERS)]
+        median_s = float(np.median(rows[:, 1:1 + len(PHASES)].sum(axis=1)))
+        if total <= SLOW_ITER_MEDIANS * median_s * 1e9:
+            return
+        worst = max(range(len(PHASES)), key=phases.__getitem__)
+        record = {
+            "at": started_wall, "total_s": total / 1e9,
+            "phase": PHASES[worst], "phase_s": phases[worst] / 1e9,
+            "median_s": median_s, "compiles": compiles, "gc_s": gc_ns / 1e9,
+            "cpu_s": cpu_ns / 1e9, "loop_cpu_s": loop_cpu_ns / 1e9,
+            "steal_s": _steal_s() - self._steal0_s,
+            "queued": self._queued(), "active": active,
+        }
+        self._slow_iters.append(record)
+        now = time.perf_counter()
+        if now - self._slow_logged_at < SLOW_LOG_EVERY_S:
+            self._slow_unlogged += 1
+            return
+        self._slow_logged_at = now
+        logger.warning(
+            "slow engine iteration: %.3f s at %.3f (median %.3f s); longest "
+            "phase %s %.3f s; compiles %d, gc %.3f s; cpu %.3f s (loop thread "
+            "%.3f s), steal %.2f s since start; queued %d, active %d; %d "
+            "earlier ones not logged", record["total_s"], started_wall,
+            median_s, record["phase"], record["phase_s"], compiles,
+            record["gc_s"], record["cpu_s"], record["loop_cpu_s"],
+            record["steal_s"], record["queued"], active, self._slow_unlogged)
 
 
 class LLMDeployment:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Any, Dict, Optional, Tuple
 
 from ray_tpu.utils.logging import get_logger
@@ -21,6 +22,10 @@ logger = get_logger("serve.proxy")
 
 _STREAM_DONE = object()
 _STREAM_ERR = object()
+# (_STREAM_HOPS, record): the stream's closing record carries "hops" (wall
+# instants of the request's way in and of its first and last item's way
+# back); puller and writer add theirs before it is encoded
+_STREAM_HOPS = object()
 
 
 def _encode_stream_item(item: Any) -> bytes:
@@ -235,6 +240,7 @@ class ProxyActor:
 
     async def _handle(self, method: str, path: str, headers: Dict[str, str],
                       body: bytes) -> Tuple[bytes, bytes, bytes]:
+        received = time.time()
         loop = asyncio.get_event_loop()
         path = path.split("?", 1)[0]
         if path in ("/-/healthz", "/-/routes"):
@@ -288,9 +294,17 @@ class ProxyActor:
                 loop.call_soon_threadsafe(q.put_nowait, item)
 
             def pull() -> None:
-                stream = router.call_streaming("__call__", call_args, {})
+                stream = router.call_streaming(
+                    "__call__", call_args, {}, hops={"proxy_recv": received})
+                first_recv = None
                 try:
                     for item in stream:
+                        if first_recv is None:
+                            first_recv = time.time()
+                        if type(item) is dict and "hops" in item:
+                            item["hops"].update(first_recv=first_recv,
+                                                done_recv=time.time())
+                            item = (_STREAM_HOPS, item)
                         while not window.acquire(timeout=0.5):
                             if closed.is_set():
                                 return
@@ -363,6 +377,7 @@ class ProxyActor:
         On client disconnect the puller is stopped and its stream closed so
         no thread or replica ongoing-slot leaks."""
         q, window, closed = payload
+        first_write = None
         try:
             writer.write(
                 b"HTTP/1.1 200 OK\r\n"
@@ -377,15 +392,22 @@ class ProxyActor:
                 if item is _STREAM_DONE:
                     break
                 window.release()
-                if isinstance(item, tuple) and len(item) == 2 and item[0] is _STREAM_ERR:
-                    # mid-stream failure: terminate the chunk stream with an
-                    # in-band error record (headers are already sent)
-                    data = json.dumps({"error": str(item[1])}).encode() + b"\n"
-                    writer.write(hex(len(data))[2:].encode() + b"\r\n" + data + b"\r\n")
-                    break
+                if isinstance(item, tuple) and len(item) == 2:
+                    if item[0] is _STREAM_ERR:
+                        # mid-stream failure: terminate the chunk stream with an
+                        # in-band error record (headers are already sent)
+                        data = json.dumps({"error": str(item[1])}).encode() + b"\n"
+                        writer.write(hex(len(data))[2:].encode() + b"\r\n" + data + b"\r\n")
+                        break
+                    if item[0] is _STREAM_HOPS:
+                        item = item[1]
+                        item["hops"].update(first_write=first_write,
+                                            done_write=time.time())
                 data = _encode_stream_item(item)
                 writer.write(hex(len(data))[2:].encode() + b"\r\n" + data + b"\r\n")
                 await writer.drain()
+                if first_write is None:
+                    first_write = time.time()
             writer.write(b"0\r\n\r\n")
             await writer.drain()
         finally:

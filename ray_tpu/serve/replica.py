@@ -10,12 +10,26 @@ router (probe path) and autoscaling (controller scrapes stats).
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import inspect
 import threading
 import time
 from typing import Any, Dict, Optional
 
 from ray_tpu import exceptions as exc
+
+_request_hops: contextvars.ContextVar = contextvars.ContextVar(
+    "serve_request_hops", default=None)
+
+
+def current_request_hops() -> Optional[Dict[str, float]]:
+    """Inside a streaming request: the wall instants (``time.time()``, one
+    host) at which it passed the proxy (``proxy_recv``), the router
+    (``router_submit``) and this replica's handler (``replica_enter``). A
+    deployment that ends its stream with a record holding ``"hops"`` (the
+    LLM engine's done record) copies them there, and the proxy adds its
+    own. None outside a streaming request."""
+    return _request_hops.get()
 
 
 class ReplicaOverloadedError(exc.RayTpuError):
@@ -92,17 +106,20 @@ class Replica:
                 self._ongoing -= 1
 
     def handle_request_streaming(self, method: str, args: tuple, kwargs: dict,
-                                 multiplexed_model_id: str = ""):
+                                 multiplexed_model_id: str = "",
+                                 hops: Optional[Dict[str, float]] = None):
         """Streaming variant: a generator method, invoked by routers with
         ``num_returns="streaming"`` so each yielded item is sealed and
         consumable before the request finishes (reference:
         serve/_private/proxy.py:542 streaming send_request_to_replica +
         replica.py:533 handle_request_streaming). Non-generator results
-        stream as a single item."""
+        stream as a single item. ``hops``: see ``current_request_hops``."""
         from ray_tpu.serve.multiplex import (
             _reset_request_model_id, _set_request_model_id,
         )
 
+        hops_token = _request_hops.set(
+            {**(hops or {}), "replica_enter": time.time()})
         with self._lock:
             if self._ongoing >= self._max_ongoing:
                 raise ReplicaOverloadedError(
@@ -133,6 +150,7 @@ class Replica:
             else:
                 yield result
         finally:
+            _request_hops.reset(hops_token)
             _reset_request_model_id(mux_token)
             with self._lock:
                 self._ongoing -= 1

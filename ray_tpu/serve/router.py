@@ -162,9 +162,12 @@ class Router:
         raise exc.RayTpuError(f"no route for {self._app}.{method}: {last}")
 
     def route_streaming(self, method: str, args: tuple, kwargs: dict,
-                        max_attempts: int = 10, multiplexed_model_id: str = ""):
+                        max_attempts: int = 10, multiplexed_model_id: str = "",
+                        hops: Optional[Dict[str, float]] = None):
         """Submit a streaming request; returns (ObjectRefGenerator, replica).
-        Items become available as the replica's generator yields."""
+        Items become available as the replica's generator yields. ``hops``
+        (the caller's stamps, see ``replica.current_request_hops``) go to
+        the replica with this router's submission instant."""
         self._refresh()
         last: Optional[Exception] = None
         for _ in range(max_attempts):
@@ -179,12 +182,14 @@ class Router:
             gen = replica.handle_request_streaming.options(
                 num_returns="streaming"
             ).remote(method, args, kwargs,
-                     multiplexed_model_id=multiplexed_model_id)
+                     multiplexed_model_id=multiplexed_model_id,
+                     hops={**(hops or {}), "router_submit": time.time()})
             return gen, replica
         raise exc.RayTpuError(f"no route for {self._app}.{method}: {last}")
 
     def call_streaming(self, method: str, args: tuple, kwargs: dict,
-                       multiplexed_model_id: str = ""):
+                       multiplexed_model_id: str = "",
+                       hops: Optional[Dict[str, float]] = None):
         """Route AND stream VALUES, retrying overload/replica-death on other
         replicas while no item has been delivered yet (after the first item
         the stream is already partially consumed; mid-stream failures
@@ -195,7 +200,7 @@ class Router:
         while True:
             gen, replica = self.route_streaming(
                 method, args, kwargs,
-                multiplexed_model_id=multiplexed_model_id)
+                multiplexed_model_id=multiplexed_model_id, hops=hops)
             it = iter(gen)
             try:
                 try:

@@ -19,6 +19,8 @@ import uuid
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
+from ray_tpu.profiling import span
+
 
 class Checkpoint:
     """A directory of files on shared/local storage (reference:
@@ -133,7 +135,8 @@ def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None) -> 
     s = _get_session()
     if s is None:
         raise RuntimeError("ray_tpu.train.report() called outside a training session")
-    s.report(metrics, checkpoint)
+    with span("train.report"):
+        s.report(metrics, checkpoint)
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

@@ -1,0 +1,147 @@
+"""The readers of the engine's counters, flight recorder and hops, each on a
+hand-made ``ctx``; and the benchmark's own fast CPU tests, imported so that
+tier-1 guards its yardstick (rates, traffic, roofline counts, the trace
+reducer, the manifest, the reference) against an edit. The rehearsal, which
+starts a cluster a cell, stays under ``benchmarks/tests`` only."""
+import pytest
+
+from benchmarks.harness.loadgen import RequestRecord
+from benchmarks.readers import (
+    engine_counters, engine_longest_iter, engine_queue_wait, hops_percentile)
+from benchmarks.tests.test_manifest import *  # noqa: F401,F403
+from benchmarks.tests.test_rates import *  # noqa: F401,F403
+from benchmarks.tests.test_readers import *  # noqa: F401,F403
+from benchmarks.tests.test_reference import *  # noqa: F401,F403
+from benchmarks.tests.test_roofline import *  # noqa: F401,F403
+from benchmarks.tests.test_trace_reduce import *  # noqa: F401,F403
+from benchmarks.tests.test_traffic import *  # noqa: F401,F403
+
+EDGES = [1e-3 * 10 ** (i / 5) for i in range(25)]
+COLUMNS = ["start", "admit", "prefill_dispatch", "decode_dispatch",
+           "device_get", "emit", "retire", "active", "admitted", "retired"]
+
+
+def stats(iters, iter_ms, get_ms, prefill_ms=0.0, real=0, padded=0, compiles=0,
+          gc_ms=0.0, waits=None, ring=None, slow=()):
+    counts = [0] * (len(EDGES) + 1)
+    for bucket, n in (waits or {}).items():
+        counts[bucket] = n
+    return {
+        "decode_steps": 8 * iters, "active": 28, "iters": iters,
+        "iter_ns": int(iter_ms * 1e6),
+        "phase_ns": {"device_get": int(get_ms * 1e6),
+                     "prefill_dispatch": int(prefill_ms * 1e6)},
+        "prefill_tokens_real": real, "prefill_tokens_padded": padded,
+        "compiles": compiles, "gc_pause_ns": int(gc_ms * 1e6),
+        "queue_wait_hist": {"edges_s": EDGES, "counts": counts},
+        "ring": {"columns": COLUMNS, "rows": ring or []},
+        "slow_iters": list(slow),
+    }
+
+
+def row(start, total):
+    return [start, 0.0, 0.25 * total, 0.05 * total, 0.6 * total, 0.1 * total,
+            0.0, 28.0, 0.0, 0.0]
+
+
+@pytest.fixture
+def polled_ctx():
+    """Window 100-150 on the runner's clock (wall = clock + 1000). Polls at
+    110 and 120, the profiler's call 121-127 with its polls left out, polls at
+    130 and 140: two segments of 10 s, 10 + 12 iterations."""
+    polls = [
+        (110.0, stats(100, 80_000, 36_000, 30_000, 1000, 4000, 5, 40.0, {10: 7})),
+        (120.0, stats(110, 88_500, 39_900, 33_300, 1800, 6000, 5, 41.0, {10: 9, 16: 1})),
+        (124.0, stats(113, 95_000, 40_500, 33_500, 1900, 6200, 9, 900.0, {10: 9, 16: 5})),
+        (130.0, stats(118, 99_000, 43_000, 35_000, 2000, 7000, 9, 950.0, {10: 9, 16: 6})),
+        (140.0, stats(130, 109_200, 47_560, 39_440, 2600, 10000, 9, 952.0,
+                      {10: 17, 16: 8})),
+    ]
+    return {"marks": {"open": 100.0, "close": 150.0, "open_wall": 1100.0,
+                      "trace_call": (121.0, 127.0), "polls": polls}}
+
+
+@pytest.mark.parametrize("params,expected", [
+    # (8500 - 3900) + (10200 - 4560) ms over 10 + 12 iterations
+    ({"plus": ["iter_ns"], "minus": ["phase_ns.device_get"], "over": "iters",
+      "scale": 1e-6}, (4600 + 5640) / 22),
+    ({"plus": ["phase_ns.device_get"], "over": "iters", "scale": 1e-6},
+     (3900 + 4560) / 22),
+    ({"plus": ["phase_ns.prefill_dispatch"], "over": "iters", "scale": 1e-6},
+     (3300 + 4440) / 22),
+    # 1 - (800 + 600) / (2000 + 3000)
+    ({"plus": ["prefill_tokens_padded"], "minus": ["prefill_tokens_real"],
+      "over": "prefill_tokens_padded", "scale": 100.0}, 72.0),
+    # the profiler's own four compiles and its 0.9 s collection are left out
+    ({"plus": ["compiles"]}, 0.0),
+    ({"plus": ["gc_pause_ns"], "scale": 1e-6}, 3.0),
+])
+def test_counter_differences_leave_out_the_traced_seconds(polled_ctx, params,
+                                                          expected):
+    assert engine_counters.read(polled_ctx, params) == pytest.approx(expected)
+
+
+def test_counter_readers_read_nothing_from_a_program_without_counters(polled_ctx):
+    for _t, s in polled_ctx["marks"]["polls"]:
+        del s["iters"], s["phase_ns"], s["queue_wait_hist"]
+    assert engine_counters.read(polled_ctx, {
+        "plus": ["phase_ns.device_get"], "over": "iters"}) is None
+    assert engine_counters.read(polled_ctx, {"plus": ["iters"]}) is None
+    assert engine_queue_wait.read(polled_ctx, {"q": 0.9}) is None
+    assert engine_counters.read({"marks": {"polls": []}}, {"plus": ["iters"]}) is None
+
+
+def test_queue_wait_percentile_of_the_histogram_difference(polled_ctx):
+    # the difference: 10 waits in bucket 10 (63-100 ms), 3 in bucket 16
+    # (1.0-1.58 s); rank 0.9 x 13 = 11.7 lies 1.7 of 3 into the latter
+    p90 = engine_queue_wait.read(polled_ctx, {"q": 0.9})
+    assert p90 == pytest.approx(1e3 * EDGES[15] * (EDGES[16] / EDGES[15]) ** (1.7 / 3))
+    p50 = engine_queue_wait.read(polled_ctx, {"q": 0.5})
+    assert 1e3 * EDGES[9] < p50 < 1e3 * EDGES[10]
+
+
+def test_longest_iteration_inside_the_window_and_outside_the_profile(polled_ctx):
+    polls = polled_ctx["marks"]["polls"]
+    polls[1][1]["ring"]["rows"] = [row(1090.0, 9.0), row(1105.0, 0.8),
+                                   row(1112.0, 1.4)]
+    polls[3][1]["ring"]["rows"] = [row(1112.0, 1.4), row(1122.5, 6.0),
+                                   row(1128.5, 0.9)]
+    polled_ctx["device_report"] = {"engine": stats(
+        140, 0, 0, ring=[row(1128.5, 0.9), row(1152.0, 7.0)],
+        slow=[{"at": 1141.0, "total_s": 4.5}, {"at": 1050.0, "total_s": 30.0}])}
+    # 9.0 and 30.0 began before the window, 7.0 after it, 6.0 inside the
+    # profiler's call; the ring's 1.4 loses to the slow record the ring has
+    # already dropped
+    assert engine_longest_iter.read(polled_ctx, {}) == pytest.approx(4.5)
+    polled_ctx["device_report"]["engine"]["slow_iters"] = []
+    assert engine_longest_iter.read(polled_ctx, {}) == pytest.approx(1.4)
+
+
+def test_longest_iteration_is_none_not_zero_when_none_was_recorded(polled_ctx):
+    assert engine_longest_iter.read(polled_ctx, {}) is None
+    polled_ctx["device_report"] = {"engine": {"decode_steps": 5, "active": 1}}
+    assert engine_longest_iter.read(polled_ctx, {}) is None
+    assert engine_longest_iter.read({}, {}) is None
+
+
+def test_hops_percentile_reads_the_done_record():
+    def rec(i, way_in_ms, back_ms, measured=True, error=None, hops=True):
+        r = RequestRecord(i, 0.0, 1500, 64, measured)
+        r.error = error
+        r.done = {"done": True, "latency_s": 7.0}
+        if hops:
+            r.done["hops"] = {
+                "proxy_recv": 1000.0, "engine_enter": 1000.0 + way_in_ms / 1e3,
+                "first_push": 1005.0, "first_write": 1005.0 + back_ms / 1e3}
+        return r
+
+    records = [rec(0, 4.0, 300.0), rec(1, 6.0, 500.0), rec(2, 9.0, 900.0),
+               rec(3, 500.0, 5000.0, measured=False),
+               rec(4, 700.0, 7000.0, error="timeout"), rec(5, 0, 0, hops=False)]
+    way_in = {"from": "proxy_recv", "to": "engine_enter", "q": 0.5}
+    back = {"from": "first_push", "to": "first_write", "q": 0.5}
+    assert hops_percentile.read({"records": records}, way_in) == pytest.approx(6.0)
+    assert hops_percentile.read({"records": records}, back) == pytest.approx(500.0)
+    # a parent commit's done record has no hops: nothing to read, no error
+    assert hops_percentile.read({"records": [rec(0, 0, 0, hops=False)]}, way_in) is None
+    assert hops_percentile.read({}, way_in) is None

@@ -1,0 +1,280 @@
+"""The engine's always-on counters, flight recorder, spans and hops, on the
+CPU at a tiny size. What they count is asserted; what they cost is measured
+on the chip (PERF.md), never here."""
+import glob
+import logging
+import os
+import threading
+import time
+from concurrent.futures import Future
+
+import numpy as np
+import pytest
+
+from ray_tpu.serve import llm
+from ray_tpu.serve.llm import GenRequest, LLMEngine
+
+
+@pytest.fixture(scope="module")
+def engine():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig, llama_init
+
+    config = LlamaConfig.tiny(dtype=jnp.float32, remat=None,
+                              attention_impl="reference")
+    eng = LLMEngine(config, llama_init(config, jax.random.key(3)), num_slots=16,
+                    decode_chunk=4, max_seq_len=256, prefill_buckets=[128])
+    eng.generate([5, 6, 7], max_tokens=5, timeout=300)  # compile both programs
+    yield eng
+    eng.stop()
+
+
+def submit_together(engine, requests):
+    """All of ``requests`` in ONE admission: the loop's ``get_nowait`` waits
+    for the queue's mutex while they are appended."""
+    with engine._pending.mutex:
+        engine._pending.queue.extend(requests)
+    return [r.future.result(timeout=300) for r in requests]
+
+
+def request(tokens, max_tokens=4, waited_s=0.0):
+    return GenRequest(tokens=list(tokens), max_tokens=max_tokens, eos_token=None,
+                      future=Future(),
+                      submitted_at=time.perf_counter() - waited_s)
+
+
+def test_phases_partition_the_iteration(engine):
+    submit_together(engine, [request([1, 2, 3], 9) for _ in range(3)])
+    s = engine.stats()
+    assert s["iters"] > 0 and set(s["phase_ns"]) == set(llm.PHASES)
+    assert sum(s["phase_ns"].values()) == pytest.approx(s["iter_ns"], rel=0.02)
+    assert s["idle_ns"] > 0
+    ring = s["ring"]
+    assert ring["columns"] == list(llm.RING_COLUMNS)
+    assert len(ring["rows"]) == min(s["iters"], llm.RING_ITERS)
+    starts = [r[0] for r in ring["rows"]]
+    assert starts == sorted(starts) and abs(starts[-1] - time.time()) < 60
+    longest = max(sum(r[1:7]) for r in ring["rows"])
+    assert s["longest_iter_s"] >= longest - 1e-6
+    assert s["retired"] == s["admitted"] and s["active"] == 0
+
+
+def test_prefill_padding_is_counted_exactly(engine):
+    before = engine.stats()
+    submit_together(engine, [request([7 + i] * 100) for i in range(3)])
+    after = engine.stats()
+    rise = {k: after[k] - before[k] for k in before if k.startswith("prefill_")}
+    assert rise == {"prefill_calls": 1, "prefill_rows_real": 3,
+                    "prefill_rows_padded": 8, "prefill_tokens_real": 300,
+                    "prefill_tokens_padded": 1024}
+    assert after["admitted"] - before["admitted"] == 3
+
+
+def test_queue_wait_histogram_and_its_p90(engine):
+    from benchmarks.readers import engine_queue_wait
+
+    before = engine.stats()
+    waits = [3.0, 3.0] + [0.02] * 8
+    submit_together(engine, [request([9, 8, 7], waited_s=w) for w in waits])
+    after = engine.stats()
+    counts = [b - a for a, b in zip(before["queue_wait_hist"]["counts"],
+                                    after["queue_wait_hist"]["counts"])]
+    edges = after["queue_wait_hist"]["edges_s"]
+    assert len(counts) == len(edges) + 1 and sum(counts) == 10
+    slow_bucket = next(i for i, e in enumerate(edges) if e > 3.0)
+    assert counts[slow_bucket] == 2 and sum(counts[slow_bucket:]) == 2
+    ctx = {"marks": {"open": 0.0, "close": 9.0,
+                     "polls": [(1.0, before), (2.0, after)]}}
+    assert 2500 < engine_queue_wait.read(ctx, {"q": 0.9}) < 4000
+    assert 15 < engine_queue_wait.read(ctx, {"q": 0.5}) < 200
+
+
+def test_a_slow_iteration_leaves_one_record_and_one_warning_line(engine):
+    lines = []
+
+    class Catch(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    handler = Catch(level=logging.WARNING)
+    llm.logger.addHandler(handler)
+    real, slept = engine._decode, []
+
+    def stalled(*args):
+        if not slept:
+            slept.append(True)
+            time.sleep(llm.SLOW_ITER_FLOOR_S + 0.3)
+        return real(*args)
+
+    before = engine.stats()
+    engine._decode = stalled
+    try:
+        engine.generate([4, 5, 6], max_tokens=9, timeout=300)
+    finally:
+        engine._decode = real
+        llm.logger.removeHandler(handler)
+    after = engine.stats()
+    new = after["slow_iters"][len(before["slow_iters"]):]
+    assert len(new) == 1
+    rec = new[0]
+    assert rec["phase"] in ("decode_dispatch", "device_get")
+    assert rec["total_s"] >= llm.SLOW_ITER_FLOOR_S and rec["phase_s"] >= 0.3
+    assert rec["total_s"] > llm.SLOW_ITER_MEDIANS * rec["median_s"]
+    assert rec["compiles"] == 0 and rec["active"] == 1
+    # the loop thread slept through the stall: it used far less CPU than wall
+    assert 0 <= rec["loop_cpu_s"] <= rec["cpu_s"] + 0.05
+    assert rec["loop_cpu_s"] < rec["total_s"] - 1.0
+    assert rec["steal_s"] >= 0
+    assert abs(rec["at"] - time.time()) < 60
+    assert after["longest_iter_s"] >= rec["total_s"]
+    slow_lines = [m for m in lines if m.startswith("slow engine iteration")]
+    assert len(slow_lines) == 1 and rec["phase"] in slow_lines[0]
+
+
+def test_a_new_shape_raises_compiles_by_one(engine):
+    import jax
+
+    fn = jax.jit(lambda x: x * 2 + 1)
+    fn(np.zeros(5, np.float32))
+    before = engine.stats()
+    fn(np.zeros(5, np.float32))
+    assert engine.stats()["compiles"] == before["compiles"]
+    fn(np.zeros(7, np.float32))
+    after = engine.stats()
+    assert after["compiles"] == before["compiles"] + 1
+    assert after["compile_s"] > before["compile_s"]
+
+
+def test_gc_pauses_are_counted(engine):
+    import gc
+
+    before = engine.stats()
+    gc.collect()
+    after = engine.stats()
+    assert after["gc_pause_ns"] > before["gc_pause_ns"]
+    assert after["gc_longest_s"] > 0
+    assert after["gc_pauses_over_50ms"] >= before["gc_pauses_over_50ms"]
+
+
+def sizes(value, path=""):
+    """{path: len} of every container in ``value``."""
+    out = {}
+    if isinstance(value, dict):
+        out[path] = len(value)
+        for k, v in value.items():
+            out.update(sizes(v, f"{path}.{k}"))
+    elif isinstance(value, (list, tuple)):
+        out[path] = len(value)
+        if value and isinstance(value[0], (list, dict)):
+            out.update(sizes(value[0], f"{path}[]"))
+    return out
+
+
+def test_stats_does_not_grow_with_requests(engine):
+    def run(n):
+        reqs = [request([1 + i % 200, 2, 3], max_tokens=2) for i in range(n)]
+        for r in reqs:
+            engine._submit(r)
+        for r in reqs:
+            r.future.result(timeout=300)
+        return engine.stats()
+
+    bounded = {".ring.rows": llm.RING_ITERS, ".slow_iters": 16}
+    few, many = sizes(run(10)), sizes(run(1000))
+    for path, limit in bounded.items():
+        assert few.pop(path) <= limit and many.pop(path) <= limit
+    assert few == many
+    assert engine.stats()["admitted"] >= 1010
+
+
+def test_a_profile_holds_the_six_phases_and_the_programs_by_name(engine, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        engine.generate([3, 1, 4, 1, 5], max_tokens=6, timeout=300)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    names, prefill_attrs = set(), None
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                names.add(ev.name)
+                if ev.name == "engine.prefill_dispatch":
+                    prefill_attrs = dict(ev.stats)
+    assert {"engine." + p for p in llm.PHASES} <= names
+    assert prefill_attrs == {"bucket": 128, "rows_real": 1, "rows_padded": 8}
+    assert "PjitFunction(paged_decode_steps)" in names
+    assert "PjitFunction(paged_prefill)" in names
+    # the module line of a device trace reads the lowered module's name
+    assert "module @jit_paged_decode_steps" in engine.decode_program_text()
+
+
+def test_streamed_tokens_latencies_and_hops(engine):
+    prompt = [11, 12, 13, 14]
+    whole = engine.generate(prompt, max_tokens=9, timeout=300)
+    t0 = time.time()
+    items = list(engine.generate_stream(prompt, max_tokens=9, timeout=300))
+    t1 = time.time()
+    assert all(type(i) is dict and set(i) == {"token"} for i in items[:-1])
+    assert [i["token"] for i in items[:-1]] == whole["tokens"]
+    done = items[-1]
+    assert set(done) == {"done", "ttft_s", "latency_s", "num_tokens", "hops"}
+    assert done["num_tokens"] == 9 and 0 < done["ttft_s"] <= done["latency_s"]
+    assert set(whole) == {"tokens", "ttft_s", "latency_s"}
+    hops = done["hops"]
+    order = ["engine_enter", "first_push", "first_pickup", "done_push",
+             "done_pickup"]
+    assert set(hops) == set(order)
+    stamps = [hops[k] for k in order]
+    assert stamps == sorted(stamps) and t0 <= stamps[0] and stamps[-1] <= t1
+
+
+def test_hops_of_the_way_in_reach_the_done_record(engine):
+    from ray_tpu.serve import replica
+
+    token = replica._request_hops.set({"proxy_recv": 1.0, "router_submit": 2.0,
+                                       "replica_enter": 3.0})
+    try:
+        done = list(engine.generate_stream([2, 3], max_tokens=2, timeout=300))[-1]
+    finally:
+        replica._request_hops.reset(token)
+    assert done["hops"]["proxy_recv"] == 1.0 and done["hops"]["replica_enter"] == 3.0
+    assert replica.current_request_hops() is None
+
+
+def test_span_is_null_without_jax_and_profile_keeps_its_buffer():
+    import subprocess
+    import sys
+
+    code = ("import sys; from ray_tpu import profiling as p; s = p.span('x', n=1);"
+            "assert s is p.span('y') and 'jax' not in sys.modules; "
+            "exec('with s: pass')")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+    from ray_tpu import profiling
+
+    profiling.drain()
+    with profiling.profile("user-span", extra={"k": 1}):
+        pass
+    spans = profiling.drain()
+    assert [s["name"] for s in spans] == ["user-span"] and spans[0]["extra"] == {"k": 1}
+
+
+def test_idle_iterations_enter_neither_iters_nor_the_ring(engine):
+    before = engine.stats()
+    deadline = time.time() + 5
+    while engine.stats()["idle_ns"] == before["idle_ns"] and time.time() < deadline:
+        threading.Event().wait(0.02)
+    after = engine.stats()
+    assert after["idle_ns"] > before["idle_ns"]
+    assert after["iters"] == before["iters"] and after["iter_ns"] == before["iter_ns"]
+    assert len(after["ring"]["rows"]) == len(before["ring"]["rows"])
