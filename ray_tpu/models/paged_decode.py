@@ -2,8 +2,10 @@
 
 Reference capability: the reference serves LLMs through vLLM's PagedAttention
 (external engine); here paging is first-class and TPU-native. The KV cache
-is a PAGE POOL [L, n_kv, total_pages, page_size, D]; each slot owns a list
-of pages recorded in a device block table [num_slots, max_pages_per_slot].
+is ONE PAGE POOL a side, [n_kv, L * total_pages, page_size, D]: layer ``l``
+owns pages ``l*P .. l*P + P - 1`` (P = total_pages) and a page id means the
+same page of every layer's block. Each slot owns a list of page ids
+recorded in a device block table [num_slots, max_pages_per_slot].
 HBM is committed per-request (ceil((prompt+max_tokens)/page_size) pages),
 not per-slot*max_seq — so slot count is bounded by real demand, and mixed
 short/long workloads pack 3-8x more concurrent requests into the same HBM
@@ -16,8 +18,14 @@ path computes the same thing for CPU tests and for head dims the kernel does
 not tile. Which of the two a decode program holds is its builder's decision
 (``use_kernel``), made once and visible in the lowered text
 (``tpu_custom_call``); nothing inside the traced function asks the backend.
+Both read the same pool through the same table, offset by ``l*P``.
 
 Layout notes:
+- the pool's shape is the kernel's operand shape, so the layer scan hands it
+  the whole pool and ``table + l*P``; no layer is sliced out, re-laid or
+  written back. A decode step costs what it touches, not what is reserved
+  (a 5-D [L, n_kv, P, ps, D] carry cost six copies of a pool layer a layer a
+  step: 28 of 38 ms at 1537 pages on a v5e).
 - page_size is a multiple of 8 (TPU sublane) and prefill buckets are
   multiples of page_size so prompt K/V scatter is a clean reshape-scatter.
 - the pool rides layer-scan carries DONATED through jit, like decode.py.
@@ -38,25 +46,39 @@ from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 
 class PagedKVCache(NamedTuple):
-    k: jax.Array  # [L, n_kv, total_pages, page_size, D]
-    v: jax.Array  # [L, n_kv, total_pages, page_size, D]
+    k: jax.Array  # [n_kv, L * total_pages, page_size, D]
+    v: jax.Array  # [n_kv, L * total_pages, page_size, D]
 
 
 def init_paged_cache(config: LlamaConfig, total_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> PagedKVCache:
-    shape = (config.num_layers, config.num_kv_heads, total_pages, page_size,
+    shape = (config.num_kv_heads, config.num_layers * total_pages, page_size,
              config.head_dim_)
     return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
+def _pages_per_layer(pool, config: LlamaConfig) -> int:
+    return pool.shape[1] // config.num_layers
+
+
 def _scatter_token_rows(pool, rows, pages, rownum):
-    """pool: [n_kv, P_total, ps, D]; rows: [B, n_kv, D]; pages/rownum: [B].
-    One decoded token per slot -> scatter into (page, row). Measured on
-    v5e: the extract-layer/scatter/writeback pattern XLA fuses in place is
-    ~25% faster per decode chunk than a batched-layer-index advanced
-    scatter into the full [L, ...] cache."""
+    """pool: [n_kv, L*P, ps, D]; rows: [B, n_kv, D]; pages (already offset
+    to the layer's block) / rownum: [B]. One decoded token per slot, written
+    into (head, page, row) of the whole donated pool.
+
+    The index runs over n_kv as well, so the scatter's update window is D
+    alone and XLA:TPU keeps the operand in row-major layout, the one the
+    paged-attention kernel reads: the compiled decode program updates the
+    pool in place and holds no operation the size of a pool layer. The
+    shorter ``pool.at[:, pages, rownum]`` has a window of [n_kv, D]; XLA
+    then wants n_kv next to D in the operand's layout and re-lays the whole
+    pool around every scatter (tests/test_chip_compile.py holds the form).
+    On a v5e the 512 row writes of 64 slots x 8 heads take 0.043 ms a call
+    whatever the pool's size."""
     vals = rows.transpose(1, 0, 2)  # [n_kv, B, D]
-    return pool.at[:, pages, rownum].set(vals.astype(pool.dtype))
+    heads = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
+    return pool.at[heads, pages[None, :], rownum[None, :]].set(
+        vals.astype(pool.dtype))
 
 
 def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
@@ -101,35 +123,36 @@ def _paged_attention(q, k_pool, v_pool, table, lengths, scale,
 # --------------------------------------------------------------------------- #
 # Prefill
 # --------------------------------------------------------------------------- #
-def _scatter_prompt_rows_full(cache_full, rows, layer, pages, page_size):
-    """cache_full: [L, n_kv, P_total, ps, D]; rows: [PB, S, n_kv, D]
-    (S = NP*ps); pages: [PB, NP]. Scatters every prompt's K/V pages
-    directly into the full cache (one advanced-index scatter per layer)."""
-    pb, s, nkv, d = rows.shape
-    np_ = s // page_size
-    vals = rows.reshape(pb * np_, page_size, nkv, d).transpose(0, 2, 1, 3)
-    li = jnp.full((pb * np_,), layer, jnp.int32)
-    return cache_full.at[li, :, pages.reshape(-1)].set(
-        vals.astype(cache_full.dtype))
+def _scatter_prompt_rows_full(pool, rows, pages):
+    """pool: [n_kv, L*P, ps, D]; rows: [PB, S, n_kv, D] (S = NP*ps); pages:
+    [PB, NP], already offset to the layer's block. Scatters every prompt's
+    K/V pages into the whole pool in place (one scatter per layer, a page
+    of every head, [n_kv, ps, D], as the window)."""
+    *_, nkv, d = rows.shape
+    page_size = pool.shape[2]
+    vals = rows.reshape(pages.size, page_size, nkv, d).transpose(2, 0, 1, 3)
+    return pool.at[:, pages.reshape(-1)].set(vals.astype(pool.dtype))
 
 
 def paged_prefill(params, cache: PagedKVCache, tokens, pages, lengths,
                   config: LlamaConfig, page_size: int) -> Tuple[jax.Array, PagedKVCache]:
     """BATCHED prefill: tokens [PB, S_bucket] (padded, S_bucket %
     page_size == 0); pages [PB, S_bucket // page_size] page ids per prompt;
-    lengths [PB] true prompt lengths. Returns (last-token logits [PB, V],
-    cache). Batching prompts of the same bucket into one program is what
-    keeps admission off the serving critical path — 64 slots admit in ~8
-    programs instead of 64 (the reference's analogue is vLLM's batched
-    prefill scheduling)."""
+    lengths [PB] true prompt lengths; layer ``l`` writes them at
+    ``l*P + pages``. Returns (last-token logits [PB, V], cache). Batching
+    prompts of the same bucket into one program is what keeps admission off
+    the serving critical path — 64 slots admit in ~8 programs instead of 64
+    (the reference's analogue is vLLM's batched prefill scheduling)."""
     from ray_tpu.ops.attention import attention
 
     _, s = tokens.shape
     cos, sin = rope_frequencies(config.head_dim_, s, config.rope_theta)
     x = params["embed_tokens"][tokens].astype(config.dtype)
+    per_layer = _pages_per_layer(cache.k, config)
 
     def body(carry, lp):
         x, ck_full, cv_full, layer = carry
+        layer_pages = pages + layer * per_layer
         _, q, k, v = _project_qkv(config, lp, x)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -137,10 +160,8 @@ def paged_prefill(params, cache: PagedKVCache, tokens, pages, lengths,
         b, t, nh, hd = q.shape
         x = x + o.reshape(b, t, nh * hd) @ lp["wo"]
         x = x + _mlp(config, lp, x)
-        ck_full = _scatter_prompt_rows_full(ck_full, k, layer, pages,
-                                            page_size)
-        cv_full = _scatter_prompt_rows_full(cv_full, v, layer, pages,
-                                            page_size)
+        ck_full = _scatter_prompt_rows_full(ck_full, k, layer_pages)
+        cv_full = _scatter_prompt_rows_full(cv_full, v, layer_pages)
         return (x, ck_full, cv_full, layer + 1), None
 
     (x, new_k, new_v, _), _ = jax.lax.scan(
@@ -160,7 +181,8 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
                      use_kernel: bool) -> Tuple[jax.Array, PagedKVCache]:
     """One decode tick. tokens/positions: [B]; table: [B, max_pages].
     positions[b] = cache index the current token writes to; attention spans
-    [0, positions[b]] inclusive."""
+    [0, positions[b]] inclusive. An inactive slot's table row is zeros, so
+    its frozen write lands in page ``l*P + 0``, each layer's own trash page."""
     scale = config.head_dim_ ** -0.5
     max_ctx = table.shape[1] * page_size
     cos, sin = rope_frequencies(config.head_dim_, max_ctx, config.rope_theta)
@@ -173,21 +195,19 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
         table, (safe_pos // page_size)[:, None], axis=1)[:, 0]  # [B]
     rows = safe_pos % page_size
     lengths = safe_pos + 1
+    per_layer = _pages_per_layer(cache.k, config)
 
     def body(carry, lp):
         x, ck, cv, layer = carry
         _, q, k, v = _project_qkv(config, lp, x)
         q = apply_rope(q, cos, sin, positions=positions[:, None])
         k = apply_rope(k, cos, sin, positions=positions[:, None])
-        ck_layer = _scatter_token_rows(
-            jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False),
-            k[:, 0], pages, rows)
-        cv_layer = _scatter_token_rows(
-            jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False),
-            v[:, 0], pages, rows)
-        ck = jax.lax.dynamic_update_index_in_dim(ck, ck_layer, layer, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cv, cv_layer, layer, 0)
-        o = _paged_attention(q, ck_layer, cv_layer, table, lengths, scale,
+        # layer l owns pages [l*P, (l+1)*P) of the one pool: the write and
+        # the read both go through the offset, nothing is sliced out
+        base = layer * per_layer
+        ck = _scatter_token_rows(ck, k[:, 0], pages + base, rows)
+        cv = _scatter_token_rows(cv, v[:, 0], pages + base, rows)
+        o = _paged_attention(q, ck, cv, table + base, lengths, scale,
                              use_kernel)
         b, t, nh, hd = q.shape
         x = x + o.reshape(b, t, nh * hd) @ lp["wo"]
@@ -256,8 +276,10 @@ class PageAllocator:
 
     PAGE 0 IS THE TRASH PAGE and is never handed out: inactive slots keep
     block-table rows of zeros, so their frozen-position writes inside the
-    compiled decode loop land in page 0 instead of stomping a live slot's
-    pages (the paged analogue of the dense cache's per-slot frozen row)."""
+    compiled decode loop land in page 0 (of each layer's block of the pool)
+    instead of stomping a live slot's pages (the paged analogue of the dense
+    cache's per-slot frozen row). Page ids are per layer: the allocator
+    knows nothing of the pool's layer blocks."""
 
     TRASH_PAGE = 0
 

@@ -12,7 +12,9 @@ scanned layer.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -94,42 +96,118 @@ def test_library_paged_attention_compiles_at_engine_shapes(v5e, q_dtype):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_chunk_holds_the_kernel(v5e):
-    """The engine's decode program, built the way the engine builds it on a
-    TPU (use_kernel=True), without patching what jax thinks the backend is."""
+def _decode_shapes(v5e, pool_pages=POOL_PAGES):
+    """(config, arguments of the engine's decode program as shapes)."""
     one = SingleDeviceSharding(v5e.devices[0])
     config = _llama_1b(2, attention_impl="flash")
     params = _on(one, jax.eval_shape(lambda k: llama_init(config, k),
                                      jax.random.key(0)))
     cache = _on(one, jax.eval_shape(
-        lambda: pd.init_paged_cache(config, POOL_PAGES, PAGE)))
+        lambda: pd.init_paged_cache(config, pool_pages, PAGE)))
     ints = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one)
     active = jax.ShapeDtypeStruct((SLOTS,), jnp.bool_, sharding=one)
     table = jax.ShapeDtypeStruct((SLOTS, TABLE_PAGES), jnp.int32, sharding=one)
     key = _on(one, jax.eval_shape(lambda: jax.random.key(0)))
-    decode = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=True)
-    lowered = decode.lower(params, cache, ints, ints, active, table, key)
-    assert "tpu_custom_call" in lowered.as_text()
-    assert "tpu_custom_call" in lowered.compile().as_text()
-    gather = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=False)
-    assert "tpu_custom_call" not in gather.lower(
-        params, cache, ints, ints, active, table, key).as_text()
+    return config, (params, cache, ints, ints, active, table, key)
 
 
-def test_prefill_bucket_compiles(v5e):
+def _prefill_shapes(v5e, bucket, pool_pages=POOL_PAGES):
     one = SingleDeviceSharding(v5e.devices[0])
-    bucket = 512
     config = _llama_1b(2, attention_impl="flash")
     params = _on(one, jax.eval_shape(lambda k: llama_init(config, k),
                                      jax.random.key(0)))
     cache = _on(one, jax.eval_shape(
-        lambda: pd.init_paged_cache(config, POOL_PAGES, PAGE)))
+        lambda: pd.init_paged_cache(config, pool_pages, PAGE)))
     tokens = jax.ShapeDtypeStruct((8, bucket), jnp.int32, sharding=one)
     pages = jax.ShapeDtypeStruct((8, bucket // PAGE), jnp.int32, sharding=one)
     lengths = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+    return config, (params, cache, tokens, pages, lengths)
+
+
+def test_paged_decode_chunk_holds_the_kernel(v5e):
+    """The engine's decode program, built the way the engine builds it on a
+    TPU (use_kernel=True), without patching what jax thinks the backend is."""
+    config, args = _decode_shapes(v5e)
+    decode = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=True)
+    lowered = decode.lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    gather = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=False)
+    assert "tpu_custom_call" not in gather.lower(*args).as_text()
+
+
+def test_prefill_bucket_compiles(v5e):
+    config, args = _prefill_shapes(v5e, 512)
     prefill = pd.make_paged_prefill_fn(config, PAGE)
-    text = prefill.lower(params, cache, tokens, pages, lengths).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" in prefill.lower(*args).compile().as_text()
+
+
+def _pool_bytes(cache):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+
+
+def _pool_sized_moves(text, elements):
+    """Lines of a compiled program whose ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` (inside a fusion or not) yields ``elements`` or
+    more: a pool layer, or the pool, moved or re-laid."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(?:copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) >= elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _compile_decode(v5e, pool_pages):
+    """(compiled decode program, the pool's bytes, one layer's elements)."""
+    config, args = _decode_shapes(v5e, pool_pages)
+    decode = pd.make_paged_decode_fn(config, CHUNK, PAGE, use_kernel=True)
+    layer = config.num_kv_heads * pool_pages * PAGE * config.head_dim_
+    return decode.lower(*args).compile(), _pool_bytes(args[1]), layer
+
+
+def test_decode_program_never_moves_a_pool_layer(v5e):
+    """The pool is one array in the kernel's own layout and the token write
+    updates it in place: what a decode step costs follows what it touches,
+    not ``total_pages``. With the pool a 5-D scan carry the temporaries grew
+    by 1.3 bytes a pool byte (slice, re-layout and write-back of a layer,
+    for K and for V, a layer a step)."""
+    small, small_pool, small_layer = _compile_decode(v5e, POOL_PAGES)
+    big, big_pool, _ = _compile_decode(v5e, 2 * POOL_PAGES - 1)
+    grown = (big.memory_analysis().temp_size_in_bytes
+             - small.memory_analysis().temp_size_in_bytes)
+    assert abs(grown) < 0.05 * (big_pool - small_pool)
+    assert _pool_sized_moves(small.as_text(), small_layer) == []
+    assert small.memory_analysis().alias_size_in_bytes == small_pool
+    assert big.memory_analysis().alias_size_in_bytes == big_pool
+
+
+def test_token_write_with_a_window_over_heads_relays_the_pool(v5e, monkeypatch):
+    """Why ``_scatter_token_rows`` indexes the heads too: the shorter form
+    gives the scatter a [n_kv, D] window, XLA:TPU then keeps n_kv next to D
+    in the operand's layout, and the whole pool is re-laid around every
+    write for the kernel, which reads row-major."""
+
+    def window_over_heads(pool, rows, pages, rownum):
+        return pool.at[:, pages, rownum].set(
+            rows.transpose(1, 0, 2).astype(pool.dtype))
+
+    monkeypatch.setattr(pd, "_scatter_token_rows", window_over_heads)
+    compiled, _, layer = _compile_decode(v5e, POOL_PAGES)
+    assert _pool_sized_moves(compiled.as_text(), layer)
+
+
+def test_prefill_temporaries_do_not_grow_with_the_pool(v5e):
+    temps, pools = [], []
+    for pool_pages in (POOL_PAGES, 2 * POOL_PAGES - 1):
+        config, args = _prefill_shapes(v5e, 512, pool_pages)
+        mem = pd.make_paged_prefill_fn(config, PAGE).lower(
+            *args).compile().memory_analysis()
+        pools.append(_pool_bytes(args[1]))
+        assert mem.alias_size_in_bytes == pools[-1]
+        temps.append(mem.temp_size_in_bytes)
+    assert abs(temps[1] - temps[0]) < 0.05 * (pools[1] - pools[0])
 
 
 def test_fsdp4_train_step_compiles_with_flash(v5e):

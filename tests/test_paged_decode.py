@@ -101,3 +101,87 @@ def test_page_allocator_reserves_trash_and_recycles():
     assert a.free_pages == 3
     again = a.alloc(3)
     assert set(again) == set(got[:3])
+
+
+# --------------------------------------------------------------------------- #
+# One pool a side, [n_kv, L*P, ps, D]: layer l owns pages l*P .. l*P + P - 1
+# --------------------------------------------------------------------------- #
+P_TOTAL = 9  # pages a layer
+
+
+def _touched(before, after):
+    """{(page, row)} of the pool's rows that differ, over heads and D."""
+    diff = np.asarray(before != after).any(axis=(0, 3))
+    return {(int(p), int(r)) for p, r in zip(*np.nonzero(diff))}
+
+
+def _decode_one_tick(cfg, params, table, positions):
+    cache = pd.init_paged_cache(cfg, P_TOTAL, PS, dtype=jnp.float32)
+    slots = table.shape[0]
+    _, new = pd.paged_decode_one(
+        params, cache, jnp.arange(1, slots + 1, dtype=jnp.int32),
+        jnp.asarray(positions, jnp.int32), jnp.asarray(table, jnp.int32),
+        cfg, PS, use_kernel=False)
+    return cache, new
+
+
+def _layer_block_case(cfg, params):
+    """A live slot's token is written once a layer, at the same (page, row)
+    of that layer's block, and nowhere else."""
+    table = np.zeros((1, 4), np.int32)
+    table[0] = [5, 2, 7, 3]
+    old, new = _decode_one_tick(cfg, params, table, [PS + 3])  # page 2, row 3
+    want = {(l * P_TOTAL + 2, 3) for l in range(cfg.num_layers)}
+    assert cfg.num_layers >= 2
+    assert _touched(old.k, new.k) == want
+    assert _touched(old.v, new.v) == want
+
+
+def _inactive_trash_case(cfg, params):
+    """Slots with zeroed table rows write page l*P + 0 of each layer only;
+    the live slot's pages hold its row and nothing of theirs."""
+    table = np.zeros((5, 4), np.int32)
+    table[2] = [4, 6, 1, 8]
+    positions = [0, 7, 2 * PS + 1, 0, 21]  # slot 2 live: page 1, row 1
+    old, new = _decode_one_tick(cfg, params, table, positions)
+    for pool_old, pool_new in ((old.k, new.k), (old.v, new.v)):
+        got = _touched(pool_old, pool_new)
+        live = {(l * P_TOTAL + 1, 1) for l in range(cfg.num_layers)}
+        trash = got - live
+        assert live <= got
+        assert trash and {p for p, _ in trash} <= {
+            l * P_TOTAL for l in range(cfg.num_layers)}
+        assert {r for _, r in trash} == {0, 7, 21 % PS}
+
+
+def _interleaved_case(cfg, params):
+    """Two slots whose page ids interleave (1,3,5,7 / 2,4,6,8): prefill then
+    decode through the gather path gives each the dense cache's tokens."""
+    rng = np.random.default_rng(3)
+    prompts = [list(rng.integers(0, cfg.vocab_size, 13)),
+               list(rng.integers(0, cfg.vocab_size, 21))]
+    owned = [[1, 3, 5, 7], [2, 4, 6, 8]]
+    cache = pd.init_paged_cache(cfg, P_TOTAL, PS, dtype=jnp.float32)
+    padded = np.zeros((2, BUCKET), np.int32)
+    for b, prompt in enumerate(prompts):
+        padded[b, :len(prompt)] = prompt
+    logits, cache = pd.paged_prefill(
+        params, cache, jnp.asarray(padded),
+        jnp.asarray([pages[: BUCKET // PS] for pages in owned], jnp.int32),
+        jnp.asarray([len(p) for p in prompts], jnp.int32), cfg, PS)
+    first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    dec = pd.make_paged_decode_fn(cfg, T, PS, 0.0, use_kernel=False)
+    sampled, *_ = dec(params, cache, first,
+                      jnp.asarray([len(p) for p in prompts], jnp.int32),
+                      jnp.ones((2,), bool), jnp.asarray(owned, jnp.int32),
+                      jax.random.key(1))
+    for b, prompt in enumerate(prompts):
+        got = [int(first[b])] + [int(t) for t in sampled[b]]
+        assert got == _dense_generate(cfg, params, prompt, T), b
+
+
+@pytest.mark.parametrize("case", [_layer_block_case, _inactive_trash_case,
+                                  _interleaved_case],
+                         ids=["layer_block", "inactive_trash", "interleaved"])
+def test_one_pool_keeps_layers_and_slots_apart(setup, case):
+    case(*setup)
