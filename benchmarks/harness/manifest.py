@@ -66,9 +66,16 @@ def metric_file(name: str, root: str = ROOT, manifest=None) -> Dict[str, Any]:
 
 
 def load_plugin(kind: str, name: str):
-    """``generators/<name>.py``, ``readers/<name>.py``, ``runners/<name>.py``:
-    a new one is a new module in a directory the harness looks in."""
+    """``generators/<name>.py``, ``readers/<name>.py``, ``runners/<name>.py``,
+    ``families/<name>.py``: a new one is a new module in a directory the
+    harness looks in."""
     return importlib.import_module(f"benchmarks.{kind}.{name}")
+
+
+def family_of(cfg: Dict[str, Any]):
+    """The module of the configuration's model family: everything that depends
+    on the family is asked of it (``families/__init__.py`` lists what)."""
+    return load_plugin("families", cfg["family"])
 
 
 def read_metrics(manifest, workload: str, group: str, ctx: Dict[str, Any],

@@ -1,7 +1,8 @@
 """Peaks, and the operations and bytes an algorithm needs, from shapes.
 
 One table of peaks keyed by ``device_kind``; a device that is not in it is
-an error, never a default."""
+an error, never a default. The FLOPs a trained token needs depend on the
+family's layer and are the family's (``families/<name>.py``)."""
 
 from __future__ import annotations
 
@@ -20,27 +21,9 @@ def peaks_for(device_kind: str) -> Dict[str, Any]:
     return PEAKS[device_kind]
 
 
-def layer_matmul_params(cfg: Dict[str, Any]) -> int:
-    h, f = cfg["hidden_size"], cfg["intermediate_size"]
-    nh, nkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                   cfg["head_dim"])
-    return h * nh * hd * 2 + h * nkv * hd * 2 + 3 * h * f
-
-
 def causal_attention_flops_fwd(seq: int, heads: int, head_dim: int) -> float:
     """QK^T and PV over the causal half: 2 matmuls x 2 flops x S^2/2 x D."""
     return 2 * 2 * heads * head_dim * seq * (seq + 1) / 2
-
-
-def train_flops_per_token(cfg: Dict[str, Any], seq: int) -> float:
-    """Forward and backward, no recompute, no embedding gather: 6 flops per
-    matmul parameter (layers and the output head), plus causal attention
-    (forward once, backward twice)."""
-    dense = cfg["num_hidden_layers"] * layer_matmul_params(cfg) \
-        + cfg["hidden_size"] * cfg["vocab_size"]
-    attn = cfg["num_hidden_layers"] * 3 * causal_attention_flops_fwd(
-        seq, cfg["num_attention_heads"], cfg["head_dim"]) / seq
-    return 6 * dense + attn
 
 
 def flash_fwd_flops(batch: int, seq: int, heads: int, head_dim: int) -> float:

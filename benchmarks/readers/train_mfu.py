@@ -1,6 +1,8 @@
 """tokens/s/chip x FLOPs a token (forward and backward, no recompute, no
-embedding gather, attention counted) over the chip's bf16 peak. %."""
+embedding gather, attention counted: the configuration's family counts them
+for its own layer) over the chip's bf16 peak. %."""
 from benchmarks.harness import roofline
+from benchmarks.harness.manifest import family_of
 from benchmarks.readers import train_token_rate
 
 
@@ -10,4 +12,5 @@ def read(ctx, params):
         return None
     peak = roofline.peaks_for(ctx["device_report"]["kind"])["bf16_flops"]
     seq = ctx["cfg"]["deployment"]["max_seq_len"]
-    return 100.0 * rate * roofline.train_flops_per_token(ctx["cfg"], seq) / peak
+    flops = family_of(ctx["cfg"]).train_flops_per_token(ctx["cfg"], seq)
+    return 100.0 * rate * flops / peak

@@ -1,8 +1,9 @@
-"""The benchmark's own thin deployment: ``LLMDeployment`` takes preset names
-only, so this subclass builds ``LlamaConfig`` from the configuration file and
-hands ``LLMEngine`` weights made by one jitted program from the seed. It also
+"""The benchmark's own thin deployment: it asks the configuration's family
+(``families/<name>.py``) for the program's configuration, for weights made by
+one jitted program from the seed and for the engine of the ``deployment``
+block, and serves requests from that engine as ``LLMDeployment`` does. It also
 carries what only the chip's holder can do: take a device trace, report the
-device, and run the plain reference."""
+device, and run the family's plain reference."""
 
 from __future__ import annotations
 
@@ -10,34 +11,50 @@ import os
 import time
 from typing import Any, Dict, List
 
-from ray_tpu.serve.llm import LLMDeployment, LLMEngine
 
-
-class BenchLLM(LLMDeployment):
+class BenchLLM:
     def __init__(self, config_file: str, seed: int, rehearse: bool = False):
         import jax
 
         from ray_tpu.utils.compile_cache import enable_compile_cache
 
-        from benchmarks.harness.weights import (
-            llama_config_from_file, load_config_file, make_weights)
+        from benchmarks.harness.manifest import family_of
+        from benchmarks.harness.weights import load_config_file
 
         t0 = time.time()
         enable_compile_cache()
         self.cfg = load_config_file(config_file, rehearse)
-        dep = self.cfg["deployment"]
-        config = llama_config_from_file(self.cfg)
-        self.params = make_weights(config, seed)
+        self.family = family_of(self.cfg)
+        self.config = self.family.program_config(self.cfg)
+        self.params = self.family.make_weights(self.config, seed)
         jax.block_until_ready(self.params)
         t1 = time.time()
-        self.engine = LLMEngine(
-            config, self.params, num_slots=dep["num_slots"],
-            max_seq_len=dep["max_seq_len"], decode_chunk=dep["decode_chunk"],
-            prefill_buckets=dep["prefill_buckets"], paged=True,
-            page_size=dep["page_size"], total_pages=dep["total_pages"])
+        self.engine = self.family.make_engine(
+            self.config, self.params, self.cfg["deployment"])
         self.timings = {"constructor_started": t0, "weights_s": t1 - t0,
                         "engine_s": time.time() - t1}
         self._trace_dir = None
+
+    # ----------------------------------------------------------- requests
+    def __call__(self, request: Dict[str, Any]):
+        """A request as ``ray_tpu.serve.llm.LLMDeployment`` takes it: with
+        ``"stream"`` the engine's generator of token records and a final done
+        record, else its whole answer."""
+        serve = self.engine.generate_stream if request.get("stream") \
+            else self.engine.generate
+        return serve(tokens=request["tokens"],
+                     max_tokens=int(request.get("max_tokens", 64)),
+                     eos_token=request.get("eos_token"),
+                     timeout=request.get("timeout"))
+
+    def engine_stats(self) -> Dict[str, Any]:
+        return self.engine.stats()
+
+    def __del__(self):
+        try:
+            self.engine.stop()
+        except Exception:  # noqa: BLE001 - the process is going away
+            pass
 
     # ------------------------------------------------------------ reports
     def bench_report(self) -> Dict[str, Any]:
@@ -46,9 +63,10 @@ class BenchLLM(LLMDeployment):
         from ray_tpu.utils.device_report import device_report
 
         stats = [d.memory_stats() or {} for d in jax.devices()]
-        return {**device_report(), "engine": self.engine.stats(),
+        engine = self.engine.stats()
+        return {**device_report(), "engine": engine,
                 "timings": self.timings,
-                "decode_attention": self.engine.decode_attention,
+                "decode_attention": engine["decode_attention"],
                 "memory_peak_bytes": max(
                     (s.get("peak_bytes_in_use") or 0) for s in stats),
                 "bytes_limit": max((s.get("bytes_limit") or 0) for s in stats)}
@@ -57,12 +75,11 @@ class BenchLLM(LLMDeployment):
         """New seeded weights in place (tools only; the engine is idle)."""
         import jax
 
-        from benchmarks.harness.weights import make_weights
-
-        self.params = self.engine.params = None
-        self.params = make_weights(self.engine.config, seed)
+        self.params = None
+        self.family.set_weights(self.engine, None)
+        self.params = self.family.make_weights(self.config, seed)
         jax.block_until_ready(self.params)
-        self.engine.params = self.params
+        self.family.set_weights(self.engine, self.params)
         return True
 
     # -------------------------------------------------------------- trace
@@ -92,10 +109,10 @@ class BenchLLM(LLMDeployment):
     def check_requests(self, samples: List[Dict[str, Any]], length: int,
                        quant=None) -> Dict[str, Any]:
         """Teacher-forced gaps of the tokens the SERVED path emitted, against
-        the plain float32 reference on the seed's weights."""
+        the family's plain float32 reference on the seed's weights."""
         from benchmarks.harness import reference as ref
 
-        gap_fn = ref.make_gap_fn(self.cfg, quant)
+        gap_fn = self.family.make_gap_fn(self.cfg, quant)
         gaps, first = [], []
         for s in samples:
             g = ref.teacher_forced_gaps(gap_fn, self.params, s["prompt"],
@@ -111,6 +128,6 @@ class BenchLLM(LLMDeployment):
         """The reference in a lower precision, put in the program's place."""
         from benchmarks.harness import reference as ref
 
-        fn = ref.make_greedy_fn(self.cfg, quant)
+        fn = self.family.make_greedy_fn(self.cfg, quant)
         return [ref.greedy_decode(fn, self.params, p, steps, length)
                 for p in prompts]

@@ -1,5 +1,5 @@
 """Runner for ``"kind": "train"`` configurations: TpuTrainer -> Data feed
-(streaming_split -> iter_jax_batches) -> make_train_step, one
+(streaming_split -> iter_jax_batches) -> the family's train step, one
 ``train.report`` every ``report_every`` steps. A traced run goes on after the
 window for ``report_probe_steps`` steps with one report a step, the path
 ISSUE 24 asked for, and times them. The train loop below is the benchmark's
@@ -16,15 +16,13 @@ from typing import Any, Dict
 from benchmarks.harness.manifest import load_plugin
 
 
-def init_state(config, optimizer, key):
-    """TrainState from the benchmark's seeded weights; trace it under jit."""
+def init_state(family, config, optimizer, key):
+    """TrainState from the family's seeded weights; trace it under jit."""
     import jax.numpy as jnp
 
     from ray_tpu.train.step import TrainState
 
-    from benchmarks.harness.weights import init_weights
-
-    params = init_weights(config, key)
+    params = family.init_weights(config, key)
     return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                       opt_state=optimizer.init(params))
 
@@ -37,16 +35,12 @@ def _global_norm(tree):
                         for g in jax.tree.leaves(tree)))
 
 
-def make_checkers(cfg, config):
+def make_checkers(family, cfg, config):
     """jitted (params, tokens, targets) -> (loss, gradients): the program's
-    ``llama_loss``, the plain float32 reference, and the reference in fp8
+    loss, the family's plain float32 reference, and the reference in fp8
     (the control); and ``diff`` over two gradient trees."""
     import jax
     import jax.numpy as jnp
-
-    from ray_tpu.models.llama import llama_loss
-
-    from benchmarks.harness import reference as ref
 
     def pair(fn):
         return jax.jit(lambda params, tokens, targets: jax.value_and_grad(
@@ -58,9 +52,10 @@ def make_checkers(cfg, config):
         return _global_norm(got), _global_norm(want), _global_norm(delta)
 
     return {
-        "program": pair(lambda p, t, y: llama_loss(p, t, y, config)),
-        "reference": pair(lambda p, t, y: ref.reference_loss(p, t, y, cfg)),
-        "control": pair(lambda p, t, y: ref.reference_loss(p, t, y, cfg, "fp8")),
+        "program": pair(lambda p, t, y: family.loss(p, t, y, config)),
+        "reference": pair(lambda p, t, y: family.reference_loss(p, t, y, cfg)),
+        "control": pair(
+            lambda p, t, y: family.reference_loss(p, t, y, cfg, "fp8")),
         "diff": jax.jit(diff),
     }
 
@@ -85,37 +80,34 @@ def train_loop(job: Dict[str, Any]) -> None:
 
     from ray_tpu import train
     from ray_tpu.train.session import get_dataset_shard
-    from ray_tpu.train.step import default_optimizer, make_train_step
+    from ray_tpu.train.step import default_optimizer
     from ray_tpu.utils.compile_cache import enable_compile_cache
     from ray_tpu.utils.device_report import device_report
 
-    from benchmarks.harness.weights import (
-        llama_config_from_file, load_config_file, seed_key)
+    from benchmarks.harness.manifest import family_of
     from benchmarks.harness.trace_reduce import summarize_dir
+    from benchmarks.harness.weights import load_config_file, seed_key
 
     enable_compile_cache()
     cfg = load_config_file(job["config_file"], job["rehearse"])
     dep = cfg["deployment"]
-    config = llama_config_from_file(cfg)
+    family = family_of(cfg)
+    config = family.program_config(cfg)
     fsdp = int(dep.get("fsdp", 1))
     mesh = batch_sh = None
     opt = default_optimizer(warmup_steps=10, total_steps=1000)
     if fsdp > 1:
         # untested on four chips in PR 24 (PERF.md, Open question 1)
         from ray_tpu.parallel.mesh import MeshConfig, batch_sharding_spec, make_mesh
-        from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES
-        from ray_tpu.train.step import _state_shardings, state_logical_axes
 
         mesh = make_mesh(MeshConfig(fsdp=fsdp))
         batch_sh = jax.sharding.NamedSharding(mesh, batch_sharding_spec())
-        shardings = _state_shardings(state_logical_axes(config, opt), mesh,
-                                     DEFAULT_LLM_RULES)
-        make = jax.jit(lambda k: init_state(config, opt, k),
-                       out_shardings=shardings)
+        make = jax.jit(lambda k: init_state(family, config, opt, k),
+                       out_shardings=family.state_shardings(config, opt, mesh))
     else:
-        make = jax.jit(lambda k: init_state(config, opt, k))
+        make = jax.jit(lambda k: init_state(family, config, opt, k))
     state = make(seed_key(job["seed"]))
-    step = make_train_step(config, opt, mesh=mesh)
+    step = family.make_train_step(config, opt, mesh=mesh)
     jax.block_until_ready(state)
 
     rows, seq = dep["batch_rows"], dep["max_seq_len"]
@@ -192,10 +184,8 @@ def train_loop(job: Dict[str, Any]) -> None:
     peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use") or 0)
                for d in jax.devices())
     del state
-    from benchmarks.harness.weights import make_weights
-
-    check = compare(make_checkers(cfg, config), "program",
-                    make_weights(config, job["seed"]), *first_rows)
+    check = compare(make_checkers(family, cfg, config), "program",
+                    family.make_weights(config, job["seed"]), *first_rows)
     summary = summarize_dir(trace_dir) if job["trace"] else None
     train.report({"final": True, "reports": reports, "input_waits": waits,
                   "report_waits": report_waits,

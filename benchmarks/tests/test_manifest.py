@@ -1,5 +1,7 @@
 """BENCHMARK.json holds to the contract, every cell's files resolve by name,
-and a cell, a configuration and a metric can be ADDED as new files only."""
+every configuration keeps its source's published shape but for what it lists
+as reduced, and a cell, a configuration of another family and a metric can be
+ADDED as new files only: the copy that has them passes this whole contract."""
 import json
 import os
 import shutil
@@ -95,69 +97,190 @@ def test_every_cell_resolves_and_reports(manifest):
 
 
 def test_configs_keep_the_published_widths(manifest):
-    published = {"hidden_size": 4096, "intermediate_size": 14336,
-                 "num_attention_heads": 32, "num_key_value_heads": 8,
-                 "head_dim": 128, "vocab_size": 32768, "rope_theta": 1000000.0,
-                 "rms_norm_eps": 1e-05, "num_hidden_layers": 32,
-                 "max_position_embeddings": 32768, "sliding_window": None,
-                 "tie_word_embeddings": False, "torch_dtype": "bfloat16"}
+    """EVERY configuration names the file that holds its source's published
+    shape (``benchmarks/published/<name>.json``), and differs from it in
+    exactly the keys ``reduced`` lists, here and in ``BENCHMARK.json``. Which
+    keys those are is the configuration's business (a depth, a count of what
+    this chip holds of a stated deployment); that each has its reason is not."""
+    base = os.path.join(mf.ROOT, manifest["paths"][0])
+    cut = {}
     for c in manifest["configs"]:
         with open(os.path.join(mf.ROOT, c["file"])) as f:
             cfg = json.load(f)
-        changed = {k for k, v in published.items() if cfg[k] != v}
-        assert changed == set(c["reduced"]) == {"num_hidden_layers"}
-        assert set(cfg["reduced"]) == set(c["reduced"]) and cfg["assumed"]
-        assert cfg["hbm_reckoning"]
+        with open(os.path.join(base, "published", cfg["published"] + ".json")) as f:
+            published = json.load(f)
+        assert published["source"] == cfg["source"] == c["source"]
+        missing = object()
+        changed = {k for k, v in published["config"].items()
+                   if cfg.get(k, missing) != v}
+        assert changed == set(c["reduced"]) == set(cfg["reduced"]), (c["name"], changed)
+        assert all(isinstance(why, str) and why.strip()
+                   for why in cfg["reduced"].values())
+        assert cfg["assumed"] and cfg["hbm_reckoning"]
+        assert cfg["deployment"]["stands_for"]
+        mf.family_of(cfg)
+        cut[c["name"]] = changed
+    assert cut["mistral-7b-v0.3-serve"] == cut["mistral-7b-v0.3-train"] \
+        == {"num_hidden_layers"}
     serve = json.load(open(os.path.join(
         mf.ROOT, "benchmarks/configs/mistral-7b-v0.3-serve.json")))
     assert serve["deployment"]["decode_chunk"] == 8
 
 
-def test_a_cell_a_configuration_and_a_metric_drop_in_as_new_files(tmp_path):
-    """Copy the benchmark, ADD four files and three manifest entries, edit no
-    file that was there, and the harness picks all of it up."""
+# A configuration of another shape than the benchmark's own: no Mistral key
+# beyond ``vocab_size``; two keys cut, one of them a count of what is held here.
+DUMMY_PUBLISHED = {"vocab_size": 64, "model_width": 8, "num_blocks": 12,
+                   "num_tables": 16, "mixer": {"taps": 4, "gate": "relu2"}}
+DUMMY_CONFIG = {
+    "kind": "dummy_runner", "family": "dummy_family", "source": "none",
+    "published": "dummy-model", "vocab_size": 64, "model_width": 8,
+    "num_blocks": 3, "num_tables": 8, "mixer": {"taps": 4, "gate": "relu2"},
+    "reduced": {"num_blocks": "12 -> 3: a test",
+                "num_tables": "16 -> 8: the half this chip holds of two"},
+    "assumed": {"weights": "seeded"}, "hbm_reckoning": {"sum": "nothing"},
+    "deployment": {"stands_for": "a test"}, "rehearsal": {}}
+DUMMY_FAMILY = '''
+"""A family of another shape: one table, looked up and multiplied back."""
+import jax
+import jax.numpy as jnp
+
+from benchmarks.harness.reference import gap_fn_of, mm
+from benchmarks.harness.weights import seed_key
+
+
+def program_config(cfg):
+    return {"vocab": cfg["vocab_size"], "width": cfg["model_width"],
+            "tables": cfg["num_tables"]}
+
+
+def init_weights(config, key):
+    return {"tables": jax.random.normal(
+        key, (config["tables"], config["vocab"], config["width"]), jnp.float32)}
+
+
+def make_weights(config, seed):
+    return jax.jit(lambda k: init_weights(config, k))(seed_key(seed))
+
+
+def reference_logits(params, tokens, cfg, quant=None):
+    table = jnp.sum(params["tables"], axis=0)
+    return mm(table[tokens], table.T, quant)
+
+
+def make_gap_fn(cfg, quant=None):
+    return gap_fn_of(lambda p, t: reference_logits(p, t, cfg, quant))
+'''
+# what its runner hands the readers: its own counter, and what the two
+# metrics that take the cell into their ``workloads`` read
+DUMMY_CTX = {"things": 21, "setup_s": 1.0, "chips": 4,
+             "cfg": {"deployment": {"warmup_steps": 1}},
+             "input_waits": [9.0, 0.002, 0.004],
+             "reports": [10.0, 20.0, 30.0], "report_tokens": [5.0, 400.0, 400.0],
+             "window_open": 10.0, "window_close": 35.0}
+DROP_IN = '''
+import json, sys
+sys.path.insert(0, %r); sys.path.append(%r)
+import numpy as np
+from benchmarks.harness import manifest as mf
+from benchmarks.harness.weights import load_config_file
+from benchmarks.tests import test_manifest as contract
+m = mf.load_manifest()
+r = mf.resolve_cell(m, "dummy_cell")
+cfg = json.load(open(r["config_file"]))
+ctx = mf.load_plugin("runners", cfg["kind"]).run()
+# the whole contract of the manifest, against THIS copy
+contract.test_top_level_and_limits(m)
+contract.test_names_units_and_keys(m)
+contract.test_every_cell_resolves_and_reports(m)
+contract.test_configs_keep_the_published_widths(m)
+# every family of the copy: a configuration, weights at tiny widths, one
+# call of its reference
+shapes = {}
+tokens = np.arange(8, dtype=np.int32)
+for c in m["configs"]:
+    cfg = load_config_file(mf.ROOT + "/" + c["file"], rehearse=True)
+    family = mf.family_of(cfg)
+    params = family.make_weights(family.program_config(cfg), 3000000019)
+    logits = family.reference_logits(params, tokens, cfg)
+    gaps = family.make_gap_fn(cfg)(params, tokens, np.argmax(logits, -1))
+    assert float(abs(gaps).max()) == 0.0
+    shapes[c["name"]] = [family.__name__, list(logits.shape)]
+print(json.dumps({"root": mf.ROOT, "chips": r["cell"]["chips"], "shapes": shapes,
+  "per": mf.read_metrics(m, "dummy_cell", "per_layer", ctx),
+  "e2e": mf.read_metrics(m, "dummy_cell", "end_to_end", ctx)}))
+'''
+
+
+@pytest.mark.parametrize("unlisted", [None, "model_width"],
+                         ids=["whole_contract", "unlisted_change_fails"])
+def test_a_cell_a_configuration_and_a_metric_drop_in_as_new_files(tmp_path, unlisted):
+    """Copy the benchmark, ADD a configuration of another family (its file,
+    its published file, its family module), a traffic file, a metric with its
+    reader, a runner, and manifest entries (the cell appended to the
+    ``workloads`` of the metrics it reports); edit no file that was there. The
+    copy then passes the whole contract above, its families answer, and the
+    harness reads the new metric. A value changed without an entry in
+    ``reduced`` fails the same contract."""
     root = tmp_path / "checkout"
     shutil.copytree(os.path.join(mf.ROOT, "benchmarks"), root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
     manifest = mf.load_manifest()
     b = root / "benchmarks"
-    (b / "configs" / "dummy-model.json").write_text(json.dumps({
-        "kind": "dummy_runner", "vocab_size": 16, "deployment": {}}))
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    config = dict(DUMMY_CONFIG)
+    if unlisted:
+        config[unlisted] = 4
+    (b / "configs" / "dummy-model.json").write_text(json.dumps(config))
+    (b / "published" / "dummy-model.json").write_text(json.dumps(
+        {"source": "none", "config": DUMMY_PUBLISHED}))
+    (b / "families" / "dummy_family.py").write_text(DUMMY_FAMILY)
     (b / "traffic" / "dummy_mix.json").write_text(json.dumps({
         "generator": "token_dataset", "params": {"seq": 4, "rows": 2}}))
     (b / "metrics" / "dummy_metric.json").write_text(json.dumps({
         "name": "dummy_metric", "layer": "Dummy", "unit": "things",
-        "moves": "setup_s", "reader": "dummy_reader", "params": {"scale": 2}}))
+        "moves": "train_tokens_per_s_chip", "reader": "dummy_reader",
+        "params": {"scale": 2}}))
     (b / "readers" / "dummy_reader.py").write_text(
         "def read(ctx, params):\n    return ctx['things'] * params['scale']\n")
     (b / "runners" / "dummy_runner.py").write_text(
-        "def run(*a):\n    return {'things': 21, 'setup_s': 1.0}\n")
+        "def run(*a):\n    return %r\n" % DUMMY_CTX)
     manifest["configs"].append({"name": "dummy-model", "source": "none",
                                 "file": "benchmarks/configs/dummy-model.json",
-                                "reduced": [], "why": "test"})
+                                "reduced": ["num_blocks", "num_tables"],
+                                "why": "test"})
     manifest["workloads"].append({"name": "dummy_cell", "config": "dummy-model",
                                   "traffic": "dummy_mix", "chips": 4, "why": "test"})
+    # an end-to-end metric that is there, and a per-layer metric that moves
+    # it, take the new cell as one more entry of their ``workloads``
+    for group, name in (("end_to_end", "train_tokens_per_s_chip"),
+                        ("per_layer", "input_wait_per_step")):
+        next(m for m in manifest[group] if m["name"] == name)[
+            "workloads"].append("dummy_cell")
     manifest["per_layer"].append({
         "name": "dummy_metric", "unit": "things", "better": "higher",
-        "source": "program_counter", "layer": "Dummy", "moves": "setup_s",
-        "workloads": ["dummy_cell"]})
+        "source": "program_counter", "layer": "Dummy",
+        "moves": "train_tokens_per_s_chip", "workloads": ["dummy_cell"]})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
-    code = (
-        "import sys, json; sys.path.insert(0, %r)\n"
-        "from benchmarks.harness import manifest as mf\n"
-        "m = mf.load_manifest()\n"
-        "r = mf.resolve_cell(m, 'dummy_cell')\n"
-        "cfg = json.load(open(r['config_file']))\n"
-        "ctx = mf.load_plugin('runners', cfg['kind']).run()\n"
-        "print(json.dumps({'root': mf.ROOT, 'chips': r['cell']['chips'],\n"
-        "  'per': mf.read_metrics(m, 'dummy_cell', 'per_layer', ctx),\n"
-        "  'e2e': mf.read_metrics(m, 'dummy_cell', 'end_to_end', ctx)}))\n" % str(root))
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd=str(root), check=True).stdout
-    got = json.loads(out.strip().splitlines()[-1])
+    assert all(p.read_bytes() == data for p, data in before.items())
+    p = subprocess.run([sys.executable, "-c", DROP_IN % (str(root), mf.ROOT)],
+                       capture_output=True, text=True, cwd=str(root),
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    if unlisted:
+        assert p.returncode != 0 and "AssertionError" in p.stderr
+        assert "'dummy-model', {" in p.stderr and unlisted in p.stderr
+        return
+    assert p.returncode == 0, p.stderr[-3000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
     assert got["root"] == str(root) and got["chips"] == 4
-    assert got["per"] == {"dummy_metric": {"value": 42, "unit": "things"}}
-    assert got["e2e"] == {"setup_s": {"value": 1.0, "unit": "s"}}
+    assert got["per"] == {
+        "dummy_metric": {"value": 42, "unit": "things"},
+        "input_wait_per_step": {"value": pytest.approx(3.0), "unit": "ms"}}
+    assert got["e2e"] == {
+        "setup_s": {"value": 1.0, "unit": "s"},
+        "train_tokens_per_s_chip": {"value": 10.0, "unit": "tokens/s/chip"}}
+    assert got["shapes"]["dummy-model"] == ["benchmarks.families.dummy_family", [8, 64]]
+    assert got["shapes"]["mistral-7b-v0.3-serve"] == [
+        "benchmarks.families.llama", [8, 256]]
 
 
 def test_no_result_without_the_program(tmp_path):

@@ -76,7 +76,7 @@ def test_train_rate_mfu_and_waits():
     ctx = {"reports": [100.0, 110.0, 120.0, 130.0], "report_tokens": [3e4, 1e5, 1e5, 1e5],
            "window_open": 100.0, "window_close": 125.0, "chips": 1,
            "device_report": {"kind": "TPU v5 lite"},
-           "cfg": {"hidden_size": 4096, "intermediate_size": 14336,
+           "cfg": {"family": "llama", "hidden_size": 4096, "intermediate_size": 14336,
                    "num_attention_heads": 32, "num_key_value_heads": 8,
                    "head_dim": 128, "vocab_size": 32768, "num_hidden_layers": 4,
                    "deployment": {"max_seq_len": 4096, "warmup_steps": 1}},

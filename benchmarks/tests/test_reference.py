@@ -1,13 +1,14 @@
-"""The plain reference against ``models/llama.py`` at a tiny size, and the
-control (the reference in fp8) coming out as NOT correct."""
+"""The Llama family's plain reference against ``models/llama.py`` at a tiny
+size (this test names the family on purpose), and the control (the reference
+in fp8) coming out as NOT correct."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.families import llama
 from benchmarks.harness import reference as ref
-from benchmarks.harness.weights import (
-    init_weights, llama_config_from_file, make_weights, seed_key)
+from benchmarks.harness.weights import seed_key
 
 TINY = {
     "hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 2,
@@ -20,8 +21,8 @@ TINY = {
 
 @pytest.fixture(scope="module")
 def setup():
-    config = llama_config_from_file(TINY)
-    params = make_weights(config, 3_000_000_019)
+    config = llama.program_config(TINY)
+    params = llama.make_weights(config, 3_000_000_019)
     tokens = np.random.default_rng(0).integers(0, 256, (2, 128), dtype=np.int32)
     return config, params, tokens
 
@@ -32,7 +33,7 @@ def test_reference_matches_the_program_in_float32(setup):
     config, params, tokens = setup
     with jax.default_matmul_precision("highest"):
         prog = llama_forward(params, jnp.asarray(tokens), config)
-    mine = jnp.stack([ref.reference_logits(params, t, TINY, block=32) for t in tokens])
+    mine = jnp.stack([llama.reference_logits(params, t, TINY, block=32) for t in tokens])
     assert float(jnp.max(jnp.abs(prog - mine))) < 2e-4
 
 
@@ -44,7 +45,7 @@ def test_reference_loss_and_gradient_match_the_program(setup):
     with jax.default_matmul_precision("highest"):
         lp, gp = jax.value_and_grad(lambda p: llama_loss(p, t, y, config))(params)
     lr, gr = jax.value_and_grad(
-        lambda p: ref.reference_loss(p, t, y, TINY, block=32))(params)
+        lambda p: llama.reference_loss(p, t, y, TINY, block=32))(params)
     assert abs(float(lp) - float(lr)) < 1e-4
     for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr)):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-3 * (1 + float(jnp.max(jnp.abs(b))))
@@ -52,15 +53,15 @@ def test_reference_loss_and_gradient_match_the_program(setup):
 
 def test_weights_are_seeded_and_seeds_over_31_bits_differ(setup):
     config, params, _ = setup
-    again = make_weights(config, 3_000_000_019)
+    again = llama.make_weights(config, 3_000_000_019)
     assert all(bool(jnp.array_equal(a, b)) for a, b in
                zip(jax.tree.leaves(params), jax.tree.leaves(again)))
-    other = jax.jit(lambda k: init_weights(config, k))(seed_key(3_000_000_019 - 2 ** 31))
+    other = jax.jit(lambda k: llama.init_weights(config, k))(seed_key(3_000_000_019 - 2 ** 31))
     assert not bool(jnp.array_equal(params["lm_head"], other["lm_head"]))
 
 
 def _served_like(params, prompt, steps, quant):
-    fn = ref.make_greedy_fn(TINY, quant)
+    fn = llama.make_greedy_fn(TINY, quant)
     return ref.greedy_decode(fn, params, prompt, steps, 128)
 
 
@@ -69,7 +70,7 @@ def test_control_in_fp8_is_not_correct_and_bf16_is(setup):
     below the configuration's: bf16 stands in for a sound program here, fp8
     for the control. The limits are the ones a run at this size would set."""
     _config, params, tokens = setup
-    gap_fn = ref.make_gap_fn(TINY)
+    gap_fn = llama.make_gap_fn(TINY)
     sound, control = [], []
     for row in tokens:
         prompt = row[:48].tolist()
@@ -91,5 +92,5 @@ def test_teacher_forcing_reads_the_right_positions(setup):
     out = _served_like(params, prompt, 6, None)
     wrong = list(out)
     wrong[3] = (wrong[3] + 1) % 256
-    gaps = ref.teacher_forced_gaps(ref.make_gap_fn(TINY), params, prompt, wrong, 128)
+    gaps = ref.teacher_forced_gaps(llama.make_gap_fn(TINY), params, prompt, wrong, 128)
     assert gaps[0] == gaps[1] == gaps[2] == 0.0 and gaps[3] > 0.0

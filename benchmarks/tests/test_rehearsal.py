@@ -22,17 +22,30 @@ def rehearse(workload, seconds, trace=0):
     return json.loads(lines[-1]), lines
 
 
-@pytest.mark.parametrize("workload,seconds", [
-    ("serve_chat", 5), ("serve_longprompt", 5), ("train_4k", 3)])
-def test_rehearsal_runs_and_reports_no_device_metric(workload, seconds):
-    out, lines = rehearse(workload, seconds)
+SECONDS = {"serve": 5, "train": 3}
+
+
+def cells():
+    """Every cell of ``BENCHMARK.json`` with its configuration's ``kind``: a
+    new cell is rehearsed at its ``rehearsal`` widths without an edit here."""
+    manifest = mf.load_manifest()
+    out = []
+    for w in manifest["workloads"]:
+        with open(mf.resolve_cell(manifest, w["name"])["config_file"]) as f:
+            out.append((w["name"], json.load(f)["kind"]))
+    return out
+
+
+@pytest.mark.parametrize("workload,kind", cells())
+def test_rehearsal_runs_and_reports_no_device_metric(workload, kind):
+    out, lines = rehearse(workload, SECONDS[kind])
     assert set(out) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert out["rehearsal"] is True and out["metrics"] == {}
     assert out["device"]["platform"] == "cpu"
     assert out["attempted"] > 0 and out["failed"] == 0
     assert any(line.startswith("check ") for line in lines)
     check = out["observed"]["check"]
-    if workload == "train_4k":
+    if kind == "train":
         assert check["loss_abs_diff"] < 0.02 and check["grad_norm_rel_diff"] < 0.02
     else:
         assert check["decisions"] > 0 and check["mean_gap"] < 0.01

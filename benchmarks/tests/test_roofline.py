@@ -1,9 +1,11 @@
-"""FLOP and byte functions against hand counts at the Mistral-7B widths."""
+"""FLOP and byte functions against hand counts at the Mistral-7B widths:
+the kernels' in ``harness/roofline.py``, a trained token's in the family."""
 import json
 import os
 
 import pytest
 
+from benchmarks.families import llama
 from benchmarks.harness import roofline
 from benchmarks.harness.manifest import BENCH_DIR
 
@@ -18,7 +20,7 @@ def test_layer_parameters_by_hand():
     # wq, wo: 4096 x 4096 each; wk, wv: 4096 x 1024 each; three 4096 x 14336
     by_hand = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
     assert by_hand == 218_103_808
-    assert roofline.layer_matmul_params(c) == by_hand
+    assert llama.layer_matmul_params(c) == by_hand
 
 
 def test_train_flops_per_token_by_hand():
@@ -26,7 +28,7 @@ def test_train_flops_per_token_by_hand():
     dense = 4 * 218_103_808 + 4096 * 32768          # no embedding gather
     attn_fwd_per_seq = 2 * 2 * 32 * 128 * 4096 * 4097 / 2
     by_hand = 6 * dense + 4 * 3 * attn_fwd_per_seq / 4096
-    assert roofline.train_flops_per_token(c, 4096) == pytest.approx(by_hand, rel=1e-12)
+    assert llama.train_flops_per_token(c, 4096) == pytest.approx(by_hand, rel=1e-12)
     # the strict count is below 6N with the embedding table in N
     n_all = 4 * (218_103_808 + 2 * 4096) + 2 * 4096 * 32768 + 4096
     assert 6 * dense < 6 * n_all

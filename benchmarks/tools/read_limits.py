@@ -66,25 +66,25 @@ def train_limits(args, resolved, cfg, traffic, out):
     """In this process: the tool holds the chip itself."""
     import jax
 
-    from benchmarks.harness.manifest import load_plugin
-    from benchmarks.harness.weights import llama_config_from_file, make_weights
+    from benchmarks.harness.manifest import family_of, load_plugin
     from benchmarks.runners.train import compare, make_checkers
 
     if jax.devices()[0].platform != "tpu" and not args.rehearse:
         raise SystemExit("no accelerator")
-    config = llama_config_from_file(cfg)
+    family = family_of(cfg)
+    config = family.program_config(cfg)
     params = dict(traffic["params"])
     if args.rehearse:
         params.update(traffic.get("rehearsal", {}))
     params["rows"] = cfg["deployment"]["batch_rows"]
     gen = load_plugin("generators", traffic["generator"])
-    checkers = make_checkers(cfg, config)
+    checkers = make_checkers(family, cfg, config)
     r = cfg["deployment"]["check_rows"]
     for k in range(args.seeds):
         seed = args.first_seed + 7919 * k
         data = gen.generate(params, seed, 1.0, cfg["vocab_size"])
         tokens, targets = data["tokens"][:r], data["targets"][:r]
-        weights = make_weights(config, seed)
+        weights = family.make_weights(config, seed)
         sides = ["program"] + (["control"] if k < args.control else [])
         for side in sides:
             row = {"seed": seed, "side": side,
