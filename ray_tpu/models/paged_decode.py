@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
 
 from ray_tpu.models.decode import _lm_head, _mlp, _project_qkv, sample_token
-from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.llama import LlamaConfig, llama_init as init_params  # noqa: F401
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 
@@ -55,6 +55,17 @@ def init_paged_cache(config: LlamaConfig, total_pages: int, page_size: int,
     shape = (config.num_kv_heads, config.num_layers * total_pages, page_size,
              config.head_dim_)
     return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+# what ``serve/llm.py`` asks of a model's module (``_model_of``) beside the
+# two ``make_*`` functions and ``paged_kernel_fits``: this family keeps pages
+# only, nothing a slot
+SLOT_STATE = False
+
+
+def init_cache(config: LlamaConfig, num_slots: int, total_pages: int,
+               page_size: int) -> PagedKVCache:
+    return init_paged_cache(config, total_pages, page_size)
 
 
 def _pages_per_layer(pool, config: LlamaConfig) -> int:

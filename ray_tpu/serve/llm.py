@@ -3,8 +3,10 @@
 Reference capability: the reference serves LLMs by orchestrating external
 GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
 
-- a slotted KV cache in HBM (models/decode.py) — one slot per in-flight
-  request, no paging tables needed with a static XLA buffer;
+- the model's cache in HBM, one donated pytree: a page pool
+  (models/paged_decode.py) and, for a model with recurrent layers, per-slot
+  state beside it (models/nemotron_h.py); or the dense slotted cache of
+  models/decode.py — one slot per in-flight request;
 - CONTINUOUS batching: new requests are prefilled into free slots while
   other slots keep decoding — no batch barrier (Orca-style iteration-level
   scheduling);
@@ -23,6 +25,7 @@ from __future__ import annotations
 import bisect
 import os
 import queue
+import sys
 import threading
 import time
 from collections import deque
@@ -49,6 +52,40 @@ RING_COLUMNS = ("start",) + PHASES + ("active", "admitted", "retired")
 SLOW_ITER_FLOOR_S = 1.0
 SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
+MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
+                "moe_experts_touched", "moe_expert_load_max")
+
+
+def _model_of(config):
+    """The module that builds ``config``'s weights, cache and programs:
+    ``init_params``, ``init_cache``, ``make_paged_prefill_fn``,
+    ``make_paged_decode_fn``, ``paged_kernel_fits``, ``SLOT_STATE``.
+    ``models/paged_decode.py`` for a ``LlamaConfig``; any other family's
+    module holds its configuration class beside its programs, and whoever
+    made ``config`` has imported it: a Llama replica imports no other
+    family."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    if isinstance(config, LlamaConfig):
+        from ray_tpu.models import paged_decode
+
+        return paged_decode
+    return sys.modules[type(config).__module__]
+
+
+def _nemotron_h_tiny():
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    return NemotronHConfig.tiny()
+
+
+def model_presets() -> Dict[str, Any]:
+    """``LLMDeployment``'s preset names."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    return {"tiny": LlamaConfig.tiny, "llama_1b": LlamaConfig.llama_1b,
+            "llama3_8b": LlamaConfig.llama3_8b,
+            "nemotron_h_tiny": _nemotron_h_tiny}
 
 
 def _steal_s() -> float:
@@ -84,8 +121,11 @@ class GenRequest:
 
 
 class LLMEngine:
-    """Continuous-batching loop around models/decode.py (dense slots) or
-    models/paged_decode.py (paged KV cache).
+    """Continuous-batching loop around a model's prefill and decode programs:
+    models/paged_decode.py (Llama family, paged KV cache), models/nemotron_h.py
+    (hybrid family: pages and per-slot recurrent state) or models/decode.py
+    (Llama family, dense slots). One loop, one admission, one set of counters
+    for all of them.
 
     Paged mode (default): HBM is committed per REQUEST
     (ceil((prompt+max_tokens)/page_size) pages from a shared pool), not
@@ -139,7 +179,26 @@ class LLMEngine:
       and ``steal_s`` (seconds, summed over CPUs, the hypervisor gave to
       others since the engine started), ``queued`` and ``active``; each is
       also one warning line in the log (at most one every 10 s;
-      ``slow_iters_unlogged`` counts the rest)."""
+      ``slow_iters_unlogged`` counts the rest).
+    - ``kv_bytes_per_token``: bytes of K and V a cached token takes over
+      all the layers that keep pages. ``state_slots``, ``state_bytes``:
+      slots that keep recurrent state beside their pages, and the bytes of
+      it (all slots and the trash row); 0 for a model that keeps none.
+    - ``moe_assignments``, ``moe_assignments_held``, ``moe_experts_touched``,
+      ``moe_expert_load_max``: over decode ticks and expert layers, summed:
+      the routed choices of active slots, those that fell on experts held
+      here, held experts with at least one token, and the fullest held
+      expert's tokens. Counted on the device and fetched with the chunk's
+      one ``device_get``; 0 for a model without routed experts.
+
+    Which model: ``_model_of`` maps the configuration's type to the module
+    that builds its weights, its cache and its two programs
+    (``models/paged_decode.py`` for ``LlamaConfig``,
+    ``models/nemotron_h.py`` for ``NemotronHConfig``). The cache is one
+    donated pytree. Where the module says ``SLOT_STATE``, the cache also
+    holds per-slot recurrent state: prefill is told each row's slot (a pad
+    row: the trash row ``num_slots``) and overwrites it, so a retired slot
+    needs no clearing; decode moves the state of active slots only."""
 
     def __init__(self, config, params=None, *, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, decode_chunk: int = 8,
@@ -149,31 +208,21 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.decode import (
-            init_kv_cache,
-            make_decode_fn,
-            make_prefill_fn,
-        )
-        from ray_tpu.models.llama import llama_init
         from ray_tpu.utils.compile_cache import enable_compile_cache
 
         enable_compile_cache()
+        model = _model_of(config)
         self.config = config
         self.num_slots = num_slots
         self.max_seq = max_seq_len or config.max_seq_len
         self.decode_chunk = decode_chunk
-        self.params = params if params is not None else llama_init(
+        self.params = params if params is not None else model.init_params(
             config, jax.random.key(0)
         )
         self.paged = paged
+        self._slot_state = model.SLOT_STATE
         if paged:
-            from ray_tpu.models.paged_decode import (
-                PageAllocator,
-                init_paged_cache,
-                make_paged_decode_fn,
-                make_paged_prefill_fn,
-                paged_kernel_fits,
-            )
+            from ray_tpu.models.paged_decode import PageAllocator
 
             self.page_size = page_size
             self.pages_per_slot = -(-self.max_seq // page_size)
@@ -184,17 +233,24 @@ class LLMEngine:
             self.total_pages = total_pages or (
                 1 + num_slots * self.pages_per_slot)
             self.allocator = PageAllocator(self.total_pages)
-            self.cache = init_paged_cache(config, self.total_pages, page_size)
+            self.cache = model.init_cache(config, num_slots, self.total_pages,
+                                          page_size)
             self._table = jnp.zeros((num_slots, self.pages_per_slot), jnp.int32)
             self._slot_pages: List[Optional[List[int]]] = [None] * num_slots
-            self._prefill = make_paged_prefill_fn(config, page_size)
+            self._prefill = model.make_paged_prefill_fn(config, page_size)
             use_kernel = (jax.default_backend() == "tpu"
-                          and paged_kernel_fits(config))
+                          and model.paged_kernel_fits(config))
             self.decode_attention = "pallas_paged" if use_kernel else "gather"
-            self._decode = make_paged_decode_fn(
+            self._decode = model.make_paged_decode_fn(
                 config, decode_chunk, page_size, temperature,
                 use_kernel=use_kernel)
         else:
+            from ray_tpu.models.decode import (
+                init_kv_cache,
+                make_decode_fn,
+                make_prefill_fn,
+            )
+
             self.decode_attention = "dense"
             self.cache = init_kv_cache(config, num_slots, self.max_seq)
             self._prefill = make_prefill_fn(config)
@@ -239,6 +295,8 @@ class LLMEngine:
         self._prefill_rows_padded = 0
         self._prefill_tokens_real = 0
         self._prefill_tokens_padded = 0
+        self._moe_counts = np.zeros((4,), np.int64)
+        self._cache_stats = self._describe_cache()
         self._queue_wait_counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
         self._ring = np.zeros((RING_ITERS, len(RING_COLUMNS)))
         self._longest_iter_ns = 0
@@ -358,7 +416,22 @@ class LLMEngine:
             "longest_iter_s": self._longest_iter_ns / 1e9,
             "slow_iters": list(self._slow_iters),
             "slow_iters_unlogged": self._slow_unlogged,
+            **self._cache_stats,
+            **dict(zip(MOE_COUNTERS, self._moe_counts.tolist())),
         }
+
+    def _describe_cache(self) -> Dict[str, int]:
+        """What the cache's shapes say, read once: the loop thread donates
+        the cache itself every step."""
+        if not self.paged:
+            return {}
+        pool = self.cache.k
+        kv = 2 * pool.shape[0] * (pool.shape[1] // self.total_pages) \
+            * pool.shape[3] * pool.dtype.itemsize
+        state = sum(x.nbytes for name, x in self.cache._asdict().items()
+                    if name not in ("k", "v"))
+        return {"kv_bytes_per_token": kv, "state_bytes": state,
+                "state_slots": self.num_slots if self._slot_state else 0}
 
     def _queued(self) -> int:
         return self._pending.qsize() + len(self._admit_backlog)
@@ -512,18 +585,20 @@ class LLMEngine:
         tokens = np.zeros((size, bucket), np.int32)
         page_arr = np.zeros((size, n_pages), np.int32)  # pad rows -> trash
         lengths = np.ones((size,), np.int32)
+        slots = np.full((size,), self.num_slots, np.int32)  # pad rows -> trash
         tokens_real = 0
         for row, (req, slot, pages, _b) in enumerate(chunk):
             n = len(req.tokens)
             tokens[row, :n] = req.tokens
             page_arr[row] = pages[:n_pages]
             lengths[row] = min(n, bucket)
+            slots[row] = slot
             tokens_real += n
         self._count_prefill(len(chunk), size, tokens_real, bucket)
-        logits, self.cache = self._prefill(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(page_arr), jnp.asarray(lengths),
-        )
+        args = [jnp.asarray(tokens), jnp.asarray(page_arr), jnp.asarray(lengths)]
+        if self._slot_state:
+            args.append(jnp.asarray(slots))
+        logits, self.cache = self._prefill(self.params, self.cache, *args)
         firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [size]
         for row, (req, slot, pages, _b) in enumerate(chunk):
             n = len(req.tokens)
@@ -631,7 +706,8 @@ class LLMEngine:
         t1 = clock()
         for chunk, bucket, size in groups:
             with span("engine.prefill_dispatch", bucket=bucket,
-                      rows_real=len(chunk), rows_padded=size):
+                      rows_real=len(chunk), rows_padded=size,
+                      state_rows=len(chunk) if self._slot_state else 0):
                 self._prefill_group(chunk, bucket, size)
         t2 = clock()
         if not any(r is not None for r in self._slots):
@@ -640,11 +716,14 @@ class LLMEngine:
             return
         with span("engine.decode_dispatch"):
             self._key, sub = jax.random.split(self._key)
+            counts = None
             if self.paged:
-                sampled, last, self._positions, self.cache = self._decode(
-                    self.params, self.cache, self._tokens,
-                    self._positions, self._active, self._table, sub,
-                )
+                # a model with routed experts returns their counts as well
+                sampled, last, self._positions, self.cache, *counts = \
+                    self._decode(
+                        self.params, self.cache, self._tokens,
+                        self._positions, self._active, self._table, sub,
+                    )
             else:
                 sampled, last, self._positions, self.cache = self._decode(
                     self.params, self.cache, self._tokens,
@@ -659,7 +738,10 @@ class LLMEngine:
                       if req is not None and req.pending_first is not None}
         t3 = clock()
         with span("engine.device_get"):
-            host_tokens, host_firsts = jax.device_get((sampled, firsts))
+            host_tokens, host_firsts, host_counts = jax.device_get(
+                (sampled, firsts, counts))
+            if host_counts:
+                self._moe_counts += host_counts[0]
         t4 = clock()
         now = t4 / 1e9  # perf_counter's clock, as submitted_at
         now_wall = time.time()
@@ -769,20 +851,19 @@ class LLMDeployment:
         handle.generate.remote({"tokens": [...], "max_tokens": 32}).result()
     """
 
-    def __init__(self, model: str = "tiny", num_slots: int = 8,
+    def __init__(self, model: Any = "tiny", num_slots: int = 8,
                  decode_chunk: int = 8, max_seq_len: Optional[int] = None,
                  temperature: float = 0.0, params=None,
                  total_pages: Optional[int] = None):
-        from ray_tpu.models.llama import LlamaConfig
-
-        factories = {
-            "tiny": LlamaConfig.tiny,
-            "llama_1b": LlamaConfig.llama_1b,
-            "llama3_8b": LlamaConfig.llama3_8b,
-        }
-        if model not in factories:
-            raise ValueError(f"unknown model '{model}'; options: {sorted(factories)}")
-        config = factories[model]()
+        """``model``: a preset's name (``model_presets()``) or a configuration
+        object of either family (``LlamaConfig``, ``NemotronHConfig``)."""
+        config = model
+        if isinstance(model, str):
+            factories = model_presets()
+            if model not in factories:
+                raise ValueError(
+                    f"unknown model '{model}'; options: {sorted(factories)}")
+            config = factories[model]()
         self.engine = LLMEngine(
             config, params, num_slots=num_slots, decode_chunk=decode_chunk,
             max_seq_len=max_seq_len, temperature=temperature,

@@ -12,6 +12,7 @@ from __future__ import annotations
 import asyncio
 import contextvars
 import inspect
+import queue
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -30,6 +31,100 @@ def current_request_hops() -> Optional[Dict[str, float]]:
     LLM engine's done record) copies them there, and the proxy adds its
     own. None outside a streaming request."""
     return _request_hops.get()
+
+
+class StreamBatch(list):
+    """Items of one stream that travel as ONE object (an item costs the
+    object plane a seal, a report and a long-poll through the GCS: one
+    replica carried 590 a second whatever the number of streams; PERF.md
+    section 6, PR 29). ``Router.call_streaming`` hands them on one by one,
+    in order: a consumer sees the generator's items and never a batch."""
+
+
+_STREAM_AHEAD = 256  # items a coalesced generator may run in front of its consumer
+# a stream is BEHIND once its generator had this many items in a row ready
+# when it was asked: four of the LLM engine's bursts (a chunk of 8 tokens, 9
+# with the first token or the closing record) without once waiting for the
+# engine; two bursts run into each other whenever an iteration is short.
+# READY = within a millisecond
+_STREAM_BEHIND = 32
+_STREAM_READY_S = 1e-3
+
+
+def _coalesced(gen):
+    """The generator's items, one object each, by the parent's own path
+    (this thread runs ``gen`` and the runtime seals what it yields) for as
+    long as the object plane keeps up with the generator. Once
+    ``_STREAM_BEHIND`` items in a row were ready the moment they were asked
+    for, the stream is behind, and from then on a pump thread runs ``gen``
+    (in the caller's context: request hops, multiplexed model id; at most
+    ``_STREAM_AHEAD`` items in front) while this one sends whatever waits
+    together, as a ``StreamBatch``. Nothing waits for a batch to fill, so an
+    item is never later than it was.
+
+    A stream that keeps up is exactly what it was: coalescing every stream
+    from its first item was measured too, and in a closed loop whose engine
+    is the limit it moved 0.13-0.24 s of a request's cycle from the tail of
+    its reply to the queue in front of the engine; a pump thread for every
+    stream cost that cell 1% (PERF.md section 6, PR 29). Closing this
+    generator closes ``gen``: at once while it keeps up, at its next item
+    once pumped, as the runtime's own close does."""
+    ready = 0
+    try:
+        while ready < _STREAM_BEHIND:
+            asked = time.perf_counter()
+            try:
+                item = next(gen)
+            except StopIteration:
+                return
+            ready = ready + 1 if time.perf_counter() - asked < _STREAM_READY_S else 0
+            yield item
+    except GeneratorExit:
+        gen.close()
+        raise
+
+    items: "queue.Queue" = queue.Queue(maxsize=_STREAM_AHEAD)
+    abandoned = threading.Event()
+
+    def put(entry) -> None:
+        while not abandoned.is_set():
+            try:
+                return items.put(entry, timeout=0.5)
+            except queue.Full:
+                continue
+
+    def pump() -> None:
+        try:
+            for item in gen:
+                put((True, item))
+                if abandoned.is_set():
+                    break
+            put((False, None))
+        except BaseException as e:  # noqa: BLE001 - raised again by the consumer
+            put((False, e))
+        finally:
+            gen.close()
+
+    threading.Thread(target=contextvars.copy_context().run, args=(pump,),
+                     daemon=True, name="replica-stream-pump").start()
+    try:
+        while True:
+            entries = [items.get()]  # (True, item) or (False, None | error)
+            try:
+                while entries[-1][0]:
+                    entries.append(items.get_nowait())
+            except queue.Empty:
+                pass
+            batch = [value for is_item, value in entries if is_item]
+            if batch:
+                yield batch[0] if len(batch) == 1 else StreamBatch(batch)
+            is_item, error = entries[-1]
+            if not is_item:
+                if error is not None:
+                    raise error
+                return
+    finally:
+        abandoned.set()
 
 
 class ReplicaOverloadedError(exc.RayTpuError):
@@ -113,7 +208,9 @@ class Replica:
         consumable before the request finishes (reference:
         serve/_private/proxy.py:542 streaming send_request_to_replica +
         replica.py:533 handle_request_streaming). Non-generator results
-        stream as a single item. ``hops``: see ``current_request_hops``."""
+        stream as a single item; a stream that falls behind the object plane
+        sends what waits together (``_coalesced``). ``hops``: see
+        ``current_request_hops``."""
         from ray_tpu.serve.multiplex import (
             _reset_request_model_id, _set_request_model_id,
         )
@@ -142,11 +239,11 @@ class Replica:
             if inspect.iscoroutine(result):
                 result = _run_coro(result)
             if inspect.isgenerator(result):
-                yield from result
+                yield from _coalesced(result)
             elif inspect.isasyncgen(result):
                 from ray_tpu.core.streaming import iter_async_gen
 
-                yield from iter_async_gen(result)
+                yield from _coalesced(iter_async_gen(result))
             else:
                 yield result
         finally:

@@ -21,6 +21,14 @@ from ray_tpu.utils.logging import get_logger
 logger = get_logger("serve.router")
 
 
+def _items(value):
+    """What one object of a replica's stream holds: an item, or the items
+    of a ``StreamBatch``."""
+    from ray_tpu.serve.replica import StreamBatch
+
+    return value if type(value) is StreamBatch else (value,)
+
+
 class Router:
     """Routers subscribe to the controller's versioned config bus
     (reference: serve/long_poll.py LongPollClient): a daemon thread blocks
@@ -190,10 +198,10 @@ class Router:
     def call_streaming(self, method: str, args: tuple, kwargs: dict,
                        multiplexed_model_id: str = "",
                        hops: Optional[Dict[str, float]] = None):
-        """Route AND stream VALUES, retrying overload/replica-death on other
-        replicas while no item has been delivered yet (after the first item
-        the stream is already partially consumed; mid-stream failures
-        propagate)."""
+        """Route AND stream VALUES (a ``StreamBatch`` item by item), retrying
+        overload/replica-death on other replicas while no item has been
+        delivered yet (after the first item the stream is already partially
+        consumed; mid-stream failures propagate)."""
         from ray_tpu.serve.replica import ReplicaOverloadedError
 
         attempts = 0
@@ -227,9 +235,9 @@ class Router:
                         time.sleep(min(0.05 * attempts, 0.5))
                         continue
                     raise
-                yield first
+                yield from _items(first)
                 for ref in it:
-                    yield ray_tpu.get(ref)
+                    yield from _items(ray_tpu.get(ref))
                 return
             finally:
                 self._note(replica, -1)

@@ -9,6 +9,7 @@ from benchmarks.harness.loadgen import RequestRecord
 from benchmarks.readers import (
     engine_counters, engine_longest_iter, engine_queue_wait, hops_percentile)
 from benchmarks.tests.test_manifest import *  # noqa: F401,F403
+from benchmarks.tests.test_nemotron_h_family import *  # noqa: F401,F403
 from benchmarks.tests.test_rates import *  # noqa: F401,F403
 from benchmarks.tests.test_readers import *  # noqa: F401,F403
 from benchmarks.tests.test_reference import *  # noqa: F401,F403

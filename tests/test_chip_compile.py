@@ -245,3 +245,76 @@ def test_fsdp4_train_step_compiles_with_flash(v5e):
     whole = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 0.3 * whole
+
+
+# --------------------------------------------------------------------------- #
+# The second family through the engine (PR 29)
+# --------------------------------------------------------------------------- #
+LLAMA_TINY_DECODE_SHA = \
+    "b47635e63964b6b8171f77683df70d12db7d6425c76acde4c53e5cdfdb72d27c"
+
+
+def test_llama_decode_program_is_what_it_was_before_the_second_family():
+    """``LLMEngine`` builds its programs through ``_model_of`` since PR 29.
+    The Llama decode program it lowers is the text the parent commit lowered
+    (the hash was taken on both trees; at the benchmark's rehearsal widths
+    decode and prefill were compared too, equal). It depends on the jax that
+    lowers it, so another version skips."""
+    import hashlib
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip(f"the hash was taken under jax 0.9.0, not {jax.__version__}")
+    engine = LLMEngine(LlamaConfig.tiny(attention_impl="reference"),
+                       num_slots=8, decode_chunk=4, max_seq_len=256,
+                       prefill_buckets=[128])
+    try:
+        text = engine.decode_program_text()
+    finally:
+        engine.stop()
+    assert hashlib.sha256(text.encode()).hexdigest() == LLAMA_TINY_DECODE_SHA
+
+
+def _hybrid_decode(v5e, slots):
+    """The hybrid family's decode program at published widths (one layer of
+    each kind and a Mamba layer more, 8 experts held, a slice of the
+    vocabulary, so that it compiles in seconds) for ``slots`` slots."""
+    from ray_tpu.models import nemotron_h as nh
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    config = nh.NemotronHConfig(
+        pattern="ME*M", n_routed_experts=8, held_experts=(0, 8),
+        vocab_size=8192, attention_impl="flash")
+    params = _on(one, jax.eval_shape(lambda k: nh.init_params(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: nh.init_cache(config, slots, POOL_PAGES, PAGE)))
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    table = jax.ShapeDtypeStruct((slots, TABLE_PAGES), jnp.int32, sharding=one)
+    key = _on(one, jax.eval_shape(lambda: jax.random.key(0)))
+    decode = nh.make_paged_decode_fn(config, 8, PAGE, use_kernel=True)
+    compiled = decode.lower(params, cache, ints, ints, active, table, key).compile()
+    return config, compiled, cache
+
+
+def test_hybrid_decode_updates_pool_and_state_in_place(v5e):
+    """Pages and per-slot Mamba state ride one donated cache: the compiled
+    program aliases all of it, holds the paged-attention kernel, and neither
+    copies nor re-lays anything the size of one layer's state; its
+    temporaries do not grow with the state when the slots double."""
+    config, small, cache = _hybrid_decode(v5e, 64)
+    _, big, big_cache = _hybrid_decode(v5e, 128)
+    state_layer = 64 * config.mamba_inner * config.ssm_state_size
+    text = small.as_text()
+    assert "tpu_custom_call" in text
+    copies = [line.strip()[:160] for line in text.splitlines() if (
+        m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                      r"(?:copy|transpose)\(", line))
+        and math.prod(int(d) for d in m.group(1).split(",")) >= state_layer]
+    assert copies == []
+    assert small.memory_analysis().alias_size_in_bytes >= _pool_bytes(cache)
+    grown = (big.memory_analysis().temp_size_in_bytes
+             - small.memory_analysis().temp_size_in_bytes)
+    assert grown < 0.1 * (_pool_bytes(big_cache) - _pool_bytes(cache))

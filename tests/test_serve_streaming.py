@@ -162,3 +162,105 @@ def test_llm_stream_abandon_frees_slot(ray_tpu_local):
         assert len(out["tokens"]) == 4
     finally:
         engine.stop()
+
+
+# ---------------------------------------------------------------- coalescing
+def test_a_stream_that_falls_behind_travels_together_and_arrives_one_by_one():
+    """A consumer that found 32 items in a row ready when it asked (it is
+    slower than the generator, as the object plane is: a seal, a report and
+    a long-poll an object) gets what waits as one ``StreamBatch`` from then
+    on; the router's ``_items`` gives the generator's items back, in
+    order."""
+    from ray_tpu.serve.replica import StreamBatch, _coalesced
+    from ray_tpu.serve.router import _items
+
+    objects = []
+    for obj in _coalesced(i for i in range(200)):
+        objects.append(obj)
+        time.sleep(0.002)
+    assert [i for o in objects for i in _items(o)] == list(range(200))
+    assert len(objects) < 100
+    assert not any(type(o) is StreamBatch for o in objects[:32])
+    assert any(type(o) is StreamBatch for o in objects)
+
+
+def test_a_stream_that_keeps_up_goes_item_by_item():
+    """Bursts of 9 (the LLM engine's largest: a chunk of 8 tokens and the
+    first token or the closing record) that drain before the next one: every
+    item is its own object and no pump thread runs, as before there was a
+    ``StreamBatch``."""
+    import threading
+
+    from ray_tpu.serve.replica import _coalesced
+
+    def bursts():
+        for burst in range(4):
+            for i in range(9):
+                yield {"i": 9 * burst + i}
+            time.sleep(0.2)
+
+    objects = []
+    for obj in _coalesced(bursts()):
+        objects.append(obj)
+        assert not any(t.name == "replica-stream-pump"
+                       for t in threading.enumerate())
+        time.sleep(0.005)
+    assert objects == [{"i": i} for i in range(36)]
+
+
+def test_coalesced_stream_raises_after_its_items_and_closes_when_abandoned():
+    from ray_tpu.serve.replica import _coalesced
+    from ray_tpu.serve.router import _items
+
+    def failing():
+        yield 1
+        yield 2
+        raise ValueError("mid-stream")
+
+    seen = []
+    with pytest.raises(ValueError, match="mid-stream"):
+        for obj in _coalesced(failing()):
+            seen += _items(obj)
+    assert seen == [1, 2]
+
+    closed = []
+
+    def endless():
+        try:
+            i = 0
+            while True:
+                yield i
+                i += 1
+        finally:
+            closed.append(True)
+
+    stream = _coalesced(endless())
+    next(stream)
+    stream.close()  # while it keeps up: closed with its consumer
+    assert closed == [True]
+    stream = _coalesced(endless())
+    for _ in range(60):  # behind by now: a pump thread runs the generator
+        next(stream)
+    stream.close()
+    deadline = time.time() + 5
+    while len(closed) < 2 and time.time() < deadline:
+        time.sleep(0.01)
+    assert closed == [True, True], "the abandoned stream's generator was never closed"
+
+
+def test_a_burst_reaches_the_http_client_whole_and_in_order(serve_session):
+    @serve.deployment(stream=True)
+    def burst(request=None):
+        for i in range(300):
+            yield {"token": i}
+        yield {"done": True}
+
+    handle = serve.run(burst.bind(), name="burst")
+    host, port = serve.http_address().replace("http://", "").split(":")
+    lines = b"".join(c for c, _ in _http_stream_chunks(
+        host, int(port), "/burst")).splitlines()
+    records = [json.loads(line) for line in lines]
+    assert [r["token"] for r in records[:-1]] == list(range(300))
+    assert records[-1] == {"done": True}
+    vals = list(handle.options(stream=True).remote(None))
+    assert [v["token"] for v in vals[:-1]] == list(range(300))
