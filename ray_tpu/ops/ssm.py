@@ -10,11 +10,11 @@ for a prompt (``mamba2_prefill``, chunked) and for one token a slot
 thousands of steps. ``X``, ``B``, ``C`` come in the model's dtype and enter
 the products as they are, accumulated in float32.
 
-The serving engine pads a prefill batch to 8 rows x bucket. A position at or
-past a row's true length takes ``dt = 0``: the decay is then 1 and the input
-0, so ``S`` stays what the last real token left, and the convolution rows
-kept for decode are the last REAL inputs. What the padded positions output is
-never read.
+The serving engine pads a prefill batch to a few rows x bucket. A position
+at or past a row's true length takes ``dt = 0``: the decay is then 1 and the
+input 0, so ``S`` stays what the last real token left, and the convolution
+rows kept for decode are the last REAL inputs. What the padded positions
+output is never read.
 
 Plain ``jax.numpy`` / ``lax``: XLA fuses the elementwise state update and
 runs the chunk products on the MXU. A Pallas scan is the next step only if a

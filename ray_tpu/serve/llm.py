@@ -10,8 +10,10 @@ GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
 - CONTINUOUS batching: new requests are prefilled into free slots while
   other slots keep decoding — no batch barrier (Orca-style iteration-level
   scheduling);
-- prefill is bucketed (prompt padded to the next bucket) so each bucket
-  compiles once; decode is one compiled multi-step program (T tokens per
+- prefill is bucketed (prompt padded to the next bucket) and batched at a
+  few row counts (as many rows as the group admitted needs), so each bucket
+  compiles a fixed handful of programs, all when the bucket is first met;
+  decode is one compiled multi-step program (T tokens per
   host round trip, so per-program dispatch and the host sync amortize);
 - per-request metrics: TTFT (first token latency) and decode tok/s, scraped
   by bench_serve.py for the BASELINE req/s + p50 TTFT headline.
@@ -54,6 +56,18 @@ SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
 MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                 "moe_experts_touched", "moe_expert_load_max")
+# the row counts a paged prefill program is compiled at (those that fit the
+# slots): a group of one bucket takes the smallest that holds it. Two, not
+# the four powers of two up to 8: every count is one more program to bring
+# up a bucket (2.3 s each for the hybrid family, from a warm cache), a lone
+# request needs 1, and 4 leaves no group more than 2 rows of padding
+PREFILL_ROWS = (1, 4)
+# padded prompt tokens ONE iteration may prefill (at least one request).
+# Streams get their tokens once an iteration, so a burst is admitted over
+# several, a decode chunk between: who decodes, and the burst's own first
+# tokens, wait for about 0.2 s of prefill and not for all of it; and a closed
+# loop of callers cannot fall into ONE cohort that starts and ends together
+PREFILL_TOKENS_PER_ITER = 8192
 
 
 def _model_of(config):
@@ -153,7 +167,8 @@ class LLMEngine:
     - ``iters``, ``iter_ns``: busy iterations of ``_step`` and their time.
       ``phase_ns``: the same time split into the six phases that partition
       an iteration: ``admit`` (pull requests, pages, slots),
-      ``prefill_dispatch`` (``_prefill_group``, host side),
+      ``prefill_dispatch`` (``_prefill_group``, host side; for a bucket
+      met for the first time also ``_bring_up``),
       ``decode_dispatch`` (key split + the decode call), ``device_get`` (the
       one host sync a chunk), ``emit`` (tokens to requests and streams),
       ``retire``. ``idle_ns``: iterations with no slot in use (a 10 ms
@@ -162,7 +177,11 @@ class LLMEngine:
     - ``prefill_calls``, ``prefill_rows_real``, ``prefill_rows_padded``,
       ``prefill_tokens_real``, ``prefill_tokens_padded``: prefill programs
       dispatched, their rows holding a request and rows in all, prompt
-      tokens and rows x bucket: what padding to 8 rows x bucket costs.
+      tokens and rows x bucket: what padding a group to the next compiled
+      row count (``PREFILL_ROWS``) and a prompt to its bucket costs.
+      ``prefill_calls_by_rows``: the same calls by the row count each ran
+      at, one key a compiled row count. The all-pad calls that bring a
+      bucket's programs up prefill no request and enter none of these.
     - ``queue_wait_hist``: ``edges_s`` and ``counts`` (one more than
       edges: under the first edge, between edges, over the last) of
       admission instant minus submission, one count an admitted request.
@@ -295,6 +314,11 @@ class LLMEngine:
         self._prefill_rows_padded = 0
         self._prefill_tokens_real = 0
         self._prefill_tokens_padded = 0
+        # the dense mode prefills one request a program
+        self._prefill_rows = tuple(
+            r for r in PREFILL_ROWS if r <= num_slots) if paged else (1,)
+        self._prefill_calls_by_rows = dict.fromkeys(self._prefill_rows, 0)
+        self._buckets_up: set = set()
         self._moe_counts = np.zeros((4,), np.int64)
         self._cache_stats = self._describe_cache()
         self._queue_wait_counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
@@ -403,6 +427,7 @@ class LLMEngine:
             "prefill_rows_padded": self._prefill_rows_padded,
             "prefill_tokens_real": self._prefill_tokens_real,
             "prefill_tokens_padded": self._prefill_tokens_padded,
+            "prefill_calls_by_rows": dict(self._prefill_calls_by_rows),
             "queue_wait_hist": {"edges_s": list(QUEUE_WAIT_EDGES_S),
                                 "counts": list(self._queue_wait_counts)},
             "compiles": host.compiles,
@@ -520,22 +545,30 @@ class LLMEngine:
     def _count_prefill(self, rows_real: int, rows_padded: int,
                        tokens_real: int, bucket: int) -> None:
         self._prefill_calls += 1
+        self._prefill_calls_by_rows[rows_padded] += 1
         self._prefill_rows_real += rows_real
         self._prefill_rows_padded += rows_padded
         self._prefill_tokens_real += tokens_real
         self._prefill_tokens_padded += rows_padded * bucket
 
     def _admit_paged_batched(self) -> List[tuple]:
-        """Pull every admissible request and group by prefill bucket: ONE
-        batched prefill program per group, as (chunk, bucket, size) for
-        ``_prefill_group``. Every group pads to a FIXED batch size
-        (min(8, num_slots)), which keeps ONE compile per bucket; what the
-        padding costs is counted where the program is dispatched
-        (``prefill_rows_*`` / ``prefill_tokens_*`` of ``stats()``; the
+        """Pull the admissible requests, in order, up to one iteration's
+        budget of padded prompt tokens (``PREFILL_TOKENS_PER_ITER``; what is
+        over it heads the next iteration's line), and group them by prefill
+        bucket: ONE batched prefill program per group, as (chunk, bucket,
+        size) for ``_prefill_group``. ``size``, the program's rows, follows
+        what was admitted: the smallest of the bucket's row counts
+        (``_rows_of``) that holds the group; a group over the largest is cut
+        into programs of the largest first. So a bucket has one compiled
+        program a row count, all brought up when the bucket is first met
+        (``_bring_up``). What the padding that is left costs is counted
+        where the program is dispatched (``prefill_rows_*`` /
+        ``prefill_tokens_*`` / ``prefill_calls_by_rows`` of ``stats()``; the
         benchmark's ``prefill_padding_share``)."""
         now = time.perf_counter()
         free_slots = [i for i, r in enumerate(self._slots) if r is None]
         admitted: List[tuple] = []  # (req, slot, pages, bucket)
+        budget = PREFILL_TOKENS_PER_ITER
         while free_slots:
             if self._admit_backlog:
                 req = self._admit_backlog.popleft()
@@ -546,6 +579,10 @@ class LLMEngine:
                     break
             n = len(req.tokens)
             bucket = self._bucket_for(n)
+            if admitted and bucket > budget:
+                # the next iteration's first (the head of the line)
+                self._admit_backlog.appendleft(req)
+                break
             need = max(bucket // self.page_size,
                        -(-(n + req.max_tokens) // self.page_size))
             if need > self.allocator.total - 1:
@@ -569,37 +606,69 @@ class LLMEngine:
             self._slot_pages[slot] = pages
             self._count_admitted(req, now)
             admitted.append((req, slot, pages, bucket))
+            budget -= bucket
         by_bucket: Dict[int, List[tuple]] = {}
         for item in admitted:
             by_bucket.setdefault(item[3], []).append(item)
-        size = min(8, self.num_slots)
-        return [(group[i:i + size], bucket, size)
-                for bucket, group in by_bucket.items()
-                for i in range(0, len(group), size)]
+        groups = []
+        for bucket, group in by_bucket.items():
+            rows = self._rows_of(bucket)
+            for i in range(0, len(group), rows[-1]):
+                chunk = group[i:i + rows[-1]]
+                groups.append((chunk, bucket,
+                               next(r for r in rows if r >= len(chunk))))
+        return groups
 
-    def _prefill_group(self, chunk: List[tuple], bucket: int, size: int) -> None:
-        """One batched prefill program for `chunk` (padded to `size` rows;
-        pad rows write to the trash page and are discarded)."""
+    def _rows_of(self, bucket: int) -> tuple:
+        """The row counts of ``bucket``'s programs: those an iteration's
+        budget can fill."""
+        fit = tuple(r for r in self._prefill_rows
+                    if r * bucket <= PREFILL_TOKENS_PER_ITER)
+        return fit or self._prefill_rows[:1]
+
+    def _run_prefill(self, chunk: List[tuple], bucket: int, size: int):
+        """The prefill program of `bucket` at `size` rows over `chunk`; pad
+        rows write to the trash page and the trash state row and are
+        discarded. Returns the rows' first tokens, [size], on the device."""
         jnp = self._jnp
         n_pages = bucket // self.page_size
         tokens = np.zeros((size, bucket), np.int32)
         page_arr = np.zeros((size, n_pages), np.int32)  # pad rows -> trash
         lengths = np.ones((size,), np.int32)
         slots = np.full((size,), self.num_slots, np.int32)  # pad rows -> trash
-        tokens_real = 0
         for row, (req, slot, pages, _b) in enumerate(chunk):
             n = len(req.tokens)
             tokens[row, :n] = req.tokens
             page_arr[row] = pages[:n_pages]
             lengths[row] = min(n, bucket)
             slots[row] = slot
-            tokens_real += n
-        self._count_prefill(len(chunk), size, tokens_real, bucket)
         args = [jnp.asarray(tokens), jnp.asarray(page_arr), jnp.asarray(lengths)]
         if self._slot_state:
             args.append(jnp.asarray(slots))
         logits, self.cache = self._prefill(self.params, self.cache, *args)
-        firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [size]
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def _bring_up(self, bucket: int) -> None:
+        """A bucket met for the first time: compile (or load from the
+        persistent cache) its program at EVERY row count now, so that no
+        later group meets a new shape, by one call each whose rows are all
+        pad rows (largest first: the device runs one while the host brings
+        the next up). The read of a row is a small program a row count too."""
+        t0 = time.perf_counter()
+        rows = self._rows_of(bucket)
+        for size in reversed(rows):
+            self._run_prefill([], bucket, size)[0]
+        self._buckets_up.add(bucket)
+        logger.info("prefill bucket %d: programs of %s rows up in %.2f s",
+                    bucket, rows, time.perf_counter() - t0)
+
+    def _prefill_group(self, chunk: List[tuple], bucket: int, size: int) -> None:
+        """One batched prefill program for `chunk`, at the `size` rows that
+        admission chose for it, and each request's slot made live."""
+        jnp = self._jnp
+        self._count_prefill(len(chunk), size,
+                            sum(len(req.tokens) for req, *_ in chunk), bucket)
+        firsts = self._run_prefill(chunk, bucket, size)
         for row, (req, slot, pages, _b) in enumerate(chunk):
             n = len(req.tokens)
             trow = np.zeros((self.pages_per_slot,), np.int32)
@@ -705,6 +774,9 @@ class LLMEngine:
             groups = self._admit()
         t1 = clock()
         for chunk, bucket, size in groups:
+            if bucket not in self._buckets_up:
+                with span("engine.prefill_bring_up", bucket=bucket):
+                    self._bring_up(bucket)
             with span("engine.prefill_dispatch", bucket=bucket,
                       rows_real=len(chunk), rows_padded=size,
                       state_rows=len(chunk) if self._slot_state else 0):

@@ -13,6 +13,10 @@ variable itself) or ``<checkout>/.jax_cache``, derived from this package's
 location. Never the cwd: a cluster worker may run from a staged
 runtime-env directory.
 
+Every compile is kept, not only those over jax's default of one second: a
+replica's small programs (a 128-token prefill bucket at each row count, the
+eager programs around them) are most of what a warm start still compiled.
+
 A process pinned to the CPU backend (``JAX_PLATFORMS=cpu``: the test suite,
 ``chip_smoke.py --rehearse``) gets no persistent cache from here. XLA:CPU
 stores ahead-of-time code whose recorded machine features it then refuses to
@@ -58,6 +62,8 @@ def enable_compile_cache() -> Optional[str]:
     if not _listening:
         jax.monitoring.register_event_listener(_on_event)
         _listening = True
+    # jax's default keeps only what took over a second to compile
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     pinned = os.environ.get(CACHE_DIR_ENV)
     if pinned:
         return pinned

@@ -41,29 +41,32 @@ def _last_json(proc):
 CACHE_PROBE = (
     "from ray_tpu.utils.compile_cache import enable_compile_cache\n"
     "import jax\n"
-    "print(enable_compile_cache()); print(jax.config.jax_compilation_cache_dir)"
+    "print(enable_compile_cache()); print(jax.config.jax_compilation_cache_dir)\n"
+    "print(jax.config.jax_persistent_cache_min_compile_time_secs)"
 )
 
 
 @pytest.mark.parametrize("case", ["pinned", "default", "cpu"])
 def test_compile_cache_placement(case, tmp_path):
-    """Variable set: nothing overridden in code. Unset: the fixed path under
-    the checkout, from any cwd. Pinned to the CPU backend: no cache."""
+    """Variable set: the place is not overridden in code. Unset: the fixed
+    path under the checkout, from any cwd. Pinned to the CPU backend: no
+    cache. Wherever it is, it keeps every compile (jax's default: those
+    over a second)."""
     checkout_cache = os.path.join(REPO, ".jax_cache")
     if case == "pinned":
         pinned = str(tmp_path / "operator_cache")
         out = _run(CACHE_PROBE, cwd=str(tmp_path), env={
             "JAX_COMPILATION_CACHE_DIR": pinned, "JAX_PLATFORMS": None})
-        assert out.stdout.split() == [pinned, pinned], out.stderr[-2000:]
+        assert out.stdout.split() == [pinned, pinned, "0.0"], out.stderr[-2000:]
     elif case == "default":
         outs = [_run(CACHE_PROBE, cwd=cwd, env={"JAX_PLATFORMS": None})
                 for cwd in (str(tmp_path), REPO)]
         for out in outs:
-            assert out.stdout.split() == [checkout_cache, checkout_cache], \
-                out.stderr[-2000:]
+            assert out.stdout.split() == [checkout_cache, checkout_cache,
+                                          "0.0"], out.stderr[-2000:]
     else:
         out = _run(CACHE_PROBE, cwd=str(tmp_path), env={"JAX_PLATFORMS": "cpu"})
-        assert out.stdout.split() == ["None", "None"], out.stderr[-2000:]
+        assert out.stdout.split() == ["None", "None", "0.0"], out.stderr[-2000:]
 
 
 def test_compile_cache_counts_hits_and_misses():

@@ -111,16 +111,16 @@ def _decode_shapes(v5e, pool_pages=POOL_PAGES):
     return config, (params, cache, ints, ints, active, table, key)
 
 
-def _prefill_shapes(v5e, bucket, pool_pages=POOL_PAGES):
+def _prefill_shapes(v5e, bucket, pool_pages=POOL_PAGES, rows=8, config=None):
     one = SingleDeviceSharding(v5e.devices[0])
-    config = _llama_1b(2, attention_impl="flash")
+    config = config or _llama_1b(2, attention_impl="flash")
     params = _on(one, jax.eval_shape(lambda k: llama_init(config, k),
                                      jax.random.key(0)))
     cache = _on(one, jax.eval_shape(
         lambda: pd.init_paged_cache(config, pool_pages, PAGE)))
-    tokens = jax.ShapeDtypeStruct((8, bucket), jnp.int32, sharding=one)
-    pages = jax.ShapeDtypeStruct((8, bucket // PAGE), jnp.int32, sharding=one)
-    lengths = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+    tokens = jax.ShapeDtypeStruct((rows, bucket), jnp.int32, sharding=one)
+    pages = jax.ShapeDtypeStruct((rows, bucket // PAGE), jnp.int32, sharding=one)
+    lengths = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one)
     return config, (params, cache, tokens, pages, lengths)
 
 
@@ -140,6 +140,25 @@ def test_prefill_bucket_compiles(v5e):
     config, args = _prefill_shapes(v5e, 512)
     prefill = pd.make_paged_prefill_fn(config, PAGE)
     assert "tpu_custom_call" in prefill.lower(*args).compile().as_text()
+
+
+def test_one_row_prefill_of_the_largest_bucket_compiles(v5e):
+    """The engine compiles a bucket's prefill program at each row count of
+    ``serve.llm.PREFILL_ROWS`` (PR 30). This is the smaller, 1 x 2048, at
+    Mistral-7B-v0.3's published widths (two of its layers: the rest repeat
+    them). Its temporaries, noted from this compile (no device number):
+    135,153,152 bytes, where the 8-row program of the same bucket, which the
+    engine ran for every group before, takes 2.42 GB (PERF.md 4)."""
+    config = LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=4096, rope_theta=1e6, attention_impl="flash")
+    _, args = _prefill_shapes(v5e, 2048, rows=1, config=config)
+    compiled = pd.make_paged_prefill_fn(config, PAGE).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.42e9 / 8
+    assert mem.alias_size_in_bytes == _pool_bytes(args[1])
 
 
 def _pool_bytes(cache):

@@ -6,13 +6,15 @@ import logging
 import os
 import threading
 import time
-from concurrent.futures import Future
 
 import numpy as np
 import pytest
 
+from prefill_groups import (GROUPS, calls_by_rows,
+                            check_rows_follow_the_group, request,
+                            submit_together)
 from ray_tpu.serve import llm
-from ray_tpu.serve.llm import GenRequest, LLMEngine
+from ray_tpu.serve.llm import LLMEngine
 
 
 @pytest.fixture(scope="module")
@@ -29,20 +31,6 @@ def engine():
     eng.generate([5, 6, 7], max_tokens=5, timeout=300)  # compile both programs
     yield eng
     eng.stop()
-
-
-def submit_together(engine, requests):
-    """All of ``requests`` in ONE admission: the loop's ``get_nowait`` waits
-    for the queue's mutex while they are appended."""
-    with engine._pending.mutex:
-        engine._pending.queue.extend(requests)
-    return [r.future.result(timeout=300) for r in requests]
-
-
-def request(tokens, max_tokens=4, waited_s=0.0):
-    return GenRequest(tokens=list(tokens), max_tokens=max_tokens, eos_token=None,
-                      future=Future(),
-                      submitted_at=time.perf_counter() - waited_s)
 
 
 def test_phases_partition_the_iteration(engine):
@@ -65,11 +53,40 @@ def test_prefill_padding_is_counted_exactly(engine):
     before = engine.stats()
     submit_together(engine, [request([7 + i] * 100) for i in range(3)])
     after = engine.stats()
-    rise = {k: after[k] - before[k] for k in before if k.startswith("prefill_")}
+    rise = {k: after[k] - before[k] for k in before
+            if k.startswith("prefill_") and k != "prefill_calls_by_rows"}
+    # 3 rows of 100 tokens take the 4-row program of the 128 bucket
     assert rise == {"prefill_calls": 1, "prefill_rows_real": 3,
-                    "prefill_rows_padded": 8, "prefill_tokens_real": 300,
-                    "prefill_tokens_padded": 1024}
+                    "prefill_rows_padded": 4, "prefill_tokens_real": 300,
+                    "prefill_tokens_padded": 512}
+    assert calls_by_rows(before, after) == {1: 0, 4: 1}
     assert after["admitted"] - before["admitted"] == 3
+
+
+@pytest.mark.parametrize("n,calls", GROUPS)
+def test_prefill_rows_follow_the_group(engine, n, calls):
+    """The fixture's first request met the 128 bucket alone: every row count
+    of ``PREFILL_ROWS`` came up then, and a group of any size finds its
+    program compiled."""
+    assert tuple(engine.stats()["prefill_calls_by_rows"]) == llm.PREFILL_ROWS
+    check_rows_follow_the_group(engine, n, calls, prompt_len=90)
+
+
+def test_a_burst_is_admitted_over_iterations(engine, monkeypatch):
+    """One iteration prefills at most ``PREFILL_TOKENS_PER_ITER`` padded
+    tokens (here 4 rows of the 128 bucket): 18 requests at once enter as 4,
+    4, 4, 4 and 2, a decode chunk after each, in the order they came."""
+    monkeypatch.setattr(llm, "PREFILL_TOKENS_PER_ITER", 512)
+    before = engine.stats()
+    reqs = [request([3 + i] * 40, 9) for i in range(18)]
+    submit_together(engine, reqs)
+    after = engine.stats()
+    new = after["ring"]["rows"][-(after["iters"] - before["iters"]):]
+    col = after["ring"]["columns"].index("admitted")
+    assert [int(r[col]) for r in new if r[col]] == [4, 4, 4, 4, 2]
+    assert calls_by_rows(before, after) == {1: 0, 4: 5}
+    ttfts = [r.ttft_s for r in reqs]
+    assert all(max(ttfts[i - 4:i]) < min(ttfts[i:i + 4]) for i in (4, 8, 12, 16))
 
 
 def test_queue_wait_histogram_and_its_p90(engine):
@@ -219,7 +236,7 @@ def test_a_profile_holds_the_six_phases_and_the_programs_by_name(engine, tmp_pat
     assert {"engine." + p for p in llm.PHASES} <= names
     # state_rows: slots whose recurrent state the prefill wrote (PR 29); a
     # Llama keeps none
-    assert prefill_attrs == {"bucket": 128, "rows_real": 1, "rows_padded": 8,
+    assert prefill_attrs == {"bucket": 128, "rows_real": 1, "rows_padded": 1,
                              "state_rows": 0}
     assert "PjitFunction(paged_decode_steps)" in names
     assert "PjitFunction(paged_prefill)" in names

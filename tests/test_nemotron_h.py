@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from benchmarks.families import nemotron_h_reference as ref
+from prefill_groups import GROUPS, check_rows_follow_the_group
 from ray_tpu.models import nemotron_h as nh
 from ray_tpu.ops import moe, ssm
 
@@ -258,6 +259,28 @@ def test_a_slot_reused_after_retirement_gives_a_fresh_engines_run(tiny):
             assert fresh.generate(p, max_tokens=13, timeout=600)["tokens"] == tokens
     finally:
         fresh.stop()
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine(tiny):
+    from ray_tpu.serve.llm import LLMEngine
+
+    config, params = tiny
+    eng = LLMEngine(config, params, num_slots=12, decode_chunk=4,
+                    max_seq_len=128, prefill_buckets=[64], page_size=PAGE)
+    eng.generate([5, 6, 7], max_tokens=5, timeout=600)  # meets the 64 bucket
+    yield eng
+    eng.stop()
+
+
+@pytest.mark.parametrize("n,calls", GROUPS)
+def test_prefill_rows_follow_the_group(hybrid_engine, n, calls):
+    """As ``test_engine_tracing.py`` holds for Llama, through the one path
+    both families take: the group's row count, pad rows into the trash page
+    AND the trash state row, each request's answer its answer alone (the
+    scan stops a row at its own length; the expert product is dropless), no
+    compile after the bucket has been met."""
+    check_rows_follow_the_group(hybrid_engine, n, calls, prompt_len=50)
 
 
 def test_one_process_drives_the_engine_with_each_family(tiny):
