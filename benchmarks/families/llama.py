@@ -100,9 +100,21 @@ def set_weights(engine, params) -> None:
     engine.params = params
 
 
+def largest_prefill_rows(bucket: int) -> int:
+    """The rows of the tallest prefill program the engine brings up for
+    ``bucket``: the largest of its row counts that an iteration's budget of
+    padded tokens can fill (``serve/llm.py`` ``_rows_of``; 4 for every bucket
+    up to 2048 since PR 30)."""
+    from ray_tpu.serve.llm import PREFILL_ROWS, PREFILL_TOKENS_PER_ITER
+
+    fit = [r for r in PREFILL_ROWS if r * bucket <= PREFILL_TOKENS_PER_ITER]
+    return max(fit) if fit else min(PREFILL_ROWS)
+
+
 def serve_programs(config, deployment: Dict[str, Any]) -> Dict[str, Any]:
-    """The decode chunk over all slots and one 8-row prefill a bucket, as
-    ``LLMEngine`` builds them on a TPU (the Pallas paged-attention kernel)."""
+    """The decode chunk over all slots and the tallest prefill program of
+    each bucket (``largest_prefill_rows``), as ``LLMEngine`` builds them on a
+    TPU (the Pallas paged-attention kernel)."""
     import jax
     import jax.numpy as jnp
 
@@ -122,9 +134,10 @@ def serve_programs(config, deployment: Dict[str, Any]) -> Dict[str, Any]:
     programs = [("decode", decode, (params, cache, ints, ints, active, table, key))]
     prefill = pd.make_paged_prefill_fn(config, page)
     for bucket in dep["prefill_buckets"]:
-        programs.append((f"prefill_{bucket}", prefill, (
-            params, cache, shape((8, bucket), jnp.int32),
-            shape((8, bucket // page), jnp.int32), shape((8,), jnp.int32))))
+        rows = largest_prefill_rows(bucket)
+        programs.append((f"prefill_{rows}x{bucket}", prefill, (
+            params, cache, shape((rows, bucket), jnp.int32),
+            shape((rows, bucket // page), jnp.int32), shape((rows,), jnp.int32))))
     return {"weights": params, "state": cache, "programs": programs}
 
 

@@ -121,10 +121,14 @@ def set_weights(engine, params) -> None:
 
 
 def serve_programs(config, deployment: Dict[str, Any]) -> Dict[str, Any]:
-    """The decode chunk over all slots and one 8-row prefill a bucket, as
-    ``LLMEngine`` builds them on a TPU (the Pallas paged-attention kernel)."""
+    """The decode chunk over all slots and the tallest prefill program of
+    each bucket (``families/llama.py`` ``largest_prefill_rows``: the engine is
+    the same), as ``LLMEngine`` builds them on a TPU (the Pallas
+    paged-attention kernel)."""
     import jax
     import jax.numpy as jnp
+
+    from benchmarks.families.llama import largest_prefill_rows
 
     nh = _program()
     dep, shape = deployment, jax.ShapeDtypeStruct
@@ -141,10 +145,11 @@ def serve_programs(config, deployment: Dict[str, Any]) -> Dict[str, Any]:
     programs = [("decode", decode, (params, cache, ints, ints, active, table, key))]
     prefill = nh.make_paged_prefill_fn(config, page)
     for bucket in dep["prefill_buckets"]:
-        programs.append((f"prefill_{bucket}", prefill, (
-            params, cache, shape((8, bucket), jnp.int32),
-            shape((8, bucket // page), jnp.int32), shape((8,), jnp.int32),
-            shape((8,), jnp.int32))))
+        rows = largest_prefill_rows(bucket)
+        programs.append((f"prefill_{rows}x{bucket}", prefill, (
+            params, cache, shape((rows, bucket), jnp.int32),
+            shape((rows, bucket // page), jnp.int32), shape((rows,), jnp.int32),
+            shape((rows,), jnp.int32))))
     return {"weights": params, "state": cache, "programs": programs}
 
 

@@ -10,7 +10,9 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Any, Dict, List, Optional, Tuple
+import re
+from itertools import chain
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -80,6 +82,14 @@ def _self_times(events: List[List]) -> Dict[str, float]:
     return sums
 
 
+def _whole(events: List[List], first: int, last: int) -> List[List]:
+    """The events a profile holds whole: those that touch neither its first
+    nor its last instant. A profile taken over a span of wall time cuts the
+    program that was running at either end, and records the part it saw as an
+    event of its own; counted as a call, it makes a time a call read short."""
+    return [e for e in events if e[1] > first and e[1] + e[2] < last]
+
+
 def _ops_by_module(modules: List[List], ops: List[List]) -> Dict[str, Dict[str, float]]:
     """Total duration of each op by the module whose span holds its start."""
     import bisect
@@ -119,9 +129,15 @@ def _attribute(gap: Tuple[int, int], host: List[List]) -> str:
     return best
 
 
-def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
+def reduce(trace: Dict[str, Any], top: int = 10,
+           keep: Sequence[str] = ()) -> Dict[str, Any]:
     """busy_s and window_s are averaged over the device planes; op and
-    module sums are totals over them, in seconds."""
+    module sums are totals over them, in seconds. ``module_whole_*`` count
+    only the calls the profile holds whole (``_whole``). ``module_ops`` keeps
+    a program's twelve largest operations and, whatever their time, those a
+    ``keep`` pattern matches (a reader that knows a program's rows by one
+    small operation's shape names it there)."""
+    kept = [re.compile(k) for k in keep]
     devices = trace["devices"]
     if not devices:
         return {"planes": 0}
@@ -130,6 +146,8 @@ def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
     op_count: Dict[str, int] = {}
     mod_sum: Dict[str, float] = {}
     mod_count: Dict[str, int] = {}
+    whole_sum: Dict[str, float] = {}
+    whole_count: Dict[str, int] = {}
     mod_ops: Dict[str, Dict[str, float]] = {}
     gaps: List[Tuple[int, int]] = []
     for dev in devices.values():
@@ -146,6 +164,11 @@ def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
         for name, _s, dur in dev["modules"]:
             mod_sum[name] = mod_sum.get(name, 0.0) + dur
             mod_count[name] = mod_count.get(name, 0) + 1
+        first = min(e[1] for e in chain(ops, dev["modules"]))
+        last = max(e[1] + e[2] for e in chain(ops, dev["modules"]))
+        for name, _s, dur in _whole(dev["modules"], first, last):
+            whole_sum[name] = whole_sum.get(name, 0.0) + dur
+            whole_count[name] = whole_count.get(name, 0) + 1
         for mod, inside in _ops_by_module(dev["modules"], ops).items():
             table = mod_ops.setdefault(mod, {})
             for name, ns in inside.items():
@@ -161,10 +184,14 @@ def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
         "op_count": op_count,
         "module_s": {k: v / 1e9 for k, v in mod_sum.items()},
         "module_count": mod_count,
+        "module_whole_s": {k: v / 1e9 for k, v in whole_sum.items()},
+        "module_whole_count": whole_count,
         # a jitted partial has no name of its own ("jit__unknown(hash)"), so a
         # program is recognised by the operations that ran inside it
-        "module_ops": {m: {k: v / 1e9 for k, v in sorted(
-            t.items(), key=lambda kv: -kv[1])[:12]} for m, t in mod_ops.items()},
+        "module_ops": {m: {k: v / 1e9 for i, (k, v) in enumerate(sorted(
+            t.items(), key=lambda kv: -kv[1]))
+            if i < 12 or any(rx.search(k) for rx in kept)}
+            for m, t in mod_ops.items()},
         "device_ops": [[k, v / 1e9] for k, v in sorted(
             op_self.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": [[_attribute(g, host), (g[1] - g[0]) / 1e9]
@@ -174,15 +201,13 @@ def reduce(trace: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
 
 def sanitize(name: str) -> str:
     """Names in the result line hold no space, comma or slash."""
-    import re
-
     return re.sub(r"[^A-Za-z0-9_.\-:]", "_", name)[:64]
 
 
-def summarize_dir(trace_dir: str) -> Dict[str, Any]:
+def summarize_dir(trace_dir: str, keep: Sequence[str] = ()) -> Dict[str, Any]:
     """What the process that took the profile sends back: ``reduce`` of the
     newest trace under ``trace_dir``."""
     path = find_xplane(trace_dir) if trace_dir else None
     if path is None:
         return {"planes": 0}
-    return reduce(load_xplane(path))
+    return reduce(load_xplane(path), keep=keep)

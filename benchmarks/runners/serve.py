@@ -10,7 +10,7 @@ import time
 from typing import Any, Dict, List
 
 from benchmarks.harness import loadgen
-from benchmarks.harness.manifest import load_plugin
+from benchmarks.harness import manifest as mf
 
 PATH = "/llm"
 
@@ -28,7 +28,7 @@ def _buckets_in_play(plan, buckets: List[int]) -> List[int]:
 
 
 def make_plan(args, cfg, traffic, seed=None, rate=None):
-    gen = load_plugin("generators", traffic["generator"])
+    gen = mf.load_plugin("generators", traffic["generator"])
     params = dict(traffic["params"])
     if args.rehearse:
         params.update(traffic.get("rehearsal", {}))
@@ -59,7 +59,8 @@ def deploy(args, resolved, cfg, plan):
     address = serve.http_address()
 
     # warm every shape this cell's traffic uses, and no other: one request
-    # per prefill bucket in play (groups always pad to 8 rows) + the decode
+    # per prefill bucket in play (the engine brings up the bucket's programs
+    # at every row count it runs when it first meets it) + the decode
     rng = np.random.default_rng(0)
     warm = []
     for b in _buckets_in_play(plan, sorted(
@@ -162,10 +163,17 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
     report = handle.bench_report.remote().result(timeout=60)
     summary = None
     if args.trace:
-        summary = handle.trace_summary.remote().result(timeout=600)
+        # the operations a metric of this cell knows a program's rows by
+        manifest = mf.load_manifest()
+        specs = [mf.metric_file(m["name"], manifest=manifest)
+                 for m in mf.metrics_for(manifest, args.workload, "per_layer")]
+        keep = sorted({s["params"]["rows_from"] for s in specs
+                       if "rows_from" in s.get("params", {})})
+        summary = handle.trace_summary.remote(keep).result(timeout=600)
     return {
         "cfg": cfg, "plan_offered": plan["offered"],
         "records": result.records, "t_open": result.t_open, "t_close": result.t_close,
+        "drain_limit_s": plan["drain_limit_s"],
         "attempted": len(measured), "failed": len(measured) - len(good),
         "errors": [r.error for r in measured if r.error][:5],
         "setup_s": marks["open_wall"] - t_process,

@@ -100,10 +100,10 @@ class BenchLLM:
         jax.profiler.stop_trace()
         return True
 
-    def trace_summary(self) -> Dict[str, Any]:
+    def trace_summary(self, keep=()) -> Dict[str, Any]:
         from benchmarks.harness.trace_reduce import summarize_dir
 
-        return summarize_dir(self._trace_dir)
+        return summarize_dir(self._trace_dir, keep)
 
     # ---------------------------------------------------------- reference
     def check_requests(self, samples: List[Dict[str, Any]], length: int,

@@ -96,6 +96,33 @@ def test_every_cell_resolves_and_reports(manifest):
         assert set(m.get("workloads", [])) <= cells
 
 
+def test_every_moves_names_an_end_to_end_metric_of_the_same_cells(manifest):
+    """A per-layer metric, in ``BENCHMARK.json`` and in its own file, moves an
+    end-to-end metric that EVERY cell it lists reports (a cell that judges
+    another one reads the quantity under another name: the ``.chat`` twins);
+    and every file under ``metrics/`` is a metric of the manifest whose reader
+    is there."""
+    cells = [w["name"] for w in manifest["workloads"]]
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    per = {m["name"]: m for m in manifest["per_layer"]}
+    for m in per.values():
+        reported_in = e2e[m["moves"]].get("workloads", cells)
+        for cell in m.get("workloads", []):
+            assert cell in reported_in, (m["name"], m["moves"], cell)
+    base = os.path.join(mf.ROOT, manifest["paths"][0])
+    for fname in sorted(os.listdir(os.path.join(base, "metrics"))):
+        with open(os.path.join(base, "metrics", fname)) as f:
+            spec = json.load(f)
+        assert fname == spec["name"] + ".json"
+        assert spec["name"] in e2e or spec["name"] in per, fname
+        assert os.path.isfile(os.path.join(base, "readers", spec["reader"] + ".py")), \
+            (fname, spec["reader"])
+        if spec["name"] in per:
+            assert spec["moves"] == per[spec["name"]]["moves"], fname
+        else:
+            assert "moves" not in spec, fname
+
+
 def test_configs_keep_the_published_widths(manifest):
     """EVERY configuration names the file that holds its source's published
     shape (``benchmarks/published/<name>.json``), and differs from it in
@@ -192,6 +219,7 @@ ctx = mf.load_plugin("runners", cfg["kind"]).run()
 contract.test_top_level_and_limits(m)
 contract.test_names_units_and_keys(m)
 contract.test_every_cell_resolves_and_reports(m)
+contract.test_every_moves_names_an_end_to_end_metric_of_the_same_cells(m)
 contract.test_configs_keep_the_published_widths(m)
 # every family of the copy: a configuration, weights at tiny widths, one
 # call of its reference

@@ -17,7 +17,7 @@ from benchmarks.families import nemotron_h as family
 from benchmarks.harness import manifest as mf
 from benchmarks.harness import reference as ref
 from benchmarks.harness.weights import load_config_file
-from benchmarks.readers import bytes_roofline, ops_share, program_time
+from benchmarks.readers import bytes_roofline, module_time, ops_share
 
 CONFIG_FILE = os.path.join(mf.ROOT, "benchmarks", "configs",
                            "nemotron-3-nano-30b-a3b-serve.json")
@@ -66,7 +66,7 @@ def test_hybrid_family_gives_the_serve_surface(hybrid_setup):
         assert callable(getattr(family, name)), name
     sized = family.serve_programs(config, cfg["deployment"])
     assert [p[0] for p in sized["programs"]] == [
-        "decode", "prefill_128", "prefill_512"]
+        "decode", "prefill_4x128", "prefill_4x512"]
     assert set(sized["state"]._fields) == {"k", "v", "ssm", "conv"}
     again = family.make_weights(config, 3_000_000_019)
     assert all(bool(jnp.array_equal(a, b)) for a, b in
@@ -133,7 +133,17 @@ def hybrid_ctx():
                            "hybrid_trace.json")) as f:
         rec = json.load(f)
     polls = [(t, s) for t, s in rec["polls"]]
-    return {"trace": rec["trace"], "cfg": load_config_file(CONFIG_FILE),
+    # the record predates the reducer's tables of whole calls and of a
+    # program's operations (PR 32): its calls are taken as whole, and its
+    # prefill program ran 8 rows a call (the engine before PR 30)
+    trace = rec["trace"]
+    trace["module_whole_s"] = trace["module_s"]
+    trace["module_whole_count"] = trace["module_count"]
+    trace["module_ops"] = {name: {
+        "nemotron_h_prefill.2 = bf16[8,32,512,128]{3,2,1,0:T(8,128)(2,1)S(1)} "
+        "custom-call(bf16[8,32,512,128]{3,2,1,0:T(8,128)(2,1)S(1)} %x, ": 0.001}
+        for name in trace["module_s"] if name.startswith("jit_nemotron_h_prefill")}
+    return {"trace": trace, "cfg": load_config_file(CONFIG_FILE),
             "device_report": {"kind": "TPU v5 lite"},
             "marks": {"polls": polls, "open": polls[0][0] - 1,
                       "close": polls[-1][0] + 1}}
@@ -147,11 +157,11 @@ def _hybrid_read(ctx, name):
 def test_hybrid_readers_on_a_recorded_context(hybrid_ctx):
     trace = hybrid_ctx["trace"]
     decode = trace["module_s"]["jit_nemotron_h_decode_steps(10832319702325659996)"]
-    # 6 calls of 8 steps took 0.874 s; 7 prefill calls 1.285 s
+    # 6 calls of 8 steps took 0.874 s; 7 prefill calls of 8 rows 1.285 s
     assert _hybrid_read(hybrid_ctx, "decode_device_per_step.hybrid") \
         == pytest.approx(1e3 * decode / 48)
     assert _hybrid_read(hybrid_ctx, "prefill_device_per_call.hybrid") \
-        == pytest.approx(1e3 * 1.285057821 / 7)
+        == pytest.approx(1e3 * 1.285057821 / (7 * 8))
     # one fusion a layer and tick holds both expert products: 5 x 48 of them
     moe, n_moe = ops_share.ops_seconds_and_count(
         trace, mf.metric_file("moe_decode_share")["params"]["ops"])
@@ -219,7 +229,8 @@ def test_hybrid_readers_read_nothing_from_a_program_without_the_family(hybrid_ct
         (t, {k: v for k, v in s.items() if not k.startswith("moe_")})
         for t, s in hybrid_ctx["marks"]["polls"]]
     assert _hybrid_read(hybrid_ctx, "moe_decode_roofline") is None
-    assert program_time.read(bare, {"module": "^jit_paged_decode",
-                                    "steps_key": "decode_chunk"}) \
+    assert module_time.read(bare, {"module": "^jit_paged_decode",
+                                   "steps_key": "decode_chunk",
+                                   "cut_at_edges": False}) \
         == pytest.approx(1e3 * 2.0 / (4 * 8))
     assert bytes_roofline.read(bare, {"ops": "nothing", "bytes": "ssm_update_bytes"}) is None

@@ -3,12 +3,15 @@ import math
 
 import pytest
 
+from benchmarks.harness import manifest as mf
+from benchmarks.harness import trace_reduce
 from benchmarks.harness.loadgen import RequestRecord
 from benchmarks.readers import (
     engine_step_wall, generator_lag, idle_share, ingress_overhead, input_wait,
     kernel_roofline, module_time, peak_hbm, report_stall, report_wait,
-    serve_token_rate,
-    slot_occupancy, tpot_percentile, train_mfu, train_token_rate, ttft_percentile)
+    ring_percentile, serve_token_rate,
+    slot_occupancy, tpot_percentile, train_mfu, train_token_rate, ttft_mean,
+    ttft_percentile)
 
 
 def rec(i, due, first, n, gap, measured=True, error=None, engine_latency=None):
@@ -41,6 +44,18 @@ def test_failed_requests_count_as_missing(serve_ctx):
     serve_ctx["records"][1].error = "timeout"
     assert ttft_percentile.read(serve_ctx, {"q": 0.9}) == math.inf
     assert ttft_percentile.read(serve_ctx, {"q": 0.5}) < 1.0
+
+
+def test_ttft_mean_counts_a_failed_request_as_the_drain_limit(serve_ctx):
+    serve_ctx["drain_limit_s"] = 60.0
+    # ten measured requests, first tokens 0.50, 0.51 ... 0.59 s after due
+    assert ttft_mean.read(serve_ctx, {}) == pytest.approx(0.545)
+    serve_ctx["records"][0].error = "HTTP 500"      # it had waited 0.50 s
+    serve_ctx["records"][9].arrivals = []           # no token ever came
+    assert ttft_mean.read(serve_ctx, {}) == pytest.approx(
+        (sum(0.5 + 0.01 * i for i in range(1, 9)) + 2 * 60.0) / 10)
+    assert ttft_percentile.read(serve_ctx, {"q": 0.9}) == math.inf
+    assert ttft_mean.read({"records": [], "drain_limit_s": 60.0}, {}) is None
 
 
 def test_serve_token_rate_is_read_between_arrivals():
@@ -105,6 +120,11 @@ def test_trace_readers():
                           "jit_step_fn(3)": 2.7},
              "module_count": {"jit__unknown(1)": 8, "jit__unknown(2)": 2,
                               "jit_step_fn(3)": 3},
+             # of the eight decode calls the profile cut one at either edge
+             "module_whole_s": {"jit__unknown(1)": 2.1, "jit__unknown(2)": 0.6,
+                                "jit_step_fn(3)": 0.9},
+             "module_whole_count": {"jit__unknown(1)": 6, "jit__unknown(2)": 2,
+                                    "jit_step_fn(3)": 1},
              "module_ops": {"jit__unknown(1)": {"paged_attention.10 = (f32[64": 0.2},
                             "jit__unknown(2)": {
                                 "closed_call.15 = bf16[8,32,2048,128]{3} "
@@ -118,10 +138,14 @@ def test_trace_readers():
     assert idle_share.read(ctx, {}) == pytest.approx(25.0)
     assert peak_hbm.read(ctx, {}) == pytest.approx(7.0)
     assert module_time.read(ctx, {"contains": "^paged_attention",
+                                  "steps_key": "decode_chunk"}) == pytest.approx(43.75)
+    assert module_time.read(ctx, {"contains": "^paged_attention", "cut_at_edges": False,
                                   "steps_key": "decode_chunk"}) == pytest.approx(37.5)
     assert module_time.read(ctx, {"contains": r"custom-call\(bf16\[8,32,\d+,128\]",
                                   "without": "^paged_attention"}) == pytest.approx(300.0)
-    assert module_time.read(ctx, {"module": "^jit_step_fn"}) == pytest.approx(900.0)
+    # the training runner starts and stops its profile between steps
+    assert module_time.read(ctx, {"module": "^jit_step_fn",
+                                  "cut_at_edges": False}) == pytest.approx(900.0)
     assert module_time.read(ctx, {"contains": "nothing"}) is None
     # 10,000 live tokens through the traced interval
     r = rec(0, 0.0, 1.0, 50, 0.1)
@@ -133,3 +157,113 @@ def test_trace_readers():
     need = 500 * (2 * live * 8 * 128 * 2 + 2 * 64 * 32 * 128 * 2)
     assert share == pytest.approx(100 * need / 819e9 / 0.25)
     assert kernel_roofline.read({"trace": {}, "cfg": cfg}, {"pattern": "x", "kind": "flash_fwd"}) is None
+
+
+# ---- a profile over a span of wall time, as the serving runner takes it ----
+MS = 1_000_000  # ns
+FLASH = ("closed_call.14 = bf16[{r},32,2048,128]{{3,2,1,0:T(8,128)(2,1)S(1)}} "
+         "custom-call(bf16[{r},32,2048,128]{{3,2,1,0:T(8,128)(2,1)S(1)}} %q, ")
+CONCAT = ("custom-call.7 = bf16[4,32,2048,128]{3,2,1,0:T(8,128)(2,1)S(1)} "
+          "custom-call(bf16[1,32,2048,128]{3,2,1,0:T(8,128)(2,1)S(1)} %slice-done, ")
+
+
+def _call(ops, modules, name, start, dur, rows=None):
+    """One call of program ``name``: thirteen fusions larger than its flash
+    call, so that only a ``keep`` pattern holds the flash call in the table."""
+    modules.append([name, start, dur])
+    inside = [[f"fusion.{i} = bf16[2048,14336]{{1,0}} fusion(", start + i * dur // 20,
+               dur // 25] for i in range(13)]
+    if rows is not None:
+        inside.append([FLASH.format(r=rows), start + 14 * dur // 20, dur // 100])
+        if rows == 4:   # four slices of one row each, put together: not the rows
+            inside.append([CONCAT, start + 15 * dur // 20, dur // 50])
+    ops.extend(inside)
+
+
+@pytest.fixture
+def wall_profile():
+    """4-row calls of 200 ms and 1-row calls of 50 ms, a decode chunk of 80 ms
+    between; the profile begins 100 ms before a 4-row call ends and stops
+    20 ms into a 1-row call."""
+    ops, modules = [], []
+    four, one, decode = ("jit_paged_prefill(11)", "jit_paged_prefill(22)",
+                         "jit_paged_decode_steps(33)")
+    t = 0
+    for name, dur, rows in [(four, 100 * MS, 4), (decode, 80 * MS, None),
+                            (four, 200 * MS, 4), (one, 50 * MS, 1),
+                            (decode, 80 * MS, None), (four, 200 * MS, 4),
+                            (one, 50 * MS, 1), (decode, 80 * MS, None),
+                            (one, 20 * MS, 1)]:
+        _call(ops, modules, name, t, dur, rows)
+        t += dur + 5 * MS
+    return {"devices": {"/device:TPU:0": {"ops": ops, "modules": modules}},
+            "host": []}
+
+
+@pytest.mark.parametrize("params,expected", [
+    # a row: (2 x 200 + 2 x 50) ms over 2 x 4 + 2 x 1 rows
+    ({"module": "^jit_paged_prefill", "rows_from": "metric"}, 50.0),
+    # a call, whatever its rows
+    ({"module": "^jit_paged_prefill"}, 125.0),
+    # a step of a call that holds eight
+    ({"module": "^jit_paged_decode", "steps_key": "decode_chunk"}, 10.0),
+    # with the two cut calls counted as calls: what the reader did before
+    ({"module": "^jit_paged_prefill", "cut_at_edges": False}, 620.0 / 6),
+    # no such program in the trace
+    ({"module": "^jit_nemotron_h_prefill", "rows_from": "metric"}, None),
+    # a program whose rows no operation names
+    ({"module": "^jit_paged_decode", "rows_from": "metric"}, None),
+])
+def test_module_time_leaves_out_cut_calls_and_reads_a_row(wall_profile, params,
+                                                          expected):
+    rows_from = mf.metric_file("prefill_device_per_call")["params"]["rows_from"]
+    if params.get("rows_from") == "metric":
+        params = {**params, "rows_from": rows_from}
+    trace = trace_reduce.reduce(wall_profile, keep=[rows_from])
+    ctx = {"trace": trace, "cfg": {"deployment": {"decode_chunk": 8}}}
+    got = module_time.read(ctx, params)
+    assert got is None if expected is None else got == pytest.approx(expected)
+
+
+def test_the_reducer_keeps_the_operation_a_pattern_names(wall_profile):
+    rows_from = mf.metric_file("prefill_device_per_call")["params"]["rows_from"]
+    bare = trace_reduce.reduce(wall_profile)
+    assert not any("closed_call" in k for k in bare["module_ops"]["jit_paged_prefill(11)"])
+    assert module_time.read({"trace": bare}, {"module": "^jit_paged_prefill",
+                                              "rows_from": rows_from}) is None
+    kept = trace_reduce.reduce(wall_profile, keep=[rows_from])
+    assert len(kept["module_ops"]["jit_paged_prefill(11)"]) == 13
+    assert kept["module_count"]["jit_paged_prefill(11)"] == 3
+    assert kept["module_whole_count"] == {
+        "jit_paged_prefill(11)": 2, "jit_paged_prefill(22)": 2,
+        "jit_paged_decode_steps(33)": 3}
+
+
+# ---- the flight recorder's ring: which shape admission settled in ----
+def _ring_ctx(admitted):
+    """Window 100-150 on the runner's clock (wall = clock + 1000); an
+    iteration every 0.4 s from 1101, admitting what ``admitted`` cycles
+    through; one iteration before the window and one inside the profiler's
+    call (121-127) admit 30 and are left out."""
+    cols = ["start", "admit", "prefill_dispatch", "decode_dispatch",
+            "device_get", "emit", "retire", "active", "admitted", "retired"]
+    rows = [[1090.0, 0, 0.2, 0, 0.1, 0, 0, 28.0, 30.0, 0.0],
+            [1123.0, 0, 0.2, 0, 0.1, 0, 0, 28.0, 30.0, 0.0]]
+    for i in range(45):
+        rows.append([1101.0 + 0.4 * i, 0, 0.2, 0, 0.1, 0, 0, 28.0,
+                     float(admitted[i % len(admitted)]), 0.0])
+    stats = {"ring": {"columns": cols, "rows": rows}}
+    return {"marks": {"open": 100.0, "close": 150.0, "open_wall": 1100.0,
+                      "trace_call": (121.0, 127.0), "polls": [(110.0, stats)]}}
+
+
+@pytest.mark.parametrize("admitted,expected", [
+    ([3, 4, 3, 4], 4.0),                       # the smooth loop: ring 3434...
+    ([10, 22, 0, 0, 0, 0, 0, 0, 0], 22.0),     # one cohort: ring ++0000000
+])
+def test_admit_burst_says_which_shape_the_loop_is_in(admitted, expected):
+    params = mf.metric_file("admit_burst_p90")["params"]
+    assert ring_percentile.read(_ring_ctx(admitted), params) == expected
+    assert ring_percentile.read({}, params) is None
+    assert ring_percentile.read(_ring_ctx(admitted),
+                                {"column": "nothing", "q": 0.9}) is None
