@@ -41,9 +41,9 @@ from typing import Any, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.decode import sample_token
 from ray_tpu.models.paged_decode import (
-    _paged_attention, _scatter_prompt_rows_full, _scatter_token_rows)
+    _paged_attention, _scatter_prompt_rows_full, _scatter_token_rows,
+    sample_token)
 from ray_tpu.ops import ssm
 from ray_tpu.ops.moe import relu2_mlp, routed_experts
 from ray_tpu.ops.norms import rms_norm

@@ -7,9 +7,13 @@ owns pages ``l*P .. l*P + P - 1`` (P = total_pages) and a page id means the
 same page of every layer's block. Each slot owns a list of page ids
 recorded in a device block table [num_slots, max_pages_per_slot].
 HBM is committed per-request (ceil((prompt+max_tokens)/page_size) pages),
-not per-slot*max_seq — so slot count is bounded by real demand, and mixed
-short/long workloads pack 3-8x more concurrent requests into the same HBM
-than the dense slotted cache (models/decode.py).
+not per-slot*max_seq — so slot count is bounded by real demand, and short
+requests do not pay for max_seq rows.
+
+Prefill is one compiled program per prompt bucket and row count; decode is
+ONE compiled program for the whole batch: ``paged_decode_steps`` lax.scans T
+greedy/temperature ticks on the device, feeding each sampled token into the
+next, so one host round trip buys T tokens a slot.
 
 Decode attention runs the TPU Pallas paged_attention kernel
 (jax.experimental.pallas.ops.tpu.paged_attention): block-sparse reads of
@@ -28,7 +32,8 @@ Layout notes:
   step: 28 of 38 ms at 1537 pages on a v5e).
 - page_size is a multiple of 8 (TPU sublane) and prefill buckets are
   multiples of page_size so prompt K/V scatter is a clean reshape-scatter.
-- the pool rides layer-scan carries DONATED through jit, like decode.py.
+- the pool rides the layer scan as CARRY (not xs/ys, which would stack a
+  copy of it a layer) and is DONATED through jit, so XLA updates it in place.
 """
 
 from __future__ import annotations
@@ -40,8 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
 
-from ray_tpu.models.decode import _lm_head, _mlp, _project_qkv, sample_token
 from ray_tpu.models.llama import LlamaConfig, llama_init as init_params  # noqa: F401
+from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 
@@ -66,6 +71,39 @@ SLOT_STATE = False
 def init_cache(config: LlamaConfig, num_slots: int, total_pages: int,
                page_size: int) -> PagedKVCache:
     return init_paged_cache(config, total_pages, page_size)
+
+
+def _project_qkv(config: LlamaConfig, lp: Dict[str, Any], x):
+    """x: [B, T, H] -> q [B,T,nh,hd], k/v [B,T,nkv,hd] (pre-rope)."""
+    b, t, _ = x.shape
+    nh, nkv, hd = config.num_heads, config.num_kv_heads, config.head_dim_
+    y = rms_norm(x, lp["attn_norm"], config.rms_eps)
+    q = (y @ lp["wq"]).reshape(b, t, nh, hd)
+    k = (y @ lp["wk"]).reshape(b, t, nkv, hd)
+    v = (y @ lp["wv"]).reshape(b, t, nkv, hd)
+    return y, q, k, v
+
+
+def _mlp(config: LlamaConfig, lp: Dict[str, Any], x):
+    y = rms_norm(x, lp["mlp_norm"], config.rms_eps)
+    gate = jax.nn.silu(y @ lp["w_gate"])
+    up = y @ lp["w_up"]
+    return (gate * up) @ lp["w_down"]
+
+
+def _lm_head(params, x, config: LlamaConfig):
+    x = rms_norm(x, params["final_norm"], config.rms_eps)
+    head = params.get("lm_head")
+    if head is None:
+        head = params["embed_tokens"].T.astype(config.dtype)
+    return (x @ head).astype(jnp.float32)
+
+
+def sample_token(logits, key, temperature: float):
+    """logits: [B, V]. temperature <= 0 -> greedy."""
+    if temperature <= 0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(key, logits / temperature, axis=-1).astype(jnp.int32)
 
 
 def _pages_per_layer(pool, config: LlamaConfig) -> int:
@@ -199,8 +237,8 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
     cos, sin = rope_frequencies(config.head_dim_, max_ctx, config.rope_theta)
     x = params["embed_tokens"][tokens[:, None]].astype(config.dtype)  # [B,1,H]
     # clamp: a slot finishing mid-chunk keeps ticking to the chunk end (the
-    # host truncates its output later); its position may overrun the table —
-    # pin it to the last row like dynamic_update_slice does in the dense path
+    # host truncates its output later); its position may overrun the table:
+    # pin it to the table's last row
     safe_pos = jnp.minimum(positions, max_ctx - 1)
     pages = jnp.take_along_axis(
         table, (safe_pos // page_size)[:, None], axis=1)[:, 0]  # [B]
@@ -236,8 +274,11 @@ def paged_decode_steps(params, cache: PagedKVCache, tokens, positions, active,
                        table, key, config: LlamaConfig, num_steps: int,
                        page_size: int, use_kernel: bool,
                        temperature: float = 0.0):
-    """T decode ticks on device (like decode.decode_steps, paged). The host
-    pre-provisions table pages covering positions+T before each chunk."""
+    """T decode ticks on the device. tokens/positions/active: [B]; returns
+    (sampled [B, T], last tokens [B], new positions [B], cache). An inactive
+    slot still flows through the math: its position does not advance and its
+    writes land in the trash page. The host has given every active slot the
+    pages that cover positions+T (``PageAllocator``: at admission)."""
 
     def tick(carry, k_):
         toks, pos, cache = carry
@@ -288,9 +329,8 @@ class PageAllocator:
     PAGE 0 IS THE TRASH PAGE and is never handed out: inactive slots keep
     block-table rows of zeros, so their frozen-position writes inside the
     compiled decode loop land in page 0 (of each layer's block of the pool)
-    instead of stomping a live slot's pages (the paged analogue of the dense
-    cache's per-slot frozen row). Page ids are per layer: the allocator
-    knows nothing of the pool's layer blocks."""
+    instead of stomping a live slot's pages. Page ids are per layer: the
+    allocator knows nothing of the pool's layer blocks."""
 
     TRASH_PAGE = 0
 

@@ -34,9 +34,9 @@ from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("ops.attention")
 
-# Block sizes tuned on v5e (see tools/attn_tune.py): (256, 512) maximizes
-# fwd and fwd+bwd throughput at seq 2048 (43/86 TF/s vs 15/? at 128/128 —
-# small blocks leave the MXU idle between grid steps).
+# Block sizes for a v5e: small blocks (128/128) leave the MXU idle between
+# grid steps. What these reach is measured where the kernel is used: the
+# benchmark's flash_fwd_roofline / flash_bwd_roofline in train_4k (PERF.md 3).
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30

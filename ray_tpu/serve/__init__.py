@@ -3,7 +3,7 @@
 Reference capability: python/ray/serve (controller, proxy, replicas, pow-2
 routing, dynamic batching, autoscaling) re-designed TPU-first: the flagship
 deployment is a continuous-batched LLM decode engine (serve.llm) with a
-slotted KV cache resident in HBM and one compiled step per decode tick.
+paged KV cache resident in HBM and one compiled program per decode chunk.
 """
 
 from ray_tpu.serve.api import (

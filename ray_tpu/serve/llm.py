@@ -5,8 +5,7 @@ GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
 
 - the model's cache in HBM, one donated pytree: a page pool
   (models/paged_decode.py) and, for a model with recurrent layers, per-slot
-  state beside it (models/nemotron_h.py); or the dense slotted cache of
-  models/decode.py — one slot per in-flight request;
+  state beside it (models/nemotron_h.py) — one slot per in-flight request;
 - CONTINUOUS batching: new requests are prefilled into free slots while
   other slots keep decoding — no batch barrier (Orca-style iteration-level
   scheduling);
@@ -15,8 +14,9 @@ GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
   compiles a fixed handful of programs, all when the bucket is first met;
   decode is one compiled multi-step program (T tokens per
   host round trip, so per-program dispatch and the host sync amortize);
-- per-request metrics: TTFT (first token latency) and decode tok/s, scraped
-  by bench_serve.py for the BASELINE req/s + p50 TTFT headline.
+- per-request metrics (TTFT, latency) in every reply, and ``stats()``: the
+  counters and the flight recorder the benchmark's per-layer metrics read
+  (``python3 benchmarks/run.py --workload <cell> ...``; PERF.md 3).
 
 ``LLMDeployment`` wraps the engine as a serve deployment; requests are
 dicts {"tokens": [...], "max_tokens": N} -> {"tokens": [...], "ttft_s": ...}.
@@ -56,7 +56,7 @@ SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
 MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                 "moe_experts_touched", "moe_expert_load_max")
-# the row counts a paged prefill program is compiled at (those that fit the
+# the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
 # up a bucket (2.3 s each for the hybrid family, from a warm cache), a lone
@@ -136,15 +136,13 @@ class GenRequest:
 
 class LLMEngine:
     """Continuous-batching loop around a model's prefill and decode programs:
-    models/paged_decode.py (Llama family, paged KV cache), models/nemotron_h.py
-    (hybrid family: pages and per-slot recurrent state) or models/decode.py
-    (Llama family, dense slots). One loop, one admission, one set of counters
-    for all of them.
+    models/paged_decode.py (Llama family, paged KV cache) or
+    models/nemotron_h.py (hybrid family: pages and per-slot recurrent
+    state). One loop, one admission, one set of counters for both.
 
-    Paged mode (default): HBM is committed per REQUEST
-    (ceil((prompt+max_tokens)/page_size) pages from a shared pool), not
-    per-slot*max_seq — so ``num_slots`` can far exceed what a dense cache
-    would fit, and short requests stop paying for max_seq rows. Decode
+    HBM is committed per REQUEST (ceil((prompt+max_tokens)/page_size) pages
+    from a shared pool), not per-slot*max_seq — so ``num_slots`` is bounded
+    by real demand, and short requests do not pay for max_seq rows. Decode
     attention is decided here, once: the TPU Pallas paged_attention kernel
     on a TPU backend when head_dim tiles the lane register file (128), else
     the gather reference. ``decode_attention`` names the choice.
@@ -224,9 +222,17 @@ class LLMEngine:
                  temperature: float = 0.0, prefill_buckets: Optional[List[int]] = None,
                  paged: bool = True, page_size: int = 64,
                  total_pages: Optional[int] = None):
+        if paged is not True:
+            # both benchmarks/families/*.py still pass paged=True: the
+            # argument goes when they stop (ROADMAP Design 1 (i))
+            raise ValueError(
+                "paged must be True: the dense slot cache was removed and "
+                "the page pool is the engine's only cache; the argument goes "
+                "once the benchmark stops passing it")
         import jax
         import jax.numpy as jnp
 
+        from ray_tpu.models.paged_decode import PageAllocator
         from ray_tpu.utils.compile_cache import enable_compile_cache
 
         enable_compile_cache()
@@ -238,51 +244,33 @@ class LLMEngine:
         self.params = params if params is not None else model.init_params(
             config, jax.random.key(0)
         )
-        self.paged = paged
         self._slot_state = model.SLOT_STATE
-        if paged:
-            from ray_tpu.models.paged_decode import PageAllocator
-
-            self.page_size = page_size
-            self.pages_per_slot = -(-self.max_seq // page_size)
-            # default pool: dense-equivalent capacity (+1 trash page) — same
-            # worst-case guarantees as the slotted cache. The paging WIN is
-            # opting into a smaller pool (or more slots at the same pool):
-            # HBM then tracks real demand instead of slots * max_seq
-            self.total_pages = total_pages or (
-                1 + num_slots * self.pages_per_slot)
-            self.allocator = PageAllocator(self.total_pages)
-            self.cache = model.init_cache(config, num_slots, self.total_pages,
-                                          page_size)
-            self._table = jnp.zeros((num_slots, self.pages_per_slot), jnp.int32)
-            self._slot_pages: List[Optional[List[int]]] = [None] * num_slots
-            self._prefill = model.make_paged_prefill_fn(config, page_size)
-            use_kernel = (jax.default_backend() == "tpu"
-                          and model.paged_kernel_fits(config))
-            self.decode_attention = "pallas_paged" if use_kernel else "gather"
-            self._decode = model.make_paged_decode_fn(
-                config, decode_chunk, page_size, temperature,
-                use_kernel=use_kernel)
-        else:
-            from ray_tpu.models.decode import (
-                init_kv_cache,
-                make_decode_fn,
-                make_prefill_fn,
-            )
-
-            self.decode_attention = "dense"
-            self.cache = init_kv_cache(config, num_slots, self.max_seq)
-            self._prefill = make_prefill_fn(config)
-            self._decode = make_decode_fn(config, decode_chunk, temperature)
+        self.page_size = page_size
+        self.pages_per_slot = -(-self.max_seq // page_size)
+        # default pool: every slot can hold max_seq rows (+1 trash page), so
+        # no request ever waits for pages. A smaller pool (or more slots at
+        # the same pool) is the caller's choice: HBM then tracks real demand
+        # instead of slots * max_seq
+        self.total_pages = total_pages or (
+            1 + num_slots * self.pages_per_slot)
+        self.allocator = PageAllocator(self.total_pages)
+        self.cache = model.init_cache(config, num_slots, self.total_pages,
+                                      page_size)
+        self._table = jnp.zeros((num_slots, self.pages_per_slot), jnp.int32)
+        self._slot_pages: List[Optional[List[int]]] = [None] * num_slots
+        self._prefill = model.make_paged_prefill_fn(config, page_size)
+        use_kernel = (jax.default_backend() == "tpu"
+                      and model.paged_kernel_fits(config))
+        self.decode_attention = "pallas_paged" if use_kernel else "gather"
+        self._decode = model.make_paged_decode_fn(
+            config, decode_chunk, page_size, temperature,
+            use_kernel=use_kernel)
+        # buckets are page multiples so prompt K/V scatter is a clean
+        # reshape-scatter
         self.prefill_buckets = sorted({
-            min(b, self.max_seq) for b in (prefill_buckets or [128, 512, 2048])
+            -(-min(b, self.max_seq) // page_size) * page_size
+            for b in (prefill_buckets or [128, 512, 2048])
         })
-        if paged:
-            # buckets must be page multiples so prompt K/V scatter is a
-            # clean reshape-scatter
-            self.prefill_buckets = sorted({
-                -(-b // page_size) * page_size for b in self.prefill_buckets
-            })
         self._key = jax.random.key(0)
         # device-side batch state
         self._tokens = jnp.zeros((num_slots,), jnp.int32)
@@ -314,9 +302,7 @@ class LLMEngine:
         self._prefill_rows_padded = 0
         self._prefill_tokens_real = 0
         self._prefill_tokens_padded = 0
-        # the dense mode prefills one request a program
-        self._prefill_rows = tuple(
-            r for r in PREFILL_ROWS if r <= num_slots) if paged else (1,)
+        self._prefill_rows = tuple(r for r in PREFILL_ROWS if r <= num_slots)
         self._prefill_calls_by_rows = dict.fromkeys(self._prefill_rows, 0)
         self._buckets_up: set = set()
         self._moe_counts = np.zeros((4,), np.int64)
@@ -448,8 +434,6 @@ class LLMEngine:
     def _describe_cache(self) -> Dict[str, int]:
         """What the cache's shapes say, read once: the loop thread donates
         the cache itself every step."""
-        if not self.paged:
-            return {}
         pool = self.cache.k
         kv = 2 * pool.shape[0] * (pool.shape[1] // self.total_pages) \
             * pool.shape[3] * pool.dtype.itemsize
@@ -466,14 +450,11 @@ class LLMEngine:
         ``tpu_custom_call`` in it is the Pallas kernel; its absence is the
         gather path. For checks that must not trust ``decode_attention``."""
         jax = self._jax
-        args = [self.params, self.cache, self._tokens, self._positions,
-                self._active]
-        if self.paged:
-            args.append(self._table)
-        args.append(self._key)
+        args = (self.params, self.cache, self._tokens, self._positions,
+                self._active, self._table, self._key)
         # shapes only: the loop thread donates the live cache every step
         shapes = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tuple(args))
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
         return self._decode.lower(*shapes).as_text()
 
     def stop(self) -> None:
@@ -491,51 +472,8 @@ class LLMEngine:
         # multiple (one extra compile) rather than silently truncating the
         # prompt — max_seq admission already guaranteed it fits
         bucket = min(self.max_seq, -(-n // 128) * 128)
-        if self.paged:
-            bucket = -(-bucket // self.page_size) * self.page_size
-            bucket = min(bucket, self.pages_per_slot * self.page_size)
-        return bucket
-
-    def _admit(self) -> List[tuple]:
-        """Prefill waiting requests into free slots WITHOUT a host sync: the
-        first sampled token stays on device and is fetched together with the
-        next decode chunk (one host sync per loop iteration, however many
-        requests were admitted). Returns the paged mode's prefill groups for
-        ``_step`` to dispatch; the dense mode prefills here, one request a
-        program, and returns none."""
-        if self.paged:
-            return self._admit_paged_batched()
-        jnp = self._jnp
-        now = time.perf_counter()
-        while True:
-            try:
-                free = self._slots.index(None)
-            except ValueError:
-                return []
-            try:
-                req = self._pending.get_nowait()
-            except queue.Empty:
-                return []
-            self._count_admitted(req, now)
-            n = len(req.tokens)
-            bucket = self._bucket_for(n)
-            assert bucket >= n, (bucket, n)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = req.tokens
-            # the slot owns the request before its program runs: a prefill
-            # that raises must find it there (_fail_all)
-            req.slot = free
-            self._slots[free] = req
-            self._count_prefill(1, 1, n, bucket)
-            logits, self.cache = self._prefill(
-                self.params, self.cache, jnp.asarray(padded),
-                jnp.int32(free), jnp.int32(min(n, bucket)),
-            )
-            first = jnp.argmax(logits).astype(jnp.int32)  # device scalar
-            req.pending_first = first
-            self._tokens = self._tokens.at[free].set(first)
-            self._positions = self._positions.at[free].set(n)
-            self._active = self._active.at[free].set(True)
+        bucket = -(-bucket // self.page_size) * self.page_size
+        return min(bucket, self.pages_per_slot * self.page_size)
 
     def _count_admitted(self, req: GenRequest, now: float) -> None:
         self._admitted += 1
@@ -551,7 +489,7 @@ class LLMEngine:
         self._prefill_tokens_real += tokens_real
         self._prefill_tokens_padded += rows_padded * bucket
 
-    def _admit_paged_batched(self) -> List[tuple]:
+    def _admit(self) -> List[tuple]:
         """Pull the admissible requests, in order, up to one iteration's
         budget of padded prompt tokens (``PREFILL_TOKENS_PER_ITER``; what is
         over it heads the next iteration's line), and group them by prefill
@@ -564,7 +502,9 @@ class LLMEngine:
         (``_bring_up``). What the padding that is left costs is counted
         where the program is dispatched (``prefill_rows_*`` /
         ``prefill_tokens_*`` / ``prefill_calls_by_rows`` of ``stats()``; the
-        benchmark's ``prefill_padding_share``)."""
+        benchmark's ``prefill_padding_share``). Admission makes no host sync:
+        a request's first sampled token stays on the device and is fetched
+        together with the next decode chunk."""
         now = time.perf_counter()
         free_slots = [i for i, r in enumerate(self._slots) if r is None]
         admitted: List[tuple] = []  # (req, slot, pages, bucket)
@@ -704,7 +644,7 @@ class LLMEngine:
         req = self._slots[slot]
         self._slots[slot] = None
         self._active = self._active.at[slot].set(False)
-        if self.paged and self._slot_pages[slot] is not None:
+        if self._slot_pages[slot] is not None:
             self.allocator.release(self._slot_pages[slot])
             self._slot_pages[slot] = None
             # table row back to the trash page so the retired slot's frozen
@@ -788,18 +728,11 @@ class LLMEngine:
             return
         with span("engine.decode_dispatch"):
             self._key, sub = jax.random.split(self._key)
-            counts = None
-            if self.paged:
-                # a model with routed experts returns their counts as well
-                sampled, last, self._positions, self.cache, *counts = \
-                    self._decode(
-                        self.params, self.cache, self._tokens,
-                        self._positions, self._active, self._table, sub,
-                    )
-            else:
-                sampled, last, self._positions, self.cache = self._decode(
+            # a model with routed experts returns their counts as well
+            sampled, last, self._positions, self.cache, *counts = \
+                self._decode(
                     self.params, self.cache, self._tokens,
-                    self._positions, self._active, sub,
+                    self._positions, self._active, self._table, sub,
                 )
             self._tokens = last
             self._steps += self.decode_chunk

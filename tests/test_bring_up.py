@@ -1,8 +1,7 @@
 """What the chip bring-up changed around the device path: no fallback that
 hides the device, one place for the compile cache, one chip detector, and
-how ``chip_smoke.py`` and the bench scripts fail without a chip. The
-script's full rehearsal takes over a minute and lives in
-``test_smoke_rehearsal.py``.
+how ``chip_smoke.py`` fails without a chip. The script's full rehearsal
+takes over a minute and lives in ``test_smoke_rehearsal.py``.
 """
 
 import json
@@ -225,8 +224,6 @@ def test_controller_keeps_a_replica_that_is_still_constructing(ray_tpu_local):
 # --------------------------------------------------------------- the scripts
 @pytest.mark.parametrize("script,env", [
     ("chip_smoke.py", {"JAX_PLATFORMS": "cpu"}),
-    ("bench.py", {"JAX_PLATFORMS": "cpu", "RAY_TPU_BENCH_TRANSFER": "0"}),
-    ("bench_serve.py", {"JAX_PLATFORMS": "cpu"}),
 ])
 def test_scripts_fail_without_a_chip(script, env):
     out = _run([script], env=env, timeout=300)

@@ -6,9 +6,8 @@ would refuse on the chip is refused here, on the CPU, in tier-1: a kernel
 that cannot be partitioned, a slice off the tiling, a program over the
 device's memory. Nothing runs, so nothing here is a device number.
 
-Shapes are the ones ``chip_smoke.py`` and the bench scripts use at
-``LlamaConfig.llama_1b`` widths; depth is cut where it only repeats a
-scanned layer.
+Shapes are the ones ``chip_smoke.py`` uses at ``LlamaConfig.llama_1b``
+widths; depth is cut where it only repeats a scanned layer.
 """
 
 import dataclasses
