@@ -42,8 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.paged_decode import (
-    _paged_attention, _scatter_prompt_rows_full, _scatter_token_rows,
-    sample_token)
+    _live_lengths, _paged_attention, _scatter_prompt_rows_full,
+    _scatter_token_rows, sample_token)
 from ray_tpu.ops import ssm
 from ray_tpu.ops.moe import relu2_mlp, routed_experts
 from ray_tpu.ops.norms import rms_norm
@@ -360,7 +360,7 @@ def paged_decode_one(params, cache: HybridCache, tokens, positions, active,
     pages = jnp.take_along_axis(
         table, (safe_pos // page_size)[:, None], axis=1)[:, 0]
     rows = safe_pos % page_size
-    lengths = safe_pos + 1
+    lengths = _live_lengths(safe_pos, active)
     per_layer = _pages_per_layer(cache, config)
     ck, cv, cs, cc = cache
     counts = jnp.zeros((4,), jnp.int32)

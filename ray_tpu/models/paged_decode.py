@@ -15,14 +15,15 @@ ONE compiled program for the whole batch: ``paged_decode_steps`` lax.scans T
 greedy/temperature ticks on the device, feeding each sampled token into the
 next, so one host round trip buys T tokens a slot.
 
-Decode attention runs the TPU Pallas paged_attention kernel
-(jax.experimental.pallas.ops.tpu.paged_attention): block-sparse reads of
-exactly the pages a slot owns, no gather materialization. A reference gather
-path computes the same thing for CPU tests and for head dims the kernel does
-not tile. Which of the two a decode program holds is its builder's decision
-(``use_kernel``), made once and visible in the lowered text
-(``tpu_custom_call``); nothing inside the traced function asks the backend.
-Both read the same pool through the same table, offset by ``l*P``.
+Decode attention runs the repo's Pallas kernel (``ops/paged_attention.py``):
+reads of exactly the pages that hold a live slot's rows, no gather
+materialization, and nothing at all for a slot that is not active: the
+decode step hands it a length of 0 there and gets zeros back. A reference
+gather path computes the same thing for CPU tests and for head dims the
+kernel does not tile. Which of the two a decode program holds is its
+builder's decision (``use_kernel``), made once and visible in the lowered
+text (``tpu_custom_call``); nothing inside the traced function asks the
+backend. Both read the same pool through the same table, offset by ``l*P``.
 
 Layout notes:
 - the pool's shape is the kernel's operand shape, so the layer scan hands it
@@ -43,10 +44,10 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
 
 from ray_tpu.models.llama import LlamaConfig, llama_init as init_params  # noqa: F401
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 
@@ -133,7 +134,8 @@ def _scatter_token_rows(pool, rows, pages, rownum):
 def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
     """Gather-based paged attention (CPU tests / non-TPU fallback).
     q: [B, nh, D]; pools: [n_kv, P_total, ps, D]; table: [B, max_pages];
-    lengths: [B] (inclusive count of valid rows)."""
+    lengths: [B] (inclusive count of valid rows; 0: the slot holds nothing
+    and its output is zeros, as the kernel's)."""
     b, nh, d = q.shape
     nkv, _, ps, _ = k_pool.shape
     max_pages = table.shape[1]
@@ -151,22 +153,27 @@ def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bnrs,bnsd->bnrd", probs.astype(vg.dtype), vg,
                      preferred_element_type=jnp.float32)
+    out = jnp.where((lengths > 0)[:, None, None, None], out, 0.0)
     return out.reshape(b, nh, d).astype(q.dtype)
 
 
 def _paged_attention(q, k_pool, v_pool, table, lengths, scale,
-                     use_kernel: bool, pages_per_block: int = 4):
+                     use_kernel: bool):
     """q: [B, 1, nh, D] -> [B, 1, nh, D]."""
     qs = (q[:, 0] * scale).astype(q.dtype)  # kernel does NOT scale q
     if use_kernel:
-        out = paged_attention(
-            qs.astype(jnp.float32), k_pool, v_pool,
-            lengths.astype(jnp.int32), table.astype(jnp.int32),
-            pages_per_compute_block=min(pages_per_block, table.shape[1]),
-        )
-        return out[:, None].astype(q.dtype)
+        return paged_attention(qs, k_pool, v_pool, lengths, table)[:, None]
     out = _paged_attention_reference(qs, k_pool, v_pool, table, lengths, 1.0)
     return out[:, None]
+
+
+def _live_lengths(safe_pos, active):
+    """Rows a slot attends over this tick: its clamped position inclusive,
+    and 0 for an inactive slot. A never-used slot sits at position 0 and a
+    retired one keeps the position it ended at: either would read as a
+    length of 1 or more, a block of kernel work a head a layer a step over
+    trash-page rows."""
+    return jnp.where(active, safe_pos + 1, 0)
 
 
 # --------------------------------------------------------------------------- #
@@ -225,13 +232,14 @@ def paged_prefill(params, cache: PagedKVCache, tokens, pages, lengths,
 # --------------------------------------------------------------------------- #
 # Decode
 # --------------------------------------------------------------------------- #
-def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
-                     config: LlamaConfig, page_size: int,
+def paged_decode_one(params, cache: PagedKVCache, tokens, positions, active,
+                     table, config: LlamaConfig, page_size: int,
                      use_kernel: bool) -> Tuple[jax.Array, PagedKVCache]:
-    """One decode tick. tokens/positions: [B]; table: [B, max_pages].
+    """One decode tick. tokens/positions/active: [B]; table: [B, max_pages].
     positions[b] = cache index the current token writes to; attention spans
     [0, positions[b]] inclusive. An inactive slot's table row is zeros, so
-    its frozen write lands in page ``l*P + 0``, each layer's own trash page."""
+    its frozen write lands in page ``l*P + 0``, each layer's own trash page;
+    it attends over nothing and its attention output is zeros."""
     scale = config.head_dim_ ** -0.5
     max_ctx = table.shape[1] * page_size
     cos, sin = rope_frequencies(config.head_dim_, max_ctx, config.rope_theta)
@@ -243,7 +251,7 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, table,
     pages = jnp.take_along_axis(
         table, (safe_pos // page_size)[:, None], axis=1)[:, 0]  # [B]
     rows = safe_pos % page_size
-    lengths = safe_pos + 1
+    lengths = _live_lengths(safe_pos, active)
     per_layer = _pages_per_layer(cache.k, config)
 
     def body(carry, lp):
@@ -276,14 +284,15 @@ def paged_decode_steps(params, cache: PagedKVCache, tokens, positions, active,
                        temperature: float = 0.0):
     """T decode ticks on the device. tokens/positions/active: [B]; returns
     (sampled [B, T], last tokens [B], new positions [B], cache). An inactive
-    slot still flows through the math: its position does not advance and its
-    writes land in the trash page. The host has given every active slot the
+    slot still flows through the projections and the MLP: its position does
+    not advance, its writes land in the trash page and its attention is
+    skipped (length 0). The host has given every active slot the
     pages that cover positions+T (``PageAllocator``: at admission)."""
 
     def tick(carry, k_):
         toks, pos, cache = carry
-        logits, cache = paged_decode_one(params, cache, toks, pos, table,
-                                         config, page_size, use_kernel)
+        logits, cache = paged_decode_one(params, cache, toks, pos, active,
+                                         table, config, page_size, use_kernel)
         nxt = sample_token(logits, k_, temperature)
         nxt = jnp.where(active, nxt, toks)
         new_pos = jnp.where(active, pos + 1, pos)
@@ -297,7 +306,8 @@ def paged_decode_steps(params, cache: PagedKVCache, tokens, positions, active,
 
 
 def paged_kernel_fits(config: LlamaConfig) -> bool:
-    """The Pallas kernel tiles head_dim onto the 128-lane register file."""
+    """The Pallas kernel (``ops/paged_attention.py``) tiles head_dim onto
+    the 128-lane register file."""
     return config.head_dim_ % 128 == 0
 
 

@@ -1,0 +1,175 @@
+"""Decode attention over the page pool: one query token a slot against the
+pages that slot owns, costing what is live.
+
+The pool is ``[n_kv, pages, page_size, D]`` a side and a slot's pages are
+named by its row of the block table; ``lengths[b]`` is how many cached rows
+slot ``b`` attends over, 0 for a slot that holds nothing.
+
+Design (see /opt/skills/guides/pallas_guide.md):
+- ONE invocation (a grid of one): the kernel walks the slots itself. A dead slot
+  (length 0) is skipped by a scalar read of ``lengths``: no DMA, no block of
+  compute; its output rows are the zeros the kernel starts from.
+- a block is ``pages_per_block`` pages of EVERY KV head of one slot, fetched
+  by one strided DMA a page and side (``n_kv`` rows of a page each) into a
+  double buffer; the next block, of this slot or of the next live one, is
+  in flight while this one is multiplied. Only the pages that hold live
+  rows are fetched: ``cdiv(length, page_size)``, not the block's worth.
+- q and K/V go to the MXU as stored (bfloat16), accumulation is float32;
+  running max, sum and the output accumulator stay float32 across blocks
+  (the online-softmax recurrence of ``ops/attention.py``).
+- the heads are told by the shapes (``q`` is ``[B, n_kv, G, D]``), what is
+  live by ``lengths``: one kernel for every family that keeps pages.
+
+The jitted wrapper is named ``paged_attention`` and so is the call: a
+profile's operation reads ``paged_attention.N``, the name the benchmark's
+readers look for.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import NEG_INF
+
+# 8 pages of 64 rows: 512 tokens, 128 KB a head and side; K and V double
+# buffered are 4 MB of VMEM at 8 KV heads. Measured on a v5e (PERF.md 6)
+PAGES_PER_BLOCK = 8
+
+
+def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+            sems, *, pages_per_block: int):
+    # lengths_ref: [B] and table_ref: [B * pages_per_slot] in SMEM;
+    # q_ref / o_ref: [B, n_kv, G, D] in VMEM; k_hbm / v_hbm: the pool, in
+    # HBM; kbuf / vbuf: [2, n_kv, pages_per_block, page_size, D]; sems: DMA
+    # semaphores [side, buffer]
+    nb, nkv, g, d = q_ref.shape
+    ps = k_hbm.shape[2]
+    ppb = pages_per_block
+    bk = ppb * ps
+    pages_per_slot = table_ref.shape[0] // nb
+
+    def next_live(b):
+        """The first slot at or after ``b`` with something to attend over;
+        ``nb`` where there is none."""
+        return jax.lax.while_loop(
+            lambda b: jnp.logical_and(
+                b < nb, lengths_ref[jnp.minimum(b, nb - 1)] == 0),
+            lambda b: b + 1, b)
+
+    def block_dma(b, i, buf, wait: bool):
+        """Start, or wait for, the live pages of block ``i`` of slot ``b``."""
+        live_pages = pl.cdiv(lengths_ref[b], ps) - i * ppb
+        for j in range(ppb):
+            @pl.when(j < live_pages)
+            def _():
+                # a wait needs the copy's shape and semaphore, not its source
+                page = 0 if wait else table_ref[
+                    b * pages_per_slot + i * ppb + j]
+                for side, (hbm, vmem) in enumerate(((k_hbm, kbuf),
+                                                    (v_hbm, vbuf))):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[:, page], vmem.at[buf, :, j],
+                        sems.at[side, buf])
+                    copy.wait() if wait else copy.start()
+
+    # a dead slot's rows stay as they are here. A masked column's weight is
+    # an exact 0, but 0 x NaN is NaN: no page is fetched behind a slot's
+    # last live one, so what V's buffers hold there must be finite
+    o_ref[...] = jnp.zeros_like(o_ref)
+    vbuf[...] = jnp.zeros_like(vbuf)
+
+    def slot(carry):
+        b, buf = carry
+        length = lengths_ref[b]
+        blocks = pl.cdiv(length, bk)
+        after = next_live(b + 1)
+
+        def block(i, carry):
+            m, l, acc, buf = carry
+            last = i + 1 == blocks
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                block_dma(b, i + 1, 1 - buf, wait=False)
+
+            @pl.when(jnp.logical_and(last, after < nb))
+            def _():
+                block_dma(after, 0, 1 - buf, wait=False)
+
+            block_dma(b, i, buf, wait=True)
+            q = q_ref[b]                                   # [n_kv, G, D]
+            k = kbuf[buf].reshape(nkv, bk, d)
+            v = vbuf[buf].reshape(nkv, bk, d)
+            s = jnp.einsum("hgd,htd->hgt", q, k,
+                           preferred_element_type=jnp.float32)
+            cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            s = jnp.where(cols < length, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "hgt,htd->hgd", p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - buf
+
+        m0 = jnp.full((nkv, g, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((nkv, g, 1), jnp.float32)
+        acc0 = jnp.zeros((nkv, g, d), jnp.float32)
+        _, l, acc, buf = jax.lax.fori_loop(0, blocks, block,
+                                           (m0, l0, acc0, buf))
+        o_ref[b] = (acc / l).astype(o_ref.dtype)  # length >= 1: l > 0
+        return after, buf
+
+    first = next_live(0)
+
+    @pl.when(first < nb)
+    def _():
+        block_dma(first, 0, 0, wait=False)
+
+    jax.lax.while_loop(lambda c: c[0] < nb, slot, (first, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+def paged_attention(q, k_pool, v_pool, lengths, table, *,
+                    pages_per_block: int = PAGES_PER_BLOCK,
+                    interpret: bool = False):
+    """q: [B, nh, D], already scaled; pools: [n_kv, pages, page_size, D];
+    lengths: int32 [B], the cached rows a slot attends over, 0 for a slot
+    that holds nothing; table: int32 [B, pages_per_slot]. Returns
+    [B, nh, D] in q's dtype: softmax(q k^T) v over the slot's first
+    ``lengths[b]`` rows, exact zeros where ``lengths[b]`` is 0. Only table
+    entries that cover live rows are read."""
+    nb, nh, d = q.shape
+    nkv, _, ps, _ = k_pool.shape
+    if nh % nkv:
+        raise ValueError(f"{nh} query heads over {nkv} KV heads")
+    if k_pool.shape != v_pool.shape or k_pool.shape[3] != d:
+        raise ValueError(f"q {q.shape} against pools {k_pool.shape}, "
+                         f"{v_pool.shape}")
+    ppb = min(pages_per_block, table.shape[1])
+    buffers = pltpu.VMEM((2, nkv, ppb, ps, d), k_pool.dtype)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_kernel, pages_per_block=ppb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[buffers, buffers,
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((nb, nkv, nh // nkv, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), table.astype(jnp.int32).reshape(-1),
+      q.reshape(nb, nkv, nh // nkv, d), k_pool, v_pool)
+    return out.reshape(nb, nh, d)

@@ -143,9 +143,10 @@ class LLMEngine:
     HBM is committed per REQUEST (ceil((prompt+max_tokens)/page_size) pages
     from a shared pool), not per-slot*max_seq — so ``num_slots`` is bounded
     by real demand, and short requests do not pay for max_seq rows. Decode
-    attention is decided here, once: the TPU Pallas paged_attention kernel
-    on a TPU backend when head_dim tiles the lane register file (128), else
-    the gather reference. ``decode_attention`` names the choice.
+    attention is decided here, once: the repo's Pallas kernel
+    (``ops/paged_attention.py``, which does work only for active slots) on
+    a TPU backend when head_dim tiles the lane register file (128), else the
+    gather reference. ``decode_attention`` names the choice.
 
     A step that raises (a kernel the chip's compiler refuses, device OOM)
     fails every in-flight and queued request with that exception and stops
@@ -162,6 +163,10 @@ class LLMEngine:
     - ``decode_steps``, ``tokens_generated``, ``uptime_s``,
       ``decode_attention``: decode ticks dispatched, tokens of retired
       requests, seconds since construction, the decode attention chosen.
+    - ``decode_rows_run``, ``decode_rows_live``: slot-rows the decode chunks
+      ran (slots x ticks, a chunk) and those of them whose slot was active:
+      their ratio is the share of the batch that decode attention works
+      for; the rest it skips. Counted on the host, a chunk.
     - ``iters``, ``iter_ns``: busy iterations of ``_step`` and their time.
       ``phase_ns``: the same time split into the six phases that partition
       an iteration: ``admit`` (pull requests, pages, slots),
@@ -286,6 +291,7 @@ class LLMEngine:
         self._jnp = jnp
         self._jax = jax
         self._steps = 0
+        self._decode_rows_live = 0
         self._tokens_out = 0
         self._started = time.perf_counter()
         # always-on counters and the flight recorder (see the class docstring)
@@ -400,6 +406,8 @@ class LLMEngine:
             "queued": self._queued(),
             "decode_steps": self._steps,
             "decode_attention": self.decode_attention,
+            "decode_rows_run": self._steps * self.num_slots,
+            "decode_rows_live": self._decode_rows_live,
             "tokens_generated": self._tokens_out,
             "uptime_s": time.perf_counter() - self._started,
             "iters": n,
@@ -751,6 +759,7 @@ class LLMEngine:
         now = t4 / 1e9  # perf_counter's clock, as submitted_at
         now_wall = time.time()
         active = self._admitted - retired0  # every admitted request retires
+        self._decode_rows_live += active * self.decode_chunk
         retire_ns = self._emit(host_tokens, host_firsts, now, now_wall)
         t5 = clock()
         # a slot retires inside the emit loop, where its last token is

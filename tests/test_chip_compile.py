@@ -61,6 +61,14 @@ def _llama_1b(layers, **kw):
         LlamaConfig.llama_1b(max_seq_len=S, **kw), num_layers=layers)
 
 
+def _mistral_7b(layers):
+    """Mistral-7B-v0.3's published widths, ``layers`` of its 32 layers."""
+    return LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=layers, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=4096, rope_theta=1e6, attention_impl="flash")
+
+
 def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
@@ -80,25 +88,10 @@ def test_flash_attention_compiles(v5e, passes, kernels):
     assert _compiled_text(fn, q, kv, kv).count("tpu_custom_call") == kernels
 
 
-@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16])
-def test_library_paged_attention_compiles_at_engine_shapes(v5e, q_dtype):
-    one = SingleDeviceSharding(v5e.devices[0])
-    q = jax.ShapeDtypeStruct((SLOTS, HQ, D), q_dtype, sharding=one)
-    pool = jax.ShapeDtypeStruct((HKV, POOL_PAGES, PAGE, D), jnp.bfloat16,
-                                sharding=one)
-    lengths = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one)
-    table = jax.ShapeDtypeStruct((SLOTS, TABLE_PAGES), jnp.int32, sharding=one)
-    text = _compiled_text(
-        lambda q, k, v, n, t: pd.paged_attention(
-            q, k, v, n, t, pages_per_compute_block=4),
-        q, pool, pool, lengths, table)
-    assert "tpu_custom_call" in text
-
-
-def _decode_shapes(v5e, pool_pages=POOL_PAGES):
+def _decode_shapes(v5e, pool_pages=POOL_PAGES, config=None):
     """(config, arguments of the engine's decode program as shapes)."""
     one = SingleDeviceSharding(v5e.devices[0])
-    config = _llama_1b(2, attention_impl="flash")
+    config = config or _llama_1b(2, attention_impl="flash")
     params = _on(one, jax.eval_shape(lambda k: llama_init(config, k),
                                      jax.random.key(0)))
     cache = _on(one, jax.eval_shape(
@@ -135,6 +128,30 @@ def test_paged_decode_chunk_holds_the_kernel(v5e):
     assert "tpu_custom_call" not in gather.lower(*args).as_text()
 
 
+def _mosaic_calls(text):
+    """Names of a compiled program's Mosaic kernel instructions."""
+    return re.findall(
+        r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+
+
+@pytest.mark.parametrize("family", ["llama", "hybrid"])
+def test_decode_attention_kernel_compiles_and_keeps_its_name(v5e, family):
+    """The repo's kernel (``ops/paged_attention.py``) inside the decode
+    program of each family at its benchmark cell's head counts and slots
+    (32/8 heads, 64 slots; 32/2 heads, 128 slots): Mosaic takes it, and the
+    compiled instruction is named ``paged_attention.N``. The benchmark's
+    readers find the operation, and the Llama decode program by it, under
+    ``^paged_attention`` in a profile (PERF.md 3, Kernels)."""
+    if family == "llama":
+        config, args = _decode_shapes(v5e, config=_mistral_7b(2))
+        decode = pd.make_paged_decode_fn(config, 8, PAGE, use_kernel=True)
+        text = decode.lower(*args).compile().as_text()
+    else:
+        text = _hybrid_decode(v5e, 128)[1].as_text()
+    calls = _mosaic_calls(text)
+    assert calls and all(c.startswith("paged_attention") for c in calls), calls
+
+
 def test_prefill_bucket_compiles(v5e):
     config, args = _prefill_shapes(v5e, 512)
     prefill = pd.make_paged_prefill_fn(config, PAGE)
@@ -148,10 +165,7 @@ def test_one_row_prefill_of_the_largest_bucket_compiles(v5e):
     them). Its temporaries, noted from this compile (no device number):
     135,153,152 bytes, where the 8-row program of the same bucket, which the
     engine ran for every group before, takes 2.42 GB (PERF.md 4)."""
-    config = LlamaConfig(
-        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
-        max_seq_len=4096, rope_theta=1e6, attention_impl="flash")
+    config = _mistral_7b(2)
     _, args = _prefill_shapes(v5e, 2048, rows=1, config=config)
     compiled = pd.make_paged_prefill_fn(config, PAGE).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -269,7 +283,7 @@ def test_fsdp4_train_step_compiles_with_flash(v5e):
 # The second family through the engine (PR 29)
 # --------------------------------------------------------------------------- #
 LLAMA_TINY_DECODE_SHA = \
-    "b47635e63964b6b8171f77683df70d12db7d6425c76acde4c53e5cdfdb72d27c"
+    "92f77e847eca75f025ee282bd1c8d85a9051395468b808526443d554492f9549"
 
 
 def test_llama_decode_program_is_what_it_was_before_the_second_family():
@@ -277,7 +291,9 @@ def test_llama_decode_program_is_what_it_was_before_the_second_family():
     The Llama decode program it lowers is the text the parent commit lowered
     (the hash was taken on both trees; at the benchmark's rehearsal widths
     decode and prefill were compared too, equal). It depends on the jax that
-    lowers it, so another version skips."""
+    lowers it, so another version skips. PR 34 moved the hash in place: tiny
+    widths run the GATHER path, whose lengths are 0 for an inactive slot and
+    whose output is zeros there since then (``_live_lengths``)."""
     import hashlib
 
     from ray_tpu.serve.llm import LLMEngine

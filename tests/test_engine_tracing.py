@@ -49,6 +49,18 @@ def test_phases_partition_the_iteration(engine):
     assert s["retired"] == s["admitted"] and s["active"] == 0
 
 
+def test_decode_rows_run_and_live_are_counted(engine):
+    """One request alone in 16 slots: every chunk runs 16 slot-rows a tick,
+    one of them live. The ring's ``active`` column says the same."""
+    before = engine.stats()
+    engine.generate([9, 8, 7], max_tokens=11, timeout=300)
+    after = engine.stats()
+    ticks = after["decode_steps"] - before["decode_steps"]
+    assert ticks >= 11 - 1 and ticks % engine.decode_chunk == 0
+    assert after["decode_rows_run"] - before["decode_rows_run"] == 16 * ticks
+    assert after["decode_rows_live"] - before["decode_rows_live"] == ticks
+
+
 def test_prefill_padding_is_counted_exactly(engine):
     before = engine.stats()
     submit_together(engine, [request([7 + i] * 100) for i in range(3)])

@@ -130,8 +130,9 @@ def _decode_one_tick(cfg, params, table, positions):
     slots = table.shape[0]
     _, new = pd.paged_decode_one(
         params, cache, jnp.arange(1, slots + 1, dtype=jnp.int32),
-        jnp.asarray(positions, jnp.int32), jnp.asarray(table, jnp.int32),
-        cfg, PS, use_kernel=False)
+        jnp.asarray(positions, jnp.int32),
+        jnp.asarray(table.any(axis=1)),  # a slot with pages is live
+        jnp.asarray(table, jnp.int32), cfg, PS, use_kernel=False)
     return cache, new
 
 
