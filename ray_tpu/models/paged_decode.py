@@ -131,11 +131,13 @@ def _scatter_token_rows(pool, rows, pages, rownum):
         vals.astype(pool.dtype))
 
 
-def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
+def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale,
+                               starts=None):
     """Gather-based paged attention (CPU tests / non-TPU fallback).
     q: [B, nh, D]; pools: [n_kv, P_total, ps, D]; table: [B, max_pages];
     lengths: [B] (inclusive count of valid rows; 0: the slot holds nothing
-    and its output is zeros, as the kernel's)."""
+    and its output is zeros, as the kernel's); starts: optional [B], the
+    first row attended (a sliding window), as the kernel's."""
     b, nh, d = q.shape
     nkv, _, ps, _ = k_pool.shape
     max_pages = table.shape[1]
@@ -149,6 +151,8 @@ def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
     logits = jnp.einsum("bnrd,bnsd->bnrs", qg, kg,
                         preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(max_pages * ps)[None, :] < lengths[:, None]  # [B, S]
+    if starts is not None:
+        mask = mask & (jnp.arange(max_pages * ps)[None, :] >= starts[:, None])
     logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bnrs,bnsd->bnrd", probs.astype(vg.dtype), vg,
@@ -158,12 +162,15 @@ def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale):
 
 
 def _paged_attention(q, k_pool, v_pool, table, lengths, scale,
-                     use_kernel: bool):
-    """q: [B, 1, nh, D] -> [B, 1, nh, D]."""
+                     use_kernel: bool, starts=None):
+    """q: [B, 1, nh, D] -> [B, 1, nh, D]. ``starts``: the first row each
+    slot attends over (a sliding layer), else row 0."""
     qs = (q[:, 0] * scale).astype(q.dtype)  # kernel does NOT scale q
     if use_kernel:
-        return paged_attention(qs, k_pool, v_pool, lengths, table)[:, None]
-    out = _paged_attention_reference(qs, k_pool, v_pool, table, lengths, 1.0)
+        return paged_attention(qs, k_pool, v_pool, lengths, table,
+                               starts=starts)[:, None]
+    out = _paged_attention_reference(qs, k_pool, v_pool, table, lengths, 1.0,
+                                     starts)
     return out[:, None]
 
 

@@ -7,7 +7,11 @@ Design (see /opt/skills/guides/pallas_guide.md):
   recurrence (running max m, normalizer l, fp32 accumulator) — the classic
   flash pattern, so S×S scores never touch HBM.
 - causal masking skips fully-masked K blocks via the loop bound (block-level
-  skip), and applies an elementwise mask only on the diagonal block.
+  skip), and applies an elementwise mask only on the diagonal block. With a
+  sliding ``window`` (forward only: serving's prefill) the loop also STARTS
+  at the first K block a row of the q block can see, so a window layer's
+  prefill costs S x window and not S^2 / 2; that call is named
+  ``flash_window_fwd`` in a profile.
 - GQA: q heads map onto kv heads through the BlockSpec index_map
   (h // q_per_kv), so kv tensors are never materialized per-q-head.
 - backward: Pallas kernels with the standard flash-bwd recurrence — the
@@ -29,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.utils.logging import get_logger
 
@@ -45,8 +50,11 @@ NEG_INF = -1e30
 # --------------------------------------------------------------------------- #
 # Reference implementation (also the backward path)
 # --------------------------------------------------------------------------- #
-def reference_attention(q, k, v, causal: bool = True, scale: Optional[float] = None):
-    """q: [B, Sq, Hq, D]; k/v: [B, Skv, Hkv, D]. Returns [B, Sq, Hq, D]."""
+def reference_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """q: [B, Sq, Hq, D]; k/v: [B, Skv, Hkv, D]. Returns [B, Sq, Hq, D].
+    ``window`` (causal only): a query sees the ``window`` newest keys, itself
+    among them (``i - j < window``)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     if scale is None:
@@ -60,6 +68,8 @@ def reference_attention(q, k, v, causal: bool = True, scale: Optional[float] = N
         qpos = jnp.arange(sq)[:, None] + (skv - sq)
         kpos = jnp.arange(skv)[None, :]
         mask = qpos >= kpos
+        if window is not None:
+            mask = mask & (qpos - kpos < window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
@@ -69,7 +79,8 @@ def reference_attention(q, k, v, causal: bool = True, scale: Optional[float] = N
 # --------------------------------------------------------------------------- #
 # Pallas forward kernel
 # --------------------------------------------------------------------------- #
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, block_k, seq_kv, causal, scale, offset):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, block_k, seq_kv, causal, scale, offset,
+                      window=None):
     # refs carry leading (1, 1) batch/head block dims:
     # q_ref: [1, 1, block_q, D]; k_ref/v_ref: [1, 1, seq_kv, D]
     # offset = seq_kv - seq_q: query row i sits at absolute position offset+i
@@ -89,6 +100,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, bloc
         )
     else:
         num_k_blocks = seq_kv // block_k
+    first_k_block = 0
+    if window is not None:
+        # the oldest key the block's FIRST row sees is q_start - window + 1
+        first_k_block = jnp.maximum(q_start - window + 1, 0) // block_k
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
@@ -104,7 +119,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, bloc
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window is not None:
+                seen = jnp.logical_and(seen, rows - cols < window)
+            s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -115,14 +133,23 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, bloc
         )
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, num_k_blocks, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(first_k_block, num_k_blocks, body,
+                                  (m0, l0, acc0))
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     if lse_ref is not None:
         lse_ref[0, 0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
+# K and V of one (batch, KV head) sit whole in VMEM, double buffered: over
+# this many bytes the call asks Mosaic for more than its default 16 MB of
+# scoped VMEM (a v5e has 128 MiB). 4 MB at the 4096 rows of the training
+# cell: calls that fit are compiled as they were
+KV_VMEM_DEFAULT_BYTES = 8 * 2 ** 20
+
+
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
-               interpret: bool, with_lse: bool = True):
+               interpret: bool, with_lse: bool = True,
+               window: Optional[int] = None):
     """q: [B, Sq, Hq, D] -> (out [B, Sq, Hq, D], lse [B, Hq, Sq, 1] fp32 or
     None). lse carries a trailing singleton so its blocks satisfy the TPU
     (8, 128) tiling rule; inference-only callers pass with_lse=False to skip
@@ -146,6 +173,14 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
         scale=scale,
         offset=skv - sq,
     )
+    call = {}
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
+        call["name"] = "flash_window_fwd"
+    kv_vmem = 4 * skv * d * k.dtype.itemsize
+    if kv_vmem > KV_VMEM_DEFAULT_BYTES:
+        call["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=kv_vmem + 16 * 2 ** 20)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d), lambda bb, h, i: (bb, h, i, 0)),
         pl.BlockSpec((1, 1, skv, d), lambda bb, h, i, _g=q_per_kv: (bb, h // _g, 0, 0)),
@@ -166,6 +201,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
                 jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
             ],
             interpret=interpret,
+            **call,
         )(qt, kt, vt)
     else:
         out = pl.pallas_call(
@@ -175,6 +211,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
             out_specs=o_spec,
             out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
             interpret=interpret,
+            **call,
         )(qt, kt, vt)
         lse = None
     return out.transpose(0, 2, 1, 3), lse
@@ -376,11 +413,16 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    window: Optional[int] = None,
 ):
     """Flash attention with automatic padding to block multiples.
 
     q: [B, Sq, Hq, D]; k/v: [B, Skv, Hkv, D] with Hq % Hkv == 0.
+    ``window``: causal sliding window (a query sees its ``window`` newest
+    keys, itself among them); forward only, no gradient is defined.
     """
+    if window is not None and not causal:
+        raise ValueError("a sliding window is causal")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, sq, hq, d = q.shape
@@ -414,11 +456,15 @@ def flash_attention(
                 "flash_attention: no block padding for q %s / kv %s "
                 "(causal=%s); this call computes the S x S reference instead",
                 q.shape, k.shape, causal)
-            return reference_attention(q, k, v, causal, scale)
+            return reference_attention(q, k, v, causal, scale, window)
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    out = _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret)
+    if window is None:
+        out = _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret)
+    else:
+        out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                            with_lse=False, window=window)
     if pad:
         out = out[:, :sq]
     return out
@@ -428,8 +474,10 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl: str = "auto"):
+def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl: str = "auto",
+              window: Optional[int] = None):
     """Dispatch. impl: "flash" | "flash_interpret" | "reference" | "auto".
+    ``window``: a causal sliding window (forward only).
 
     "flash" is the Pallas kernel and nothing else: where Mosaic cannot
     compile it the compiler's error surfaces. "auto" resolves once per trace
@@ -439,9 +487,10 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl:
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
-        return reference_attention(q, k, v, causal, scale)
+        return reference_attention(q, k, v, causal, scale, window)
     if impl == "flash":
-        return flash_attention(q, k, v, causal, scale)
+        return flash_attention(q, k, v, causal, scale, window=window)
     if impl == "flash_interpret":
-        return flash_attention(q, k, v, causal, scale, interpret=True)
+        return flash_attention(q, k, v, causal, scale, interpret=True,
+                               window=window)
     raise ValueError(f"unknown attention impl {impl!r}")

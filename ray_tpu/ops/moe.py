@@ -20,7 +20,14 @@ Two ways to do the products, chosen by the caller from its token count:
   anyway, so the weights are read once either way and the extra
   multiplications hide under that read. For decode.
 
-An expert is not gated: ``relu(x W_up)^2 W_down``.
+An expert's form is the caller's (``form``): ``"relu2"``, not gated,
+``relu(x W_up)^2 W_down`` (Nemotron-H), or ``"swiglu"``, gated by a third
+matrix, ``(silu(x W_gate) * x W_up) W_down`` (Laguna). So is the router's
+scoring (``scoring``): ``"sigmoid_bias"``, sigmoid scores, the choice by
+score + a correction bias, the weights the scores without it (Nemotron-H);
+or ``"softmax"``, a softmax over all the router's outputs in float32, the
+choice and the weights both by it (Laguna, the Qwen-MoE lineage). Either way
+the chosen weights are normalised over all the chosen and scaled.
 
 ``parallel/expert.py`` is the older capacity-based layer (it drops past a
 capacity); only its tests and ``__graft_entry__.py`` use it.
@@ -40,16 +47,32 @@ def relu2_mlp(x, w_up, w_down):
     return jnp.matmul((up * up).astype(x.dtype), w_down)
 
 
-def route(x, router: Dict[str, Any], top_k: int, scale: float):
-    """x: [T, h]. Sigmoid scores in float32 over all the router's outputs;
-    the choice is the top ``top_k`` of score + ``bias`` (the published
-    ``e_score_correction_bias``), the weights are the scores WITHOUT the bias
-    over the sum of the chosen, times ``scale``. Returns (experts [T, k]
-    int32, weights [T, k] float32)."""
+def swiglu_mlp(x, w_gate, w_up, w_down):
+    """The gated form, for one dense expert: (silu(x W_gate) * x W_up) W_down."""
+    gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+    up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_down)
+
+
+def route(x, router: Dict[str, Any], top_k: int, scale: float,
+          scoring: str = "sigmoid_bias"):
+    """x: [T, h]. Scores in float32 over all the router's outputs.
+    ``"sigmoid_bias"``: sigmoid scores; the choice is the top ``top_k`` of
+    score + ``bias`` (the published ``e_score_correction_bias``), the weights
+    are the scores WITHOUT the bias. ``"softmax"``: a softmax over the
+    outputs; choice and weights by it, no bias. The weights are over the sum
+    of the chosen, times ``scale``. Returns (experts [T, k] int32, weights
+    [T, k] float32)."""
     logits = jnp.matmul(x.astype(jnp.float32), router["w"].astype(jnp.float32),
                         precision="highest")
-    scores = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(scores + router["bias"].astype(jnp.float32), top_k)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, top_k)
+    elif scoring == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + router["bias"].astype(jnp.float32), top_k)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * scale
     return chosen.astype(jnp.int32), weights
@@ -57,9 +80,11 @@ def route(x, router: Dict[str, Any], top_k: int, scale: float):
 
 def routed_experts(x, router: Dict[str, Any], experts: Dict[str, Any], *,
                    held: Tuple[int, int], top_k: int, scale: float,
-                   impl: str = "ragged", counted=None):
-    """x: [T, h]; router: {"w": [h, R], "bias": [R]}; experts: {"w_up":
-    [E, h, f], "w_down": [E, f, h]} with E = hi - lo. Returns the held
+                   impl: str = "ragged", counted=None,
+                   scoring: str = "sigmoid_bias", form: str = "relu2"):
+    """x: [T, h]; router: {"w": [h, R], "bias": [R]} (no bias under
+    ``"softmax"``); experts: {"w_up": [E, h, f], "w_down": [E, f, h]} and,
+    for ``"swiglu"``, "w_gate" like "w_up", with E = hi - lo. Returns the held
     experts' weighted sum [T, h], and with ``counted`` ([T] bool, the rows
     that are live requests) also int32 [4]: their routed choices, those that
     fell on held experts, held experts with at least one, and the fullest
@@ -67,13 +92,15 @@ def routed_experts(x, router: Dict[str, Any], experts: Dict[str, Any], *,
     lo, hi = held
     n = hi - lo
     assert experts["w_up"].shape[0] == n, (experts["w_up"].shape, held)
-    chosen, weights = route(x, router, top_k, scale)
+    if form not in ("relu2", "swiglu"):
+        raise ValueError(f"unknown expert form {form!r}")
+    chosen, weights = route(x, router, top_k, scale, scoring)
     here = (chosen >= lo) & (chosen < hi)
     local = jnp.where(here, chosen - lo, n)          # n: "not held here"
     if impl == "dense":
-        out = _dense(x, experts, local, weights, n)
+        out = _dense(x, experts, local, weights, n, form)
     elif impl == "ragged":
-        out = _ragged(x, experts, local, weights, n)
+        out = _ragged(x, experts, local, weights, n, form)
     else:
         raise ValueError(f"unknown expert product {impl!r}")
     if counted is None:
@@ -85,27 +112,42 @@ def routed_experts(x, router: Dict[str, Any], experts: Dict[str, Any], *,
     return out, counts
 
 
-def _dense(x, experts, local, weights, n: int):
+def _act(up, gate, form: str):
+    """The expert's activation in float32: ``up`` (and ``gate``) -> the rows
+    the down projection takes."""
+    if form == "swiglu":
+        return jax.nn.silu(gate) * up
+    up = jnp.maximum(up, 0.0)
+    return up * up
+
+
+def _dense(x, experts, local, weights, n: int, form: str):
     onehot = local[..., None] == jnp.arange(n)                  # [T, k, E]
     per_expert = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
     up = jnp.einsum("th,ehf->etf", x, experts["w_up"],
                     preferred_element_type=jnp.float32)
-    up = jnp.maximum(up, 0.0)
-    down = jnp.einsum("etf,efh->eth", (up * up).astype(x.dtype),
+    gate = jnp.einsum("th,ehf->etf", x, experts["w_gate"],
+                      preferred_element_type=jnp.float32) \
+        if form == "swiglu" else None
+    down = jnp.einsum("etf,efh->eth", _act(up, gate, form).astype(x.dtype),
                       experts["w_down"], preferred_element_type=jnp.float32)
     return jnp.einsum("te,eth->th", per_expert, down).astype(x.dtype)
 
 
-def _ragged(x, experts, local, weights, n: int):
+def _ragged(x, experts, local, weights, n: int, form: str):
     t, k = local.shape
     flat = local.reshape(-1)
     order = jnp.argsort(flat, stable=True)       # held first, by expert
     sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
     rows = x[order // k]                                         # [T*k, h]
-    up = jnp.maximum(jax.lax.ragged_dot(
-        rows, experts["w_up"], sizes, preferred_element_type=jnp.float32), 0.0)
-    down = jax.lax.ragged_dot((up * up).astype(x.dtype), experts["w_down"],
-                              sizes, preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(
+        rows, experts["w_up"], sizes, preferred_element_type=jnp.float32)
+    gate = jax.lax.ragged_dot(
+        rows, experts["w_gate"], sizes, preferred_element_type=jnp.float32) \
+        if form == "swiglu" else None
+    down = jax.lax.ragged_dot(_act(up, gate, form).astype(x.dtype),
+                              experts["w_down"], sizes,
+                              preferred_element_type=jnp.float32)
     # rows past the held assignments belong to no group: whatever the
     # grouped product left there is not read
     valid = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]

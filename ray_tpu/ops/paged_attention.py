@@ -19,10 +19,17 @@ Design (see /opt/skills/guides/pallas_guide.md):
   (the online-softmax recurrence of ``ops/attention.py``).
 - the heads are told by the shapes (``q`` is ``[B, n_kv, G, D]``), what is
   live by ``lengths``: one kernel for every family that keeps pages.
+- with ``starts`` a slot attends over rows ``[starts[b], lengths[b])``, a
+  sliding window: columns before the start are masked, pages wholly before
+  it are not fetched and blocks wholly before it not multiplied. The table
+  row may then be a RING in logical order (``models/laguna.py`` hands the
+  window layers the slot's ring from its oldest live page, with lengths and
+  starts counted from that page). Without ``starts`` the kernel traces to
+  the text it had before there was a window.
 
 The jitted wrapper is named ``paged_attention`` and so is the call: a
 profile's operation reads ``paged_attention.N``, the name the benchmark's
-readers look for.
+readers look for; the call with ``starts`` is ``paged_attention_window.N``.
 """
 
 from __future__ import annotations
@@ -41,12 +48,14 @@ from ray_tpu.ops.attention import NEG_INF
 PAGES_PER_BLOCK = 8
 
 
-def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
-            sems, *, pages_per_block: int):
-    # lengths_ref: [B] and table_ref: [B * pages_per_slot] in SMEM;
-    # q_ref / o_ref: [B, n_kv, G, D] in VMEM; k_hbm / v_hbm: the pool, in
-    # HBM; kbuf / vbuf: [2, n_kv, pages_per_block, page_size, D]; sems: DMA
-    # semaphores [side, buffer]
+def _kernel(*refs, pages_per_block: int, windowed: bool):
+    # lengths_ref (and, windowed, starts_ref): [B] and table_ref:
+    # [B * pages_per_slot] in SMEM; q_ref / o_ref: [B, n_kv, G, D] in VMEM;
+    # k_hbm / v_hbm: the pool, in HBM; kbuf / vbuf: [2, n_kv,
+    # pages_per_block, page_size, D]; sems: DMA semaphores [side, buffer]
+    lengths_ref, *refs = refs
+    starts_ref = refs.pop(0) if windowed else None
+    table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs
     nb, nkv, g, d = q_ref.shape
     ps = k_hbm.shape[2]
     ppb = pages_per_block
@@ -61,11 +70,20 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
                 b < nb, lengths_ref[jnp.minimum(b, nb - 1)] == 0),
             lambda b: b + 1, b)
 
+    def first_block(b):
+        """The block that holds slot ``b``'s first attended row."""
+        return starts_ref[b] // bk if windowed else 0
+
     def block_dma(b, i, buf, wait: bool):
         """Start, or wait for, the live pages of block ``i`` of slot ``b``."""
         live_pages = pl.cdiv(lengths_ref[b], ps) - i * ppb
         for j in range(ppb):
-            @pl.when(j < live_pages)
+            live = j < live_pages
+            if windowed:  # a page wholly before the window is not fetched
+                live = jnp.logical_and(live,
+                                       i * ppb + j >= starts_ref[b] // ps)
+
+            @pl.when(live)
             def _():
                 # a wait needs the copy's shape and semaphore, not its source
                 page = 0 if wait else table_ref[
@@ -99,7 +117,7 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
 
             @pl.when(jnp.logical_and(last, after < nb))
             def _():
-                block_dma(after, 0, 1 - buf, wait=False)
+                block_dma(after, first_block(after), 1 - buf, wait=False)
 
             block_dma(b, i, buf, wait=True)
             q = q_ref[b]                                   # [n_kv, G, D]
@@ -108,7 +126,10 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
             s = jnp.einsum("hgd,htd->hgt", q, k,
                            preferred_element_type=jnp.float32)
             cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            s = jnp.where(cols < length, s, NEG_INF)
+            live = cols < length
+            if windowed:
+                live = jnp.logical_and(live, cols >= starts_ref[b])
+            s = jnp.where(live, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
@@ -121,7 +142,7 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
         m0 = jnp.full((nkv, g, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((nkv, g, 1), jnp.float32)
         acc0 = jnp.zeros((nkv, g, d), jnp.float32)
-        _, l, acc, buf = jax.lax.fori_loop(0, blocks, block,
+        _, l, acc, buf = jax.lax.fori_loop(first_block(b), blocks, block,
                                            (m0, l0, acc0, buf))
         o_ref[b] = (acc / l).astype(o_ref.dtype)  # length >= 1: l > 0
         return after, buf
@@ -130,13 +151,13 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
 
     @pl.when(first < nb)
     def _():
-        block_dma(first, 0, 0, wait=False)
+        block_dma(first, first_block(first), 0, wait=False)
 
     jax.lax.while_loop(lambda c: c[0] < nb, slot, (first, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
-def paged_attention(q, k_pool, v_pool, lengths, table, *,
+def paged_attention(q, k_pool, v_pool, lengths, table, *, starts=None,
                     pages_per_block: int = PAGES_PER_BLOCK,
                     interpret: bool = False):
     """q: [B, nh, D], already scaled; pools: [n_kv, pages, page_size, D];
@@ -144,7 +165,12 @@ def paged_attention(q, k_pool, v_pool, lengths, table, *,
     that holds nothing; table: int32 [B, pages_per_slot]. Returns
     [B, nh, D] in q's dtype: softmax(q k^T) v over the slot's first
     ``lengths[b]`` rows, exact zeros where ``lengths[b]`` is 0. Only table
-    entries that cover live rows are read."""
+    entries that cover live rows are read.
+
+    ``starts``: int32 [B], the first row a slot attends over (a live slot:
+    ``starts[b] < lengths[b]``): the softmax is over rows ``[starts[b],
+    lengths[b])`` and table entries of pages wholly before ``starts[b]`` are
+    not read either."""
     nb, nh, d = q.shape
     nkv, _, ps, _ = k_pool.shape
     if nh % nkv:
@@ -155,10 +181,14 @@ def paged_attention(q, k_pool, v_pool, lengths, table, *,
     ppb = min(pages_per_block, table.shape[1])
     buffers = pltpu.VMEM((2, nkv, ppb, ps, d), k_pool.dtype)
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    windowed = starts is not None
+    scalars = (lengths.astype(jnp.int32),)
+    if windowed:
+        scalars += (starts.astype(jnp.int32),)
     out = pl.pallas_call(
-        functools.partial(_kernel, pages_per_block=ppb),
+        functools.partial(_kernel, pages_per_block=ppb, windowed=windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars) + 1,
             grid=(1,),
             in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -168,8 +198,8 @@ def paged_attention(q, k_pool, v_pool, lengths, table, *,
         out_shape=jax.ShapeDtypeStruct((nb, nkv, nh // nkv, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name="paged_attention",
+        name="paged_attention_window" if windowed else "paged_attention",
         interpret=interpret,
-    )(lengths.astype(jnp.int32), table.astype(jnp.int32).reshape(-1),
+    )(*scalars, table.astype(jnp.int32).reshape(-1),
       q.reshape(nb, nkv, nh // nkv, d), k_pool, v_pool)
     return out.reshape(nb, nh, d)

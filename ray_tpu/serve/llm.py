@@ -4,8 +4,11 @@ Reference capability: the reference serves LLMs by orchestrating external
 GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
 
 - the model's cache in HBM, one donated pytree: a page pool
-  (models/paged_decode.py) and, for a model with recurrent layers, per-slot
-  state beside it (models/nemotron_h.py) — one slot per in-flight request;
+  (models/paged_decode.py) and, beside it, what a family keeps per SLOT: the
+  recurrent state of Mamba layers (models/nemotron_h.py), the window rings
+  of sliding-attention layers (models/laguna.py: a second kind of KV storage,
+  a fixed ring of pages a slot a layer that the allocator never sees) — one
+  slot per in-flight request;
 - CONTINUOUS batching: new requests are prefilled into free slots while
   other slots keep decoding — no batch barrier (Orca-style iteration-level
   scheduling);
@@ -56,6 +59,11 @@ SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
 MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                 "moe_experts_touched", "moe_expert_load_max")
+# every counter a family's decode program may count on the device and return
+# as its fifth result; a family's module says which, in order, as
+# ``DECODE_COUNTERS`` (without it: ``MOE_COUNTERS``). ``stats()`` has them
+# all, 0 where the family counts no such thing
+DEVICE_COUNTERS = MOE_COUNTERS + ("attn_rows_full", "attn_rows_window")
 # the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
@@ -73,7 +81,8 @@ PREFILL_TOKENS_PER_ITER = 8192
 def _model_of(config):
     """The module that builds ``config``'s weights, cache and programs:
     ``init_params``, ``init_cache``, ``make_paged_prefill_fn``,
-    ``make_paged_decode_fn``, ``paged_kernel_fits``, ``SLOT_STATE``.
+    ``make_paged_decode_fn``, ``paged_kernel_fits``, ``SLOT_STATE``, and
+    where it has them ``DECODE_COUNTERS`` and ``RING_FIELDS``.
     ``models/paged_decode.py`` for a ``LlamaConfig``; any other family's
     module holds its configuration class beside its programs, and whoever
     made ``config`` has imported it: a Llama replica imports no other
@@ -93,13 +102,19 @@ def _nemotron_h_tiny():
     return NemotronHConfig.tiny()
 
 
+def _laguna_tiny():
+    from ray_tpu.models.laguna import LagunaConfig
+
+    return LagunaConfig.tiny()
+
+
 def model_presets() -> Dict[str, Any]:
     """``LLMDeployment``'s preset names."""
     from ray_tpu.models.llama import LlamaConfig
 
     return {"tiny": LlamaConfig.tiny, "llama_1b": LlamaConfig.llama_1b,
             "llama3_8b": LlamaConfig.llama3_8b,
-            "nemotron_h_tiny": _nemotron_h_tiny}
+            "nemotron_h_tiny": _nemotron_h_tiny, "laguna_tiny": _laguna_tiny}
 
 
 def _steal_s() -> float:
@@ -136,9 +151,11 @@ class GenRequest:
 
 class LLMEngine:
     """Continuous-batching loop around a model's prefill and decode programs:
-    models/paged_decode.py (Llama family, paged KV cache) or
+    models/paged_decode.py (Llama family, paged KV cache),
     models/nemotron_h.py (hybrid family: pages and per-slot recurrent
-    state). One loop, one admission, one set of counters for both.
+    state) or models/laguna.py (window and full attention layers: pages for
+    the full layers, per-slot rings for the window layers). One loop, one
+    admission, one allocator, one set of counters for all three.
 
     HBM is committed per REQUEST (ceil((prompt+max_tokens)/page_size) pages
     from a shared pool), not per-slot*max_seq — so ``num_slots`` is bounded
@@ -204,23 +221,43 @@ class LLMEngine:
       ``slow_iters_unlogged`` counts the rest).
     - ``kv_bytes_per_token``: bytes of K and V a cached token takes over
       all the layers that keep pages. ``state_slots``, ``state_bytes``:
-      slots that keep recurrent state beside their pages, and the bytes of
-      it (all slots and the trash row); 0 for a model that keeps none.
+      slots that keep state beside their pages (recurrent state, window
+      rings), and the bytes of it (all slots and the trash row); 0 for a
+      model that keeps none. ``window_ring_pages``, ``window_state_bytes``:
+      the pages held in window rings (over sliding layers, slots and the
+      trash ring) and their bytes, a part of ``state_bytes``; 0 for a model
+      without a window. ``kv_pages_in_use``, ``kv_pages_total``: pages the
+      allocator has handed out now, and those it may (the pool without its
+      trash page); rings are in neither.
     - ``moe_assignments``, ``moe_assignments_held``, ``moe_experts_touched``,
       ``moe_expert_load_max``: over decode ticks and expert layers, summed:
       the routed choices of active slots, those that fell on experts held
       here, held experts with at least one token, and the fullest held
       expert's tokens. Counted on the device and fetched with the chunk's
       one ``device_get``; 0 for a model without routed experts.
+    - ``attn_rows_full``, ``attn_rows_window``: K/V rows decode attention
+      attended over, summed over live slots, ticks and layers of each kind:
+      ``length`` a full layer, ``min(length, window)`` a sliding one.
+      Counted in the decode program and fetched with the expert counters; 0
+      for a family whose module names no such counter (``DECODE_COUNTERS``).
 
     Which model: ``_model_of`` maps the configuration's type to the module
     that builds its weights, its cache and its two programs
     (``models/paged_decode.py`` for ``LlamaConfig``,
-    ``models/nemotron_h.py`` for ``NemotronHConfig``). The cache is one
-    donated pytree. Where the module says ``SLOT_STATE``, the cache also
-    holds per-slot recurrent state: prefill is told each row's slot (a pad
+    ``models/nemotron_h.py`` for ``NemotronHConfig``, ``models/laguna.py``
+    for ``LagunaConfig``). The cache is one donated pytree. Where the module
+    says ``SLOT_STATE``, the cache also holds state addressed by slot
+    (recurrent state, window rings): prefill is told each row's slot (a pad
     row: the trash row ``num_slots``) and overwrites it, so a retired slot
-    needs no clearing; decode moves the state of active slots only."""
+    needs no clearing; decode moves the state of active slots only. A prompt
+    longer than ``PREFILL_TOKENS_PER_ITER`` is admitted alone, one to an
+    iteration, by a one-row program of its bucket.
+
+    Spans (``profiling.span``, on the device trace's clock): ``engine.admit``,
+    ``engine.prefill_bring_up`` (``bucket``), ``engine.prefill_dispatch``
+    (``bucket``, ``rows_real``, ``rows_padded``, ``state_rows``),
+    ``engine.decode_dispatch``, ``engine.device_get``, ``engine.emit``,
+    ``engine.retire``."""
 
     def __init__(self, config, params=None, *, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, decode_chunk: int = 8,
@@ -250,6 +287,7 @@ class LLMEngine:
             config, jax.random.key(0)
         )
         self._slot_state = model.SLOT_STATE
+        self._ring_fields = getattr(model, "RING_FIELDS", ())
         self.page_size = page_size
         self.pages_per_slot = -(-self.max_seq // page_size)
         # default pool: every slot can hold max_seq rows (+1 trash page), so
@@ -311,7 +349,8 @@ class LLMEngine:
         self._prefill_rows = tuple(r for r in PREFILL_ROWS if r <= num_slots)
         self._prefill_calls_by_rows = dict.fromkeys(self._prefill_rows, 0)
         self._buckets_up: set = set()
-        self._moe_counts = np.zeros((4,), np.int64)
+        self._counter_names = getattr(model, "DECODE_COUNTERS", MOE_COUNTERS)
+        self._device_counts = np.zeros((len(self._counter_names),), np.int64)
         self._cache_stats = self._describe_cache()
         self._queue_wait_counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
         self._ring = np.zeros((RING_ITERS, len(RING_COLUMNS)))
@@ -436,19 +475,28 @@ class LLMEngine:
             "slow_iters": list(self._slow_iters),
             "slow_iters_unlogged": self._slow_unlogged,
             **self._cache_stats,
-            **dict(zip(MOE_COUNTERS, self._moe_counts.tolist())),
+            "kv_pages_in_use": self.total_pages - 1 - self.allocator.free_pages,
+            "kv_pages_total": self.total_pages - 1,
+            **dict.fromkeys(DEVICE_COUNTERS, 0),
+            **dict(zip(self._counter_names, self._device_counts.tolist())),
         }
 
     def _describe_cache(self) -> Dict[str, int]:
         """What the cache's shapes say, read once: the loop thread donates
-        the cache itself every step."""
+        the cache itself every step. ``k`` and ``v`` are the page pools; every
+        other field is state addressed by slot, and those the family's module
+        lists as ``RING_FIELDS`` are pools of window rings."""
         pool = self.cache.k
         kv = 2 * pool.shape[0] * (pool.shape[1] // self.total_pages) \
             * pool.shape[3] * pool.dtype.itemsize
-        state = sum(x.nbytes for name, x in self.cache._asdict().items()
+        fields = self.cache._asdict()
+        state = sum(x.nbytes for name, x in fields.items()
                     if name not in ("k", "v"))
+        rings = [fields[name] for name in self._ring_fields]
         return {"kv_bytes_per_token": kv, "state_bytes": state,
-                "state_slots": self.num_slots if self._slot_state else 0}
+                "state_slots": self.num_slots if self._slot_state else 0,
+                "window_ring_pages": rings[0].shape[1] if rings else 0,
+                "window_state_bytes": sum(x.nbytes for x in rings)}
 
     def _queued(self) -> int:
         return self._pending.qsize() + len(self._admit_backlog)
@@ -754,7 +802,7 @@ class LLMEngine:
             host_tokens, host_firsts, host_counts = jax.device_get(
                 (sampled, firsts, counts))
             if host_counts:
-                self._moe_counts += host_counts[0]
+                self._device_counts += host_counts[0]
         t4 = clock()
         now = t4 / 1e9  # perf_counter's clock, as submitted_at
         now_wall = time.time()
@@ -870,7 +918,8 @@ class LLMDeployment:
                  temperature: float = 0.0, params=None,
                  total_pages: Optional[int] = None):
         """``model``: a preset's name (``model_presets()``) or a configuration
-        object of either family (``LlamaConfig``, ``NemotronHConfig``)."""
+        object of any family (``LlamaConfig``, ``NemotronHConfig``,
+        ``LagunaConfig``)."""
         config = model
         if isinstance(model, str):
             factories = model_presets()

@@ -8,6 +8,7 @@ import pytest
 from benchmarks.harness.loadgen import RequestRecord
 from benchmarks.readers import (
     engine_counters, engine_longest_iter, engine_queue_wait, hops_percentile)
+from benchmarks.tests.test_laguna_family import *  # noqa: F401,F403
 from benchmarks.tests.test_manifest import *  # noqa: F401,F403
 from benchmarks.tests.test_nemotron_h_family import *  # noqa: F401,F403
 from benchmarks.tests.test_rates import *  # noqa: F401,F403

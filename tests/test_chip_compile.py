@@ -11,6 +11,7 @@ widths; depth is cut where it only repeats a scanned layer.
 """
 
 import dataclasses
+import functools
 import math
 import os
 import re
@@ -352,3 +353,161 @@ def test_hybrid_decode_updates_pool_and_state_in_place(v5e):
     grown = (big.memory_analysis().temp_size_in_bytes
              - small.memory_analysis().temp_size_in_bytes)
     assert grown < 0.1 * (_pool_bytes(big_cache) - _pool_bytes(cache))
+
+
+# --------------------------------------------------------------------------- #
+# PR 35: a window in the shared kernels, and the third family
+# --------------------------------------------------------------------------- #
+def _without_locations(text):
+    """Lowered text with every Mosaic kernel's serialized module replaced by
+    its assembly WITHOUT source locations: the bytecode holds file:line of
+    the kernel's source, which moves whenever a line is added above it."""
+    import base64
+
+    from jax._src import tpu_custom_call  # noqa: F401 - registers the dialect
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def assembly(found):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True  # "stable_mosaic"
+        tpu.register_dialect(ctx)
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(found.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", assembly, text)
+
+
+# sha256 of ``_without_locations(lowered text)``, taken on the parent commit
+# of PR 35 (a7e571a) and equal on its tree, under jax 0.9.0
+ACCEPTED_PROGRAMS_SHA = {
+    "llama_decode": 
+        "4faf3387d777c75cad44fe924f5ee1365a0fbabc2bb83479003e2a9b50fd3725",
+    "llama_prefill": 
+        "52db68d74f15ab0f4793dc6ead0131a2a4ead966c0a9916a16433e6430790d9c",
+    "hybrid_decode": 
+        "e338a098eef1802d762ff9f9f0ce9b2aa20c9993a1441e5080afd8f433fef50b",
+    "hybrid_prefill": 
+        "a0d649839579a59ceec76bf48a22466fabc3a6c63fce57ae082e1f1d9549033a",
+    "flash_fwd_bwd": 
+        "f7e7ab589c6105498b819b990dbda5cda3c09b4307791981cd966bc05680969c",
+}
+
+
+def _accepted_program(v5e, name):
+    """The lowered text of one program of a family the benchmark had before
+    the third, as its engine builds it on a TPU."""
+    from ray_tpu.models import nemotron_h as nh
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    if name == "llama_decode":
+        config, args = _decode_shapes(v5e, config=_mistral_7b(2))
+        return pd.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(*args)
+    if name == "llama_prefill":
+        config, args = _prefill_shapes(v5e, 2048, rows=1, config=_mistral_7b(2))
+        return pd.make_paged_prefill_fn(config, PAGE).lower(*args)
+    if name == "flash_fwd_bwd":
+        q = shape((2, 4096, 32, 128), jnp.bfloat16)
+        kv = shape((2, 4096, 8, 128), jnp.bfloat16)
+        return jax.jit(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))).lower(q, kv, kv)
+    config = nh.NemotronHConfig(
+        pattern="ME*M", n_routed_experts=8, held_experts=(0, 8),
+        vocab_size=8192, attention_impl="flash")
+    params = _on(one, jax.eval_shape(lambda k: nh.init_params(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: nh.init_cache(config, 128, POOL_PAGES, PAGE)))
+    ints, key = shape((128,), jnp.int32), _on(one, jax.eval_shape(
+        lambda: jax.random.key(0)))
+    if name == "hybrid_decode":
+        return nh.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(
+            params, cache, ints, ints, shape((128,), jnp.bool_),
+            shape((128, TABLE_PAGES), jnp.int32), key)
+    four = shape((4,), jnp.int32)
+    return nh.make_paged_prefill_fn(config, PAGE).lower(
+        params, cache, shape((4, 512), jnp.int32),
+        shape((4, 512 // PAGE), jnp.int32), four, four)
+
+
+@pytest.mark.parametrize("name", sorted(ACCEPTED_PROGRAMS_SHA))
+def test_accepted_programs_lower_to_the_parents_text(v5e, name):
+    """``ops/paged_attention.py`` gained ``starts``, ``ops/attention.py`` a
+    ``window``, ``ops/rope.py`` a partial head and YaRN, ``ops/moe.py`` a
+    scoring and an expert form (PR 35). Called as the accepted families call
+    them, they trace to what they were: the decode and prefill programs of
+    the Llama-shaped and the hybrid family, and the flash forward and
+    backward of training, lower to the text the parent commit lowered, with
+    the Pallas kernels inside compared as assembly without source lines."""
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip(f"the hashes were taken under jax 0.9.0, not {jax.__version__}")
+    text = _without_locations(_accepted_program(v5e, name).as_text())
+    assert "tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == ACCEPTED_PROGRAMS_SHA[name]
+
+
+def _laguna(v5e, slots=24, bucket=None):
+    """The third family at Laguna-XS.2's published widths, a full and a
+    sliding layer (query groups of 6 and 8, both rotary schemes, one expert
+    layer of 8 held experts, a slice of the vocabulary): its decode program
+    over ``slots`` slots, or its one-row prefill program of ``bucket``."""
+    from ray_tpu.models import laguna as lg
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    config = lg.LagunaConfig(
+        vocab_size=8192, layer_types=(lg.FULL, lg.SLIDING),
+        mlp_layer_types=("dense", "sparse"),
+        num_attention_heads_per_layer=(48, 64), num_experts=8,
+        held_experts=(0, 8), max_seq_len=25600, attention_impl="flash")
+    pages = 25600 // PAGE
+    params = _on(one, jax.eval_shape(lambda k: lg.init_params(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: lg.init_cache(config, slots, slots * pages + 1, PAGE)))
+    if bucket:
+        lowered = lg.make_paged_prefill_fn(config, PAGE).lower(
+            params, cache, shape((1, bucket), jnp.int32),
+            shape((1, bucket // PAGE), jnp.int32), shape((1,), jnp.int32),
+            shape((1,), jnp.int32))
+    else:
+        ints = shape((slots,), jnp.int32)
+        lowered = lg.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(
+            params, cache, ints, ints, shape((slots,), jnp.bool_),
+            shape((slots, pages), jnp.int32),
+            _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered.compile(), cache
+
+
+def test_laguna_decode_holds_both_kernels_and_moves_no_pool(v5e):
+    """Mosaic takes the paged-attention kernel at a query group of 6 and, with
+    ``starts``, at 8 over a ring of 9 pages; a profile tells the two calls
+    apart by name (the benchmark's ``full_attn_decode_roofline`` and
+    ``window_attn_decode_roofline`` read them so); pages and rings ride one
+    donated cache that the program aliases and never copies."""
+    compiled, cache = _laguna(v5e)
+    calls = [c for c in _mosaic_calls(compiled.as_text())
+             if "ragged" not in c]  # the grouped expert products are XLA's
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "paged_attention", "paged_attention_window"], calls
+    assert compiled.memory_analysis().alias_size_in_bytes == _pool_bytes(cache)
+    moved = [line for line in _pool_sized_moves(compiled.as_text(),
+                                                cache.k_win.size)
+             if re.search(r"= bf16\[8,\d+,64,128\]", line)]  # a pool's shape
+    assert moved == []
+
+
+def test_laguna_prefill_of_the_longest_bucket_compiles(v5e):
+    """One row of 24,576 tokens: K and V of a (row, KV head) are 25 MB in
+    VMEM, double buffered, so the flash forward asks for more than Mosaic's
+    default scoped VMEM (``ops/attention.py`` ``KV_VMEM_DEFAULT_BYTES``); the
+    windowed call is named ``flash_window_fwd``."""
+    compiled, _ = _laguna(v5e, bucket=24576)
+    calls = [c for c in _mosaic_calls(compiled.as_text()) if "ragged" not in c]
+    assert any(c.startswith("flash_window_fwd") for c in calls), calls
+    assert len(calls) == 2
