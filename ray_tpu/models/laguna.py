@@ -148,12 +148,18 @@ class LagunaConfig:
 SLOT_STATE = True  # serve/llm.py: prefill is told each row's slot (its rings)
 RING_FIELDS = ("k_win", "v_win")  # the cache's fields that are window rings
 # what the decode program counts on the device, in the order of its fifth
-# result: ops/moe.py's four, then the K/V rows attended over live slots,
-# ticks and layers of each kind (``length`` a full layer, ``min(length,
-# window)`` a sliding one)
+# result: ops/moe.py's six (the last two: calls of the compacted expert
+# product and the blocks they ran beyond their first; a tick of 24 slots is
+# under one block and runs uncompacted, so they read 0 there), then the K/V
+# rows attended over live slots, ticks and layers of each kind (``length`` a
+# full layer, ``min(length, window)`` a sliding one)
 DECODE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                    "moe_experts_touched", "moe_expert_load_max",
+                   "moe_blocks", "moe_blocks_extra",
                    "attn_rows_full", "attn_rows_window")
+# what the prefill program counts, its third result: the same two over the
+# call's expert layers and chunks (pad rows are routed and multiplied too)
+PREFILL_COUNTERS = ("moe_blocks", "moe_blocks_extra")
 # tokens of a prefill the routed experts take at a time: the sorted copy of
 # the rows, and the float32 results before they are summed, are 8 x the
 # tokens tall (a float32 [196608, 2048] at a 24,576-token prompt is 1.6 GB)
@@ -274,7 +280,9 @@ def _attn_out(lp, o, gate):
 
 def _mlp(config: LagunaConfig, lp, y, counted=None):
     """y: [T, h] normed -> (the layer's MLP output [T, h], the expert
-    counters int32 [4] or None). A prefill's thousands of tokens go through
+    counters or None: ops/moe.py's int32 [6] over the ``counted`` rows of a
+    decode tick, the last two of them (``PREFILL_COUNTERS``) in prefill). A
+    prefill's thousands of tokens go through
     the routed experts ``MOE_PREFILL_TOKENS`` at a time. The routed products
     are grouped ("ragged") in prefill and in decode alike: a decode tick's
     24 rows reach about half of the 32 held experts a layer, and a step took
@@ -294,11 +302,19 @@ def _mlp(config: LagunaConfig, lp, y, counted=None):
     if counted is not None:
         out, counts = routed(y, counted)
         return out + shared, counts
+
+    def chunk(rows):
+        # every row counted: only the blocks are kept, and they count calls
+        out, counts = routed(rows, jnp.ones((rows.shape[0],), bool))
+        return out, counts[-len(PREFILL_COUNTERS):]
+
     t = y.shape[0]
     if t > MOE_PREFILL_TOKENS and t % MOE_PREFILL_TOKENS == 0:
-        out = jax.lax.map(routed, y.reshape(-1, MOE_PREFILL_TOKENS, y.shape[1]))
-        return out.reshape(y.shape) + shared, None
-    return routed(y) + shared, None
+        out, counts = jax.lax.map(
+            chunk, y.reshape(-1, MOE_PREFILL_TOKENS, y.shape[1]))
+        return out.reshape(y.shape) + shared, jnp.sum(counts, axis=0)
+    out, counts = chunk(y)
+    return out + shared, counts
 
 
 def _head(config: LagunaConfig, params, x):
@@ -317,7 +333,7 @@ def paged_prefill(params, cache: LagunaCache, tokens, pages, lengths, slots,
     writes the prompt's pages; a sliding layer writes the prompt's last
     ``ring`` pages into the slot's ring, logical page ``p`` at ring page
     ``p % ring``, where decode finds it. Returns (last-token logits [PB, V],
-    cache)."""
+    cache, int32 [2]: the ``PREFILL_COUNTERS`` of this call)."""
     from ray_tpu.ops.attention import attention
 
     pb, s = tokens.shape
@@ -343,6 +359,7 @@ def paged_prefill(params, cache: LagunaCache, tokens, pages, lengths, slots,
         return jnp.take_along_axis(paged, src, axis=1).reshape(
             pb, ring * page_size, *rows.shape[2:])
 
+    moe_counts = jnp.zeros((len(PREFILL_COUNTERS),), jnp.int32)
     f_idx = w_idx = 0
     for kind, nq, lp in zip(config.layer_types,
                             config.num_attention_heads_per_layer,
@@ -364,10 +381,13 @@ def paged_prefill(params, cache: LagunaCache, tokens, pages, lengths, slots,
             w_idx += 1
         x = x + _attn_out(lp, o, gate)
         y = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
-        out, _ = _mlp(config, lp, y.reshape(pb * s, -1))
+        out, layer_counts = _mlp(config, lp, y.reshape(pb * s, -1))
+        if layer_counts is not None:
+            moe_counts = moe_counts + layer_counts
         x = x + out.reshape(pb, s, -1)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return _head(config, params, last), LagunaCache(ck, cv, ckw, cvw)
+    return (_head(config, params, last), LagunaCache(ck, cv, ckw, cvw),
+            moe_counts)
 
 
 # --------------------------------------------------------------------------- #
@@ -377,7 +397,7 @@ def paged_decode_one(params, cache: LagunaCache, tokens, positions, active,
                      table, config: LagunaConfig, page_size: int,
                      use_kernel: bool, rope=None):
     """One decode tick over every slot. tokens / positions / active: [B];
-    table: [B, max_pages]. Returns (logits [B, V], cache, int32 [6]: the
+    table: [B, max_pages]. Returns (logits [B, V], cache, int32 [8]: the
     ``DECODE_COUNTERS`` of this tick). An inactive slot's K/V writes land in
     the trash page and the trash ring, and it attends over nothing.
     ``rope``: ``_rope_tables`` over the table's rows, made once a chunk by
@@ -406,7 +426,7 @@ def paged_decode_one(params, cache: LagunaCache, tokens, positions, active,
     ck, cv, ckw, cvw = cache
     per_layer = ck.shape[1] // config.count(FULL)
     rings_per_layer = ckw.shape[1] // config.count(SLIDING)
-    moe_counts = jnp.zeros((4,), jnp.int32)
+    moe_counts = jnp.zeros((6,), jnp.int32)
     f_idx = w_idx = 0
     for kind, nq, lp in zip(config.layer_types,
                             config.num_attention_heads_per_layer,

@@ -59,11 +59,14 @@ SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
 MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                 "moe_experts_touched", "moe_expert_load_max")
-# every counter a family's decode program may count on the device and return
-# as its fifth result; a family's module says which, in order, as
-# ``DECODE_COUNTERS`` (without it: ``MOE_COUNTERS``). ``stats()`` has them
-# all, 0 where the family counts no such thing
-DEVICE_COUNTERS = MOE_COUNTERS + ("attn_rows_full", "attn_rows_window")
+# every counter a family's programs may count on the device: the decode
+# program returns them as its fifth result and a family's module says which,
+# in order, as ``DECODE_COUNTERS`` (without it: ``MOE_COUNTERS``); a prefill
+# program whose module names ``PREFILL_COUNTERS`` returns those as its third.
+# ``stats()`` has them all, a name both programs count summed, 0 where the
+# family counts no such thing
+DEVICE_COUNTERS = MOE_COUNTERS + ("moe_blocks", "moe_blocks_extra",
+                                  "attn_rows_full", "attn_rows_window")
 # the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
@@ -82,7 +85,8 @@ def _model_of(config):
     """The module that builds ``config``'s weights, cache and programs:
     ``init_params``, ``init_cache``, ``make_paged_prefill_fn``,
     ``make_paged_decode_fn``, ``paged_kernel_fits``, ``SLOT_STATE``, and
-    where it has them ``DECODE_COUNTERS`` and ``RING_FIELDS``.
+    where it has them ``DECODE_COUNTERS``, ``PREFILL_COUNTERS`` and
+    ``RING_FIELDS``.
     ``models/paged_decode.py`` for a ``LlamaConfig``; any other family's
     module holds its configuration class beside its programs, and whoever
     made ``config`` has imported it: a Llama replica imports no other
@@ -240,6 +244,12 @@ class LLMEngine:
       ``length`` a full layer, ``min(length, window)`` a sliding one.
       Counted in the decode program and fetched with the expert counters; 0
       for a family whose module names no such counter (``DECODE_COUNTERS``).
+    - ``moe_blocks``, ``moe_blocks_extra``: calls of the compacted expert
+      product (``ops/moe.py``: a layer of a decode tick, a layer and chunk of
+      a prefill call) and the blocks they ran beyond their first, which is 0
+      unless this share held more of a call's choices than the block takes.
+      Counted in the decode AND the prefill program (``PREFILL_COUNTERS``);
+      a prefill's ride to the host with the next chunk's ``device_get``.
 
     Which model: ``_model_of`` maps the configuration's type to the module
     that builds its weights, its cache and its two programs
@@ -351,6 +361,10 @@ class LLMEngine:
         self._buckets_up: set = set()
         self._counter_names = getattr(model, "DECODE_COUNTERS", MOE_COUNTERS)
         self._device_counts = np.zeros((len(self._counter_names),), np.int64)
+        self._prefill_counter_names = getattr(model, "PREFILL_COUNTERS", ())
+        self._prefill_counts = np.zeros(
+            (len(self._prefill_counter_names),), np.int64)
+        self._prefill_counts_pending: list = []  # on the device, since the last get
         self._cache_stats = self._describe_cache()
         self._queue_wait_counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
         self._ring = np.zeros((RING_ITERS, len(RING_COLUMNS)))
@@ -477,9 +491,17 @@ class LLMEngine:
             **self._cache_stats,
             "kv_pages_in_use": self.total_pages - 1 - self.allocator.free_pages,
             "kv_pages_total": self.total_pages - 1,
-            **dict.fromkeys(DEVICE_COUNTERS, 0),
-            **dict(zip(self._counter_names, self._device_counts.tolist())),
+            **self._device_counter_stats(),
         }
+
+    def _device_counter_stats(self) -> Dict[str, int]:
+        counts = dict.fromkeys(DEVICE_COUNTERS, 0)
+        for names, values in ((self._counter_names, self._device_counts),
+                              (self._prefill_counter_names,
+                               self._prefill_counts)):
+            for name, value in zip(names, values.tolist()):
+                counts[name] += value
+        return counts
 
     def _describe_cache(self) -> Dict[str, int]:
         """What the cache's shapes say, read once: the loop thread donates
@@ -641,7 +663,10 @@ class LLMEngine:
         args = [jnp.asarray(tokens), jnp.asarray(page_arr), jnp.asarray(lengths)]
         if self._slot_state:
             args.append(jnp.asarray(slots))
-        logits, self.cache = self._prefill(self.params, self.cache, *args)
+        # a module that names PREFILL_COUNTERS returns them as well
+        logits, self.cache, *counts = self._prefill(
+            self.params, self.cache, *args)
+        self._prefill_counts_pending += counts
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def _bring_up(self, bucket: int) -> None:
@@ -799,10 +824,14 @@ class LLMEngine:
                       if req is not None and req.pending_first is not None}
         t3 = clock()
         with span("engine.device_get"):
-            host_tokens, host_firsts, host_counts = jax.device_get(
-                (sampled, firsts, counts))
+            host_tokens, host_firsts, host_counts, prefill_counts = \
+                jax.device_get((sampled, firsts, counts,
+                                self._prefill_counts_pending))
             if host_counts:
                 self._device_counts += host_counts[0]
+            for call_counts in prefill_counts:
+                self._prefill_counts += call_counts
+            self._prefill_counts_pending = []
         t4 = clock()
         now = t4 / 1e9  # perf_counter's clock, as submitted_at
         now_wall = time.time()

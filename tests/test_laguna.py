@@ -228,7 +228,7 @@ def _served_logits(config, params, seqs, lengths, ticks, params_ref=None,
         toks[0, :n] = seqs[s, :n]
         pages = np.zeros((2, bucket // PAGE), np.int32)
         pages[0] = table[s, : bucket // PAGE]
-        logits, cache = prefill(
+        logits, cache, _ = prefill(
             params, cache, jnp.asarray(toks), jnp.asarray(pages),
             jnp.asarray([n, 1], jnp.int32), jnp.asarray([s, SLOTS], jnp.int32))
         first.append(logits[0])
@@ -368,6 +368,42 @@ def test_engine_serves_the_family_through_its_normal_path(tiny):
     assert stats["moe_assignments"] > 0 and stats["state_slots"] == 4
 
 
+def test_engine_counts_the_blocks_of_a_compacted_share(monkeypatch):
+    """An eighth of the router's outputs held (2 of 16, top 2; tiles of 8
+    rows here for the grouped product's 512): a prefill's routed choices are
+    compacted to a block before the products, the tokens are still the
+    reference's choices, and ``stats()`` sums the prefill program's own
+    count of the products' calls with the decode program's (whose 8 choices
+    a tick are under a block: none)."""
+    from benchmarks.harness import reference as href
+    from ray_tpu.serve.llm import LLMEngine
+
+    monkeypatch.setattr(moe, "RAGGED_TILE", 8)
+    config = lg.LagunaConfig.tiny(
+        dtype=jnp.float32, attention_impl="reference", num_experts=2,
+        n_router_outputs=16, held_experts=(0, 2))
+    assert moe._capacity(96 * 2, 2, 16) == 48 < 96 * 2
+    assert lg.PREFILL_COUNTERS == lg.DECODE_COUNTERS[4:6]
+    params = lg.init_params(config, jax.random.key(4))
+    prompt = np.random.default_rng(1).integers(1, 256, 77).tolist()
+    engine = LLMEngine(config, params, num_slots=4, max_seq_len=192,
+                       decode_chunk=4, prefill_buckets=[96], page_size=PAGE)
+    try:
+        before = engine.stats()
+        out = engine.generate(tokens=prompt, max_tokens=12)
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    assert before["moe_blocks"] == before["moe_blocks_extra"] == 0
+    gaps = href.teacher_forced_gaps(ref.make_gap_fn(_cfg_dict(config)), params,
+                                    prompt, out["tokens"], 192)
+    assert max(gaps) < LOGIT_TOL
+    # 4 expert layers a call: the bucket's two programs brought up, then the
+    # request's one-row call
+    assert stats["moe_blocks"] == 4 * 3 and stats["moe_blocks_extra"] == 0
+    assert 0 < stats["moe_assignments_held"] < stats["moe_assignments"]
+
+
 def test_other_families_count_no_attended_rows():
     from ray_tpu.models.llama import LlamaConfig
     from ray_tpu.serve.llm import LLMEngine
@@ -381,5 +417,6 @@ def test_other_families_count_no_attended_rows():
     finally:
         engine.stop()
     assert stats["attn_rows_full"] == stats["attn_rows_window"] == 0
+    assert stats["moe_blocks"] == stats["moe_blocks_extra"] == 0
     assert stats["window_ring_pages"] == stats["window_state_bytes"] == 0
     assert stats["kv_pages_total"] == engine.total_pages - 1

@@ -210,7 +210,7 @@ def test_no_token_is_dropped_when_every_token_chooses_one_expert(tiny, impl):
     out, counts = moe.routed_experts(
         y, router, lp["experts"], held=(0, 4), top_k=2, scale=2.5, impl=impl,
         counted=jnp.ones((40,), bool))
-    assert counts.tolist() == [80, 80, 2, 40]
+    assert counts.tolist()[:4] == [80, 80, 2, 40]  # "ragged" counts its blocks too
     scores = jax.nn.sigmoid(y @ lp["router"]["w"])[:, 2:4]
     w = 2.5 * scores / scores.sum(-1, keepdims=True)
     want = sum(w[:, i:i + 1] * moe.relu2_mlp(
