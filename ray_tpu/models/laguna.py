@@ -54,7 +54,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.paged_decode import (
     _live_lengths, _paged_attention, _scatter_prompt_rows_full,
-    _scatter_token_rows, sample_token)
+    _scatter_token_rows, counted_decode_steps, ring_pages_of,
+    ring_prompt_pages, ring_rows, ring_tick)
 from ray_tpu.ops.moe import routed_experts, swiglu_mlp
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -174,10 +175,7 @@ class LagunaCache(NamedTuple):
 
 
 def ring_pages(config: LagunaConfig, page_size: int) -> int:
-    """Pages that hold any ``sliding_window`` consecutive rows and the page
-    being written: the window's first and last row are at most this many
-    pages apart, whatever the alignment."""
-    return (config.sliding_window - 1) // page_size + 2
+    return ring_pages_of(config.sliding_window, page_size)
 
 
 def init_cache(config: LagunaConfig, num_slots: int, total_pages: int,
@@ -344,20 +342,8 @@ def paged_prefill(params, cache: LagunaCache, tokens, pages, lengths, slots,
     ck, cv, ckw, cvw = cache
     per_layer = ck.shape[1] // config.count(FULL)
     rings_per_layer = ckw.shape[1] // config.count(SLIDING)
-    # the logical pages a ring keeps of each row: the last `ring` up to the
-    # page of the last real token; those before the prompt's first page do
-    # not exist and go to the trash ring
-    src = ((lengths - 1) // page_size)[:, None] - (ring - 1) \
-        + jnp.arange(ring, dtype=jnp.int32)[None, :]
-    dst = jnp.where(src >= 0, slots[:, None] * ring + src % ring,
-                    rings_per_layer - ring + jnp.arange(ring)[None, :])
-    src = jnp.clip(src, 0, n_pages - 1)[:, :, None, None, None]
-
-    def ring_rows(rows):
-        """rows: [PB, S, n_kv, D] -> the pages ``src`` names, as rows."""
-        paged = rows.reshape(pb, n_pages, page_size, *rows.shape[2:])
-        return jnp.take_along_axis(paged, src, axis=1).reshape(
-            pb, ring * page_size, *rows.shape[2:])
+    src, dst = ring_prompt_pages(lengths, slots, ring, n_pages,
+                                 rings_per_layer, page_size)
 
     moe_counts = jnp.zeros((len(PREFILL_COUNTERS),), jnp.int32)
     f_idx = w_idx = 0
@@ -376,8 +362,10 @@ def paged_prefill(params, cache: LagunaCache, tokens, pages, lengths, slots,
             o = attention(q, k, v, causal=True, impl=config.attention_impl,
                           window=config.sliding_window)
             layer_rings = dst + w_idx * rings_per_layer
-            ckw = _scatter_prompt_rows_full(ckw, ring_rows(k), layer_rings)
-            cvw = _scatter_prompt_rows_full(cvw, ring_rows(v), layer_rings)
+            ckw = _scatter_prompt_rows_full(
+                ckw, ring_rows(k, src, page_size), layer_rings)
+            cvw = _scatter_prompt_rows_full(
+                cvw, ring_rows(v, src, page_size), layer_rings)
             w_idx += 1
         x = x + _attn_out(lp, o, gate)
         y = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
@@ -412,16 +400,8 @@ def paged_decode_one(params, cache: LagunaCache, tokens, positions, active,
     pages = jnp.take_along_axis(table, page_idx[:, None], axis=1)[:, 0]
     rows = safe_pos % page_size
     lengths = _live_lengths(safe_pos, active)
-    # a sliding layer attends over rows [start, length) of the slot's ring,
-    # handed to the kernel in logical order from the page that holds `start`
-    start = jnp.maximum(lengths - config.sliding_window, 0)
-    first_page = start // page_size
-    slot_ring = jnp.arange(nb, dtype=jnp.int32) * ring
-    ring_table = slot_ring[:, None] + (
-        first_page[:, None] + jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring
-    win_lengths = lengths - first_page * page_size
-    win_starts = start - first_page * page_size
-    win_pages = jnp.where(active, slot_ring + page_idx % ring, nb * ring)
+    start, ring_table, win_lengths, win_starts, win_pages = ring_tick(
+        lengths, active, page_idx, config.sliding_window, ring, page_size)
     rope = rope or _rope_tables(config, max_ctx)
     ck, cv, ckw, cvw = cache
     per_layer = ck.shape[1] // config.count(FULL)
@@ -469,24 +449,13 @@ def paged_decode_steps(params, cache: LagunaCache, tokens, positions, active,
     """``num_steps`` decode ticks on the device, as
     ``models/paged_decode.py`` ``paged_decode_steps``; the fifth result is
     ``DECODE_COUNTERS`` summed over ticks and layers."""
-
     rope = _rope_tables(config, table.shape[1] * page_size)
-
-    def tick(carry, k_):
-        toks, pos, cache, counts = carry
-        logits, cache, step_counts = paged_decode_one(
+    return counted_decode_steps(
+        lambda cache, toks, pos: paged_decode_one(
             params, cache, toks, pos, active, table, config, page_size,
-            use_kernel, rope)
-        nxt = sample_token(logits, k_, temperature)
-        nxt = jnp.where(active, nxt, toks)
-        new_pos = jnp.where(active, pos + 1, pos)
-        return (nxt, new_pos, cache, counts + step_counts), nxt
-
-    keys = jax.random.split(key, num_steps)
-    (last, pos, cache, counts), sampled = jax.lax.scan(
-        tick, (tokens, positions, cache,
-               jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)), keys)
-    return sampled.T, last, pos, cache, counts
+            use_kernel, rope),
+        cache, tokens, positions, active, key, num_steps, temperature,
+        len(DECODE_COUNTERS))
 
 
 def paged_kernel_fits(config: LagunaConfig) -> bool:

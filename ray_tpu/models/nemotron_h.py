@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.paged_decode import (
     _live_lengths, _paged_attention, _scatter_prompt_rows_full,
-    _scatter_token_rows, sample_token)
+    _scatter_token_rows, counted_decode_steps)
 from ray_tpu.ops import ssm
 from ray_tpu.ops.moe import relu2_mlp, routed_experts
 from ray_tpu.ops.norms import rms_norm
@@ -403,21 +403,11 @@ def paged_decode_steps(params, cache: HybridCache, tokens, positions, active,
     """``num_steps`` decode ticks on the device, as
     ``models/paged_decode.py`` ``paged_decode_steps``; the fifth result is
     the expert layers' counts (``ops/moe.py``) summed over ticks and layers."""
-
-    def tick(carry, k_):
-        toks, pos, cache, counts = carry
-        logits, cache, step_counts = paged_decode_one(
+    return counted_decode_steps(
+        lambda cache, toks, pos: paged_decode_one(
             params, cache, toks, pos, active, table, config, page_size,
-            use_kernel)
-        nxt = sample_token(logits, k_, temperature)
-        nxt = jnp.where(active, nxt, toks)
-        new_pos = jnp.where(active, pos + 1, pos)
-        return (nxt, new_pos, cache, counts + step_counts), nxt
-
-    keys = jax.random.split(key, num_steps)
-    (last, pos, cache, counts), sampled = jax.lax.scan(
-        tick, (tokens, positions, cache, jnp.zeros((4,), jnp.int32)), keys)
-    return sampled.T, last, pos, cache, counts
+            use_kernel),
+        cache, tokens, positions, active, key, num_steps, temperature, 4)
 
 
 def paged_kernel_fits(config: NemotronHConfig) -> bool:

@@ -184,6 +184,60 @@ def _live_lengths(safe_pos, active):
 
 
 # --------------------------------------------------------------------------- #
+# Window rings (models/laguna.py, models/phi4flash.py): a fixed ring of pages
+# a slot a sliding layer, beside the pool and unseen by the allocator
+# --------------------------------------------------------------------------- #
+def ring_pages_of(window: int, page_size: int) -> int:
+    """Pages that hold any ``window`` consecutive rows and the page being
+    written: the window's first and last row are at most this many pages
+    apart, whatever the alignment."""
+    return (window - 1) // page_size + 2
+
+
+def ring_prompt_pages(lengths, slots, ring: int, n_pages: int,
+                      rings_per_layer: int, page_size: int):
+    """What a prefill writes into the rings of one sliding layer: (src, the
+    logical pages a ring keeps of each row, the last ``ring`` up to the page
+    of the last real token, as an index for ``ring_rows``; dst [PB, ring],
+    the ring page of each within the layer's block: logical page ``p`` at
+    ring page ``p % ring`` of the row's slot, and those before the prompt's
+    first page, which do not exist, in the trash ring)."""
+    src = ((lengths - 1) // page_size)[:, None] - (ring - 1) \
+        + jnp.arange(ring, dtype=jnp.int32)[None, :]
+    dst = jnp.where(src >= 0, slots[:, None] * ring + src % ring,
+                    rings_per_layer - ring + jnp.arange(ring)[None, :])
+    return jnp.clip(src, 0, n_pages - 1)[:, :, None, None, None], dst
+
+
+def ring_rows(rows, src, page_size: int):
+    """rows: [PB, S, n_kv, D] -> the pages ``src`` names, as rows."""
+    pb, s = rows.shape[:2]
+    paged = rows.reshape(pb, s // page_size, page_size, *rows.shape[2:])
+    return jnp.take_along_axis(paged, src, axis=1).reshape(
+        pb, src.shape[1] * page_size, *rows.shape[2:])
+
+
+def ring_tick(lengths, active, page_idx, window: int, ring: int,
+              page_size: int):
+    """A decode tick's view of every slot's ring: a sliding layer attends
+    over rows [start, length) of it, handed to the kernel in logical order
+    from the page that holds ``start``. Returns (start, table [B, ring] of
+    ring pages within a layer's block, lengths and starts counted from the
+    table's first page, the ring page the tick's token is written to: the
+    trash ring's first for an inactive slot)."""
+    nb = lengths.shape[0]
+    start = jnp.maximum(lengths - window, 0)
+    first_page = start // page_size
+    slot_ring = jnp.arange(nb, dtype=jnp.int32) * ring
+    table = slot_ring[:, None] + (
+        first_page[:, None] + jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring
+    from_first = (lengths - first_page * page_size,
+                  start - first_page * page_size)
+    write = jnp.where(active, slot_ring + page_idx % ring, nb * ring)
+    return (start, table, *from_first, write)
+
+
+# --------------------------------------------------------------------------- #
 # Prefill
 # --------------------------------------------------------------------------- #
 def _scatter_prompt_rows_full(pool, rows, pages):
@@ -310,6 +364,28 @@ def paged_decode_steps(params, cache: PagedKVCache, tokens, positions, active,
         tick, (tokens, positions, cache), keys
     )
     return sampled.T, last, pos, cache
+
+
+def counted_decode_steps(decode_one, cache, tokens, positions, active, key,
+                         num_steps: int, temperature: float, n_counters: int):
+    """``paged_decode_steps`` for a family whose tick also counts:
+    ``decode_one(cache, tokens, positions) -> (logits, cache, int32
+    [n_counters])``. Returns (sampled [B, T], last tokens, new positions,
+    cache, the counters summed over the ticks)."""
+
+    def tick(carry, k_):
+        toks, pos, cache, counts = carry
+        logits, cache, step_counts = decode_one(cache, toks, pos)
+        nxt = sample_token(logits, k_, temperature)
+        nxt = jnp.where(active, nxt, toks)
+        new_pos = jnp.where(active, pos + 1, pos)
+        return (nxt, new_pos, cache, counts + step_counts), nxt
+
+    keys = jax.random.split(key, num_steps)
+    (last, pos, cache, counts), sampled = jax.lax.scan(
+        tick, (tokens, positions, cache,
+               jnp.zeros((n_counters,), jnp.int32)), keys)
+    return sampled.T, last, pos, cache, counts
 
 
 def paged_kernel_fits(config: LlamaConfig) -> bool:

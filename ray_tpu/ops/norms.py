@@ -56,3 +56,16 @@ _rms_norm.defvjp(_rms_norm_fwd, _rms_norm_bwd)
 
 def rms_norm(x, weight, eps: float = 1e-6):
     return _rms_norm(x, weight, eps)
+
+
+def layer_norm(x, weight, bias, eps: float = 1e-5):
+    """LayerNorm over the last axis with weight and bias; statistics in
+    float32, the result in x's dtype. Plain ``jnp``: XLA fuses it, as the RMS
+    norm's forward."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    centred = x32 - mean
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    y = centred * jax.lax.rsqrt(var + eps)
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)

@@ -7,8 +7,11 @@ GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
   (models/paged_decode.py) and, beside it, what a family keeps per SLOT: the
   recurrent state of Mamba layers (models/nemotron_h.py), the window rings
   of sliding-attention layers (models/laguna.py: a second kind of KV storage,
-  a fixed ring of pages a slot a layer that the allocator never sees) — one
-  slot per in-flight request;
+  a fixed ring of pages a slot a layer that the allocator never sees), or
+  both beside pages that ONE layer writes and several read
+  (models/phi4flash.py: a third arrangement, in which the layers that keep
+  pages are fewer than the layers that read them) — one slot per in-flight
+  request;
 - CONTINUOUS batching: new requests are prefilled into free slots while
   other slots keep decoding — no batch barrier (Orca-style iteration-level
   scheduling);
@@ -66,7 +69,9 @@ MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
 # ``stats()`` has them all, a name both programs count summed, 0 where the
 # family counts no such thing
 DEVICE_COUNTERS = MOE_COUNTERS + ("moe_blocks", "moe_blocks_extra",
-                                  "attn_rows_full", "attn_rows_window")
+                                  "attn_rows_full", "attn_rows_window",
+                                  "attn_rows_shared", "scan_slots",
+                                  "prefill_rows_self", "prefill_rows_cross")
 # the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
@@ -112,13 +117,20 @@ def _laguna_tiny():
     return LagunaConfig.tiny()
 
 
+def _phi4flash_tiny():
+    from ray_tpu.models.phi4flash import Phi4FlashConfig
+
+    return Phi4FlashConfig.tiny()
+
+
 def model_presets() -> Dict[str, Any]:
     """``LLMDeployment``'s preset names."""
     from ray_tpu.models.llama import LlamaConfig
 
     return {"tiny": LlamaConfig.tiny, "llama_1b": LlamaConfig.llama_1b,
             "llama3_8b": LlamaConfig.llama3_8b,
-            "nemotron_h_tiny": _nemotron_h_tiny, "laguna_tiny": _laguna_tiny}
+            "nemotron_h_tiny": _nemotron_h_tiny, "laguna_tiny": _laguna_tiny,
+            "phi4flash_tiny": _phi4flash_tiny}
 
 
 def _steal_s() -> float:
@@ -157,9 +169,11 @@ class LLMEngine:
     """Continuous-batching loop around a model's prefill and decode programs:
     models/paged_decode.py (Llama family, paged KV cache),
     models/nemotron_h.py (hybrid family: pages and per-slot recurrent
-    state) or models/laguna.py (window and full attention layers: pages for
-    the full layers, per-slot rings for the window layers). One loop, one
-    admission, one allocator, one set of counters for all three.
+    state), models/laguna.py (window and full attention layers: pages for
+    the full layers, per-slot rings for the window layers) or
+    models/phi4flash.py (pages that one layer writes and eight read, rings
+    for the window layers, per-slot scan state). One loop, one admission,
+    one allocator, one set of counters for all four.
 
     HBM is committed per REQUEST (ceil((prompt+max_tokens)/page_size) pages
     from a shared pool), not per-slot*max_seq — so ``num_slots`` is bounded
@@ -224,7 +238,11 @@ class LLMEngine:
       also one warning line in the log (at most one every 10 s;
       ``slow_iters_unlogged`` counts the rest).
     - ``kv_bytes_per_token``: bytes of K and V a cached token takes over
-      all the layers that keep pages. ``state_slots``, ``state_bytes``:
+      all the layers that KEEP (write) pages: what a token costs the pool.
+      Layers that only READ another layer's pages (models/phi4flash.py: one
+      layer keeps, eight read) are not in it; what a decode tick reads of
+      the pool is ``attn_rows_shared`` rows of that width.
+      ``state_slots``, ``state_bytes``:
       slots that keep state beside their pages (recurrent state, window
       rings), and the bytes of it (all slots and the trash row); 0 for a
       model that keeps none. ``window_ring_pages``, ``window_state_bytes``:
@@ -244,6 +262,15 @@ class LLMEngine:
       ``length`` a full layer, ``min(length, window)`` a sliding one.
       Counted in the decode program and fetched with the expert counters; 0
       for a family whose module names no such counter (``DECODE_COUNTERS``).
+    - ``attn_rows_shared``, ``scan_slots``: K/V rows attended in pages that
+      several layers read, summed over live slots, ticks and the READING
+      layers (``length`` x readers; a family whose every reading layer keeps
+      its own pages counts ``attn_rows_full`` instead), and the per-slot
+      scan states a selective scan moved (active slots x scan layers x
+      ticks). ``prefill_rows_self``, ``prefill_rows_cross``: prompt tokens
+      that ran the layers every row runs, and rows that ran the layers only
+      a prompt's last row needs (models/phi4flash.py: one a prompt); counted
+      in the prefill program. 0 for the other families.
     - ``moe_blocks``, ``moe_blocks_extra``: calls of the compacted expert
       product (``ops/moe.py``: a layer of a decode tick, a layer and chunk of
       a prefill call) and the blocks they ran beyond their first, which is 0
@@ -255,7 +282,8 @@ class LLMEngine:
     that builds its weights, its cache and its two programs
     (``models/paged_decode.py`` for ``LlamaConfig``,
     ``models/nemotron_h.py`` for ``NemotronHConfig``, ``models/laguna.py``
-    for ``LagunaConfig``). The cache is one donated pytree. Where the module
+    for ``LagunaConfig``, ``models/phi4flash.py`` for ``Phi4FlashConfig``).
+    The cache is one donated pytree. Where the module
     says ``SLOT_STATE``, the cache also holds state addressed by slot
     (recurrent state, window rings): prefill is told each row's slot (a pad
     row: the trash row ``num_slots``) and overwrites it, so a retired slot

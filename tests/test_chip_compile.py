@@ -397,6 +397,13 @@ ACCEPTED_PROGRAMS_SHA = {
         "7aa2520cbdeae4f4b4180246a934686474e3e9e6e42299a5f5bf1400c3b313c7",
     "flash_fwd_bwd": 
         "f7e7ab589c6105498b819b990dbda5cda3c09b4307791981cd966bc05680969c",
+    # the window family, taken on the parent commit of PR 38 (e8140a7), whose
+    # ring arithmetic PR 38 moved into models/paged_decode.py for the fourth
+    # family to share
+    "laguna_decode":
+        "373b08f3cc3b766fdc496f79d802db901e990b84f58b47925d5908068f7daca8",
+    "laguna_prefill":
+        "3e289e0cde0ba08fe7ac7fa176851ce1125cd6f0259b31ee1a7bef23c84592e7",
 }
 
 
@@ -407,6 +414,9 @@ def _accepted_program(v5e, name):
 
     one = SingleDeviceSharding(v5e.devices[0])
     shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    if name.startswith("laguna"):
+        return _laguna_lowered(
+            v5e, bucket=8192 if name == "laguna_prefill" else None)[0]
     if name == "llama_decode":
         config, args = _decode_shapes(v5e, config=_mistral_7b(2))
         return pd.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(*args)
@@ -444,7 +454,9 @@ def test_accepted_programs_lower_to_the_parents_text(v5e, name):
     """``ops/paged_attention.py`` gained ``starts``, ``ops/attention.py`` a
     ``window``, ``ops/rope.py`` a partial head and YaRN, ``ops/moe.py`` a
     scoring and an expert form (PR 35), and a block that compacts a small
-    share's assignments (PR 37: a half share's block holds every assignment).
+    share's assignments (PR 37: a half share's block holds every assignment);
+    ``ops/ssm.py`` gained the selective scan beside the Mamba-2 pair and
+    ``models/paged_decode.py`` the ring arithmetic that was Laguna's (PR 38).
     Called as the accepted families call
     them, they trace to what they were: the decode and prefill programs of
     the Llama-shaped and the hybrid family, and the flash forward and
@@ -459,12 +471,12 @@ def test_accepted_programs_lower_to_the_parents_text(v5e, name):
     assert hashlib.sha256(text.encode()).hexdigest() == ACCEPTED_PROGRAMS_SHA[name]
 
 
-def _laguna(v5e, slots=24, bucket=None):
+def _laguna_lowered(v5e, slots=24, bucket=None):
     """The third family at Laguna-XS.2's published widths, a full and a
     sliding layer (query groups of 6 and 8, both rotary schemes, one expert
     layer of 32 held experts of the router's 256, a slice of the vocabulary):
     its decode program over ``slots`` slots, or its one-row prefill program
-    of ``bucket``."""
+    of ``bucket``, lowered; and its cache."""
     from ray_tpu.models import laguna as lg
 
     one = SingleDeviceSharding(v5e.devices[0])
@@ -490,6 +502,11 @@ def _laguna(v5e, slots=24, bucket=None):
             params, cache, ints, ints, shape((slots,), jnp.bool_),
             shape((slots, pages), jnp.int32),
             _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered, cache
+
+
+def _laguna(v5e, slots=24, bucket=None):
+    lowered, cache = _laguna_lowered(v5e, slots, bucket)
     return lowered.compile(), cache
 
 
@@ -535,3 +552,76 @@ def test_laguna_prefill_of_the_longest_bucket_compiles(v5e):
     assert re.search(rf"ragged-dot\S* = f32\[{block},2048\]", text)
     assert not re.search(rf"f32\[{choices},\d+\]", text)
     assert not re.search(rf"bf16\[{choices},\d+\]", text)
+
+
+# --------------------------------------------------------------------------- #
+# PR 38: the fourth family, a selective scan and one layer's pages for eight
+# --------------------------------------------------------------------------- #
+def _phi4flash(v5e, slots=24, bucket=None):
+    """The fourth family at Phi-4-mini-flash-reasoning's published widths and
+    EIGHT of its 32 layers, one of every kind in the published order (scan,
+    window, scan, window, scan, full, memory unit, cross), a slice of the
+    vocabulary: its decode program over ``slots`` slots, or its one-row
+    prefill program of ``bucket``, compiled; and its cache."""
+    from ray_tpu.models import phi4flash as pf
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    config = pf.Phi4FlashConfig(
+        vocab_size=8192, num_hidden_layers=8, max_seq_len=16896,
+        attention_impl="flash", scan_impl="pallas")
+    pages = 16896 // PAGE
+    params = _on(one, jax.eval_shape(lambda k: pf.init_params(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: pf.init_cache(config, slots, slots * pages + 1, PAGE)))
+    if bucket:
+        lowered = pf.make_paged_prefill_fn(config, PAGE).lower(
+            params, cache, shape((1, bucket), jnp.int32),
+            shape((1, bucket // PAGE), jnp.int32), shape((1,), jnp.int32),
+            shape((1,), jnp.int32))
+    else:
+        ints = shape((slots,), jnp.int32)
+        lowered = pf.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(
+            params, cache, ints, ints, shape((slots,), jnp.bool_),
+            shape((slots, pages), jnp.int32),
+            _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered.compile(), cache
+
+
+def test_phi4flash_decode_holds_its_kernels_and_moves_no_pool_or_state(v5e):
+    """Mosaic takes the paged-attention kernel at packed rows (10 KV pairs
+    of 128, a query group of 4) over the ONE layer's pages, once for the
+    layer that keeps them and once for the cross layer that reads them, and
+    with ``starts`` over the rings; and the one-token scan, a slot a grid
+    step, over the array of every layer's state. A profile tells the three
+    apart by name (the benchmark's ``shared_kv_decode_roofline``,
+    ``window_attn_decode_roofline.phi`` and ``selective_scan_step_roofline``
+    read them so). Pages, rings, scan state and convolution rows ride one
+    donated cache that the program aliases and never copies: nothing the
+    size of a layer's scan state (25 slots x 320 KB) is moved either."""
+    compiled, cache = _phi4flash(v5e)
+    calls = sorted(c.split(".")[0] for c in _mosaic_calls(compiled.as_text()))
+    assert calls == ["paged_attention"] * 2 + ["paged_attention_window"] * 2 \
+        + ["selective_scan_step"] * 3, calls
+    # the whole cache and nothing else of size: the convolution's three rows
+    # a slot are padded to a tile in the device's layout
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert 0 <= aliased - _pool_bytes(cache) < 1e6
+    layer_state = cache.ssm.size // cache.ssm.shape[0]
+    moved = [line for line in _pool_sized_moves(compiled.as_text(), layer_state)
+             if re.search(r"= (bf16\[10,\d+,64,128\]|f32\[\d+,\d+,16,40,128\])",
+                          line)]
+    assert moved == []
+
+
+def test_phi4flash_prefill_of_the_longest_bucket_has_no_attention_over_the_prompt(v5e):
+    """One row of 16,384 tokens: the scan kernel (``selective_scan_fwd``, a
+    call a piece of 4,096 rows inside a loop) and the windowed flash forward
+    are there, and NO full causal flash call: the full layer projects K/V for
+    every row and attends from the last row alone, so nothing quadratic in
+    the prompt is left. The program fits beside the weights and the cache."""
+    compiled, _ = _phi4flash(v5e, bucket=16384)
+    calls = {c.split(".")[0] for c in _mosaic_calls(compiled.as_text())}
+    assert calls == {"selective_scan_fwd", "flash_window_fwd"}, calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
