@@ -33,6 +33,7 @@ from ray_tpu.core.resources import (
 from ray_tpu.core.rpc import RpcConnectionError, RpcError, SyncRpcClient
 from ray_tpu.core.runtime import CoreRuntime
 from ray_tpu.core.shm_store import ShmReader, ShmWriter, segment_name
+from ray_tpu.core.streaming import stream_item_id
 from ray_tpu.core.task_spec import TaskSpec
 from ray_tpu.core.worker import Worker, global_worker
 from ray_tpu.utils.logging import get_logger
@@ -151,6 +152,9 @@ class ClusterRuntime(CoreRuntime):
         # ids NOT here (puts, borrowed refs) go straight to the ensure path
         self._pending_task_returns: Dict[str, bool] = {}
         self._actor_pipelines: Dict[str, "_ActorPipeline"] = {}
+        # task hex -> this process's end of a streaming actor call whose
+        # stream directory is in the actor's worker (core/streaming.py)
+        self._caller_streams: Dict[str, "_CallerStream"] = {}
         # batched actor-call ref pins/unpins: one FIFO thread preserves
         # pin-before-unpin order per task while coalescing into pin_tasks/
         # unpin_tasks RPCs (the lockstep path pays one GCS round trip per
@@ -532,6 +536,9 @@ class ClusterRuntime(CoreRuntime):
                 if ent is None or h in self._inline_promoted:
                     continue
                 self._inline_promoted.add(h)
+            # the value enters the object directory here: so does this
+            # process's hold on it (a stream's item had none until now)
+            self._queue_ref_op("add", h)
             try:
                 self.agent.call(
                     "put_object", object_id=h, payload=ent["payload"],
@@ -618,20 +625,24 @@ class ClusterRuntime(CoreRuntime):
         if not refs:
             return []
         self._barrier_submit_acks()
+        deadline = None if timeout is None else time.monotonic() + timeout
+        ids = [r.id.hex() for r in refs]
+        resolved: Dict[str, Any] = {}
+        todo: List[str] = []
+        seen: set = set()
+        for h in ids:
+            if h in seen:
+                continue
+            seen.add(h)
+            if not (self.pipelined and self._resolve_cached(h, resolved)):
+                todo.append(h)
+        if not todo:
+            # everything was in this process already (inline results, a
+            # stream's items): nothing to wait for, no one to tell
+            return [resolved[h] for h in ids]
         blocked = self._notify_blocked(True)
         try:
-            deadline = None if timeout is None else time.monotonic() + timeout
-            ids = [r.id.hex() for r in refs]
-            resolved: Dict[str, Any] = {}
-            todo: List[str] = []
-            seen: set = set()
-            for h in ids:
-                if h in seen:
-                    continue
-                seen.add(h)
-                if not (self.pipelined and self._resolve_cached(h, resolved)):
-                    todo.append(h)
-            if todo and self.pipelined:
+            if self.pipelined:
                 # push phase: completions stream in over the sealed-event
                 # channel (and actor-call replies); zero RPCs while they flow
                 todo = self._await_pushed(todo, deadline, resolved)
@@ -904,21 +915,36 @@ class ClusterRuntime(CoreRuntime):
 
     # ------------------------------------------------- streaming generators
     def stream_next(self, task_hex: str, index: int, timeout: Optional[float]):
-        """Long-poll the GCS stream directory in bounded chunks (same pattern
-        as get(): a dropped frame costs one chunk, not the whole deadline)."""
+        """Long-poll the stream's directory in bounded chunks (same pattern
+        as get(): a dropped frame costs one chunk, not the whole deadline):
+        the actor's worker for a streaming actor call of this process, else
+        the GCS."""
         self._barrier_submit_acks()  # a dropped submit must raise, not hang
         deadline = None if timeout is None else time.monotonic() + timeout
+        cs = self._caller_streams.get(task_hex)
+        # the attempt window doubles, as a retry-safe call's does: a lost
+        # frame is asked for again soon, a quiet stream is polled rarely
+        attempt_s = max(0.2, config.rpc_retry_attempt_timeout_s) \
+            if cs is not None else 5.0
         while True:
+            if cs is not None:
+                out = self._caller_stream_take(cs, task_hex, index)
+                if out is not None:
+                    return out
             remaining = None if deadline is None else deadline - time.monotonic()
             if remaining is not None and remaining <= 0:
                 raise exc.GetTimeoutError(
                     f"stream item {index} of {task_hex[:16]} not ready in {timeout}s"
                 )
-            attempt_s = 5.0 if remaining is None else min(remaining, 5.0)
+            wait_s = attempt_s if remaining is None else min(remaining, attempt_s)
+            if cs is not None:
+                self._caller_stream_poll(cs, task_hex, index, wait_s)
+                attempt_s = min(2 * attempt_s, 5.0)
+                continue
             try:
                 resp = self.gcs.call(
                     "stream_next", task_id=task_hex, index=index,
-                    timeout=attempt_s + 5.0, timeout_s=attempt_s,
+                    timeout=wait_s + 5.0, timeout_s=wait_s,
                 )
             except TimeoutError:
                 continue
@@ -928,7 +954,96 @@ class ClusterRuntime(CoreRuntime):
                 return ("end", resp["end"])
             return ("item", resp["object_id"])
 
+    def _caller_stream_take(self, cs: "_CallerStream", task_hex: str,
+                            index: int):
+        """What this process already has of the stream at ``index``: the
+        item (its payload lands in the inline cache as an actor call's small
+        result does: ``get`` finds it here, and a ref that escapes is
+        promoted), the end, or the call's failure as the next item. None:
+        ask the worker."""
+        with cs.lock:
+            entry = cs.ready.pop(index, None)
+            if entry is None and cs.failure is not None and (
+                    cs.total is None or index < cs.total):
+                # the call failed for good (the actor died): what was not
+                # read is lost; the failure is the next item, then the end
+                cs.total = index + 1
+                entry = self._error_entry(cs.pipeline.actor_hex, *cs.failure)
+            total = cs.total
+        if entry is None:
+            if total is None or index < total:
+                return None
+            self._drop_caller_stream(task_hex, cs)
+            return ("end", total)
+        oid_hex = entry.get("object_id")
+        if oid_hex is None:
+            oid_hex = stream_item_id(task_hex, index).hex()
+            with self._seal_cond:
+                self._inline_cache[oid_hex] = {
+                    "object_id": oid_hex, "payload": entry["payload"],
+                    "is_error": entry["is_error"]}
+            self._evict_inline_overflow()
+        return ("item", oid_hex)
+
+    @staticmethod
+    def _error_entry(actor_hex: str, message: str,
+                     error_type: str) -> Dict[str, Any]:
+        err = (exc.ActorUnavailableError(message)
+               if error_type == "ActorUnavailableError"
+               else exc.ActorDiedError(actor_hex, message))
+        payload, _ = serialization.pack(err)
+        return {"payload": bytes(payload), "is_error": True}
+
+    def _caller_stream_poll(self, cs: "_CallerStream", task_hex: str,
+                            index: int, wait_s: float) -> None:
+        """One long-poll to the actor's worker over the call's connection;
+        what it brings goes into ``cs``. A worker that cannot be reached is
+        the call's failure path's to judge (it retries the call on the
+        restarted actor, or fails the stream), unless the call is over:
+        then the items died with the worker."""
+        try:
+            resp = cs.pipeline._get_client().call(  # noqa: SLF001
+                "actor_stream_next", task_id=task_hex, index=index,
+                timeout_s=wait_s, create=not cs.call_done,
+                timeout=wait_s + max(1.0, wait_s))
+        except TimeoutError:
+            return
+        except (exc.ActorDiedError, exc.ActorUnavailableError,
+                ConnectionError, RpcError) as e:
+            if cs.call_done:
+                cs.fail(f"actor's worker lost with the stream unread: {e}",
+                        "ActorDiedError")
+            else:
+                time.sleep(0.05)
+            return
+        if resp.get("lost"):
+            cs.fail("actor restarted with the stream unread", "ActorDiedError")
+            return
+        with cs.lock:
+            for offset, entry in enumerate(resp.get("items") or ()):
+                cs.ready[index + offset] = entry
+            if "end" in resp:
+                cs.total = resp["end"]
+
+    def _drop_caller_stream(self, task_hex: str, cs: "_CallerStream") -> None:
+        """The consumer is done (read to the end, or closed): forget the
+        stream here and tell the worker, which then drops its record and
+        stops a producer that still runs. One frame, no reply awaited."""
+        if self._caller_streams.pop(task_hex, None) is None:
+            return
+        client = cs.pipeline._client  # noqa: SLF001 - no route: no record
+        if client is not None:
+            try:
+                client.call_async("actor_stream_close", task_id=task_hex,
+                                  timeout=10.0)
+            except Exception:  # noqa: BLE001 - teardown path
+                pass
+
     def stream_close(self, task_hex: str) -> None:
+        cs = self._caller_streams.get(task_hex)
+        if cs is not None:
+            self._drop_caller_stream(task_hex, cs)
+            return
         try:
             self.gcs.call("stream_close", task_id=task_hex)
         except Exception:  # noqa: BLE001 - teardown path
@@ -1324,8 +1439,13 @@ class ClusterRuntime(CoreRuntime):
             # never overtake it), results at most the inline threshold ride
             # back IN the completion reply, and many calls stay in flight
             # per actor (seq-ordered on the worker side).
-            if not spec.generator:
-                sd["inline_max"] = self._inline_max
+            sd["inline_max"] = self._inline_max
+            if spec.generator:
+                # the stream's directory is in the actor's worker, and this
+                # process reads it there over the pipeline's connection
+                self._caller_streams[sd["task_id"]] = _CallerStream(
+                    self._actor_pipeline(actor_id.hex()))
+            else:
                 with self._seal_cond:
                     self._pending_actor_returns.update(sd["returns"])
             self._queue_refop("pin", pin_kwargs)
@@ -1440,6 +1560,17 @@ class ClusterRuntime(CoreRuntime):
                     )
                     return
                 time.sleep(0.1 * attempts)
+
+    def _fail_caller_stream(self, sd: Dict[str, Any], message: str,
+                            error_type: str) -> bool:
+        """A streaming actor call that is read from the worker failed for
+        good: its consumer gets the failure as its next item. False for any
+        other call (its error objects go through the store)."""
+        cs = self._caller_streams.get(sd.get("task_id"))
+        if cs is None:
+            return False
+        cs.fail(message, error_type)
+        return True
 
     def _store_error_objects(self, sd: Dict[str, Any], message: str, error_type: str) -> None:
         try:
@@ -1560,6 +1691,29 @@ class ClusterRuntime(CoreRuntime):
 
     def kv_keys(self, prefix: str = "") -> List[str]:
         return self.gcs.call("kv_keys", prefix=prefix)
+
+
+class _CallerStream:
+    """The calling process's end of a streaming actor call whose directory
+    is in the actor's worker: the entries a long-poll brought and the
+    consumer has not taken yet, the end once known, and the call's fate
+    (``call_done``: its reply or its failure is in; ``failure``: it failed
+    for good, as ``(message, error type)``)."""
+
+    __slots__ = ("pipeline", "ready", "total", "failure", "call_done", "lock")
+
+    def __init__(self, pipeline: "_ActorPipeline"):
+        self.pipeline = pipeline
+        self.ready: Dict[int, Dict[str, Any]] = {}
+        self.total: Optional[int] = None
+        self.failure: Optional[Tuple[str, str]] = None
+        self.call_done = False
+        self.lock = threading.Lock()
+
+    def fail(self, message: str, error_type: str) -> None:
+        with self.lock:
+            if self.failure is None:
+                self.failure = (message, error_type)
 
 
 class _ActorPipeline:
@@ -1691,6 +1845,9 @@ class _ActorPipeline:
             "ConnectionError", "RpcConnectionError", "ActorDiedError",
         ):
             # handler-level error: results already stored as error objects
+            # (a stream that is read from the worker has none: say it there)
+            self.rt._fail_caller_stream(sd, f"actor call failed: {e}",
+                                        "ActorDiedError")
             self._finish(sd)
             return
         self._client = None  # route may be stale (worker died/restarted)
@@ -1723,7 +1880,8 @@ class _ActorPipeline:
         self.q.put(("dispatch", sd, retries, attempts))
 
     def _fail(self, sd: Dict[str, Any], message: str, error_type: str) -> None:
-        self.rt._store_error_objects(sd, message, error_type)
+        if not self.rt._fail_caller_stream(sd, message, error_type):
+            self.rt._store_error_objects(sd, message, error_type)
         self._finish(sd)
 
     def _finish(self, sd: Dict[str, Any]) -> None:
@@ -1738,6 +1896,9 @@ class _ActorPipeline:
                 "object_ids": (sd.get("deps") or []) + (sd.get("returns") or []),
             })
         rt._actor_returns_done(sd)
+        cs = rt._caller_streams.get(sd.get("task_id"))
+        if cs is not None:
+            cs.call_done = True
 
 
 def connect_driver(address: str, namespace: Optional[str] = None,

@@ -18,18 +18,21 @@ import hashlib
 import os
 import queue
 import threading
+import time
 import traceback
 from typing import Any, Dict, List, Optional
 
 import cloudpickle
 
 from ray_tpu import exceptions as exc
+from ray_tpu import profiling
 from ray_tpu.core import serialization
 from ray_tpu.core.config import config
 from ray_tpu.core.ids import ActorID, JobID, NodeID, ObjectID, TaskID, WorkerID
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.rpc import RpcClient, RpcServer, SyncRpcClient, spawn
 from ray_tpu.core.shm_store import ShmWriter
+from ray_tpu.core.streaming import WorkerStream, stream_item_id
 from ray_tpu.utils.logging import get_logger, setup_component_logging
 
 logger = get_logger("worker")
@@ -42,8 +45,10 @@ class WorkerProcess:
         self.gcs_addr = os.environ["RAY_TPU_GCS_ADDR"]
         self.node_hex = os.environ["RAY_TPU_NODE_ID"]
         # chaos-exempt: task/actor-call execution is not idempotent (the
-        # chaos tier targets the control plane — GCS + agents)
-        self.rpc = RpcServer("127.0.0.1", 0, chaos=False)
+        # chaos tier targets the control plane — GCS + agents). A stream's
+        # long-poll and close are: the caller sends them again
+        self.rpc = RpcServer("127.0.0.1", 0, chaos=(
+            "actor_stream_next", "actor_stream_close"))
         self.rpc.register_object(self)
         self.agent: Optional[RpcClient] = None
         self._fn_cache: Dict[str, Any] = {}
@@ -69,6 +74,11 @@ class WorkerProcess:
         # task_id -> future: a duplicate push of a STILL-RUNNING call
         # piggybacks on the original execution instead of starting a second
         self._actor_inflight: Dict[str, asyncio.Future] = {}
+        # task_id -> stream directory entry of a streaming ACTOR call whose
+        # caller reads it here, over the call's own connection
+        # (core/streaming.py WorkerStream)
+        self._streams: Dict[str, WorkerStream] = {}
+        self._streams_lock = threading.Lock()
 
     async def start(self) -> None:
         self._loop = asyncio.get_running_loop()
@@ -100,7 +110,6 @@ class WorkerProcess:
         # tracing bridge: trace spans (created only for specs that carry a
         # __trace_ctx__ from a tracing-enabled driver) fold into the
         # profiling pipeline and land on the cluster timeline
-        from ray_tpu import profiling
         from ray_tpu.util import tracing
 
         def _bridge(spans) -> None:
@@ -285,6 +294,13 @@ class WorkerProcess:
             # cross-language error envelope: msgpack-able, recognized by
             # cluster_runtime._read_local AND the C++ client's is_error path
             err = {"__rtpu_error__": type(e).__name__, "message": str(err)}
+        if self._streams_to_caller(spec):
+            # the consumer reads this worker's record, not the fixed return
+            # slot: the failure is the next item there, then the end
+            st = self._worker_stream(spec["task_id"])
+            self._stream_emit(spec, st, st.produced, err, is_error=True)
+            self._stream_end(spec, st, st.produced)
+            return
         for r in spec["returns"]:
             try:
                 self._store_value(r, err, is_error=True, collector=collector,
@@ -296,7 +312,7 @@ class WorkerProcess:
             # item 0 (the fixed first return slot) followed by end-of-stream
             try:
                 self._stream_report(spec, 0, spec["returns"][0])
-                self._runtime.gcs.call("stream_end", task_id=spec["task_id"], total=1)
+                self._stream_end(spec, None, 1)
             except Exception:  # noqa: BLE001
                 logger.exception("failed to report stream error")
 
@@ -305,6 +321,123 @@ class WorkerProcess:
         return self._runtime.gcs.call(
             "stream_put", task_id=spec["task_id"], index=index, object_id=oid_hex,
         )
+
+    @staticmethod
+    def _streams_to_caller(spec: Dict[str, Any]) -> bool:
+        """A streaming ACTOR call whose caller takes small results over the
+        call's connection (``inline_max``, as for a plain call's returns)
+        reads the stream from this worker. A task's caller has no connection
+        to the worker, and a lockstep caller asks for nothing inline: their
+        streams go through the store and the GCS."""
+        return bool(spec.get("streaming") and spec.get("actor_id")
+                    and int(spec.get("inline_max") or 0) > 0)
+
+    def _worker_stream(self, task_hex: str,
+                       create: bool = True) -> Optional[WorkerStream]:
+        with self._streams_lock:
+            st = self._streams.get(task_hex)
+            if st is None and create:
+                # records whose consumer went away without a word: a finished
+                # or closed one after the holder lease, any after ten
+                stale = time.monotonic() - config.object_holder_lease_s
+                for t, old in list(self._streams.items()):
+                    if old.abandoned() or (
+                            old.polled < stale and (old.finished or old.closed)):
+                        self._retire_stream(t, old)
+                st = self._streams[task_hex] = WorkerStream(self._loop)
+            return st
+
+    def _retire_stream(self, task_hex: str, st: WorkerStream) -> None:
+        """Forget the record (under ``_streams_lock``); what it sealed loses
+        the stream's pin at the GCS, whose own record of those items goes
+        with it."""
+        if self._streams.get(task_hex) is st:
+            del self._streams[task_hex]
+        if st.sealed_any:
+            self._runtime.gcs.call_async("stream_close", task_id=task_hex)
+
+    def _stream_emit(self, spec: Dict[str, Any], st: Optional[WorkerStream],
+                     idx: int, value: Any, is_error: bool = False) -> bool:
+        """Item ``idx`` of the stream, then the backpressure gate. False once
+        the consumer closed the stream."""
+        task_hex = spec["task_id"]
+        # an error item ends the stream: it waits for no one
+        backpressure = 0 if is_error else int(spec.get("backpressure") or 0)
+        oid_hex = stream_item_id(task_hex, idx).hex()
+        if st is not None:
+            payload, refs = serialization.pack(value)
+            if len(payload) <= int(spec["inline_max"]) and not refs:
+                profiling.count_stream_item(inline=True)
+                return st.put(idx, {"payload": bytes(payload),
+                                    "is_error": is_error}, backpressure)
+        profiling.count_stream_item(inline=False)
+        try:
+            self._store_value(oid_hex, value, is_error=is_error)
+        except FileExistsError:
+            pass  # duplicate execution: item already stored
+        resp = self._stream_report(spec, idx, oid_hex)
+        if st is not None:
+            # too large for a reply, or it holds refs: sealed and pinned under
+            # the stream's holder as ever; the record carries the id
+            return st.put(idx, {"object_id": oid_hex}, backpressure)
+        if resp.get("closed"):
+            return False
+        if backpressure > 0 and idx + 1 - resp.get("consumed", 0) >= backpressure:
+            while True:
+                try:
+                    r = self._runtime.gcs.call(
+                        "stream_wait", task_id=task_hex, index=idx + 1,
+                        max_ahead=backpressure, timeout=10.0, timeout_s=5.0,
+                    )
+                except TimeoutError:
+                    continue
+                if r.get("timeout"):
+                    continue
+                break
+            if r.get("closed"):
+                return False
+        return True
+
+    def _stream_end(self, spec: Dict[str, Any], st: Optional[WorkerStream],
+                    total: int) -> None:
+        if st is None:
+            self._runtime.gcs.call("stream_end", task_id=spec["task_id"],
+                                   total=total)
+            return
+        st.end(total)
+        if st.closed:
+            with self._streams_lock:
+                self._retire_stream(spec["task_id"], st)
+
+    async def rpc_actor_stream_next(self, task_id: str, index: int,
+                                    timeout_s: Optional[float] = None,
+                                    create: bool = True) -> Dict[str, Any]:
+        """The caller's long-poll on a streaming actor call: every item
+        ready from ``index`` on, payloads inline, and the end marker once it
+        is there. ``index`` is the consumer's watermark. ``create`` is false
+        once the caller has the call's own reply: a worker that then has no
+        record (the actor restarted after the generator finished) says so
+        rather than wait for a producer that will not come."""
+        st = self._worker_stream(task_id, create=create)
+        if st is None:
+            return {"lost": True}
+        reply = await st.poll(index, timeout_s)
+        passed = st.take_passed()
+        if passed:
+            self._runtime.gcs.call_async(
+                "remove_object_refs", object_ids=passed,
+                holder=f"stream:{task_id}")
+        return reply
+
+    async def rpc_actor_stream_close(self, task_id: str) -> bool:
+        """The consumer is done with the stream (read to its end, or
+        abandoned): stop the producer if it still runs, drop what waits."""
+        st = self._worker_stream(task_id)
+        st.close()
+        if st.finished:
+            with self._streams_lock:
+                self._retire_stream(task_id, st)
+        return True
 
     def _sync_iter_async_gen(self, agen):
         """Iterate an async generator from an executor thread by driving each
@@ -318,17 +451,14 @@ class WorkerProcess:
                 return
 
     def _drive_streaming(self, spec: Dict[str, Any], gen: Any) -> Dict[str, Any]:
-        """Producer side of num_returns='streaming' on a cluster worker: seal
-        each yielded item via the normal object path, report it to the GCS
-        stream directory, honor consumer backpressure via stream_wait.
+        """Producer side of num_returns='streaming' on a cluster worker: each
+        yielded item goes into the stream's directory (this worker's record
+        for an actor call, else sealed via the normal object path and
+        reported to the GCS), and the consumer's backpressure is honored.
         Mid-stream exceptions become an error item + end-of-stream.
         (reference: _raylet.pyx:1206,1263 per-item report paths)"""
         import inspect
 
-        from ray_tpu.core.streaming import stream_item_id
-
-        task_hex = spec["task_id"]
-        backpressure = int(spec.get("backpressure") or 0)
         if inspect.isasyncgen(gen):
             gen = self._sync_iter_async_gen(gen)
         elif not inspect.isgenerator(gen):
@@ -337,47 +467,24 @@ class WorkerProcess:
                 f"{spec.get('name', '?')} returned {type(gen).__name__}"
             ))
             return {"state": "error"}
+        st = (self._worker_stream(spec["task_id"])
+              if self._streams_to_caller(spec) else None)
         idx = 0
         try:
             for item in gen:
-                oid_hex = stream_item_id(task_hex, idx).hex()
-                try:
-                    self._store_value(oid_hex, item)
-                except FileExistsError:
-                    pass  # duplicate execution: item already stored
-                resp = self._stream_report(spec, idx, oid_hex)
+                more = self._stream_emit(spec, st, idx, item)
                 idx += 1
-                if resp.get("closed"):
+                if not more:
                     gen.close()
                     break
-                if backpressure > 0 and idx - resp.get("consumed", 0) >= backpressure:
-                    while True:
-                        try:
-                            r = self._runtime.gcs.call(
-                                "stream_wait", task_id=task_hex, index=idx,
-                                max_ahead=backpressure, timeout=10.0, timeout_s=5.0,
-                            )
-                        except TimeoutError:
-                            continue
-                        if r.get("timeout"):
-                            continue
-                        break
-                    if r.get("closed"):
-                        gen.close()
-                        break
         except BaseException as e:  # noqa: BLE001 - delivered as an error item
             err = exc.TaskError.from_exception(
                 e, spec.get("name", "?"), pid=os.getpid(), node_id=self.node_hex
             )
-            oid_hex = stream_item_id(task_hex, idx).hex()
-            try:
-                self._store_value(oid_hex, err, is_error=True)
-            except FileExistsError:
-                pass
-            self._stream_report(spec, idx, oid_hex)
-            self._runtime.gcs.call("stream_end", task_id=task_hex, total=idx + 1)
+            self._stream_emit(spec, st, idx, err, is_error=True)
+            self._stream_end(spec, st, idx + 1)
             return {"state": "error"}
-        self._runtime.gcs.call("stream_end", task_id=task_hex, total=idx)
+        self._stream_end(spec, st, idx)
         return {"state": "ok"}
 
     # ------------------------------------------------------------- task rpc
@@ -394,7 +501,6 @@ class WorkerProcess:
     def _flush_profile_spans(self) -> None:
         """Ship this thread's recorded profile spans to the agent (one RPC,
         only when ray_tpu.profile() was used in the task)."""
-        from ray_tpu import profiling
         from ray_tpu.util import tracing
 
         tracing.flush()  # bridge exporter folds trace spans into profiling

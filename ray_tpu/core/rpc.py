@@ -39,7 +39,7 @@ import itertools
 import random
 import struct
 import threading
-from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
+from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple, Union
 
 import msgpack
 
@@ -147,12 +147,18 @@ class _Chaos:
     a payload to a sealed event) — so retry/fallback coverage tracks the
     pipelined protocol instead of silently shrinking to the lockstep one."""
 
-    def __init__(self, enabled: bool = True) -> None:
+    def __init__(self, enabled: bool = True,
+                 methods: Optional[frozenset] = None) -> None:
         prob = config.rpc_chaos_failure_prob if enabled else 0.0
         self.prob = prob
         self.rng = random.Random(config.rpc_chaos_seed or None) if prob > 0 else None
+        # not None: only requests/responses of these methods are dropped
+        # (no pushes, no raw frames)
+        self.methods = methods
 
-    def should_drop(self) -> bool:
+    def should_drop(self, method: Optional[str] = None) -> bool:
+        if self.methods is not None and method not in self.methods:
+            return False
         return self.rng is not None and self.rng.random() < self.prob
 
     # distinct names so call sites read as what they inject; same process
@@ -259,10 +265,14 @@ class RpcServer:
     ``chaos=False`` exempts this server from fault injection — used by worker
     processes, whose task/actor-call handlers are not idempotent (the chaos
     tier targets the control plane: GCS + node agents, like the reference's
-    rpc_chaos on GCS RPCs)."""
+    rpc_chaos on GCS RPCs). A tuple of method names injects faults into
+    those methods alone: a worker's stream long-poll is retry-safe beside
+    handlers that are not."""
 
-    def __init__(self, host: str = "127.0.0.1", port: int = 0, chaos: bool = True):
-        self._chaos_enabled = chaos
+    def __init__(self, host: str = "127.0.0.1", port: int = 0,
+                 chaos: Union[bool, Tuple[str, ...]] = True):
+        self._chaos_enabled = bool(chaos)
+        self._chaos_methods = None if isinstance(chaos, bool) else frozenset(chaos)
         self.host = host
         self.port = port
         self._handlers: Dict[str, Callable[..., Awaitable[Any]]] = {}
@@ -303,7 +313,7 @@ class RpcServer:
                 self._handlers[prefix + attr[4:]] = getattr(obj, attr)
 
     async def start(self) -> Tuple[str, int]:
-        self._chaos = _Chaos(self._chaos_enabled)
+        self._chaos = _Chaos(self._chaos_enabled, self._chaos_methods)
         self._server = await asyncio.start_server(self._on_client, self.host, self.port)
         sock = self._server.sockets[0]
         self.host, self.port = sock.getsockname()[:2]
@@ -414,7 +424,7 @@ class RpcServer:
     async def _dispatch(self, msg: Dict, writer: asyncio.StreamWriter) -> None:
         req_id = msg.get("i")
         method = msg.get("m", "")
-        if self._chaos.should_drop():
+        if self._chaos.should_drop(method):
             logger.warning("rpc chaos: dropping request %s", method)
             return
         if method == "__subscribe__":
@@ -443,7 +453,7 @@ class RpcServer:
             resp = {"i": req_id, "r": result}
         except Exception as e:  # noqa: BLE001 - serialize handler errors to caller
             resp = {"i": req_id, "e": [type(e).__name__, str(e)]}
-        if self._chaos.should_drop():
+        if self._chaos.should_drop(method):
             logger.warning("rpc chaos: dropping response for %s", method)
             return
         await self._reply(writer, resp)

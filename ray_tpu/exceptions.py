@@ -82,6 +82,11 @@ class ActorDiedError(ActorError):
         self.reason = reason
         super().__init__(f"Actor {actor_id[:8]} died: {reason}")
 
+    def __reduce__(self):
+        # by its fields: pickled by its message alone it came back as the
+        # death of an actor named after the message, the reason lost
+        return (type(self), (self.actor_id, self.reason))
+
 
 class ActorUnavailableError(ActorError):
     """The actor is temporarily unreachable (e.g. restarting)."""

@@ -69,7 +69,10 @@ class HostEvents:
     from outside its own code: XLA compiles (one ``jax.monitoring`` listener
     on the backend-compile event, which also covers a program fetched from
     the persistent cache) and garbage collections (one ``gc.callbacks``
-    hook). Cumulative and monotone; read without a lock."""
+    hook); and of the items this process's streaming calls yielded
+    (``count_stream_item``), with how many of them went to the caller in a
+    reply, past the agent and the GCS. Cumulative and monotone; read without
+    a lock."""
 
     GC_LONG_NS = 50_000_000
 
@@ -80,6 +83,8 @@ class HostEvents:
         self.gc_pauses_over_50ms = 0
         self.gc_longest_ns = 0
         self._gc_started_ns = 0
+        self.stream_items = 0
+        self.stream_items_inline = 0
 
     def _on_duration(self, event: str, duration_s: float, **_kw) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
@@ -98,23 +103,31 @@ class HostEvents:
             self.gc_longest_ns = pause
 
 
-_host_events: Optional[HostEvents] = None
+_host_events = HostEvents()
+_hooked = False
 
 
 def host_events() -> HostEvents:
     """The process's ``HostEvents``, hooked in on first use (jax must be
     imported by then: the caller is about to compile with it)."""
-    global _host_events
+    global _hooked
     with _lock:
-        if _host_events is None:
+        if not _hooked:
             import jax.monitoring
 
-            events = HostEvents()
             jax.monitoring.register_event_duration_secs_listener(
-                events._on_duration)
-            gc.callbacks.append(events._on_gc)
-            _host_events = events
+                _host_events._on_duration)
+            gc.callbacks.append(_host_events._on_gc)
+            _hooked = True
     return _host_events
+
+
+def count_stream_item(inline: bool) -> None:
+    """One item yielded by a streaming call of this process (a worker calls
+    this from many request threads, and none of them needs jax)."""
+    with _lock:
+        _host_events.stream_items += 1
+        _host_events.stream_items_inline += inline
 
 
 @contextmanager

@@ -224,7 +224,11 @@ class LLMEngine:
       edges: under the first edge, between edges, over the last) of
       admission instant minus submission, one count an admitted request.
     - ``compiles``, ``compile_s``, ``gc_pause_ns``, ``gc_pauses_over_50ms``,
-      ``gc_longest_s``: the process's ``profiling.host_events()``.
+      ``gc_longest_s``: the process's ``profiling.host_events()``; so are
+      ``stream_items`` (items the process's streaming calls yielded: a
+      replica's tokens) and ``stream_items_inline`` (those that went to the
+      caller in a reply of the call's own connection, past the agent and
+      the GCS).
     - ``ring``: ``columns`` and ``rows`` of the last 256 busy iterations
       (wall start, phases in seconds, slots active, admitted, retired),
       oldest first. ``longest_iter_s``: the longest ever.
@@ -510,6 +514,8 @@ class LLMEngine:
             "gc_pause_ns": host.gc_pause_ns,
             "gc_pauses_over_50ms": host.gc_pauses_over_50ms,
             "gc_longest_s": host.gc_longest_ns / 1e9,
+            "stream_items": host.stream_items,
+            "stream_items_inline": host.stream_items_inline,
             "ring": {"columns": list(RING_COLUMNS),
                      "rows": np.roll(ring, -(n % len(ring)), axis=0).tolist()
                      if n else []},

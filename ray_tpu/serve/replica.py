@@ -34,11 +34,13 @@ def current_request_hops() -> Optional[Dict[str, float]]:
 
 
 class StreamBatch(list):
-    """Items of one stream that travel as ONE object (an item costs the
-    object plane a seal, a report and a long-poll through the GCS: one
-    replica carried 590 a second whatever the number of streams; PERF.md
-    section 6, PR 29). ``Router.call_streaming`` hands them on one by one,
-    in order: a consumer sees the generator's items and never a batch."""
+    """Items of one stream that travel as ONE item (until PR 39 an item cost
+    the object plane a seal, a report and a long-poll through the GCS, and
+    one replica carried 590 a second whatever the number of streams;
+    PERF.md section 6, PR 29 and PR 39: an item now rides the reply of the
+    caller's long-poll to this worker, ``core/streaming.py``).
+    ``Router.call_streaming`` hands them on one by one, in order: a consumer
+    sees the generator's items and never a batch."""
 
 
 _STREAM_AHEAD = 256  # items a coalesced generator may run in front of its consumer

@@ -54,16 +54,44 @@ def test_put_get_and_deps_survive_chaos(chaos_cluster):
 
 
 
-def test_streaming_generator_survives_chaos(chaos_cluster):
+@pytest.mark.parametrize("producer", ["task", "actor"])
+def test_streaming_generator_survives_chaos(chaos_cluster, producer):
     """Mid-stream chaos: every yielded item arrives exactly once, in order
-    (stream_put/stream_next are retry-safe; VERDICT r4 weak #5)."""
-    @ray_tpu.remote(num_returns="streaming")
+    (stream_put/stream_next are retry-safe; VERDICT r4 weak #5). An actor's
+    stream is read from its worker, whose server drops request and reply
+    frames of that long-poll alone: the consumer asks again for the index it
+    wants and gets the same items."""
     def produce(n):
         for i in range(n):
             yield {"i": i, "blob": bytes([i % 256]) * 1000}
 
-    items = [ray_tpu.get(r, timeout=60) for r in produce.remote(30)]
-    assert [x["i"] for x in items] == list(range(30))
+    if producer == "task":
+        gen = ray_tpu.remote(num_returns="streaming")(produce).remote(30)
+        n = 30
+    else:
+        @ray_tpu.remote
+        class Producer:
+            def ready(self):
+                return True
+
+            def produce(self, n):
+                for i in range(n):
+                    time.sleep(0.002)  # a poll an item or two: many frames
+                    yield {"i": i, "blob": bytes([i % 256]) * 1000}
+
+        for _ in range(5):
+            # the GCS starts an actor with one frame it never sends again:
+            # dropped, the actor stays PENDING, and that is not under test
+            a = Producer.remote()
+            try:
+                assert ray_tpu.get(a.ready.remote(), timeout=20)
+                break
+            except Exception:  # noqa: BLE001
+                continue
+        n = 120
+        gen = a.produce.options(num_returns="streaming").remote(n)
+    items = [ray_tpu.get(r, timeout=60) for r in gen]
+    assert [x["i"] for x in items] == list(range(n))
 
 
 def test_actor_restart_under_chaos(chaos_cluster):

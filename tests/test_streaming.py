@@ -265,3 +265,178 @@ def test_cluster_streaming_actor(stream_cluster):
     out = [ray_tpu.get(r)["token"]
            for r in a.tokens.options(num_returns="streaming").remote(20)]
     assert out == list(range(20))
+
+
+# ------------------------------------------- an actor's stream, read from its worker
+
+
+@ray_tpu.remote(max_concurrency=4)
+class _Source:
+    """A generator actor that can be watched and told to die from its other
+    call places."""
+
+    def __init__(self):
+        self.produced = 0
+        self.stopped = False
+        self.go = threading.Event()
+
+    def counts(self):
+        from ray_tpu import profiling
+
+        ev = profiling._host_events  # noqa: SLF001 - the process's counters
+        return ev.stream_items, ev.stream_items_inline
+
+    def state(self):
+        return self.produced, self.stopped
+
+    def tokens(self, n):
+        try:
+            for i in range(n):
+                self.produced += 1
+                yield {"token": i}
+        finally:
+            self.stopped = True
+
+    def mixed(self, big_bytes):
+        yield "small"
+        yield b"x" * big_bytes
+        yield {"ref": ray_tpu.put([1, 2, 3])}
+        yield "last"
+
+    def three_then_die(self, how):
+        yield from range(3)
+        self.go.wait(30)
+        if how == "exit":
+            import os
+
+            os._exit(1)
+        raise RuntimeError("told to fail")
+
+    def release(self):
+        self.go.set()
+
+
+def _stream(method, *args, **options):
+    return method.options(num_returns="streaming", **options).remote(*args)
+
+
+def test_cluster_actor_stream_1000_small_items_touch_no_agent_and_no_gcs(stream_cluster):
+    """A small item goes from the worker's record to the caller in the
+    long-poll's reply: nothing is sealed, the GCS hears of no item, and the
+    store stays where it was."""
+    a = _Source.remote()
+    items0, inline0 = ray_tpu.get(a.counts.remote())
+    agent = SyncRpcClient(stream_cluster.nodes[0].address)
+    gcs = SyncRpcClient(stream_cluster.gcs_address)
+    try:
+        used0 = agent.call("node_info")["store"]["used"]
+        gen = _stream(a.tokens, 1000)
+        assert [ray_tpu.get(r)["token"] for r in gen] == list(range(1000))
+        assert gen.completed()
+        items1, inline1 = ray_tpu.get(a.counts.remote())
+        assert (items1 - items0, inline1 - inline0) == (1000, 1000)
+        state = gcs.call("stream_state", task_id=gen.task_id_hex)
+        assert state["produced"] == 0 and not state["finished"]
+        assert agent.call("node_info")["store"]["used"] - used0 < 64 * 1024
+    finally:
+        agent.close()
+        gcs.close()
+
+
+def test_cluster_actor_stream_large_and_ref_items_go_through_the_store(stream_cluster):
+    """An item over the inline limit and an item that holds an ObjectRef
+    are sealed and registered as ever, between small ones, in order."""
+    from ray_tpu.core.config import inline_max_bytes
+
+    a = _Source.remote()
+    items0, inline0 = ray_tpu.get(a.counts.remote())
+    big = inline_max_bytes() + 1
+    small, large, holder, last = (
+        ray_tpu.get(r) for r in _stream(a.mixed, big))
+    assert (small, large, last) == ("small", b"x" * big, "last")
+    assert ray_tpu.get(holder["ref"]) == [1, 2, 3]
+    items1, inline1 = ray_tpu.get(a.counts.remote())
+    assert (items1 - items0, inline1 - inline0) == (4, 2)
+
+
+def test_cluster_actor_stream_ref_kept_after_the_end_resolves_elsewhere(stream_cluster):
+    """A ref that leaves the consumer's process is promoted to the store
+    first, as an actor call's inline result is."""
+    @ray_tpu.remote
+    def read(box):
+        return ray_tpu.get(box[0])["token"]
+
+    a = _Source.remote()
+    refs = list(_stream(a.tokens, 5))
+    assert ray_tpu.get(read.remote([refs[3]]), timeout=60) == 3
+    assert ray_tpu.get(refs[3])["token"] == 3
+
+
+def test_cluster_actor_stream_backpressure_blocks_producer(stream_cluster):
+    a = _Source.remote()
+    it = iter(_stream(a.tokens, 50, _generator_backpressure=2))
+    assert ray_tpu.get(next(it))["token"] == 0
+    time.sleep(0.5)  # the producer's chance to run ahead
+    produced, _ = ray_tpu.get(a.state.remote())
+    # the consumer asked for item 0 only: at most two items ahead of that
+    assert produced <= 2, produced
+    assert [ray_tpu.get(r)["token"] for r in it] == list(range(1, 50))
+    assert ray_tpu.get(a.state.remote()) == (50, True)
+
+
+def test_cluster_actor_stream_close_stops_generator(stream_cluster):
+    a = _Source.remote()
+    gen = _stream(a.tokens, 10_000, _generator_backpressure=2)
+    it = iter(gen)
+    assert [ray_tpu.get(next(it))["token"] for _ in range(3)] == [0, 1, 2]
+    gen.close()
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        produced, stopped = ray_tpu.get(a.state.remote())
+        if stopped:
+            break
+        time.sleep(0.05)
+    assert stopped and produced < 100, (produced, stopped)
+    with pytest.raises(StopIteration):
+        next(it)
+
+
+@pytest.mark.parametrize("how", ["exit", "raise"])
+def test_cluster_actor_stream_failure_is_the_next_item(stream_cluster, how):
+    """The actor's process gone mid-stream raises at the next item; so does
+    the generator's own exception, and then the stream ends."""
+    from ray_tpu import exceptions
+
+    a = _Source.remote()
+    it = iter(_stream(a.three_then_die, how))
+    assert [ray_tpu.get(next(it)) for _ in range(3)] == [0, 1, 2]
+    a.release.remote()
+    expected = ((exceptions.ActorDiedError, exceptions.ActorUnavailableError)
+                if how == "exit" else RuntimeError)
+    with pytest.raises(expected):
+        ray_tpu.get(next(it), timeout=60)
+    with pytest.raises(StopIteration):
+        next(it)
+
+
+def test_cluster_actor_stream_second_execution_repeats_no_item(stream_cluster, tmp_path):
+    """The actor dies mid-stream and restarts, the call runs again from its
+    first item: the consumer, which asks by index, gets every item once."""
+    marker = str(tmp_path / "died-once")
+
+    # retries: the first ones may still be routed to the dead worker's
+    # address, until the GCS has seen it die
+    @ray_tpu.remote(max_restarts=1, max_task_retries=5)
+    class Phoenix:
+        def tokens(self, n):
+            import os
+
+            for i in range(n):
+                if i == 6 and not os.path.exists(marker):
+                    open(marker, "w").close()
+                    os._exit(1)
+                yield i
+
+    a = Phoenix.remote()
+    out = [ray_tpu.get(r, timeout=60) for r in _stream(a.tokens, 12)]
+    assert out == list(range(12))

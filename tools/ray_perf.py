@@ -9,6 +9,7 @@ Usage:
     python tools/ray_perf.py --cluster --transfer      # + data-plane MB/s
     python tools/ray_perf.py --cluster --transfer --no-raw-transfer  # A/B
     python tools/ray_perf.py --cluster --transfer --no-stripe        # A/B
+    python tools/ray_perf.py --cluster --stream        # + actor-stream items/s
     python tools/ray_perf.py --cluster --out results.json
 
 Prints one JSON line per metric. --no-pipeline sets RTPU_PIPELINE=0 before
@@ -124,6 +125,66 @@ def transfer_benchmarks(cluster, results, smoke: bool = False) -> None:
         agent3.close()
 
 
+def _cpu_seconds(pid: int) -> float:
+    """utime + stime of one process (threads included), from /proc."""
+    with open(f"/proc/{pid}/stat") as f:
+        fields = f.read().rsplit(")", 1)[1].split()
+    return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
+
+
+def stream_benchmarks(cluster, results, smoke: bool = False) -> None:
+    """The item path of an actor's streaming call: N threads each iterate
+    one ``num_returns="streaming"`` call of one actor whose generator yields
+    ``{"token": i}`` and ``get`` every ref (a serving replica's token stream
+    as its proxy reads it, with no engine and no model). Per N: items/s,
+    ms an item, and each process's CPU milliseconds an item."""
+    import threading
+
+    import ray_tpu
+
+    @ray_tpu.remote(max_concurrency=40)
+    class Streamer:
+        def pid(self):
+            return os.getpid()
+
+        def tokens(self, n):
+            for i in range(n):
+                yield {"token": i}
+
+    a = Streamer.remote()
+    pids = {"consumer": os.getpid(),
+            "actor_worker": ray_tpu.get(a.pid.remote(), timeout=120),
+            "gcs": cluster._gcs_proc.pid,  # noqa: SLF001
+            "agent": cluster.nodes[0].proc.pid}
+    for streams, per_stream in ((1, 600), (8, 300), (32, 150)):
+        if smoke:
+            per_stream //= 10
+
+        def drain(n=per_stream):
+            gen = a.tokens.options(num_returns="streaming").remote(n)
+            assert [ray_tpu.get(r)["token"] for r in gen] == list(range(n))
+
+        drain(8)  # warm: the route, the method
+        cpu0 = {k: _cpu_seconds(p) for k, p in pids.items()}
+        threads = [threading.Thread(target=drain) for _ in range(streams)]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        dt = time.perf_counter() - t0
+        items = streams * per_stream
+        cpu_ms = {k: round(1e3 * (_cpu_seconds(p) - cpu0[k]) / items, 4)
+                  for k, p in pids.items()}
+        rec = {"metric": f"cluster_stream_items_per_sec_{streams}",
+               "value": round(items / dt, 1), "unit": "items/s", "n": items,
+               "ms_per_item": round(1e3 * dt / items, 4),
+               "cpu_ms_per_item": cpu_ms}
+        print(json.dumps(rec))
+        results[rec["metric"]] = rec["value"]
+        results[f"cluster_stream_cpu_ms_per_item_{streams}"] = cpu_ms
+
+
 def emit(results, name, value, unit, nbytes, extra=None):
     rec = {"metric": name, "value": round(value, 1), "unit": unit,
            "bytes": nbytes}
@@ -145,6 +206,11 @@ def main() -> None:
     parser.add_argument("--transfer", action="store_true",
                         help="also measure data-plane transfer throughput "
                              "(pull/broadcast/striped pull; needs --cluster)")
+    parser.add_argument("--stream", action="store_true",
+                        help="measure ONLY the item path of an actor's "
+                             "streaming call: items/s, ms and CPU ms an item "
+                             "of 1, 8 and 32 concurrent streams (needs "
+                             "--cluster)")
     parser.add_argument("--no-raw-transfer", action="store_true",
                         help="serial in-band msgpack data plane (sets "
                              "RTPU_RAW_TRANSFER=0 for this process tree)")
@@ -208,10 +274,13 @@ def main() -> None:
 
     mode = "cluster" if args.cluster else "local"
     results = {}
-    bench(f"{mode}_tasks_per_sec", tasks_submit_get, int(500 * s), results)
-    bench(f"{mode}_puts_per_sec", puts, int(1000 * s), results)
-    bench(f"{mode}_batched_get_per_sec", batched_get, int(1000 * s), results)
-    bench(f"{mode}_actor_calls_per_sec", actor_calls, int(500 * s), results)
+    if args.stream and cluster is not None:
+        stream_benchmarks(cluster, results, smoke=args.smoke)
+    else:
+        bench(f"{mode}_tasks_per_sec", tasks_submit_get, int(500 * s), results)
+        bench(f"{mode}_puts_per_sec", puts, int(1000 * s), results)
+        bench(f"{mode}_batched_get_per_sec", batched_get, int(1000 * s), results)
+        bench(f"{mode}_actor_calls_per_sec", actor_calls, int(500 * s), results)
 
     if args.transfer and cluster is not None:
         transfer_benchmarks(cluster, results, smoke=args.smoke)
