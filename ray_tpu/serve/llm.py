@@ -37,6 +37,7 @@ import sys
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -57,6 +58,11 @@ RING_ITERS = 256
 # a ring row: wall start, the six phases in seconds, slots active, requests
 # admitted, requests retired
 RING_COLUMNS = ("start",) + PHASES + ("active", "admitted", "retired")
+# the host's turn by KIND of work, across the phases (``work_ns``), and the
+# events ``work_calls`` counts; ``engine.launch``'s ``program`` attribute
+WORK_KINDS = ("pack", "launch", "slot_update", "notify")
+WORK_CALLS = ("launch", "launch_waited", "slot_update", "notify")
+PREFILL, DECODE, BRING_UP = 0, 1, 2
 SLOW_ITER_FLOOR_S = 1.0
 SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
@@ -210,7 +216,47 @@ class LLMEngine:
       ``decode_dispatch`` (key split + the decode call), ``device_get`` (the
       one host sync a chunk), ``emit`` (tokens to requests and streams),
       ``retire``. ``idle_ns``: iterations with no slot in use (a 10 ms
-      sleep each), in neither ``iters`` nor the ring.
+      sleep each), in neither ``iters`` nor the ring. ``between_ns``: from
+      the end of one busy iteration to the start of the next one that
+      follows it with no idle poll between: the flight recorder's row and,
+      mostly, the loop thread waiting to run again; in no phase and not in
+      ``iter_ns``, so the loop's wall time is ``iter_ns`` + ``between_ns`` +
+      ``idle_ns``.
+    - ``phase_cpu_ns``, ``loop_cpu_ns``, ``process_cpu_ns``: the loop
+      thread's CPU time in the same six phases (``time.thread_time_ns`` at
+      the boundaries ``phase_ns`` stamps; they sum to ``loop_cpu_ns``) and
+      the whole process's over the same iterations. Wall minus CPU in a
+      phase is time the loop thread did not run: in ``device_get`` the wait
+      for the chip, in ``admit``, ``emit`` and ``retire``, which wait for no
+      device, the wait for the interpreter.
+    - ``work_ns``: the iteration by KIND of host work, across the phases:
+      ``pack`` (a prefill call's numpy arguments and their copies to the
+      chip), ``launch`` (the calls into the compiled programs, call to
+      return: the prefill program with its ``argmax``, the decode program,
+      a bucket's bring-up), ``slot_update`` (every eager device operation on
+      per-slot state: the table row, first token, position and live flag of
+      an admitted request, the flag and row of a retired one, a chunk's key
+      split; all issued by ``_slot_update``), ``notify`` (a stream's queue
+      puts, the end sentinel, the future's result). They never overlap;
+      what is left of ``iter_ns`` after them and ``phase_ns.device_get`` is
+      Python bookkeeping. ``work_calls``: ``launch``, ``slot_update``
+      (operations), ``notify`` (hand-overs: a push of a stream's new
+      tokens, or a request's end), and ``launch_waited``: launches whose
+      result was ready the moment the call returned, which is the loop
+      thread having waited the program out.
+    - ``starved_ns``: how long the chip had nothing queued while the loop was
+      busy, as far as the loop can know. It sees the chip empty when
+      ``device_get`` returns, when a launch returns with its result ready,
+      and when an eager operation behind a launch returns and finds that
+      launch's result ready (the operation is then where the loop waited
+      the program out); from there to its next launch no program is queued
+      (slot updates in between are microseconds of device work and end no
+      stretch; an idle poll is ``idle_ns`` and not in it). A LOWER bound of
+      the device's idle time: it cannot see the time between the chip
+      finishing and ``device_get`` (or the operation that waited) returning;
+      of the fetch ``phase_cpu_ns.device_get`` says how much was work.
+      The thread and process CPU clocks tick as the kernel accounts them
+      (every 10 ms on some machines): read their sums over many iterations.
     - ``admitted``, ``retired``: requests given a slot, requests answered.
     - ``prefill_calls``, ``prefill_rows_real``, ``prefill_rows_padded``,
       ``prefill_tokens_real``, ``prefill_tokens_padded``: prefill programs
@@ -235,8 +281,9 @@ class LLMEngine:
     - ``slow_iters``: the last 16 iterations longer than max(1 s, 5 x the
       ring's median), each with its wall instant, total, longest phase,
       compile and GC deltas over it, the CPU time the process (``cpu_s``)
-      and the loop thread (``loop_cpu_s``) used in it (near zero: all of it
-      waited, on the device or the kernel; near the total: a thread ran)
+      and the loop thread (``loop_cpu_s``) used in it (the readings the
+      CPU counters above add up; near zero: all of it waited, on the device
+      or the kernel; near the total: a thread ran)
       and ``steal_s`` (seconds, summed over CPUs, the hypervisor gave to
       others since the engine started), ``queued`` and ``active``; each is
       also one warning line in the log (at most one every 10 s;
@@ -299,7 +346,10 @@ class LLMEngine:
     ``engine.prefill_bring_up`` (``bucket``), ``engine.prefill_dispatch``
     (``bucket``, ``rows_real``, ``rows_padded``, ``state_rows``),
     ``engine.decode_dispatch``, ``engine.device_get``, ``engine.emit``,
-    ``engine.retire``."""
+    ``engine.retire``; nested in them ``engine.launch`` (``program``: 0
+    prefill, 1 decode, 2 bring-up) and ``engine.slot_update`` (``ops``), one
+    a prefill group, a chunk or a retired request; ``engine.idle`` around the
+    idle poll's sleep."""
 
     def __init__(self, config, params=None, *, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, decode_chunk: int = 8,
@@ -381,6 +431,19 @@ class LLMEngine:
         self._iter_ns = 0
         self._idle_ns = 0
         self._phase_ns = [0] * len(PHASES)
+        self._phase_cpu_ns = [0] * len(PHASES)
+        self._loop_cpu_ns = 0
+        self._process_cpu_ns = 0
+        self._work_ns = dict.fromkeys(WORK_KINDS, 0)
+        self._work_calls = dict.fromkeys(WORK_CALLS, 0)
+        self._starved_ns = 0
+        # the instant (perf_counter_ns) since which the loop knows the chip
+        # has nothing queued; 0 while a program it launched may be
+        # unfinished, and then ``_in_flight`` is that launch's result
+        self._empty_since = time.perf_counter_ns()
+        self._in_flight = None
+        self._between_ns = 0
+        self._ended = 0  # the last iteration's end, if it was a busy one
         self._admitted = 0
         self._retired = 0
         self._prefill_calls = 0
@@ -499,6 +562,13 @@ class LLMEngine:
             "iter_ns": self._iter_ns,
             "idle_ns": self._idle_ns,
             "phase_ns": dict(zip(PHASES, self._phase_ns)),
+            "phase_cpu_ns": dict(zip(PHASES, self._phase_cpu_ns)),
+            "loop_cpu_ns": self._loop_cpu_ns,
+            "process_cpu_ns": self._process_cpu_ns,
+            "work_ns": dict(self._work_ns),
+            "work_calls": dict(self._work_calls),
+            "starved_ns": self._starved_ns,
+            "between_ns": self._between_ns,
             "admitted": self._admitted,
             "retired": self._retired,
             "prefill_calls": self._prefill_calls,
@@ -678,11 +748,67 @@ class LLMEngine:
                     if r * bucket <= PREFILL_TOKENS_PER_ITER)
         return fit or self._prefill_rows[:1]
 
-    def _run_prefill(self, chunk: List[tuple], bucket: int, size: int):
+    def _launch(self, program: int, call, *args):
+        """Every call into a compiled program (``program``: ``PREFILL``,
+        ``DECODE`` or ``BRING_UP``), call to return: ``work_ns.launch``. It
+        ends a stretch in which the chip had nothing queued (``starved_ns``);
+        a result that is ready the moment the call returns (a query, no sync)
+        means the loop thread waited the program out (``launch_waited``) and
+        the chip is empty again; one that is not stays ``_in_flight`` for
+        the eager operations behind it to ask (``_slot_update``)."""
+        t0 = time.perf_counter_ns()
+        if self._empty_since:
+            self._starved_ns += t0 - self._empty_since
+            self._empty_since = 0
+        with span("engine.launch", program=program):
+            out = call(*args)
+        t1 = time.perf_counter_ns()
+        self._work_ns["launch"] += t1 - t0
+        self._work_calls["launch"] += 1
+        self._in_flight = out[0] if isinstance(out, tuple) else out
+        if self._in_flight.is_ready():
+            self._work_calls["launch_waited"] += 1
+            self._chip_empty(t1)
+        return out
+
+    def _chip_empty(self, since: int) -> None:
+        """The loop has learnt that everything it launched is done."""
+        self._empty_since = since
+        self._in_flight = None
+
+    @contextmanager
+    def _slot_update(self, ops: int):
+        """The ONE place the loop issues eager device operations on per-slot
+        state (``_table``, ``_tokens``, ``_positions``, ``_active``, a row of
+        a prefill's first tokens, the key split of a chunk): yields
+        ``issue(op, *args)``, which counts the operation and calls it, so
+        ``work_calls.slot_update`` is a count by construction; the stamps and
+        the span (``ops``: what the caller is about to issue) are one a
+        prefill group, a retired request or a chunk, not one an operation.
+        An operation behind a launch may be where the loop waits that
+        program out: when one returns and finds the launch's result ready (a
+        query), the chip is empty from then on."""
+        calls = self._work_calls
+
+        def issue(op, *args):
+            calls["slot_update"] += 1
+            out = op(*args)
+            if self._in_flight is not None and self._in_flight.is_ready():
+                self._chip_empty(time.perf_counter_ns())
+            return out
+
+        t0 = time.perf_counter_ns()
+        with span("engine.slot_update", ops=ops):
+            yield issue
+        self._work_ns["slot_update"] += time.perf_counter_ns() - t0
+
+    def _run_prefill(self, chunk: List[tuple], bucket: int, size: int,
+                     program: int = PREFILL):
         """The prefill program of `bucket` at `size` rows over `chunk`; pad
         rows write to the trash page and the trash state row and are
         discarded. Returns the rows' first tokens, [size], on the device."""
         jnp = self._jnp
+        t0 = time.perf_counter_ns()
         n_pages = bucket // self.page_size
         tokens = np.zeros((size, bucket), np.int32)
         page_arr = np.zeros((size, n_pages), np.int32)  # pad rows -> trash
@@ -697,11 +823,16 @@ class LLMEngine:
         args = [jnp.asarray(tokens), jnp.asarray(page_arr), jnp.asarray(lengths)]
         if self._slot_state:
             args.append(jnp.asarray(slots))
+        self._work_ns["pack"] += time.perf_counter_ns() - t0
+        return self._launch(program, self._prefill_firsts, args)
+
+    def _prefill_firsts(self, args: list):
+        """The prefill program and the pick of each row's first token."""
         # a module that names PREFILL_COUNTERS returns them as well
         logits, self.cache, *counts = self._prefill(
             self.params, self.cache, *args)
         self._prefill_counts_pending += counts
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32)
 
     def _bring_up(self, bucket: int) -> None:
         """A bucket met for the first time: compile (or load from the
@@ -712,7 +843,9 @@ class LLMEngine:
         t0 = time.perf_counter()
         rows = self._rows_of(bucket)
         for size in reversed(rows):
-            self._run_prefill([], bucket, size)[0]
+            firsts = self._run_prefill([], bucket, size, BRING_UP)
+            with self._slot_update(ops=1) as issue:
+                issue(firsts.__getitem__, 0)
         self._buckets_up.add(bucket)
         logger.info("prefill bucket %d: programs of %s rows up in %.2f s",
                     bucket, rows, time.perf_counter() - t0)
@@ -724,24 +857,42 @@ class LLMEngine:
         self._count_prefill(len(chunk), size,
                             sum(len(req.tokens) for req, *_ in chunk), bucket)
         firsts = self._run_prefill(chunk, bucket, size)
-        for row, (req, slot, pages, _b) in enumerate(chunk):
-            n = len(req.tokens)
-            trow = np.zeros((self.pages_per_slot,), np.int32)
-            trow[: len(pages)] = pages
-            self._table = self._table.at[slot].set(jnp.asarray(trow))
-            first = firsts[row]  # device scalar
-            req.pending_first = first
-            self._tokens = self._tokens.at[slot].set(first)
-            self._positions = self._positions.at[slot].set(n)
-            self._active = self._active.at[slot].set(True)
+        with self._slot_update(ops=6 * len(chunk)) as issue:
+            for row, (req, slot, pages, _b) in enumerate(chunk):
+                n = len(req.tokens)
+                trow = np.zeros((self.pages_per_slot,), np.int32)
+                trow[: len(pages)] = pages
+                self._table = issue(self._table.at[slot].set,
+                                    issue(jnp.asarray, trow))
+                first = issue(firsts.__getitem__, row)  # device scalar
+                req.pending_first = first
+                self._tokens = issue(self._tokens.at[slot].set, first)
+                self._positions = issue(self._positions.at[slot].set, n)
+                self._active = issue(self._active.at[slot].set, True)
 
-    def _push_stream(self, req: GenRequest) -> None:
-        """Forward newly-decoded tokens to a streaming consumer."""
-        if req.stream_q is None:
+    def _notify(self, req: GenRequest, done: bool = False) -> None:
+        """Hand other threads what the loop has for them (``work_ns.notify``,
+        one stamp pair and one ``work_calls.notify`` a call): newly-decoded
+        tokens to a streaming consumer, and for a request that is ``done``
+        the end sentinel and the future's result."""
+        if req.stream_q is None and not done:
             return
-        while req.streamed < len(req.out_tokens):
-            req.stream_q.put(req.out_tokens[req.streamed])
-            req.streamed += 1
+        t0 = time.perf_counter_ns()
+        if req.stream_q is not None:
+            while req.streamed < len(req.out_tokens):
+                req.stream_q.put(req.out_tokens[req.streamed])
+                req.streamed += 1
+            if done:
+                req.done_pushed_at = time.time()
+                req.stream_q.put(None)  # end-of-stream sentinel
+        if done:
+            req.future.set_result({
+                "tokens": req.out_tokens,
+                "ttft_s": req.ttft_s,
+                "latency_s": time.perf_counter() - req.submitted_at,
+            })
+        self._work_ns["notify"] += time.perf_counter_ns() - t0
+        self._work_calls["notify"] += 1
 
     def _finished(self, req: GenRequest) -> bool:
         if req.cancelled:
@@ -758,28 +909,22 @@ class LLMEngine:
     def _retire(self, slot: int) -> None:
         req = self._slots[slot]
         self._slots[slot] = None
-        self._active = self._active.at[slot].set(False)
-        if self._slot_pages[slot] is not None:
-            self.allocator.release(self._slot_pages[slot])
-            self._slot_pages[slot] = None
-            # table row back to the trash page so the retired slot's frozen
-            # decode writes can't touch recycled pages
-            self._table = self._table.at[slot].set(0)
+        pages = self._slot_pages[slot]
+        with self._slot_update(ops=1 + (pages is not None)) as issue:
+            self._active = issue(self._active.at[slot].set, False)
+            if pages is not None:
+                self.allocator.release(pages)
+                self._slot_pages[slot] = None
+                # table row back to the trash page so the retired slot's
+                # frozen decode writes can't touch recycled pages
+                self._table = issue(self._table.at[slot].set, 0)
         if req is None:
             return
         if req.eos_token is not None and req.eos_token in req.out_tokens:
             req.out_tokens = req.out_tokens[: req.out_tokens.index(req.eos_token) + 1]
         self._tokens_out += len(req.out_tokens)
         self._retired += 1
-        self._push_stream(req)
-        if req.stream_q is not None:
-            req.done_pushed_at = time.time()
-            req.stream_q.put(None)  # end-of-stream sentinel
-        req.future.set_result({
-            "tokens": req.out_tokens,
-            "ttft_s": req.ttft_s,
-            "latency_s": time.perf_counter() - req.submitted_at,
-        })
+        self._notify(req, done=True)
 
     def _fail_request(self, req: GenRequest, error: BaseException) -> None:
         try:
@@ -816,18 +961,20 @@ class LLMEngine:
     def _step(self) -> None:
         """One iteration, in six phases that partition it (``PHASES``): each
         is a span on the device trace's clock and, at the end, one integer
-        add into ``phase_ns``."""
+        add into ``phase_ns`` and one into ``phase_cpu_ns``: the seven
+        boundaries are stamped on the wall clock and on the loop thread's CPU
+        clock."""
         jax = self._jax
-        clock = time.perf_counter_ns
+        clock, cpu = time.perf_counter_ns, time.thread_time_ns
         host = self._host_events
         compiles0, gc_ns0 = host.compiles, host.gc_pause_ns
         admitted0, retired0 = self._admitted, self._retired
         started_wall = time.time()
-        cpu0, loop_cpu0 = time.process_time_ns(), time.thread_time_ns()
-        t0 = clock()
+        process_cpu0 = time.process_time_ns()
+        t0, c0 = clock(), cpu()
         with span("engine.admit"):
             groups = self._admit()
-        t1 = clock()
+        t1, c1 = clock(), cpu()
         for chunk, bucket, size in groups:
             if bucket not in self._buckets_up:
                 with span("engine.prefill_bring_up", bucket=bucket):
@@ -836,18 +983,29 @@ class LLMEngine:
                       rows_real=len(chunk), rows_padded=size,
                       state_rows=len(chunk) if self._slot_state else 0):
                 self._prefill_group(chunk, bucket, size)
-        t2 = clock()
+        t2, c2 = clock(), cpu()
         if not any(r is not None for r in self._slots):
-            time.sleep(0.01)  # idle: poll for work (_admit drains FIFO)
-            self._idle_ns += clock() - t0
+            with span("engine.idle"):
+                time.sleep(0.01)  # idle: poll for work (_admit drains FIFO)
+            idle = clock() - t0
+            self._idle_ns += idle
+            # an idle loop has launched nothing: the chip stays known empty,
+            # and the poll is idle time, not starvation of a busy loop
+            if self._empty_since:
+                self._empty_since += idle
+            self._ended = 0
             return
+        if self._ended:  # a busy iteration behind a busy one
+            self._between_ns += t0 - self._ended
         with span("engine.decode_dispatch"):
-            self._key, sub = jax.random.split(self._key)
+            with self._slot_update(ops=1) as issue:
+                self._key, sub = issue(jax.random.split, self._key)
             # a model with routed experts returns their counts as well
             sampled, last, self._positions, self.cache, *counts = \
-                self._decode(
-                    self.params, self.cache, self._tokens,
-                    self._positions, self._active, self._table, sub,
+                self._launch(
+                    DECODE, self._decode, self.params, self.cache,
+                    self._tokens, self._positions, self._active, self._table,
+                    sub,
                 )
             self._tokens = last
             self._steps += self.decode_chunk
@@ -856,7 +1014,7 @@ class LLMEngine:
             firsts = {slot: req.pending_first
                       for slot, req in enumerate(self._slots)
                       if req is not None and req.pending_first is not None}
-        t3 = clock()
+        t3, c3 = clock(), cpu()
         with span("engine.device_get"):
             host_tokens, host_firsts, host_counts, prefill_counts = \
                 jax.device_get((sampled, firsts, counts,
@@ -866,29 +1024,33 @@ class LLMEngine:
             for call_counts in prefill_counts:
                 self._prefill_counts += call_counts
             self._prefill_counts_pending = []
-        t4 = clock()
+        t4, c4 = clock(), cpu()
+        self._chip_empty(t4)  # the fetch holds the last program's result
         now = t4 / 1e9  # perf_counter's clock, as submitted_at
         now_wall = time.time()
         active = self._admitted - retired0  # every admitted request retires
         self._decode_rows_live += active * self.decode_chunk
-        retire_ns = self._emit(host_tokens, host_firsts, now, now_wall)
-        t5 = clock()
+        retire_ns, retire_cpu_ns = self._emit(host_tokens, host_firsts, now,
+                                              now_wall)
+        t5, c5 = clock(), cpu()
+        self._ended = t5
         # a slot retires inside the emit loop, where its last token is
         # pushed; the phases still partition the iteration
         self._record_iter(started_wall, (t0, t1, t2, t3, t4, t5 - retire_ns, t5),
+                          (c0, c1, c2, c3, c4, c5 - retire_cpu_ns, c5),
+                          time.process_time_ns() - process_cpu0,
                           active, self._admitted - admitted0,
                           self._retired - retired0,
-                          host.compiles - compiles0, host.gc_pause_ns - gc_ns0,
-                          time.process_time_ns() - cpu0,
-                          time.thread_time_ns() - loop_cpu0)
+                          host.compiles - compiles0, host.gc_pause_ns - gc_ns0)
 
-    def _emit(self, host_tokens, host_firsts, now: float, now_wall: float) -> int:
+    def _emit(self, host_tokens, host_firsts, now: float, now_wall: float) -> tuple:
         """Tokens to requests and streams; a finished slot retires where its
         last token is pushed. Spans ``engine.emit`` and ``engine.retire``
         alternate and do not nest, so a device gap is named by the one the
-        host was in. Returns the nanoseconds spent retiring."""
-        clock = time.perf_counter_ns
-        retire_ns = 0
+        host was in. Returns the nanoseconds spent retiring, on the wall
+        clock and on the loop thread's CPU clock."""
+        clock, cpu = time.perf_counter_ns, time.thread_time_ns
+        retire_ns = retire_cpu_ns = 0
         emitting = span("engine.emit")
         emitting.__enter__()
         for slot, first in host_firsts.items():
@@ -899,7 +1061,7 @@ class LLMEngine:
             req.ttft_s = now - req.submitted_at
             req.first_pushed_at = now_wall
             req.out_tokens.append(int(first))
-            self._push_stream(req)  # first token streams immediately
+            self._notify(req)  # first token streams immediately
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -908,30 +1070,37 @@ class LLMEngine:
                     req.out_tokens.append(int(t))
                     if self._finished(req):
                         break
-                self._push_stream(req)
+                self._notify(req)
             if self._finished(req):
                 emitting.__exit__(None, None, None)
-                t0 = clock()
+                t0, c0 = clock(), cpu()
                 with span("engine.retire"):
                     self._retire(slot)
                 retire_ns += clock() - t0
+                retire_cpu_ns += cpu() - c0
                 emitting = span("engine.emit")
                 emitting.__enter__()
         emitting.__exit__(None, None, None)
-        return retire_ns
+        return retire_ns, retire_cpu_ns
 
-    def _record_iter(self, started_wall: float, t: tuple, active: int,
-                     admitted: int, retired: int, compiles: int,
-                     gc_ns: int, cpu_ns: int, loop_cpu_ns: int) -> None:
+    def _record_iter(self, started_wall: float, t: tuple, cpu: tuple,
+                     process_cpu_ns: int, active: int, admitted: int,
+                     retired: int, compiles: int, gc_ns: int) -> None:
         """Constant work an iteration: the counters, one ring row, and the
         slow-iteration check (the ring's median and ``/proc/stat`` are read
-        only for an iteration over the 1 s floor)."""
+        only for an iteration over the 1 s floor). ``t`` and ``cpu``: the
+        seven phase boundaries on the wall clock and on the loop thread's
+        CPU clock; ``process_cpu_ns``: the process's over the iteration."""
         total = t[6] - t[0]
+        loop_cpu_ns = cpu[6] - cpu[0]
         phases = [b - a for a, b in zip(t, t[1:])]
-        acc = self._phase_ns
+        acc, acc_cpu = self._phase_ns, self._phase_cpu_ns
         for i, ns in enumerate(phases):
             acc[i] += ns
+            acc_cpu[i] += cpu[i + 1] - cpu[i]
         self._iter_ns += total
+        self._loop_cpu_ns += loop_cpu_ns
+        self._process_cpu_ns += process_cpu_ns
         self._ring[self._iters % RING_ITERS] = (
             started_wall, *(ns / 1e9 for ns in phases), active, admitted, retired)
         self._iters += 1
@@ -948,7 +1117,7 @@ class LLMEngine:
             "at": started_wall, "total_s": total / 1e9,
             "phase": PHASES[worst], "phase_s": phases[worst] / 1e9,
             "median_s": median_s, "compiles": compiles, "gc_s": gc_ns / 1e9,
-            "cpu_s": cpu_ns / 1e9, "loop_cpu_s": loop_cpu_ns / 1e9,
+            "cpu_s": process_cpu_ns / 1e9, "loop_cpu_s": loop_cpu_ns / 1e9,
             "steal_s": _steal_s() - self._steal0_s,
             "queued": self._queued(), "active": active,
         }

@@ -94,6 +94,65 @@ def test_counter_readers_read_nothing_from_a_program_without_counters(polled_ctx
     assert engine_counters.read({"marks": {"polls": []}}, {"plus": ["iters"]}) is None
 
 
+# PR 41's metrics of the host's turn: what each reads from a ``stats()`` that
+# counts, per iteration, 3 ms starved, 5 ms of loop-thread CPU (1 of it in
+# the fetch), 9 ms of process CPU, 0.5 / 2 / 1.5 / 0.25 ms of pack / launch /
+# slot_update / notify, 2 launches (1 waited out) and 7 slot operations, and
+# 6 ms between one iteration and the next
+HOST_TURN = {
+    "starved_ns": 3e6, "loop_cpu_ns": 5e6, "process_cpu_ns": 9e6,
+    "between_ns": 6e6,
+    "phase_cpu_ns": {"device_get": 1e6},
+    "work_ns": {"pack": 0.5e6, "launch": 2e6, "slot_update": 1.5e6,
+                "notify": 0.25e6},
+    "work_calls": {"launch": 2, "launch_waited": 1, "slot_update": 7},
+}
+SATURATED = ["serve_longprompt", "serve_hybrid_longreply",
+             "serve_window_longctx", "serve_yoco_longctx", "serve_chat_sat"]
+
+
+@pytest.mark.parametrize("name,unit,expected", [
+    ("engine_starved_per_iter", "ms", 3.0),
+    ("engine_starved_per_iter.chat", "ms", 3.0),
+    ("engine_host_cpu_per_iter", "ms", 4.0),
+    ("engine_host_cpu_per_iter.chat", "ms", 4.0),
+    ("engine_get_cpu_per_iter", "ms", 1.0),
+    ("engine_get_cpu_per_iter.chat", "ms", 1.0),
+    ("engine_slot_update_per_iter", "ms", 1.5),
+    ("engine_slot_update_per_iter.chat", "ms", 1.5),
+    ("engine_slot_updates_per_iter", "ops", 7.0),
+    ("engine_launch_per_iter", "ms", 2.0),
+    ("engine_launch_waited_share", "%", 50.0),
+    ("engine_pack_per_iter", "ms", 0.5),
+    ("engine_notify_per_iter", "ms", 0.25),
+    ("engine_process_cpu_per_iter", "ms", 9.0),
+    ("engine_between_iters_per_iter", "ms", 6.0),
+    ("engine_between_iters_per_iter.chat", "ms", 6.0),
+])
+def test_host_turn_metrics_read_the_change_and_nothing_from_a_parent(
+        polled_ctx, name, unit, expected):
+    from benchmarks.harness import manifest
+
+    listed = next(m for m in manifest.load_manifest()["per_layer"]
+                  if m["name"] == name)
+    chat = name.endswith(".chat")
+    assert listed == {
+        "name": name, "unit": unit, "better": "lower",
+        "source": "program_counter", "layer": "Engine",
+        "moves": "tpot_p50" if chat else "serve_tokens_per_s",
+        "workloads": ["serve_chat"] if chat else SATURATED}
+    spec = manifest.metric_file(name)
+    assert spec["reader"] == "engine_counters"
+    # a parent's stats() has none of the counters: nothing is read
+    assert engine_counters.read(polled_ctx, spec["params"]) is None
+    for _t, s in polled_ctx["marks"]["polls"]:
+        s.update({key: {k: v * s["iters"] for k, v in value.items()}
+                  if isinstance(value, dict) else value * s["iters"]
+                  for key, value in HOST_TURN.items()})
+    assert engine_counters.read(polled_ctx, spec["params"]) == \
+        pytest.approx(expected)
+
+
 def test_queue_wait_percentile_of_the_histogram_difference(polled_ctx):
     # the difference: 10 waits in bucket 10 (63-100 ms), 3 in bucket 16
     # (1.0-1.58 s); rank 0.9 x 13 = 11.7 lies 1.7 of 3 into the latter

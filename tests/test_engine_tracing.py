@@ -4,6 +4,7 @@ on the chip (PERF.md), never here."""
 import glob
 import logging
 import os
+import queue
 import threading
 import time
 
@@ -31,6 +32,67 @@ def engine():
     eng.generate([5, 6, 7], max_tokens=5, timeout=300)  # compile both programs
     yield eng
     eng.stop()
+
+
+@pytest.fixture(scope="module")
+def stepped(engine):
+    """A second engine of the same shapes whose loop thread has stopped: a
+    test calls ``_step`` itself and reads each counter between two
+    iterations. It runs the first engine's compiled programs."""
+    eng = LLMEngine(engine.config, engine.params, num_slots=16, decode_chunk=4,
+                    max_seq_len=256, prefill_buckets=[128])
+    eng.stop()
+    eng._prefill, eng._decode = engine._prefill, engine._decode
+    step_through(eng, request([5, 6, 7], 2))  # brings the 128 bucket up
+    return eng
+
+
+def step_through(eng, req, streamed=False):
+    """``req`` from submission to its answer, an iteration at a time: the
+    rise of ``stats()`` over each iteration."""
+    if streamed:
+        req.stream_q = queue.Queue()
+    eng._submit(req)
+    rises = []
+    while not req.future.done():
+        before = eng.stats()
+        eng._step()
+        rises.append(rise(before, eng.stats()))
+    return rises
+
+
+def rise(before, after):
+    """{dotted key: after - before} of every integer counter."""
+    out = {}
+    for key, value in after.items():
+        if isinstance(value, dict) and key != "ring":
+            out.update({f"{key}.{k}": v - before[key][k]
+                        for k, v in value.items() if isinstance(v, int)})
+        elif isinstance(value, int) and not isinstance(value, bool):
+            out[key] = value - before[key]
+    return out
+
+
+class Result:
+    """A fake program's result: the array, and after how many reads of a row
+    the chip is done with it (0: the moment the launch returns; None: not
+    before the fetch); ``read_s`` makes reading a row take that long on the
+    host."""
+
+    def __init__(self, array, ready_after=None, read_s=0.0):
+        self.array, self.ready_after, self.read_s = array, ready_after, read_s
+        self.reads = 0
+
+    def is_ready(self):
+        return self.ready_after is not None and self.reads >= self.ready_after
+
+    def __getitem__(self, row):
+        time.sleep(self.read_s)
+        self.reads += 1
+        return self.array[row]
+
+    def __array__(self):
+        return np.asarray(self.array)
 
 
 def test_phases_partition_the_iteration(engine):
@@ -234,20 +296,34 @@ def test_a_profile_holds_the_six_phases_and_the_programs_by_name(engine, tmp_pat
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    before = engine.stats()
     try:
         engine.generate([3, 1, 4, 1, 5], max_tokens=6, timeout=300)
+        time.sleep(0.05)  # a whole idle poll inside the profile
     finally:
         jax.profiler.stop_trace()
+    after = engine.stats()
     path = sorted(glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
-    names, prefill_attrs = set(), None
+    names, prefill_attrs, programs, ops = set(), None, [], []
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
                 names.add(ev.name)
                 if ev.name == "engine.prefill_dispatch":
                     prefill_attrs = dict(ev.stats)
+                elif ev.name == "engine.launch":
+                    programs.append(dict(ev.stats)["program"])
+                elif ev.name == "engine.slot_update":
+                    ops.append(dict(ev.stats)["ops"])
     assert {"engine." + p for p in llm.PHASES} <= names
+    assert "engine.idle" in names
+    # one prefill, then a chunk of 4 and a chunk that ends the reply; the
+    # spans' ``ops`` are what the helper counted: the group's six, a key
+    # split a chunk, the retired request's two
+    assert sorted(programs) == [llm.PREFILL, llm.DECODE, llm.DECODE]
+    assert sorted(ops) == [1, 1, 2, 6]
+    assert sum(ops) == rise(before, after)["work_calls.slot_update"]
     # state_rows: slots whose recurrent state the prefill wrote (PR 29); a
     # Llama keeps none
     assert prefill_attrs == {"bucket": 128, "rows_real": 1, "rows_padded": 1,
@@ -318,3 +394,165 @@ def test_idle_iterations_enter_neither_iters_nor_the_ring(engine):
     assert after["idle_ns"] > before["idle_ns"]
     assert after["iters"] == before["iters"] and after["iter_ns"] == before["iter_ns"]
     assert len(after["ring"]["rows"]) == len(before["ring"]["rows"])
+
+
+def test_work_kinds_are_counted_where_the_work_happens(stepped):
+    """A request of 9 tokens lives two iterations (first token and a chunk of
+    4, then a chunk of 4 that ends it). The first admits it: the six eager
+    operations that make its slot live and the chunk's key split, the
+    prefill and the decode launch. The second retires it: a key split and the
+    retired slot's two. With no stream the only hand-over to another thread
+    is the future's result."""
+    first, last = step_through(stepped, request([1, 2, 3], 9))
+    assert (first["admitted"], first["retired"]) == (1, 0)
+    assert first["work_calls.slot_update"] == 6 + 1
+    assert first["work_calls.launch"] == 2 and first["work_calls.notify"] == 0
+    assert first["work_ns.notify"] == 0 and first["work_ns.pack"] > 0
+    assert (last["admitted"], last["retired"]) == (0, 1)
+    assert last["work_calls.slot_update"] == 1 + 2
+    assert last["work_calls.launch"] == 1 and last["work_calls.notify"] == 1
+    assert last["work_ns.pack"] == 0 and last["work_ns.notify"] > 0
+    for it in (first, last):
+        assert it["work_ns.launch"] > 0 and it["work_ns.slot_update"] > 0
+        assert 0 <= it["work_calls.launch_waited"] <= it["work_calls.launch"]
+
+
+def test_a_stream_is_notified_a_push_and_never_a_token(stepped):
+    """First token and the chunk's four: two pushes; the next chunk's four
+    and the end (tokens, sentinel and result in one hand-over): two."""
+    first, last = step_through(stepped, request([4, 5, 6], 9), streamed=True)
+    assert first["work_calls.notify"] == 2 and last["work_calls.notify"] == 2
+
+
+def test_the_work_kinds_lie_inside_the_host_turn(engine, stepped):
+    submit_together(engine, [request([2 + i] * 50, 9) for i in range(5)])
+    for s in (engine.stats(), stepped.stats()):
+        assert tuple(s["work_ns"]) == llm.WORK_KINDS
+        assert tuple(s["work_calls"]) == llm.WORK_CALLS
+        assert all(ns > 0 for ns in s["work_ns"].values())
+        assert sum(s["work_ns"].values()) <= \
+            s["iter_ns"] - s["phase_ns"]["device_get"]
+
+
+def test_bringing_a_bucket_up_is_launches_of_its_own_kind(stepped):
+    """A bucket met for the first time: a launch of every row count before
+    the request's own, each with the read of a row that compiles beside it."""
+    stepped._buckets_up.discard(128)
+    first = step_through(stepped, request([7, 8, 9], 2))[0]
+    assert first["work_calls.launch"] == len(llm.PREFILL_ROWS) + 2
+    assert first["work_calls.slot_update"] == len(llm.PREFILL_ROWS) + 6 + 1 + 2
+    assert 128 in stepped._buckets_up
+
+
+def test_phase_cpu_splits_the_loop_threads_cpu_time(stepped):
+    step_through(stepped, request([3, 4, 5], 9))
+    s = stepped.stats()
+    assert tuple(s["phase_cpu_ns"]) == llm.PHASES
+    assert sum(s["phase_cpu_ns"].values()) == s["loop_cpu_ns"] > 0
+    assert s["loop_cpu_ns"] <= s["iter_ns"] * 1.05
+    # the process's clock counts every thread, the loop thread among them
+    assert s["process_cpu_ns"] >= 0.9 * s["loop_cpu_ns"]
+
+
+def test_waiting_for_the_chip_is_wall_time_and_not_cpu_time(stepped):
+    """A decode program that the chip delivers 0.3 s late: the fetch waits
+    for it asleep."""
+    real = stepped._decode
+
+    class Late(Result):
+        def __array__(self):
+            time.sleep(0.3)
+            return np.asarray(self.array)
+
+    def late(*args):
+        out = real(*args)
+        return (Late(out[0]),) + tuple(out[1:])
+
+    stepped._decode = late
+    try:
+        (it,) = step_through(stepped, request([6, 7, 8], 2))
+    finally:
+        stepped._decode = real
+    assert it["phase_ns.device_get"] >= 0.3e9
+    assert it["phase_cpu_ns.device_get"] < 0.1e9
+    assert it["loop_cpu_ns"] < it["iter_ns"] - 0.2e9
+
+
+@pytest.mark.parametrize("ready_after,waited,starved_s", [
+    (0, 1, (0.4, 9.0)), (1, 0, (0.2, 0.35)), (None, 0, (0.0, 0.1))])
+def test_the_chip_is_starved_from_an_empty_instant_to_the_next_launch(
+        stepped, ready_after, waited, starved_s):
+    """Two requests in one prefill group; between the prefill launch and the
+    decode launch the loop reads each one's first token, 0.2 s a read here.
+    A launch that returns with its result ready has waited the program out:
+    both reads are starvation. A program the FIRST read waits out leaves the
+    second as starvation: the loop learns that the chip is empty from the
+    operation that returns and finds the result ready. Behind a program that
+    is unfinished until the fetch, none of it is."""
+    real_prefill, real_decode = stepped._prefill_firsts, stepped._decode
+
+    def prefill(args):
+        firsts = real_prefill(args)
+        firsts.block_until_ready()
+        return Result(firsts, ready_after, read_s=0.2)
+
+    def decode(*args):
+        out = real_decode(*args)
+        return (Result(out[0]),) + tuple(out[1:])
+
+    stepped._prefill_firsts, stepped._decode = prefill, decode
+    reqs = [request([9, 8, 7], 2), request([6, 5, 4], 2)]
+    for r in reqs:
+        stepped._submit(r)
+    stepped._chip_empty(time.perf_counter_ns())  # observed empty: now
+    before = stepped.stats()
+    try:
+        stepped._step()
+    finally:
+        stepped._prefill_firsts, stepped._decode = real_prefill, real_decode
+    it = rise(before, stepped.stats())
+    assert all(r.future.done() for r in reqs) and it["admitted"] == 2
+    assert it["work_calls.launch"] == 2
+    assert it["work_calls.launch_waited"] == waited
+    assert it["work_ns.slot_update"] >= 0.4e9
+    assert starved_s[0] * 1e9 <= it["starved_ns"] < starved_s[1] * 1e9
+    assert it["starved_ns"] <= it["iter_ns"]
+    # the fetch saw everything done: the chip is empty from then on
+    assert stepped._empty_since > 0 and stepped._in_flight is None
+
+
+def test_between_ns_is_the_time_from_one_busy_iteration_to_the_next(stepped):
+    """A request of 9 tokens lives two iterations; 0.1 s pass between them
+    here. An idle poll ends the run of busy iterations: nothing is counted
+    across it."""
+    stepped._step()  # an idle poll: no busy iteration stands before the next
+    req = request([2, 7, 1], 9)
+    stepped._submit(req)
+    before = stepped.stats()
+    stepped._step()
+    time.sleep(0.1)
+    stepped._step()
+    assert req.future.done()
+    it = rise(before, stepped.stats())
+    assert it["iters"] == 2 and 0.1e9 <= it["between_ns"] < 0.2e9
+    time.sleep(0.1)
+    stepped._step()  # idle
+    time.sleep(0.1)
+    before = stepped.stats()
+    step_through(stepped, request([2, 7, 2], 2))
+    assert rise(before, stepped.stats())["between_ns"] == 0
+
+
+def test_an_idle_poll_raises_idle_ns_alone(stepped):
+    """Thirty idle polls between two requests are 0.3 s of ``idle_ns`` and no
+    starvation: the stretch from the last fetch to the next launch is
+    counted without them."""
+    step_through(stepped, request([1, 1, 2], 2))
+    before = stepped.stats()
+    for _ in range(30):
+        stepped._step()
+    polled = rise(before, stepped.stats())
+    assert polled.pop("idle_ns") >= 0.3e9
+    assert not any(polled.values())
+    (it,) = step_through(stepped, request([3, 5, 8], 2))
+    assert 0 < it["starved_ns"] < 0.15e9
