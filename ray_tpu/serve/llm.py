@@ -37,7 +37,6 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -139,6 +138,22 @@ def model_presets() -> Dict[str, Any]:
             "phi4flash_tiny": _phi4flash_tiny}
 
 
+def _slots_updated(table, tokens, positions, active, retired, firsts, placed):
+    """A round's changes of per-slot state as ONE program: the slots of
+    ``retired`` ([num_slots] bool) go dead, their table rows back to the
+    trash page, so that a retired slot's frozen decode writes cannot touch
+    recycled pages; THEN row ``i`` of a prefill group (``placed``,
+    [size, 2 + pages_per_slot]: slot, prompt length, table row) makes its
+    slot live at that position with its token of ``firsts`` ([size]). A pad
+    row names slot ``num_slots``, which no array has: the scatters drop it."""
+    slots = placed[:, 0]
+    table = table * ~retired[:, None]
+    return (table.at[slots].set(placed[:, 2:], mode="drop"),
+            tokens.at[slots].set(firsts, mode="drop"),
+            positions.at[slots].set(placed[:, 1], mode="drop"),
+            (active & ~retired).at[slots].set(True, mode="drop"))
+
+
 def _steal_s() -> float:
     """Seconds since boot in which the hypervisor ran something else on this
     machine's CPUs (``/proc/stat``, summed over CPUs); 0.0 where it is not
@@ -160,7 +175,6 @@ class GenRequest:
     ttft_s: Optional[float] = None
     out_tokens: List[int] = field(default_factory=list)
     slot: int = -1
-    pending_first: Any = None  # device scalar: first sampled token, unfetched
     # streaming: tokens pushed here as decoded (None sentinel = done)
     stream_q: Optional["queue.Queue"] = None
     streamed: int = 0
@@ -230,24 +244,27 @@ class LLMEngine:
       for the chip, in ``admit``, ``emit`` and ``retire``, which wait for no
       device, the wait for the interpreter.
     - ``work_ns``: the iteration by KIND of host work, across the phases:
-      ``pack`` (a prefill call's numpy arguments and their copies to the
-      chip), ``launch`` (the calls into the compiled programs, call to
+      ``pack`` (a prefill group's numpy arguments, the rows its slots go
+      live by among them, and the program's copies to the chip),
+      ``launch`` (the calls into the compiled programs, call to
       return: the prefill program with its ``argmax``, the decode program,
-      a bucket's bring-up), ``slot_update`` (every eager device operation on
-      per-slot state: the table row, first token, position and live flag of
-      an admitted request, the flag and row of a retired one, a chunk's key
-      split; all issued by ``_slot_update``), ``notify`` (a stream's queue
-      puts, the end sentinel, the future's result). They never overlap;
-      what is left of ``iter_ns`` after them and ``phase_ns.device_get`` is
-      Python bookkeeping. ``work_calls``: ``launch``, ``slot_update``
-      (operations), ``notify`` (hand-overs: a push of a stream's new
-      tokens, or a request's end), and ``launch_waited``: launches whose
-      result was ready the moment the call returned, which is the loop
-      thread having waited the program out.
+      a bucket's bring-up), ``slot_update`` (every device operation on
+      per-slot state between two launches, all issued by ``_slot_update``:
+      ONE program a prefill group, which makes its slots live and the slots
+      retired since the last one dead, one more in an iteration that retired
+      a slot and admits nobody, and a chunk's key split),
+      ``notify`` (a stream's queue puts, the end sentinel, the future's
+      result). They never overlap; what is left of ``iter_ns`` after them
+      and ``phase_ns.device_get`` is Python bookkeeping. ``work_calls``:
+      ``launch``, ``slot_update`` (operations: at most prefill groups + 2
+      an iteration, whatever it admits or retires), ``notify``
+      (hand-overs: a push of a stream's new tokens, or a request's end), and
+      ``launch_waited``: launches whose result was ready the moment the call
+      returned, which is the loop thread having waited the program out.
     - ``starved_ns``: how long the chip had nothing queued while the loop was
       busy, as far as the loop can know. It sees the chip empty when
       ``device_get`` returns, when a launch returns with its result ready,
-      and when an eager operation behind a launch returns and finds that
+      and when an operation behind a launch returns and finds that
       launch's result ready (the operation is then where the loop waited
       the program out); from there to its next launch no program is queued
       (slot updates in between are microseconds of device work and end no
@@ -347,9 +364,9 @@ class LLMEngine:
     (``bucket``, ``rows_real``, ``rows_padded``, ``state_rows``),
     ``engine.decode_dispatch``, ``engine.device_get``, ``engine.emit``,
     ``engine.retire``; nested in them ``engine.launch`` (``program``: 0
-    prefill, 1 decode, 2 bring-up) and ``engine.slot_update`` (``ops``), one
-    a prefill group, a chunk or a retired request; ``engine.idle`` around the
-    idle poll's sleep."""
+    prefill, 1 decode, 2 bring-up) and ``engine.slot_update``, one an
+    operation ``_slot_update`` issues; ``engine.idle`` around the idle
+    poll's sleep."""
 
     def __init__(self, config, params=None, *, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, decode_chunk: int = 8,
@@ -411,6 +428,13 @@ class LLMEngine:
         self._tokens = jnp.zeros((num_slots,), jnp.int32)
         self._positions = jnp.zeros((num_slots,), jnp.int32)
         self._active = jnp.zeros((num_slots,), bool)
+        # the ONE program that changes it (and ``_table``) between launches.
+        # It holds no layer and touches no cache: the same for every family,
+        # and new only to the first bucket that meets a row count (``_bring_up``)
+        self._update = jax.jit(_slots_updated)
+        # slots retired on the host that the device still holds live, until
+        # the next ``_update`` (before any decode launch: ``_step``)
+        self._retiring = np.zeros((num_slots,), bool)
         # host-side state
         self._slots: List[Optional[GenRequest]] = [None] * num_slots
         self._pending: "queue.Queue[GenRequest]" = queue.Queue()
@@ -755,7 +779,7 @@ class LLMEngine:
         a result that is ready the moment the call returns (a query, no sync)
         means the loop thread waited the program out (``launch_waited``) and
         the chip is empty again; one that is not stays ``_in_flight`` for
-        the eager operations behind it to ask (``_slot_update``)."""
+        the operations behind it to ask (``_slot_update``)."""
         t0 = time.perf_counter_ns()
         if self._empty_since:
             self._starved_ns += t0 - self._empty_since
@@ -776,55 +800,57 @@ class LLMEngine:
         self._empty_since = since
         self._in_flight = None
 
-    @contextmanager
-    def _slot_update(self, ops: int):
-        """The ONE place the loop issues eager device operations on per-slot
-        state (``_table``, ``_tokens``, ``_positions``, ``_active``, a row of
-        a prefill's first tokens, the key split of a chunk): yields
-        ``issue(op, *args)``, which counts the operation and calls it, so
-        ``work_calls.slot_update`` is a count by construction; the stamps and
-        the span (``ops``: what the caller is about to issue) are one a
-        prefill group, a retired request or a chunk, not one an operation.
-        An operation behind a launch may be where the loop waits that
-        program out: when one returns and finds the launch's result ready (a
-        query), the chip is empty from then on."""
-        calls = self._work_calls
-
-        def issue(op, *args):
-            calls["slot_update"] += 1
-            out = op(*args)
-            if self._in_flight is not None and self._in_flight.is_ready():
-                self._chip_empty(time.perf_counter_ns())
-            return out
-
+    def _slot_update(self, op, *args):
+        """The ONE place the loop issues a device operation on per-slot state
+        between two launches: ``_update`` (``_update_slots``) and a chunk's
+        key split. Counts the operation (``work_calls.slot_update``), stamps
+        it (``work_ns.slot_update``) and calls it. An operation behind a
+        launch may be where the loop waits that program out: when it returns
+        and finds the launch's result ready (a query), the chip is empty from
+        then on."""
         t0 = time.perf_counter_ns()
-        with span("engine.slot_update", ops=ops):
-            yield issue
-        self._work_ns["slot_update"] += time.perf_counter_ns() - t0
+        with span("engine.slot_update"):
+            out = op(*args)
+        t1 = time.perf_counter_ns()
+        self._work_calls["slot_update"] += 1
+        self._work_ns["slot_update"] += t1 - t0
+        if self._in_flight is not None and self._in_flight.is_ready():
+            self._chip_empty(t1)
+        return out
+
+    def _pad_rows(self, size: int) -> np.ndarray:
+        """What places a prefill group's `size` rows, [size, 2 +
+        pages_per_slot] (slot, prompt length, table row), with every row a pad
+        row: the slot no array has, the trash page."""
+        placed = np.zeros((size, 2 + self.pages_per_slot), np.int32)
+        placed[:, 0] = self.num_slots
+        placed[:, 1] = 1
+        return placed
 
     def _run_prefill(self, chunk: List[tuple], bucket: int, size: int,
                      program: int = PREFILL):
         """The prefill program of `bucket` at `size` rows over `chunk`; pad
         rows write to the trash page and the trash state row and are
-        discarded. Returns the rows' first tokens, [size], on the device."""
+        discarded. Returns the rows' first tokens, [size], on the device, and
+        what ``_update_slots`` places them by (``_pad_rows``), on the host."""
         jnp = self._jnp
         t0 = time.perf_counter_ns()
         n_pages = bucket // self.page_size
         tokens = np.zeros((size, bucket), np.int32)
-        page_arr = np.zeros((size, n_pages), np.int32)  # pad rows -> trash
-        lengths = np.ones((size,), np.int32)
-        slots = np.full((size,), self.num_slots, np.int32)  # pad rows -> trash
+        placed = self._pad_rows(size)
         for row, (req, slot, pages, _b) in enumerate(chunk):
             n = len(req.tokens)
             tokens[row, :n] = req.tokens
-            page_arr[row] = pages[:n_pages]
-            lengths[row] = min(n, bucket)
-            slots[row] = slot
-        args = [jnp.asarray(tokens), jnp.asarray(page_arr), jnp.asarray(lengths)]
+            placed[row, :2] = slot, n
+            placed[row, 2:2 + len(pages)] = pages
+        # the program's own view of the same rows: the bucket's pages, the
+        # lengths, and for a family with per-slot state the slots
+        args = [jnp.asarray(tokens), jnp.asarray(placed[:, 2:2 + n_pages]),
+                jnp.asarray(placed[:, 1])]
         if self._slot_state:
-            args.append(jnp.asarray(slots))
+            args.append(jnp.asarray(placed[:, 0]))
         self._work_ns["pack"] += time.perf_counter_ns() - t0
-        return self._launch(program, self._prefill_firsts, args)
+        return self._launch(program, self._prefill_firsts, args), placed
 
     def _prefill_firsts(self, args: list):
         """The prefill program and the pick of each row's first token."""
@@ -834,41 +860,41 @@ class LLMEngine:
         self._prefill_counts_pending += counts
         return self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32)
 
+    def _update_slots(self, firsts, placed: np.ndarray) -> None:
+        """ONE ``_update``: the slots retired since the last go dead, then
+        the real rows of ``placed`` go live, each with its token of
+        ``firsts``."""
+        retired, self._retiring = self._retiring, np.zeros_like(self._retiring)
+        self._table, self._tokens, self._positions, self._active = \
+            self._slot_update(self._update, self._table, self._tokens,
+                              self._positions, self._active, retired, firsts,
+                              placed)
+
     def _bring_up(self, bucket: int) -> None:
         """A bucket met for the first time: compile (or load from the
         persistent cache) its program at EVERY row count now, so that no
         later group meets a new shape, by one call each whose rows are all
         pad rows (largest first: the device runs one while the host brings
-        the next up). The read of a row is a small program a row count too."""
+        the next up), and behind it ``_update`` at that row count, which pad
+        rows make a no-op: a program of its own only the first time an engine
+        meets the row count, whatever the bucket."""
         t0 = time.perf_counter()
         rows = self._rows_of(bucket)
         for size in reversed(rows):
-            firsts = self._run_prefill([], bucket, size, BRING_UP)
-            with self._slot_update(ops=1) as issue:
-                issue(firsts.__getitem__, 0)
+            self._update_slots(*self._run_prefill([], bucket, size, BRING_UP))
         self._buckets_up.add(bucket)
         logger.info("prefill bucket %d: programs of %s rows up in %.2f s",
                     bucket, rows, time.perf_counter() - t0)
 
-    def _prefill_group(self, chunk: List[tuple], bucket: int, size: int) -> None:
+    def _prefill_group(self, chunk: List[tuple], bucket: int, size: int):
         """One batched prefill program for `chunk`, at the `size` rows that
-        admission chose for it, and each request's slot made live."""
-        jnp = self._jnp
+        admission chose for it, and ONE program behind it that makes each
+        request's slot live. Returns the rows' first tokens, on the device."""
         self._count_prefill(len(chunk), size,
                             sum(len(req.tokens) for req, *_ in chunk), bucket)
-        firsts = self._run_prefill(chunk, bucket, size)
-        with self._slot_update(ops=6 * len(chunk)) as issue:
-            for row, (req, slot, pages, _b) in enumerate(chunk):
-                n = len(req.tokens)
-                trow = np.zeros((self.pages_per_slot,), np.int32)
-                trow[: len(pages)] = pages
-                self._table = issue(self._table.at[slot].set,
-                                    issue(jnp.asarray, trow))
-                first = issue(firsts.__getitem__, row)  # device scalar
-                req.pending_first = first
-                self._tokens = issue(self._tokens.at[slot].set, first)
-                self._positions = issue(self._positions.at[slot].set, n)
-                self._active = issue(self._active.at[slot].set, True)
+        firsts, placed = self._run_prefill(chunk, bucket, size)
+        self._update_slots(firsts, placed)
+        return firsts
 
     def _notify(self, req: GenRequest, done: bool = False) -> None:
         """Hand other threads what the loop has for them (``work_ns.notify``,
@@ -906,25 +932,26 @@ class LLMEngine:
             return True
         return False
 
-    def _retire(self, slot: int) -> None:
-        req = self._slots[slot]
-        self._slots[slot] = None
-        pages = self._slot_pages[slot]
-        with self._slot_update(ops=1 + (pages is not None)) as issue:
-            self._active = issue(self._active.at[slot].set, False)
-            if pages is not None:
-                self.allocator.release(pages)
-                self._slot_pages[slot] = None
-                # table row back to the trash page so the retired slot's
-                # frozen decode writes can't touch recycled pages
-                self._table = issue(self._table.at[slot].set, 0)
-        if req is None:
-            return
-        if req.eos_token is not None and req.eos_token in req.out_tokens:
-            req.out_tokens = req.out_tokens[: req.out_tokens.index(req.eos_token) + 1]
-        self._tokens_out += len(req.out_tokens)
-        self._retired += 1
-        self._notify(req, done=True)
+    def _retire(self, slots: List[int]) -> None:
+        """An iteration's finished slots, on the host: their pages back to
+        the allocator and each request's answer. The device still holds them
+        live, with table rows of released pages: ``_retiring`` carries them
+        to the next ``_update``, which ``_step`` issues before any decode
+        launch (the device runs in order, and only a decode program writes
+        through the table), so no retired slot's frozen decode writes reach
+        pages the allocator has handed out again."""
+        self._retiring[slots] = True
+        for slot in slots:
+            req = self._slots[slot]
+            self._slots[slot] = None
+            self.allocator.release(self._slot_pages[slot])
+            self._slot_pages[slot] = None
+            if req.eos_token is not None and req.eos_token in req.out_tokens:
+                req.out_tokens = req.out_tokens[
+                    : req.out_tokens.index(req.eos_token) + 1]
+            self._tokens_out += len(req.out_tokens)
+            self._retired += 1
+            self._notify(req, done=True)
 
     def _fail_request(self, req: GenRequest, error: BaseException) -> None:
         try:
@@ -975,6 +1002,7 @@ class LLMEngine:
         with span("engine.admit"):
             groups = self._admit()
         t1, c1 = clock(), cpu()
+        firsts = []  # a prefill group's first tokens, on the device
         for chunk, bucket, size in groups:
             if bucket not in self._buckets_up:
                 with span("engine.prefill_bring_up", bucket=bucket):
@@ -982,7 +1010,7 @@ class LLMEngine:
             with span("engine.prefill_dispatch", bucket=bucket,
                       rows_real=len(chunk), rows_padded=size,
                       state_rows=len(chunk) if self._slot_state else 0):
-                self._prefill_group(chunk, bucket, size)
+                firsts.append(self._prefill_group(chunk, bucket, size))
         t2, c2 = clock(), cpu()
         if not any(r is not None for r in self._slots):
             with span("engine.idle"):
@@ -998,8 +1026,11 @@ class LLMEngine:
         if self._ended:  # a busy iteration behind a busy one
             self._between_ns += t0 - self._ended
         with span("engine.decode_dispatch"):
-            with self._slot_update(ops=1) as issue:
-                self._key, sub = issue(jax.random.split, self._key)
+            if self._retiring.any():  # no prefill group carried them
+                size = self._prefill_rows[0]
+                self._update_slots(np.zeros((size,), np.int32),
+                                   self._pad_rows(size))
+            self._key, sub = self._slot_update(jax.random.split, self._key)
             # a model with routed experts returns their counts as well
             sampled, last, self._positions, self.cache, *counts = \
                 self._launch(
@@ -1009,13 +1040,10 @@ class LLMEngine:
                 )
             self._tokens = last
             self._steps += self.decode_chunk
-            # ONE host sync per chunk: chunk tokens + any pending first
-            # tokens from this round's prefills
-            firsts = {slot: req.pending_first
-                      for slot, req in enumerate(self._slots)
-                      if req is not None and req.pending_first is not None}
         t3, c3 = clock(), cpu()
         with span("engine.device_get"):
+            # ONE host sync per chunk: the chunk's tokens and, one array a
+            # group, the first tokens of this round's prefills
             host_tokens, host_firsts, host_counts, prefill_counts = \
                 jax.device_get((sampled, firsts, counts,
                                 self._prefill_counts_pending))
@@ -1030,58 +1058,49 @@ class LLMEngine:
         now_wall = time.time()
         active = self._admitted - retired0  # every admitted request retires
         self._decode_rows_live += active * self.decode_chunk
-        retire_ns, retire_cpu_ns = self._emit(host_tokens, host_firsts, now,
-                                              now_wall)
-        t5, c5 = clock(), cpu()
-        self._ended = t5
-        # a slot retires inside the emit loop, where its last token is
-        # pushed; the phases still partition the iteration
-        self._record_iter(started_wall, (t0, t1, t2, t3, t4, t5 - retire_ns, t5),
-                          (c0, c1, c2, c3, c4, c5 - retire_cpu_ns, c5),
+        t5, c5 = self._emit(host_tokens, groups, host_firsts, now, now_wall)
+        t6, c6 = clock(), cpu()
+        self._ended = t6
+        self._record_iter(started_wall, (t0, t1, t2, t3, t4, t5, t6),
+                          (c0, c1, c2, c3, c4, c5, c6),
                           time.process_time_ns() - process_cpu0,
                           active, self._admitted - admitted0,
                           self._retired - retired0,
                           host.compiles - compiles0, host.gc_pause_ns - gc_ns0)
 
-    def _emit(self, host_tokens, host_firsts, now: float, now_wall: float) -> tuple:
-        """Tokens to requests and streams; a finished slot retires where its
-        last token is pushed. Spans ``engine.emit`` and ``engine.retire``
-        alternate and do not nest, so a device gap is named by the one the
-        host was in. Returns the nanoseconds spent retiring, on the wall
-        clock and on the loop thread's CPU clock."""
-        clock, cpu = time.perf_counter_ns, time.thread_time_ns
-        retire_ns = retire_cpu_ns = 0
-        emitting = span("engine.emit")
-        emitting.__enter__()
-        for slot, first in host_firsts.items():
-            req = self._slots[slot]
-            if req is None:
-                continue
-            req.pending_first = None
-            req.ttft_s = now - req.submitted_at
-            req.first_pushed_at = now_wall
-            req.out_tokens.append(int(first))
-            self._notify(req)  # first token streams immediately
-        for slot, req in enumerate(self._slots):
-            if req is None:
-                continue
-            if not self._finished(req):
-                for t in host_tokens[slot]:
-                    req.out_tokens.append(int(t))
-                    if self._finished(req):
-                        break
-                self._notify(req)
-            if self._finished(req):
-                emitting.__exit__(None, None, None)
-                t0, c0 = clock(), cpu()
-                with span("engine.retire"):
-                    self._retire(slot)
-                retire_ns += clock() - t0
-                retire_cpu_ns += cpu() - c0
-                emitting = span("engine.emit")
-                emitting.__enter__()
-        emitting.__exit__(None, None, None)
-        return retire_ns, retire_cpu_ns
+    def _emit(self, host_tokens, groups: List[tuple], host_firsts, now: float,
+              now_wall: float) -> tuple:
+        """Tokens to requests and streams: to a request of this round's
+        ``groups`` the token its prefill sampled, by its row of the group's
+        array in ``host_firsts``; to every live slot its row of the chunk.
+        Then the iteration's finished slots are retired together. Returns the
+        instant between the two, where ``engine.emit`` ends and
+        ``engine.retire`` starts, on the wall clock and on the loop thread's
+        CPU clock."""
+        finished = []
+        with span("engine.emit"):
+            for (chunk, _b, _s), firsts in zip(groups, host_firsts):
+                for (req, *_), first in zip(chunk, firsts):  # pad rows: last
+                    req.ttft_s = now - req.submitted_at
+                    req.first_pushed_at = now_wall
+                    req.out_tokens.append(int(first))
+                    self._notify(req)  # first token streams immediately
+            for slot, req in enumerate(self._slots):
+                if req is None:
+                    continue
+                if not self._finished(req):
+                    for t in host_tokens[slot]:
+                        req.out_tokens.append(int(t))
+                        if self._finished(req):
+                            break
+                    self._notify(req)
+                if self._finished(req):
+                    finished.append(slot)
+        boundary = time.perf_counter_ns(), time.thread_time_ns()
+        if finished:
+            with span("engine.retire"):
+                self._retire(finished)
+        return boundary
 
     def _record_iter(self, started_wall: float, t: tuple, cpu: tuple,
                      process_cpu_ns: int, active: int, admitted: int,

@@ -174,6 +174,7 @@ def test_engine_fails_requests_with_the_step_exception(tiny_engine, program):
         engine.generate([4, 5], max_tokens=2, timeout=30)
     with pytest.raises(RuntimeError, match="Mosaic failed"):
         list(engine.generate_stream([4, 5], max_tokens=2, timeout=30))
+    engine._thread.join(timeout=10)  # it answers the callers, then ends
     assert not engine._thread.is_alive()
 
 

@@ -7,6 +7,7 @@ import os
 import queue
 import threading
 import time
+from contextlib import contextmanager
 
 import numpy as np
 import pytest
@@ -74,25 +75,33 @@ def rise(before, after):
 
 
 class Result:
-    """A fake program's result: the array, and after how many reads of a row
-    the chip is done with it (0: the moment the launch returns; None: not
-    before the fetch); ``read_s`` makes reading a row take that long on the
-    host."""
+    """A fake program's result: the array, and after how many operations
+    issued behind it the chip is done with it (0: the moment the launch
+    returns; None: not before the fetch)."""
 
-    def __init__(self, array, ready_after=None, read_s=0.0):
-        self.array, self.ready_after, self.read_s = array, ready_after, read_s
-        self.reads = 0
+    def __init__(self, array, ready_after=None):
+        self.array, self.ready_after = array, ready_after
+        self.ops_behind = 0
 
     def is_ready(self):
-        return self.ready_after is not None and self.reads >= self.ready_after
-
-    def __getitem__(self, row):
-        time.sleep(self.read_s)
-        self.reads += 1
-        return self.array[row]
+        return self.ready_after is not None and self.ops_behind >= self.ready_after
 
     def __array__(self):
         return np.asarray(self.array)
+
+
+class SlowSplit:
+    """``jax``, with a key split that takes ``seconds`` on the host."""
+
+    def __init__(self, jax, seconds):
+        self._jax, self._seconds, self.random = jax, seconds, self
+
+    def __getattr__(self, name):
+        return getattr(self._jax, name)
+
+    def split(self, key):
+        time.sleep(self._seconds)
+        return self._jax.random.split(key)
 
 
 def test_phases_partition_the_iteration(engine):
@@ -305,7 +314,7 @@ def test_a_profile_holds_the_six_phases_and_the_programs_by_name(engine, tmp_pat
     after = engine.stats()
     path = sorted(glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
-    names, prefill_attrs, programs, ops = set(), None, [], []
+    names, prefill_attrs, programs, ops = set(), None, [], 0
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
@@ -315,15 +324,14 @@ def test_a_profile_holds_the_six_phases_and_the_programs_by_name(engine, tmp_pat
                 elif ev.name == "engine.launch":
                     programs.append(dict(ev.stats)["program"])
                 elif ev.name == "engine.slot_update":
-                    ops.append(dict(ev.stats)["ops"])
+                    ops += 1
     assert {"engine." + p for p in llm.PHASES} <= names
     assert "engine.idle" in names
-    # one prefill, then a chunk of 4 and a chunk that ends the reply; the
-    # spans' ``ops`` are what the helper counted: the group's six, a key
-    # split a chunk, the retired request's two
+    # one prefill, then a chunk of 4 and a chunk that ends the reply; a span
+    # an operation the helper counted: the group's one and a key split a
+    # chunk (the retired slot goes dead with the next group's)
     assert sorted(programs) == [llm.PREFILL, llm.DECODE, llm.DECODE]
-    assert sorted(ops) == [1, 1, 2, 6]
-    assert sum(ops) == rise(before, after)["work_calls.slot_update"]
+    assert ops == 3 == rise(before, after)["work_calls.slot_update"]
     # state_rows: slots whose recurrent state the prefill wrote (PR 29); a
     # Llama keeps none
     assert prefill_attrs == {"bucket": 128, "rows_real": 1, "rows_padded": 1,
@@ -398,18 +406,18 @@ def test_idle_iterations_enter_neither_iters_nor_the_ring(engine):
 
 def test_work_kinds_are_counted_where_the_work_happens(stepped):
     """A request of 9 tokens lives two iterations (first token and a chunk of
-    4, then a chunk of 4 that ends it). The first admits it: the six eager
-    operations that make its slot live and the chunk's key split, the
-    prefill and the decode launch. The second retires it: a key split and the
-    retired slot's two. With no stream the only hand-over to another thread
-    is the future's result."""
+    4, then a chunk of 4 that ends it). The first admits it: the ONE program
+    that makes its group's slots live (and the last request's slot dead) and
+    the chunk's key split, the prefill and the decode launch. The second
+    retires it, on the host alone: a key split. With no stream the only
+    hand-over to another thread is the future's result."""
     first, last = step_through(stepped, request([1, 2, 3], 9))
     assert (first["admitted"], first["retired"]) == (1, 0)
-    assert first["work_calls.slot_update"] == 6 + 1
+    assert first["work_calls.slot_update"] == 1 + 1
     assert first["work_calls.launch"] == 2 and first["work_calls.notify"] == 0
     assert first["work_ns.notify"] == 0 and first["work_ns.pack"] > 0
     assert (last["admitted"], last["retired"]) == (0, 1)
-    assert last["work_calls.slot_update"] == 1 + 2
+    assert last["work_calls.slot_update"] == 1
     assert last["work_calls.launch"] == 1 and last["work_calls.notify"] == 1
     assert last["work_ns.pack"] == 0 and last["work_ns.notify"] > 0
     for it in (first, last):
@@ -436,11 +444,14 @@ def test_the_work_kinds_lie_inside_the_host_turn(engine, stepped):
 
 def test_bringing_a_bucket_up_is_launches_of_its_own_kind(stepped):
     """A bucket met for the first time: a launch of every row count before
-    the request's own, each with the read of a row that compiles beside it."""
+    the request's own, each with the program that writes per-slot state
+    behind it on pad rows (new to the engine only at a row count's first
+    bucket). The request lives this one iteration: its group's program and
+    the key split."""
     stepped._buckets_up.discard(128)
     first = step_through(stepped, request([7, 8, 9], 2))[0]
     assert first["work_calls.launch"] == len(llm.PREFILL_ROWS) + 2
-    assert first["work_calls.slot_update"] == len(llm.PREFILL_ROWS) + 6 + 1 + 2
+    assert first["work_calls.slot_update"] == len(llm.PREFILL_ROWS) + 1 + 1
     assert 128 in stepped._buckets_up
 
 
@@ -483,24 +494,33 @@ def test_waiting_for_the_chip_is_wall_time_and_not_cpu_time(stepped):
 def test_the_chip_is_starved_from_an_empty_instant_to_the_next_launch(
         stepped, ready_after, waited, starved_s):
     """Two requests in one prefill group; between the prefill launch and the
-    decode launch the loop reads each one's first token, 0.2 s a read here.
-    A launch that returns with its result ready has waited the program out:
-    both reads are starvation. A program the FIRST read waits out leaves the
-    second as starvation: the loop learns that the chip is empty from the
-    operation that returns and finds the result ready. Behind a program that
-    is unfinished until the fetch, none of it is."""
+    decode launch the loop issues the group's program and the key split, 0.2 s
+    each on the host here. A launch that returns with its result ready has
+    waited the program out: both operations are starvation. A program the
+    FIRST operation waits out leaves the second as starvation: the loop
+    learns that the chip is empty from the operation that returns and finds
+    the result ready. Behind a program that is unfinished until the fetch,
+    none of it is."""
     real_prefill, real_decode = stepped._prefill_firsts, stepped._decode
+    real_update, real_jax = stepped._update, stepped._jax
 
     def prefill(args):
         firsts = real_prefill(args)
         firsts.block_until_ready()
-        return Result(firsts, ready_after, read_s=0.2)
+        return Result(firsts, ready_after)
+
+    def update(table, tokens, positions, active, retired, firsts, placed):
+        time.sleep(0.2)
+        firsts.ops_behind += 1
+        return real_update(table, tokens, positions, active, retired,
+                           firsts.array, placed)
 
     def decode(*args):
         out = real_decode(*args)
         return (Result(out[0]),) + tuple(out[1:])
 
     stepped._prefill_firsts, stepped._decode = prefill, decode
+    stepped._update, stepped._jax = update, SlowSplit(real_jax, 0.2)
     reqs = [request([9, 8, 7], 2), request([6, 5, 4], 2)]
     for r in reqs:
         stepped._submit(r)
@@ -510,6 +530,7 @@ def test_the_chip_is_starved_from_an_empty_instant_to_the_next_launch(
         stepped._step()
     finally:
         stepped._prefill_firsts, stepped._decode = real_prefill, real_decode
+        stepped._update, stepped._jax = real_update, real_jax
     it = rise(before, stepped.stats())
     assert all(r.future.done() for r in reqs) and it["admitted"] == 2
     assert it["work_calls.launch"] == 2
@@ -556,3 +577,150 @@ def test_an_idle_poll_raises_idle_ns_alone(stepped):
     assert not any(polled.values())
     (it,) = step_through(stepped, request([3, 5, 8], 2))
     assert 0 < it["starved_ns"] < 0.15e9
+
+
+# ----- the program that writes per-slot state, through every family -----
+a_request = request  # for the fixture, whose ``request`` is pytest's
+FAMILIES = ("tiny", "nemotron_h_tiny", "laguna_tiny", "phi4flash_tiny")
+FAMILY_SHAPES = dict(num_slots=4, decode_chunk=4, max_seq_len=128,
+                     prefill_buckets=[64], page_size=16)
+
+
+@pytest.fixture(scope="module", params=FAMILIES)
+def family(request):
+    """A preset's family in float32 on an engine whose loop thread has
+    stopped (a test calls ``_step``). Once the constructor, a bucket's
+    bring-up and a decode call by hand, key split and all (the decode program
+    is jitted at its first call), are done, the engine's FIRST request runs here, from admission
+    to retirement: ``first_request_compiles`` is what ``host_events.compiles``
+    rose by over it."""
+    import jax
+    import jax.numpy as jnp
+
+    preset = llm.model_presets()[request.param]()
+    config = type(preset).tiny(dtype=jnp.float32, attention_impl="reference")
+    eng = LLMEngine(config, **FAMILY_SHAPES)
+    eng.stop()
+    eng._bring_up(64)
+    eng._key, sub = jax.random.split(eng._key)
+    _, _, _, eng.cache, *_ = eng._decode(
+        eng.params, eng.cache, eng._tokens, eng._positions, eng._active,
+        eng._table, sub)
+    compiles = eng.stats()["compiles"]
+    (only,) = step_through(eng, a_request([5, 6, 7], 2))
+    assert (only["admitted"], only["retired"]) == (1, 1)
+    eng.first_request_compiles = eng.stats()["compiles"] - compiles
+    return eng
+
+
+def test_no_request_meets_a_compile_of_the_slot_program(family):
+    assert family.first_request_compiles == 0
+
+
+def test_staggered_requests_through_reused_slots_answer_as_alone(family):
+    """Seven requests of different lengths over four slots: a slot that
+    retires is admitted again in the next iteration, with the pages the
+    retired request gave back, while the other slots decode on. Each answer
+    is what a fresh engine gives that request alone."""
+    rng = np.random.default_rng(11)
+    sizes = [(30, 3), (7, 9), (60, 6), (3, 13), (55, 5), (41, 8), (12, 4)]
+    reqs = [request(rng.integers(1, 200, n).tolist(), m) for n, m in sizes]
+    pages, iters, admit = {}, [], family._admit
+
+    def admitting():
+        groups = admit()
+        pages.update((id(req), set(req_pages)) for chunk, _b, _s in groups
+                     for req, _slot, req_pages, _ in chunk)
+        return groups
+
+    family._admit = admitting
+    for r in reqs:
+        family._submit(r)
+    try:
+        while not all(r.future.done() for r in reqs):
+            before = family.stats()
+            family._step()
+            it = rise(before, family.stats())
+            iters.append((it["admitted"], it["retired"]))
+            assert it["work_calls.slot_update"] <= 3
+    finally:
+        family._admit = admit
+    assert iters[0][0] == 4 and sum(a for a, _ in iters) == 7
+    assert any(retired and admitted
+               for (_, retired), (admitted, _) in zip(iters, iters[1:]))
+    assert any(pages[id(late)] & pages[id(early)]
+               for early in reqs[:4] for late in reqs[4:])
+    fresh = LLMEngine(family.config, family.params, **FAMILY_SHAPES)
+    fresh._prefill, fresh._decode = family._prefill, family._decode
+    try:
+        for r, (_, m) in zip(reqs, sizes):
+            alone = fresh.generate(r.tokens, max_tokens=m, timeout=300)
+            assert r.future.result()["tokens"] == alone["tokens"]
+            assert len(alone["tokens"]) == m
+    finally:
+        fresh.stop()
+
+
+@contextmanager
+def recording(eng):
+    """Yields the list that ``eng``'s launches (by program) and slot updates
+    ("op") go into, in order, while the block runs."""
+    events = []
+    launch, update = eng._launch, eng._slot_update
+
+    def launched(program, *args):
+        events.append(program)
+        return launch(program, *args)
+
+    def updated(op, *args):
+        events.append("op")
+        return update(op, *args)
+
+    eng._launch, eng._slot_update = launched, updated
+    try:
+        yield events
+    finally:
+        eng._launch, eng._slot_update = launch, update
+
+
+def test_a_retirement_no_group_carries_goes_before_the_decode_launch(family):
+    """Two requests; the short one ends an iteration before the other and
+    nobody is admitted behind it: the next iteration makes its slot dead on
+    the device (table row to the trash page) before it launches the chunk."""
+    short, long = request([8, 1, 8], 6), request([2, 8, 1], 17)
+    family._submit(short)
+    family._submit(long)
+    family._step()
+    family._step()
+    assert short.future.done() and not long.future.done()
+    assert family._retiring[short.slot] and bool(family._active[short.slot])
+    with recording(family) as events:
+        family._step()
+    assert events == ["op", "op", llm.DECODE]
+    assert not family._retiring.any()
+    assert not bool(family._active[short.slot])
+    assert bool(family._active[long.slot])
+    assert not np.asarray(family._table[short.slot]).any()
+    assert np.asarray(family._table[long.slot]).any()
+    while not long.future.done():
+        family._step()
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 4])
+def test_two_operations_stand_between_a_prefill_and_the_decode_launch(
+        family, n):
+    """A group of ``n`` requests (1: the one-row program; 2-4: the four-row
+    one, with pad rows): behind the prefill launch the loop issues the
+    group's program and the key split, then launches the chunk."""
+    reqs = [request([3 + i, 1, 4], 9) for i in range(n)]
+    for r in reqs:
+        family._submit(r)
+    before = family.stats()
+    with recording(family) as events:
+        family._step()
+    assert calls_by_rows(before, family.stats()) == \
+        {1: int(n == 1), 4: int(n > 1)}
+    assert events == [llm.PREFILL, "op", "op", llm.DECODE]
+    assert sorted(r.slot for r in reqs) == list(range(n))
+    while not all(r.future.done() for r in reqs):
+        family._step()
