@@ -14,6 +14,13 @@ Design (see /opt/skills/guides/pallas_guide.md):
   ``flash_window_fwd`` in a profile.
 - GQA: q heads map onto kv heads through the BlockSpec index_map
   (h // q_per_kv), so kv tensors are never materialized per-q-head.
+- widths: q and k share one head width, any the compiler tiles (128 as the
+  other families', 192 = 128 + the 64 rotated of latent attention); v the
+  same or, forward only, one of its own (128 beside 192: the call is then
+  named ``flash_mla_fwd`` in a profile), and the output is as wide as v.
+  With ``lengths`` (forward only) a batch row's q blocks past its real rows
+  are skipped: one program for every prompt length costs what the prompt
+  needs.
 - backward: Pallas kernels with the standard flash-bwd recurrence — the
   forward also emits the logsumexp per row; bwd recomputes p = exp(qk−lse)
   blockwise, so S×S never materializes. Two kernels: dq (grid over q blocks)
@@ -80,17 +87,18 @@ def reference_attention(q, k, v, causal: bool = True, scale: Optional[float] = N
 # Pallas forward kernel
 # --------------------------------------------------------------------------- #
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, block_k, seq_kv, causal, scale, offset,
-                      window=None):
+                      window=None, qi=None):
     # refs carry leading (1, 1) batch/head block dims:
     # q_ref: [1, 1, block_q, D]; k_ref/v_ref: [1, 1, seq_kv, D]
     # offset = seq_kv - seq_q: query row i sits at absolute position offset+i
     # (the KV-cache decode case where cached keys precede the queries).
-    qi = pl.program_id(2)
+    if qi is None:  # else the caller read it (outside a branch: the interpreter's rule)
+        qi = pl.program_id(2)
     # operands stay in their storage dtype (bf16 on the hot path — the MXU
     # runs bf16 x bf16 at 2x the f32 rate); accumulation is f32 via
     # preferred_element_type, scale applied post-dot in f32.
     q = q_ref[0, 0]
-    d = q.shape[-1]
+    d = v_ref.shape[-1]  # the output is as wide as v: q and k may be wider
 
     q_start = qi * block_q + offset
     if causal:
@@ -140,6 +148,25 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_q, bloc
         lse_ref[0, 0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
+def _flash_fwd_ragged_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, *,
+                             block_q, **kw):
+    """The forward kernel told each batch row's length (scalar prefetch): a
+    q block that starts at or past it is rows of padding, costs nothing and
+    is zeros. Causal, so the live blocks never see a key past their own rows:
+    they are computed as ever."""
+    qi = pl.program_id(2)
+    live = qi * block_q < lengths_ref[pl.program_id(0)]
+
+    @pl.when(live)
+    def _():
+        _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, block_q=block_q, qi=qi,
+                          **kw)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
 # K and V of one (batch, KV head) sit whole in VMEM, double buffered: over
 # this many bytes the call asks Mosaic for more than its default 16 MB of
 # scoped VMEM (a v5e has 128 MiB). 4 MB at the 4096 rows of the training
@@ -149,14 +176,17 @@ KV_VMEM_DEFAULT_BYTES = 8 * 2 ** 20
 
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
                interpret: bool, with_lse: bool = True,
-               window: Optional[int] = None):
-    """q: [B, Sq, Hq, D] -> (out [B, Sq, Hq, D], lse [B, Hq, Sq, 1] fp32 or
+               window: Optional[int] = None, lengths=None):
+    """q: [B, Sq, Hq, D] -> (out [B, Sq, Hq, Dv], lse [B, Hq, Sq, 1] fp32 or
     None). lse carries a trailing singleton so its blocks satisfy the TPU
     (8, 128) tiling rule; inference-only callers pass with_lse=False to skip
     the extra HBM write entirely. Requires Sq % block_q == 0 and
-    Skv % block_k == 0 (caller pads)."""
+    Skv % block_k == 0 (caller pads). v may have a width of its own (Dv;
+    the call is then named ``flash_mla_fwd``). ``lengths`` (int32 [B], causal,
+    without lse): q blocks wholly past a row's length are not computed."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
+    dv = v.shape[-1]
     q_per_kv = hq // hkv
     # layout for the kernel: [B, H, S, D]
     qt = q.transpose(0, 2, 1, 3)
@@ -177,16 +207,35 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
     if window is not None:
         kernel = functools.partial(kernel, window=window)
         call["name"] = "flash_window_fwd"
-    kv_vmem = 4 * skv * d * k.dtype.itemsize
+    if dv != d:
+        call["name"] = "flash_mla_fwd"
+    kv_vmem = 2 * skv * (d + dv) * k.dtype.itemsize
     if kv_vmem > KV_VMEM_DEFAULT_BYTES:
         call["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=kv_vmem + 16 * 2 ** 20)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d), lambda bb, h, i: (bb, h, i, 0)),
         pl.BlockSpec((1, 1, skv, d), lambda bb, h, i, _g=q_per_kv: (bb, h // _g, 0, 0)),
-        pl.BlockSpec((1, 1, skv, d), lambda bb, h, i, _g=q_per_kv: (bb, h // _g, 0, 0)),
+        pl.BlockSpec((1, 1, skv, dv), lambda bb, h, i, _g=q_per_kv: (bb, h // _g, 0, 0)),
     ]
-    o_spec = pl.BlockSpec((1, 1, block_q, d), lambda bb, h, i: (bb, h, i, 0))
+    o_spec = pl.BlockSpec((1, 1, block_q, dv), lambda bb, h, i: (bb, h, i, 0))
+    o_shape = jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype)
+    if lengths is not None:
+        # the scalar rides in front of the grid's indices in every index map
+        out = pl.pallas_call(
+            functools.partial(_flash_fwd_ragged_kernel, **kernel.keywords),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid,
+                in_specs=[pl.BlockSpec(spec.block_shape,
+                                       lambda bb, h, i, _n, _m=spec.index_map:
+                                       _m(bb, h, i)) for spec in in_specs],
+                out_specs=pl.BlockSpec(o_spec.block_shape,
+                                       lambda bb, h, i, _n: (bb, h, i, 0))),
+            out_shape=o_shape,
+            interpret=interpret,
+            **call,
+        )(lengths.astype(jnp.int32), qt, kt, vt)
+        return out.transpose(0, 2, 1, 3), None
     if with_lse:
         out, lse = pl.pallas_call(
             kernel,
@@ -197,7 +246,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
                 pl.BlockSpec((1, 1, block_q, 1), lambda bb, h, i: (bb, h, i, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(qt.shape, q.dtype),
+                o_shape,
                 jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
             ],
             interpret=interpret,
@@ -209,7 +258,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
             grid=grid,
             in_specs=in_specs,
             out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            out_shape=o_shape,
             interpret=interpret,
             **call,
         )(qt, kt, vt)
@@ -414,12 +463,16 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
     window: Optional[int] = None,
+    lengths=None,
 ):
     """Flash attention with automatic padding to block multiples.
 
-    q: [B, Sq, Hq, D]; k/v: [B, Skv, Hkv, D] with Hq % Hkv == 0.
-    ``window``: causal sliding window (a query sees its ``window`` newest
-    keys, itself among them); forward only, no gradient is defined.
+    q: [B, Sq, Hq, D]; k: [B, Skv, Hkv, D] with Hq % Hkv == 0; v: [B, Skv,
+    Hkv, Dv], Dv = D or a width of its own (forward only: the unabsorbed
+    latent attention's 192 / 128). ``window``: causal sliding window (a query
+    sees its ``window`` newest keys, itself among them); forward only, no
+    gradient is defined. ``lengths``: int32 [B], rows that are real (causal,
+    forward only): q blocks past them are zeros and cost nothing.
     """
     if window is not None and not causal:
         raise ValueError("a sliding window is causal")
@@ -460,11 +513,11 @@ def flash_attention(
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    if window is None:
+    if window is None and v.shape[-1] == d and lengths is None:
         out = _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret)
     else:
         out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                            with_lse=False, window=window)
+                            with_lse=False, window=window, lengths=lengths)
     if pad:
         out = out[:, :sq]
     return out
@@ -475,9 +528,11 @@ def _round_up(x: int, m: int) -> int:
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl: str = "auto",
-              window: Optional[int] = None):
+              window: Optional[int] = None, lengths=None):
     """Dispatch. impl: "flash" | "flash_interpret" | "reference" | "auto".
-    ``window``: a causal sliding window (forward only).
+    ``window``: a causal sliding window (forward only). ``lengths``: the
+    rows of each batch row that are real; the kernel skips the blocks past
+    them, the reference computes them (nobody reads them).
 
     "flash" is the Pallas kernel and nothing else: where Mosaic cannot
     compile it the compiler's error surfaces. "auto" resolves once per trace
@@ -489,8 +544,9 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl:
     if impl == "reference":
         return reference_attention(q, k, v, causal, scale, window)
     if impl == "flash":
-        return flash_attention(q, k, v, causal, scale, window=window)
+        return flash_attention(q, k, v, causal, scale, window=window,
+                               lengths=lengths)
     if impl == "flash_interpret":
         return flash_attention(q, k, v, causal, scale, interpret=True,
-                               window=window)
+                               window=window, lengths=lengths)
     raise ValueError(f"unknown attention impl {impl!r}")

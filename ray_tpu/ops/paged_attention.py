@@ -27,14 +27,25 @@ Design (see /opt/skills/guides/pallas_guide.md):
   starts counted from that page). Without ``starts`` the kernel traces to
   the text it had before there was a window.
 
+- widths: K and V pools of ONE shape, ``[n_kv, pages, page_size, D]`` with D
+  a multiple of the 128 lanes (``paged_attention``); or ONE pool of latent
+  rows, ``[1, pages, page_size, W]``, whose first ``v_width`` columns are
+  also the values (``paged_attention_latent``: multi-head latent attention,
+  absorbed: one KV head, a query group of every head, W = 640 for 512 + 64
+  stored values, ``v_width`` 512). The same kernel body: there the block's
+  rows are fetched ONCE and the values are a lane-aligned slice of them, no
+  second pool, no second DMA. W and ``v_width`` are whole lane tiles.
+
 The jitted wrapper is named ``paged_attention`` and so is the call: a
 profile's operation reads ``paged_attention.N``, the name the benchmark's
-readers look for; the call with ``starts`` is ``paged_attention_window.N``.
+readers look for; the call with ``starts`` is ``paged_attention_window.N``,
+the call over a latent pool ``paged_attention_latent.N``.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,14 +59,23 @@ from ray_tpu.ops.attention import NEG_INF
 PAGES_PER_BLOCK = 8
 
 
-def _kernel(*refs, pages_per_block: int, windowed: bool):
+def _kernel(*refs, pages_per_block: int, windowed: bool,
+            v_width: Optional[int] = None):
     # lengths_ref (and, windowed, starts_ref): [B] and table_ref:
     # [B * pages_per_slot] in SMEM; q_ref / o_ref: [B, n_kv, G, D] in VMEM;
     # k_hbm / v_hbm: the pool, in HBM; kbuf / vbuf: [2, n_kv,
-    # pages_per_block, page_size, D]; sems: DMA semaphores [side, buffer]
+    # pages_per_block, page_size, D]; sems: DMA semaphores [side, buffer].
+    # With ``v_width`` (a latent pool) there is no v_hbm and no vbuf: the
+    # values are the first ``v_width`` columns of the fetched key rows, and
+    # o_ref is [B, n_kv, G, v_width]
     lengths_ref, *refs = refs
     starts_ref = refs.pop(0) if windowed else None
-    table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs
+    if v_width is None:
+        table_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems = refs
+        sides = ((k_hbm, kbuf), (v_hbm, vbuf))
+    else:
+        table_ref, q_ref, k_hbm, o_ref, kbuf, sems = refs
+        sides, vbuf = ((k_hbm, kbuf),), kbuf
     nb, nkv, g, d = q_ref.shape
     ps = k_hbm.shape[2]
     ppb = pages_per_block
@@ -88,8 +108,7 @@ def _kernel(*refs, pages_per_block: int, windowed: bool):
                 # a wait needs the copy's shape and semaphore, not its source
                 page = 0 if wait else table_ref[
                     b * pages_per_slot + i * ppb + j]
-                for side, (hbm, vmem) in enumerate(((k_hbm, kbuf),
-                                                    (v_hbm, vbuf))):
+                for side, (hbm, vmem) in enumerate(sides):
                     copy = pltpu.make_async_copy(
                         hbm.at[:, page], vmem.at[buf, :, j],
                         sems.at[side, buf])
@@ -122,7 +141,8 @@ def _kernel(*refs, pages_per_block: int, windowed: bool):
             block_dma(b, i, buf, wait=True)
             q = q_ref[b]                                   # [n_kv, G, D]
             k = kbuf[buf].reshape(nkv, bk, d)
-            v = vbuf[buf].reshape(nkv, bk, d)
+            v = k[..., :v_width] if v_width else \
+                vbuf[buf].reshape(nkv, bk, d)
             s = jnp.einsum("hgd,htd->hgt", q, k,
                            preferred_element_type=jnp.float32)
             cols = i * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -141,7 +161,7 @@ def _kernel(*refs, pages_per_block: int, windowed: bool):
 
         m0 = jnp.full((nkv, g, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((nkv, g, 1), jnp.float32)
-        acc0 = jnp.zeros((nkv, g, d), jnp.float32)
+        acc0 = jnp.zeros((nkv, g, v_width or d), jnp.float32)
         _, l, acc, buf = jax.lax.fori_loop(first_block(b), blocks, block,
                                            (m0, l0, acc0, buf))
         o_ref[b] = (acc / l).astype(o_ref.dtype)  # length >= 1: l > 0
@@ -203,3 +223,46 @@ def paged_attention(q, k_pool, v_pool, lengths, table, *, starts=None,
     )(*scalars, table.astype(jnp.int32).reshape(-1),
       q.reshape(nb, nkv, nh // nkv, d), k_pool, v_pool)
     return out.reshape(nb, nh, d)
+
+
+@functools.partial(jax.jit, static_argnames=("v_width", "pages_per_block",
+                                             "interpret"))
+def paged_attention_latent(q, pool, lengths, table, *, v_width: int,
+                           pages_per_block: int = PAGES_PER_BLOCK,
+                           interpret: bool = False):
+    """Decode attention over a LATENT pool (multi-head latent attention,
+    absorbed): ONE cached row a token, shared by every query head, whose
+    first ``v_width`` columns are also the values. q: [B, G, W], already
+    scaled (per head: the query carried into the latent space beside its
+    rotated part); pool: [1, pages, page_size, W]; lengths, table: as
+    ``paged_attention``. Returns [B, G, v_width] in q's dtype: softmax(q
+    k^T) k[:, :v_width] over the slot's first ``lengths[b]`` rows, zeros
+    where ``lengths[b]`` is 0. Each fetched row is read from HBM ONCE, for
+    its scores and its values: there is no second pool. The same kernel
+    body as ``paged_attention`` at one KV head and a group of G; the call
+    is named ``paged_attention_latent`` in a profile."""
+    nb, g, w = q.shape
+    one, _, ps, _ = pool.shape
+    if one != 1 or pool.shape[3] != w or not 0 < v_width <= w:
+        raise ValueError(f"q {q.shape} against a latent pool {pool.shape} "
+                         f"with values of {v_width}")
+    ppb = min(pages_per_block, table.shape[1])
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_kernel, pages_per_block=ppb, windowed=False,
+                          v_width=v_width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[pltpu.VMEM((2, 1, ppb, ps, w), pool.dtype),
+                            pltpu.SemaphoreType.DMA((1, 2))]),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, g, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention_latent",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), table.astype(jnp.int32).reshape(-1),
+      q.reshape(nb, 1, g, w), pool)
+    return out.reshape(nb, g, v_width)

@@ -10,8 +10,10 @@ GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
   a fixed ring of pages a slot a layer that the allocator never sees), or
   both beside pages that ONE layer writes and several read
   (models/phi4flash.py: a third arrangement, in which the layers that keep
-  pages are fewer than the layers that read them) — one slot per in-flight
-  request;
+  pages are fewer than the layers that read them); or a pool of LATENT rows
+  and nothing beside it (models/kimi_k2.py: a fourth arrangement, one
+  compressed row a token a layer that is key and value at once, so there is
+  no V pool) — one slot per in-flight request;
 - CONTINUOUS batching: new requests are prefilled into free slots while
   other slots keep decoding — no batch barrier (Orca-style iteration-level
   scheduling);
@@ -76,7 +78,9 @@ MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
 DEVICE_COUNTERS = MOE_COUNTERS + ("moe_blocks", "moe_blocks_extra",
                                   "attn_rows_full", "attn_rows_window",
                                   "attn_rows_shared", "scan_slots",
-                                  "prefill_rows_self", "prefill_rows_cross")
+                                  "prefill_rows_self", "prefill_rows_cross",
+                                  "attn_rows_latent", "prefill_rows",
+                                  "prefill_attn_pairs")
 # the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
@@ -128,6 +132,12 @@ def _phi4flash_tiny():
     return Phi4FlashConfig.tiny()
 
 
+def _kimi_k2_tiny():
+    from ray_tpu.models.kimi_k2 import KimiK2Config
+
+    return KimiK2Config.tiny()
+
+
 def model_presets() -> Dict[str, Any]:
     """``LLMDeployment``'s preset names."""
     from ray_tpu.models.llama import LlamaConfig
@@ -135,7 +145,7 @@ def model_presets() -> Dict[str, Any]:
     return {"tiny": LlamaConfig.tiny, "llama_1b": LlamaConfig.llama_1b,
             "llama3_8b": LlamaConfig.llama3_8b,
             "nemotron_h_tiny": _nemotron_h_tiny, "laguna_tiny": _laguna_tiny,
-            "phi4flash_tiny": _phi4flash_tiny}
+            "phi4flash_tiny": _phi4flash_tiny, "kimi_k2_tiny": _kimi_k2_tiny}
 
 
 def _slots_updated(table, tokens, positions, active, retired, firsts, placed):
@@ -190,10 +200,12 @@ class LLMEngine:
     models/paged_decode.py (Llama family, paged KV cache),
     models/nemotron_h.py (hybrid family: pages and per-slot recurrent
     state), models/laguna.py (window and full attention layers: pages for
-    the full layers, per-slot rings for the window layers) or
+    the full layers, per-slot rings for the window layers),
     models/phi4flash.py (pages that one layer writes and eight read, rings
-    for the window layers, per-slot scan state). One loop, one admission,
-    one allocator, one set of counters for all four.
+    for the window layers, per-slot scan state) or models/kimi_k2.py (pages
+    of latent rows, one pool and no V pool; prefill attends unabsorbed,
+    decode absorbed). One loop, one admission, one allocator, one set of
+    counters for all five.
 
     HBM is committed per REQUEST (ceil((prompt+max_tokens)/page_size) pages
     from a shared pool), not per-slot*max_seq — so ``num_slots`` is bounded
@@ -307,6 +319,9 @@ class LLMEngine:
       ``slow_iters_unlogged`` counts the rest).
     - ``kv_bytes_per_token``: bytes of K and V a cached token takes over
       all the layers that KEEP (write) pages: what a token costs the pool.
+      A family whose cache has no V pool (models/kimi_k2.py: one latent row
+      is key and value) counts its one pool once: the stored row's width,
+      lane padding included, times its layers.
       Layers that only READ another layer's pages (models/phi4flash.py: one
       layer keeps, eight read) are not in it; what a decode tick reads of
       the pool is ``attn_rows_shared`` rows of that width.
@@ -339,6 +354,13 @@ class LLMEngine:
       that ran the layers every row runs, and rows that ran the layers only
       a prompt's last row needs (models/phi4flash.py: one a prompt); counted
       in the prefill program. 0 for the other families.
+    - ``attn_rows_latent``: latent rows decode attention attended over,
+      summed over live slots, ticks and layers (models/kimi_k2.py; each is
+      read once, for its scores and its values). ``prefill_rows``,
+      ``prefill_attn_pairs``: the prompt tokens that family's prefill
+      programs ran, and the causal (query, key) pairs ONE layer attended over
+      for them, the sum of n (n + 1) / 2 (every layer attends the same
+      pairs; a pad row counts one of each). 0 for the other families.
     - ``moe_blocks``, ``moe_blocks_extra``: calls of the compacted expert
       product (``ops/moe.py``: a layer of a decode tick, a layer and chunk of
       a prefill call) and the blocks they ran beyond their first, which is 0
@@ -350,7 +372,8 @@ class LLMEngine:
     that builds its weights, its cache and its two programs
     (``models/paged_decode.py`` for ``LlamaConfig``,
     ``models/nemotron_h.py`` for ``NemotronHConfig``, ``models/laguna.py``
-    for ``LagunaConfig``, ``models/phi4flash.py`` for ``Phi4FlashConfig``).
+    for ``LagunaConfig``, ``models/phi4flash.py`` for ``Phi4FlashConfig``,
+    ``models/kimi_k2.py`` for ``KimiK2Config``).
     The cache is one donated pytree. Where the module
     says ``SLOT_STATE``, the cache also holds state addressed by slot
     (recurrent state, window rings): prefill is told each row's slot (a pad
@@ -633,13 +656,15 @@ class LLMEngine:
 
     def _describe_cache(self) -> Dict[str, int]:
         """What the cache's shapes say, read once: the loop thread donates
-        the cache itself every step. ``k`` and ``v`` are the page pools; every
-        other field is state addressed by slot, and those the family's module
-        lists as ``RING_FIELDS`` are pools of window rings."""
-        pool = self.cache.k
-        kv = 2 * pool.shape[0] * (pool.shape[1] // self.total_pages) \
-            * pool.shape[3] * pool.dtype.itemsize
+        the cache itself every step. ``k`` and ``v`` are the page pools (a
+        latent cache has ``k`` alone); every other field is state addressed
+        by slot, and those the family's module lists as ``RING_FIELDS`` are
+        pools of window rings."""
         fields = self.cache._asdict()
+        kv = sum(pool.shape[0] * (pool.shape[1] // self.total_pages)
+                 * pool.shape[3] * pool.dtype.itemsize
+                 for pool in (fields.get("k"), fields.get("v"))
+                 if pool is not None)
         state = sum(x.nbytes for name, x in fields.items()
                     if name not in ("k", "v"))
         rings = [fields[name] for name in self._ring_fields]
@@ -1170,7 +1195,7 @@ class LLMDeployment:
                  total_pages: Optional[int] = None):
         """``model``: a preset's name (``model_presets()``) or a configuration
         object of any family (``LlamaConfig``, ``NemotronHConfig``,
-        ``LagunaConfig``)."""
+        ``LagunaConfig``, ``Phi4FlashConfig``, ``KimiK2Config``)."""
         config = model
         if isinstance(model, str):
             factories = model_presets()

@@ -8,6 +8,7 @@ import pytest
 from benchmarks.harness.loadgen import RequestRecord
 from benchmarks.readers import (
     engine_counters, engine_longest_iter, engine_queue_wait, hops_percentile)
+from benchmarks.tests.test_kimi_k2_family import *  # noqa: F401,F403
 from benchmarks.tests.test_laguna_family import *  # noqa: F401,F403
 from benchmarks.tests.test_manifest import *  # noqa: F401,F403
 from benchmarks.tests.test_nemotron_h_family import *  # noqa: F401,F403
@@ -108,7 +109,8 @@ HOST_TURN = {
     "work_calls": {"launch": 2, "launch_waited": 1, "slot_update": 7},
 }
 SATURATED = ["serve_longprompt", "serve_hybrid_longreply",
-             "serve_window_longctx", "serve_yoco_longctx", "serve_chat_sat"]
+             "serve_window_longctx", "serve_yoco_longctx", "serve_chat_sat",
+             "serve_mla_longdoc"]
 
 
 @pytest.mark.parametrize("name,unit,expected", [
@@ -207,3 +209,19 @@ def test_hops_percentile_reads_the_done_record():
     # a parent commit's done record has no hops: nothing to read, no error
     assert hops_percentile.read({"records": [rec(0, 0, 0, hops=False)]}, way_in) is None
     assert hops_percentile.read({}, way_in) is None
+
+
+def test_every_line_of_text_in_the_manifest_is_one_the_driver_takes():
+    # test_manifest holds a cell's ``why`` to 200 characters; the driver holds
+    # a configuration's ``why`` and ``source`` and a metric's ``layer`` to the
+    # same rule (PR 44's first check was refused over a ``why`` of 203)
+    import json
+    import pathlib
+    manifest = json.loads(
+        (pathlib.Path(__file__).parent.parent / "BENCHMARK.json").read_text())
+    lines = [(c["name"], c[key]) for c in manifest["configs"] for key in ("source", "why")]
+    lines += [(w["name"], w["why"]) for w in manifest["workloads"]]
+    lines += [(m["name"], m["layer"]) for m in manifest["per_layer"]]
+    lines += [("command", word) for word in manifest["command"]]
+    for name, text in lines:
+        assert 1 <= len(text) <= 200 and text.isprintable(), (name, len(text))

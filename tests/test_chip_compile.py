@@ -625,3 +625,83 @@ def test_phi4flash_prefill_of_the_longest_bucket_has_no_attention_over_the_promp
     calls = {c.split(".")[0] for c in _mosaic_calls(compiled.as_text())}
     assert calls == {"selective_scan_fwd", "flash_window_fwd"}, calls
     assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+
+
+# --------------------------------------------------------------------------- #
+# PR 44: the fifth family, a latent page pool and two attention paths over it
+# --------------------------------------------------------------------------- #
+def _kimi_k2(v5e, bucket=None):
+    """The fifth family at Kimi-K2.6's published widths, the leading dense
+    layer and TWO of its expert layers (the scan's body is compiled once
+    whatever their number), 12 of the router's 384 experts and a slice of the
+    vocabulary, 16 slots of 25,088 as the benchmark's cell: its decode
+    program, or its one-row prefill program of ``bucket``, compiled; and its
+    cache."""
+    from ray_tpu.models import kimi_k2 as km
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    slots = 16
+    config = km.KimiK2Config(
+        vocab_size=8192, num_hidden_layers=3, n_routed_experts=12,
+        held_experts=(0, 12), max_seq_len=25088, attention_impl="flash")
+    pages = 25088 // PAGE
+    params = _on(one, jax.eval_shape(lambda k: km.init_params(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: km.init_cache(config, slots, slots * pages + 1, PAGE)))
+    if bucket:
+        lowered = km.make_paged_prefill_fn(config, PAGE).lower(
+            params, cache, shape((1, bucket), jnp.int32),
+            shape((1, bucket // PAGE), jnp.int32), shape((1,), jnp.int32))
+    else:
+        ints = shape((slots,), jnp.int32)
+        lowered = km.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(
+            params, cache, ints, ints, shape((slots,), jnp.bool_),
+            shape((slots, pages), jnp.int32),
+            _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered.compile(), cache
+
+
+def test_kimi_k2_decode_reads_one_latent_pool_and_expands_nothing(v5e):
+    """Mosaic takes the paged-attention kernel's body over a LATENT pool: one
+    KV head, a query group of 64, rows of 640 of which the first 512 are the
+    values, fetched once; a profile tells it apart by name (the benchmark's
+    ``latent_attn_decode_roofline`` reads it so). The pool rides the layer
+    scan as the one donated cache, aliased and never copied, and the program
+    holds no K or V expanded to the heads: nothing as tall as a slot's pages
+    times 64 heads. It fits beside the weights and the pool."""
+    compiled, cache = _kimi_k2(v5e)
+    text = compiled.as_text()
+    calls = sorted(c.split(".")[0] for c in _mosaic_calls(text))
+    # the dense layer's call and the scanned expert layers' one
+    assert calls == ["paged_attention_latent"] * 2, calls
+    assert cache.k.shape == (1, 3 * (16 * 392 + 1), 64, 640)
+    assert compiled.memory_analysis().alias_size_in_bytes == cache.k.size * 2
+    moved = [line for line in _pool_sized_moves(text, cache.k.size // 3)
+             if re.search(r"= bf16\[1,\d+,64,640\]", line)]  # the pool's shape
+    assert moved == []
+    assert not re.search(r"bf16\[16,\d+,64,(128|192|256)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_kimi_k2_prefill_of_the_longest_bucket_is_unabsorbed_and_fits(v5e):
+    """One row of 24,576 tokens, the ONE prefill program the cell compiles:
+    the flash forward at q / k of 192 and v of 128 (``flash_mla_fwd``), told
+    the row's length (a scalar in front of the grid), 16 heads at a time; K
+    and V of a head are 19 MB in VMEM, double buffered, more than Mosaic's
+    default scoped VMEM. The rows are walked in pieces of 2,048, a branch a
+    piece, so nothing float32 is as tall as the prompt times the dense MLP's
+    18,432; and the program's temporaries fit beside 8.4 GB of weights and
+    a pool of 3.1 GB."""
+    from ray_tpu.models import kimi_k2 as km
+
+    compiled, _ = _kimi_k2(v5e, bucket=24576)
+    text = compiled.as_text()
+    calls = [c for c in _mosaic_calls(text) if "ragged" not in c]
+    assert calls and all(c.startswith("flash_mla_fwd") for c in calls), calls
+    assert re.search(r"flash_mla_fwd\S* = bf16\[1,16,24576,128\]", text)
+    assert km.PREFILL_ROWS == 2048
+    assert re.search(r"f32\[2048,18432\]", text)
+    assert not re.search(r"f32\[24576,18432\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9

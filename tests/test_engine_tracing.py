@@ -143,7 +143,8 @@ def test_prefill_padding_is_counted_exactly(engine):
     assert rise == {"prefill_calls": 1, "prefill_rows_real": 3,
                     "prefill_rows_padded": 4, "prefill_tokens_real": 300,
                     "prefill_tokens_padded": 512,
-                    "prefill_rows_self": 0, "prefill_rows_cross": 0}
+                    "prefill_rows_self": 0, "prefill_rows_cross": 0,
+                    "prefill_rows": 0, "prefill_attn_pairs": 0}
     assert calls_by_rows(before, after) == {1: 0, 4: 1}
     assert after["admitted"] - before["admitted"] == 3
 
