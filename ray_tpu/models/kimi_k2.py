@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.paged_decode import (
-    _live_lengths, _scatter_prompt_rows_full, _scatter_token_rows,
+    _live_lengths, _scatter_prompt_rows_full, _scatter_token_rows, _walk,
     counted_decode_steps)
 from ray_tpu.ops.moe import routed_experts, swiglu_mlp
 from ray_tpu.ops.norms import rms_norm
@@ -271,35 +271,6 @@ def _rope_tables(config: KimiK2Config, positions: int):
         yarn = {**rs, "attention_factor": rs["mscale"] / rs["mscale_all_dim"]}
     return rope_frequencies(config.qk_rope_head_dim, positions,
                             float(config.rope_theta), yarn=yarn)
-
-
-def _walk(fn, arrays, lengths, most: int):
-    """``fn``: pieces [piece, ...] of ``arrays`` -> (a pytree of [piece, ...]
-    arrays, counts int32 [k]), over the rows of ``arrays`` ([PB, S, ...]
-    each) in the fewest equal pieces of at most ``most`` rows of ONE prompt
-    (whole sublanes; a prompt whole where no such split exists), one piece
-    after another inside the program. A piece that starts at or past its
-    prompt's ``lengths`` entry is padding: it is NOT computed, its outputs
-    are zeros and it counts nothing. Returns (the outputs as [PB, S, ...],
-    the counts summed)."""
-    pb, s = arrays[0].shape[:2]
-    n = next((n for n in range(-(-s // most), s // 8 + 1)
-              if s % n == 0 and (s // n) % 8 == 0), 1)
-    piece = s // n
-    cut = tuple(a.reshape(pb * n, piece, *a.shape[2:]) for a in arrays)
-    live = (jnp.arange(n) * piece)[None, :] < lengths[:, None]      # [PB, n]
-    blank = jax.tree.map(
-        lambda x: jnp.zeros(x.shape, x.dtype),
-        jax.eval_shape(fn, *(jax.ShapeDtypeStruct(c.shape[1:], c.dtype)
-                             for c in cut)))
-
-    def one(args):
-        alive, *parts = args
-        return jax.lax.cond(alive, lambda: fn(*parts), lambda: blank)
-
-    out, counts = jax.lax.map(one, (live.reshape(-1), *cut))
-    return (jax.tree.map(lambda a: a.reshape(pb, s, *a.shape[2:]), out),
-            jnp.sum(counts, axis=0))
 
 
 def _latents(config: KimiK2Config, lp, y, rope, positions=None):

@@ -10,7 +10,11 @@ HBM is committed per-request (ceil((prompt+max_tokens)/page_size) pages),
 not per-slot*max_seq — so slot count is bounded by real demand, and short
 requests do not pay for max_seq rows.
 
-Prefill is one compiled program per prompt bucket and row count; decode is
+Prefill is one compiled program per prompt bucket and row count, and what a
+call of it costs follows the prompts' LENGTHS, not the bucket: the work of a
+layer that is independent row by row (everything but attention) walks each
+prompt in pieces of ``PREFILL_PIECE`` rows and skips the pieces past the
+prompt's length, and the head runs over the one row a prompt reads. Decode is
 ONE compiled program for the whole batch: ``paged_decode_steps`` lax.scans T
 greedy/temperature ticks on the device, feeding each sampled token into the
 next, so one host round trip buys T tokens a slot.
@@ -67,6 +71,21 @@ def init_paged_cache(config: LlamaConfig, total_pages: int, page_size: int,
 # two ``make_*`` functions and ``paged_kernel_fits``: this family keeps pages
 # only, nothing a slot
 SLOT_STATE = False
+# what the prefill program counts, its third result: the rows its row-wise
+# work ran (live pieces x piece rows, summed over the call's rows, ONE layer's:
+# every layer runs the same; a pad row has length 1 and costs one piece)
+PREFILL_COUNTERS = ("prefill_rows_computed",)
+# rows of ONE prompt the row-wise work of a prefill layer takes at a time
+# (``_walk``: norm, q/k/v and rotary in front of attention; W_o, norm and the
+# MLP behind it): a piece that starts at or past its prompt's length is not
+# computed, so a prompt of 600 tokens in the 2,048 bucket runs 1,024 rows. A
+# bucket of one piece has nothing to skip and runs whole, all rows in one
+# product. 512 is the smallest piece that keeps the MXU's rate (a v5e, W_o +
+# MLP of a Mistral-7B layer over 8,192 rows, PERF.md 6, PR 45: whole 165
+# TFLOP/s, pieces of 1,024 187, of 512 183, of 256 130, of 128 78), and the
+# 4 x 2048 program with every prompt full costs 185.9 ms at 512 where it
+# costs 183.5 unwalked and 201.6 at 256
+PREFILL_PIECE = 512
 
 
 def init_cache(config: LlamaConfig, num_slots: int, total_pages: int,
@@ -240,6 +259,35 @@ def ring_tick(lengths, active, page_idx, window: int, ring: int,
 # --------------------------------------------------------------------------- #
 # Prefill
 # --------------------------------------------------------------------------- #
+def _walk(fn, arrays, lengths, most: int):
+    """``fn``: pieces [piece, ...] of ``arrays`` -> (a pytree of [piece, ...]
+    arrays, counts int32 [k]), over the rows of ``arrays`` ([PB, S, ...]
+    each) in the fewest equal pieces of at most ``most`` rows of ONE prompt
+    (whole sublanes; a prompt whole where no such split exists), one piece
+    after another inside the program. A piece that starts at or past its
+    prompt's ``lengths`` entry is padding: it is NOT computed, its outputs
+    are zeros and it counts nothing. Returns (the outputs as [PB, S, ...],
+    the counts summed)."""
+    pb, s = arrays[0].shape[:2]
+    n = next((n for n in range(-(-s // most), s // 8 + 1)
+              if s % n == 0 and (s // n) % 8 == 0), 1)
+    piece = s // n
+    cut = tuple(a.reshape(pb * n, piece, *a.shape[2:]) for a in arrays)
+    live = (jnp.arange(n) * piece)[None, :] < lengths[:, None]      # [PB, n]
+    blank = jax.tree.map(
+        lambda x: jnp.zeros(x.shape, x.dtype),
+        jax.eval_shape(fn, *(jax.ShapeDtypeStruct(c.shape[1:], c.dtype)
+                             for c in cut)))
+
+    def one(args):
+        alive, *parts = args
+        return jax.lax.cond(alive, lambda: fn(*parts), lambda: blank)
+
+    out, counts = jax.lax.map(one, (live.reshape(-1), *cut))
+    return (jax.tree.map(lambda a: a.reshape(pb, s, *a.shape[2:]), out),
+            jnp.sum(counts, axis=0))
+
+
 def _scatter_prompt_rows_full(pool, rows, pages):
     """pool: [n_kv, L*P, ps, D]; rows: [PB, S, n_kv, D] (S = NP*ps); pages:
     [PB, NP], already offset to the layer's block. Scatters every prompt's
@@ -251,43 +299,92 @@ def _scatter_prompt_rows_full(pool, rows, pages):
     return pool.at[:, pages.reshape(-1)].set(vals.astype(pool.dtype))
 
 
+def _prompt_rows(fn, arrays, lengths):
+    """A stretch of a prefill layer that is independent row by row. ``fn``:
+    [B, T, ...] arrays -> a pytree of [B, T, ...] arrays; ``arrays``:
+    [PB, S, ...] each. A bucket of one piece runs whole; a longer one walks
+    each prompt in pieces of ``PREFILL_PIECE`` rows and skips those past its
+    length, whose outputs are zeros. Returns (the outputs as [PB, S, ...],
+    the rows computed, int32)."""
+    pb, s = arrays[0].shape[:2]
+    if s <= PREFILL_PIECE:
+        return fn(*arrays), jnp.int32(pb * s)
+
+    def piece(*parts):
+        out = fn(*(part[None] for part in parts))
+        return (jax.tree.map(lambda a: a[0], out),
+                jnp.full((1,), parts[0].shape[0], jnp.int32))
+
+    out, rows = _walk(piece, arrays, lengths, PREFILL_PIECE)
+    return out, rows[0]
+
+
 def paged_prefill(params, cache: PagedKVCache, tokens, pages, lengths,
-                  config: LlamaConfig, page_size: int) -> Tuple[jax.Array, PagedKVCache]:
+                  config: LlamaConfig, page_size: int):
     """BATCHED prefill: tokens [PB, S_bucket] (padded, S_bucket %
     page_size == 0); pages [PB, S_bucket // page_size] page ids per prompt;
-    lengths [PB] true prompt lengths; layer ``l`` writes them at
-    ``l*P + pages``. Returns (last-token logits [PB, V], cache). Batching
+    lengths [PB] true prompt lengths (a pad row: 1 and the trash page); layer
+    ``l`` writes them at ``l*P + pages``. Returns (last-token logits [PB, V],
+    cache, int32 [1]: the ``PREFILL_COUNTERS`` of this call). Batching
     prompts of the same bucket into one program is what keeps admission off
-    the serving critical path — 64 slots admit in ~8 programs instead of 64
-    (the reference's analogue is vLLM's batched prefill scheduling)."""
+    the serving critical path: 64 slots admit in ~8 programs instead of 64
+    (the reference's analogue is vLLM's batched prefill scheduling).
+
+    What a call costs follows ``lengths``: the row-wise work of a layer
+    (``_prompt_rows``) skips a prompt's pieces of ``PREFILL_PIECE`` rows past
+    its length, and the head runs over each prompt's last row alone. What
+    the bucket still costs: attention, ONE causal call over all S_bucket rows
+    (8% of a 2,048-row call); the rest of a prompt's last piece; one piece a
+    pad row. K and V of a skipped piece are written as zeros, to pages no
+    decode step reads: ``lengths`` bound every read."""
     from ray_tpu.ops.attention import attention
 
-    _, s = tokens.shape
+    pb, s = tokens.shape
     cos, sin = rope_frequencies(config.head_dim_, s, config.rope_theta)
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (pb, s))
     x = params["embed_tokens"][tokens].astype(config.dtype)
     per_layer = _pages_per_layer(cache.k, config)
 
-    def body(carry, lp):
-        x, ck_full, cv_full, layer = carry
+    def body(carry, layer):
+        x, ck_full, cv_full = carry
         layer_pages = pages + layer * per_layer
-        _, q, k, v = _project_qkv(config, lp, x)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+
+        def of_layer(*names):
+            return {name: params["layers"][name][layer] for name in names}
+
+        # where a layer's weights are sliced out of the stack is what a walk
+        # costs beside its products (a v5e's profile, PERF.md 6, PR 45): the
+        # MLP's 0.35 GB and W_o fuse into their products as slices INSIDE a
+        # piece, and handed to the walk from here they are copied a layer
+        # (1 ms); q/k/v's 50 MB are a copy wherever they are sliced (the
+        # product reads them a head at a time), so here, once a layer, and
+        # not a piece (70 us x 16 pieces: the whole of what a full 4 x 2048
+        # call lost against the unwalked program)
+        attn = of_layer("attn_norm", "wq", "wk", "wv")
+
+        def front(x, pos):
+            _, q, k, v = _project_qkv(config, attn, x)
+            return (apply_rope(q, cos, sin, positions=pos),
+                    apply_rope(k, cos, sin, positions=pos), v)
+
+        def back(x, o):
+            lp = of_layer("wo", "mlp_norm", "w_gate", "w_up", "w_down")
+            x = x + o @ lp["wo"]
+            return x + _mlp(config, lp, x)
+
+        (q, k, v), rows = _prompt_rows(front, (x, positions), lengths)
         o = attention(q, k, v, causal=True, impl=config.attention_impl)
-        b, t, nh, hd = q.shape
-        x = x + o.reshape(b, t, nh * hd) @ lp["wo"]
-        x = x + _mlp(config, lp, x)
+        x, _ = _prompt_rows(back, (x, o.reshape(pb, s, -1)), lengths)
         ck_full = _scatter_prompt_rows_full(ck_full, k, layer_pages)
         cv_full = _scatter_prompt_rows_full(cv_full, v, layer_pages)
-        return (x, ck_full, cv_full, layer + 1), None
+        return (x, ck_full, cv_full), rows
 
-    (x, new_k, new_v, _), _ = jax.lax.scan(
-        body, (x, cache.k, cache.v, jnp.int32(0)), params["layers"]
-    )
-    logits = _lm_head(params, x, config)  # [PB, S, V]
-    last = jnp.take_along_axis(
-        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]  # [PB, V]
-    return last, PagedKVCache(k=new_k, v=new_v)
+    (x, new_k, new_v), rows = jax.lax.scan(
+        body, (x, cache.k, cache.v),
+        jnp.arange(config.num_layers, dtype=jnp.int32))
+    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
+    logits = _lm_head(params, last, config)[:, 0]  # [PB, V]
+    return logits, PagedKVCache(k=new_k, v=new_v), rows[:1]
 
 
 # --------------------------------------------------------------------------- #

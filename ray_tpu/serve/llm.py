@@ -19,7 +19,11 @@ GPU engines (ray.serve.llm -> vLLM); here the engine IS the framework:
   scheduling);
 - prefill is bucketed (prompt padded to the next bucket) and batched at a
   few row counts (as many rows as the group admitted needs), so each bucket
-  compiles a fixed handful of programs, all when the bucket is first met;
+  compiles a fixed handful of programs, all when the bucket is first met.
+  The padding decides a request's bucket, its group and the iteration's
+  budget; what it costs on the device is the family's program's business
+  (models/paged_decode.py skips the row pieces past a prompt's length and
+  counts what it ran: ``prefill_rows_computed``);
   decode is one compiled multi-step program (T tokens per
   host round trip, so per-program dispatch and the host sync amortize);
 - per-request metrics (TTFT, latency) in every reply, and ``stats()``: the
@@ -80,7 +84,8 @@ DEVICE_COUNTERS = MOE_COUNTERS + ("moe_blocks", "moe_blocks_extra",
                                   "attn_rows_shared", "scan_slots",
                                   "prefill_rows_self", "prefill_rows_cross",
                                   "attn_rows_latent", "prefill_rows",
-                                  "prefill_attn_pairs")
+                                  "prefill_attn_pairs",
+                                  "prefill_rows_computed")
 # the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
@@ -291,7 +296,14 @@ class LLMEngine:
       ``prefill_tokens_real``, ``prefill_tokens_padded``: prefill programs
       dispatched, their rows holding a request and rows in all, prompt
       tokens and rows x bucket: what padding a group to the next compiled
-      row count (``PREFILL_ROWS``) and a prompt to its bucket costs.
+      row count (``PREFILL_ROWS``) and a prompt to its bucket ADMITS (the
+      budget of an iteration, the pages of a call). What of it the device
+      computes is ``prefill_rows_computed``: the rows the Llama-shaped
+      family's prefill programs ran their row-wise work over (live pieces x
+      piece rows, models/paged_decode.py ``PREFILL_PIECE``; a pad row costs
+      one piece; the bring-up calls' pad rows count too), counted in the
+      program and fetched with the next chunk's ``device_get``; 0 for the
+      other families.
       ``prefill_calls_by_rows``: the same calls by the row count each ran
       at, one key a compiled row count. The all-pad calls that bring a
       bucket's programs up prefill no request and enter none of these.

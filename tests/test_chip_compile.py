@@ -164,8 +164,9 @@ def test_one_row_prefill_of_the_largest_bucket_compiles(v5e):
     ``serve.llm.PREFILL_ROWS`` (PR 30). This is the smaller, 1 x 2048, at
     Mistral-7B-v0.3's published widths (two of its layers: the rest repeat
     them). Its temporaries, noted from this compile (no device number):
-    135,153,152 bytes, where the 8-row program of the same bucket, which the
-    engine ran for every group before, takes 2.42 GB (PERF.md 4)."""
+    93,622,784 bytes since PR 45 (135,153,152 until then: the head ran over
+    every position), where the 8-row program of the same bucket, which the
+    engine ran for every group before PR 30, took 2.42 GB (PERF.md 4)."""
     config = _mistral_7b(2)
     _, args = _prefill_shapes(v5e, 2048, rows=1, config=config)
     compiled = pd.make_paged_prefill_fn(config, PAGE).lower(*args).compile()
@@ -173,6 +174,27 @@ def test_one_row_prefill_of_the_largest_bucket_compiles(v5e):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2.42e9 / 8
     assert mem.alias_size_in_bytes == _pool_bytes(args[1])
+
+
+def test_four_row_prefill_keeps_the_flash_call_and_no_logits_of_the_bucket(v5e):
+    """The 4 x 2048 program (PR 45): the head runs over each prompt's last
+    row, so no ``[4, 2048, vocabulary]`` logits are in it, lowered or
+    compiled; attention is still ONE flash call over the bucket whose output
+    and first operand are ``bf16[4, ...]``, the line the benchmark's
+    ``prefill_device_per_call`` takes a call's rows from (``rows_from`` in
+    its metric file: a profile prints an operand's shape in front of its
+    name; a ragged call's scalar prefetch would put ``s32[4]`` first)."""
+    config = _mistral_7b(2)
+    _, args = _prefill_shapes(v5e, 2048, rows=4, config=config)
+    lowered = pd.make_paged_prefill_fn(config, PAGE).lower(*args)
+    compiled = lowered.compile().as_text()
+    assert "4x2048x32768xf32" not in lowered.as_text()
+    assert "f32[4,2048," not in compiled
+    flash = [line for line in compiled.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(flash) == 1
+    assert re.search(r"= bf16\[4,32,2048,128\]\S* custom-call\(", flash[0])
+    assert "operand_layout_constraints={bf16[4,32,2048,128]" in flash[0]
 
 
 def _pool_bytes(cache):
@@ -389,8 +411,12 @@ def _without_locations(text):
 ACCEPTED_PROGRAMS_SHA = {
     "llama_decode": 
         "4faf3387d777c75cad44fe924f5ee1365a0fbabc2bb83479003e2a9b50fd3725",
-    "llama_prefill": 
-        "52db68d74f15ab0f4793dc6ead0131a2a4ead966c0a9916a16433e6430790d9c",
+    # taken again on PR 45's tree, which means to change this program: the
+    # row-wise work walks a prompt in pieces and skips those past its length,
+    # the head runs over the last row (the hash of PR 35's parent
+    # until then: 52db68d74f15ab0f4793dc6ead0131a2a4ead966c0a9916a16433e6430790d9c)
+    "llama_prefill":
+        "56b64beacc9154f146f8b1a8da0aa5e62f924fd7ae92fae43f0858d9b65dbdaf",
     "hybrid_decode": 
         "f10679f1eeeb5ec1bfe2bc568c804d4679e7872e9bb464c87d2a1e95c255fedc",
     "hybrid_prefill": 

@@ -138,11 +138,13 @@ def test_prefill_padding_is_counted_exactly(engine):
     after = engine.stats()
     rise = {k: after[k] - before[k] for k in before
             if k.startswith("prefill_") and k != "prefill_calls_by_rows"}
-    # 3 rows of 100 tokens take the 4-row program of the 128 bucket; the
-    # rows a prefill PROGRAM counts by depth are another family's (0 here)
+    # 3 rows of 100 tokens take the 4-row program of the 128 bucket, which is
+    # one piece and so computes every row it was given; the rows a prefill
+    # PROGRAM counts by depth are another family's (0 here)
     assert rise == {"prefill_calls": 1, "prefill_rows_real": 3,
                     "prefill_rows_padded": 4, "prefill_tokens_real": 300,
                     "prefill_tokens_padded": 512,
+                    "prefill_rows_computed": 512,
                     "prefill_rows_self": 0, "prefill_rows_cross": 0,
                     "prefill_rows": 0, "prefill_attn_pairs": 0}
     assert calls_by_rows(before, after) == {1: 0, 4: 1}

@@ -145,7 +145,7 @@ def _llama_tokens(active_slots, stale):
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(prompt)] = prompt
         table[slot] = 1 + 4 * slot + np.arange(4)
-        logits, cache = pd.paged_prefill(
+        logits, cache, _ = pd.paged_prefill(
             params, cache, jnp.asarray(padded),
             jnp.asarray(table[slot:slot + 1, :bucket // ps]),
             jnp.asarray([len(prompt)], jnp.int32), cfg, ps)

@@ -57,7 +57,7 @@ def _paged_generate(cfg, params, prompt, steps, num_slots=2, total_pages=9):
     assert pd.PageAllocator.TRASH_PAGE not in pages
     padded = np.zeros((1, BUCKET), np.int32)
     padded[0, :len(prompt)] = prompt
-    logits, cache = pd.paged_prefill(
+    logits, cache, _ = pd.paged_prefill(
         params, cache, jnp.asarray(padded),
         jnp.asarray([pages[: BUCKET // PS]], jnp.int32),
         jnp.asarray([len(prompt)], jnp.int32), cfg, PS)
@@ -176,7 +176,7 @@ def _interleaved_case(cfg, params):
     padded = np.zeros((2, BUCKET), np.int32)
     for b, prompt in enumerate(prompts):
         padded[b, :len(prompt)] = prompt
-    logits, cache = pd.paged_prefill(
+    logits, cache, _ = pd.paged_prefill(
         params, cache, jnp.asarray(padded),
         jnp.asarray([pages[: BUCKET // PS] for pages in owned], jnp.int32),
         jnp.asarray([len(p) for p in prompts], jnp.int32), cfg, PS)
@@ -196,6 +196,91 @@ def _interleaved_case(cfg, params):
                          ids=["layer_block", "inactive_trash", "interleaved"])
 def test_one_pool_keeps_layers_and_slots_apart(setup, case):
     case(*setup)
+
+
+# --------------------------------------------------------------------------- #
+# A prefill call costs the rows its prompts have, not the rows its bucket has
+# --------------------------------------------------------------------------- #
+PIECE = 16    # ``pd.PREFILL_PIECE`` in these tests: a bucket of 64 is 4 pieces
+LONG = 64
+
+
+def _prefill_group(cfg, params, lengths, piece, monkeypatch):
+    """One prefill call over a bucket of ``LONG``: a prompt a length, then a
+    pad row (length 1, the trash page), at ``PREFILL_PIECE`` = ``piece``.
+    Returns (prompts, their pages, logits, cache, counters)."""
+    monkeypatch.setattr(pd, "PREFILL_PIECE", piece)
+    rng = np.random.default_rng(5)
+    n_pages = LONG // PS
+    tokens = np.zeros((len(lengths) + 1, LONG), np.int32)
+    pages = np.zeros((len(lengths) + 1, n_pages), np.int32)
+    prompts = []
+    for row, n in enumerate(lengths):
+        prompts.append(rng.integers(0, cfg.vocab_size, n))
+        tokens[row, :n] = prompts[-1]
+        pages[row] = 1 + row * n_pages + np.arange(n_pages)
+    cache = pd.init_paged_cache(cfg, 1 + len(lengths) * n_pages, PS,
+                                dtype=jnp.float32)
+    logits, cache, counts = pd.paged_prefill(
+        params, cache, jnp.asarray(tokens), jnp.asarray(pages),
+        jnp.asarray(list(lengths) + [1], jnp.int32), cfg, PS)
+    return prompts, pages, logits, cache, counts
+
+
+@pytest.mark.parametrize("length", [1, PIECE - 1, PIECE, PIECE + 1, LONG])
+def test_prefill_skips_the_pieces_past_a_prompt(setup, monkeypatch, length):
+    """A group of mixed lengths in one bucket, with a pad row: every prompt's
+    last-token logits are the full forward's, every LIVE row of its pages is
+    what the whole-bucket computation (one piece: the bucket) leaves there,
+    the pages past its last piece hold zeros, and ``prefill_rows_computed``
+    is live pieces x piece rows over the call's rows, the pad row's one
+    piece included."""
+    cfg, params = setup
+    lengths = (length, 40, 23)
+    prompts, pages, logits, cache, counts = _prefill_group(
+        cfg, params, lengths, PIECE, monkeypatch)
+    *_, whole, whole_counts = _prefill_group(cfg, params, lengths, LONG,
+                                             monkeypatch)
+    per_layer = cache.k.shape[1] // cfg.num_layers
+    for row, (n, prompt) in enumerate(zip(lengths, prompts)):
+        want = np.asarray(llama_forward(
+            params, jnp.asarray([prompt], jnp.int32), cfg))[0, n - 1]
+        np.testing.assert_allclose(np.asarray(logits[row]), want, atol=2e-5)
+        computed = -(-n // PIECE) * PIECE
+        for layer in range(cfg.num_layers):
+            mine = layer * per_layer + pages[row]
+            for got, ref in ((cache.k, whole.k), (cache.v, whole.v)):
+                got = np.asarray(got[:, mine]).reshape(got.shape[0], LONG, -1)
+                ref = np.asarray(ref[:, mine]).reshape(got.shape)
+                np.testing.assert_allclose(got[:, :n], ref[:, :n], atol=2e-6)
+                # the bucket's padding past the last live piece: computed
+                # there, never computed here
+                assert ref[:, computed:].any() == (computed < LONG)
+                assert not got[:, computed:].any()
+    assert counts.tolist() == [sum(-(-n // PIECE) * PIECE
+                                   for n in lengths + (1,))]
+    assert whole_counts.tolist() == [(len(lengths) + 1) * LONG]
+
+
+def test_engine_stats_carry_the_rows_prefill_computed(setup, monkeypatch):
+    """``stats()["prefill_rows_computed"]``: the one-row bring-up call of the
+    bucket (a pad row: one piece), then a prompt of 40 in a bucket of 64 at
+    a piece of 16: 3 of its 4 pieces, beside the 64 tokens admission pads it
+    to; the reply is the full forward's."""
+    cfg, params = setup
+    monkeypatch.setattr(pd, "PREFILL_PIECE", PIECE)
+    engine = _engine(cfg, params, prefill_buckets=[LONG])
+    try:
+        prompt = [int(t) for t in
+                  np.random.default_rng(6).integers(0, cfg.vocab_size, 40)]
+        out = engine.generate(prompt, max_tokens=8, timeout=300)
+        _assert_greedy_of_full_forward(
+            cfg, params, prompt, out["tokens"], ENGINE_GAP)
+        stats = engine.stats()
+        assert stats["prefill_tokens_padded"] == LONG
+        assert stats["prefill_rows_computed"] == PIECE + 3 * PIECE
+    finally:
+        engine.stop()
 
 
 # --------------------------------------------------------------------------- #
