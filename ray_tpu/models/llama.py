@@ -325,3 +325,9 @@ def cross_entropy_loss(logits, targets, mask=None):
         nll = nll * mask
         return nll.sum() / jnp.maximum(mask.sum(), 1)
     return nll.mean()
+
+
+# what ``train/step.py`` asks a family's module for (``_family``)
+init_params = llama_init
+logical_axes = llama_logical_axes
+loss = llama_loss

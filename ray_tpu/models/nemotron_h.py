@@ -28,8 +28,9 @@ experts the router scores (``ops/moe.py``): the chip's share of an
 expert-parallel deployment, or all of them. ``vocab_size`` is the rows of the
 vocabulary held here.
 
-Training of this family is not written (``train/step.py`` closes over
-``llama_loss``).
+Training of this family is not written: ``train/step.py`` would step its
+loss, and the backward of the chunked scan and of its kernels is what is
+missing.
 """
 
 from __future__ import annotations

@@ -8,10 +8,11 @@ Design (see /opt/skills/guides/pallas_guide.md):
   flash pattern, so S×S scores never touch HBM.
 - causal masking skips fully-masked K blocks via the loop bound (block-level
   skip), and applies an elementwise mask only on the diagonal block. With a
-  sliding ``window`` (forward only: serving's prefill) the loop also STARTS
-  at the first K block a row of the q block can see, so a window layer's
-  prefill costs S x window and not S^2 / 2; that call is named
-  ``flash_window_fwd`` in a profile.
+  sliding ``window`` the loop also STARTS at the first K block a row of the
+  q block can see, so a window layer costs S x window and not S^2 / 2, in
+  serving's prefill and in training's forward and backward alike; those
+  calls are named ``flash_window_fwd``, ``flash_window_bwd_dq`` and
+  ``flash_window_bwd_dkv`` in a profile.
 - GQA: q heads map onto kv heads through the BlockSpec index_map
   (h // q_per_kv), so kv tensors are never materialized per-q-head.
 - widths: q and k share one head width, any the compiler tiles (128 as the
@@ -25,7 +26,10 @@ Design (see /opt/skills/guides/pallas_guide.md):
   forward also emits the logsumexp per row; bwd recomputes p = exp(qk−lse)
   blockwise, so S×S never materializes. Two kernels: dq (grid over q blocks)
   and dk/dv (grid over k blocks, accumulated at q-head granularity then
-  reduced onto kv heads for GQA).
+  reduced onto kv heads for GQA). Under a window the dq kernel's K loop
+  starts, and the dk/dv kernel's Q loop stops, at the blocks the window
+  admits. Defined where q, k and v share one width and no ``lengths`` are
+  given.
 
 Replaces-the-capability-of: the reference's NCCL-attached attention stacks
 are external (DeepSpeed etc. via train integrations); here attention is a
@@ -172,6 +176,10 @@ def _flash_fwd_ragged_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, *,
 # scoped VMEM (a v5e has 128 MiB). 4 MB at the 4096 rows of the training
 # cell: calls that fit are compiled as they were
 KV_VMEM_DEFAULT_BYTES = 8 * 2 ** 20
+# the same for what the dK/dV kernel keeps whole: q, dO and the two float32
+# columns of one (batch, q head). 12 MB at the 4096 rows of ``train_4k``,
+# which compiles as it did; 24 MB at 8192
+ROWS_VMEM_DEFAULT_BYTES = 12 * 2 ** 20
 
 
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
@@ -267,9 +275,12 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                         *, block_q, block_k, seq_kv, causal, scale, offset):
+                         *, block_q, block_k, seq_kv, causal, scale, offset,
+                         window=None):
     """dQ for one (batch, q_head, q_block): stream K/V blocks, recompute
-    p = exp(s - lse), ds = p * (dO·Vᵀ - delta), dq += scale · ds · K."""
+    p = exp(s - lse), ds = p * (dO·Vᵀ - delta), dq += scale · ds · K. Under a
+    ``window`` the stream starts at the first K block the q block's first
+    row sees, as the forward's does."""
     qi = pl.program_id(2)
     q = q_ref[0, 0]  # storage dtype: bf16 dots on the MXU, f32 accumulate
     do = do_ref[0, 0]
@@ -284,6 +295,9 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref
         )
     else:
         num_k_blocks = seq_kv // block_k
+    first_k_block = 0
+    if window is not None:
+        first_k_block = jnp.maximum(q_start - window + 1, 0) // block_k
 
     def body(j, dq):
         k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
@@ -294,7 +308,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window is not None:
+                seen = jnp.logical_and(seen, rows - cols < window)
+            s = jnp.where(seen, s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -304,16 +321,18 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref
             ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    dq = jax.lax.fori_loop(0, num_k_blocks, body, jnp.zeros((block_q, d), jnp.float32))
+    dq = jax.lax.fori_loop(first_k_block, num_k_blocks, body,
+                           jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q, block_k, seq_q, causal,
-                          scale, offset):
+                          scale, offset, window=None):
     """dK/dV for one (batch, q_head, k_block): stream q blocks from the first
-    causally-visible one. Accumulated per Q head; the caller reduces onto kv
-    heads (GQA)."""
+    causally-visible one, and under a ``window`` only as far as the last q
+    block whose first row still sees the k block's last key. Accumulated per
+    Q head; the caller reduces onto kv heads (GQA)."""
     ki = pl.program_id(2)
     k_blk = k_ref[0, 0]  # storage dtype (bf16 MXU path)
     v_blk = v_ref[0, 0]
@@ -327,6 +346,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         first = jax.lax.max(0, jax.lax.div(k_start - offset, block_q))
     else:
         first = 0
+    if window is not None:
+        # the newest row that sees the block's LAST key (k_start + bk - 1)
+        # is that key's position + window - 1
+        num_q_blocks = jax.lax.min(num_q_blocks, jax.lax.div(
+            k_start + block_k + window - 2 - offset, block_q) + 1)
 
     def body(i, carry):
         dk, dv = carry
@@ -342,7 +366,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 0
             )
             cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window is not None:
+                seen = jnp.logical_and(seen, rows - cols < window)
+            s = jnp.where(seen, s, NEG_INF)
         p = jnp.exp(s - lse_blk)
         p_lo = p.astype(do_blk.dtype)
         dv = dv + jax.lax.dot_general(
@@ -364,7 +391,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret):
+def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret,
+               window=None):
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     q_per_kv = hq // hkv
@@ -384,23 +412,39 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret)
     kv_blk = pl.BlockSpec((1, 1, block_k, d), lambda bb, h, j, _g=q_per_kv: (bb, h // _g, j, 0))
     row_blk = pl.BlockSpec((1, 1, block_q, 1), lambda bb, h, i: (bb, h, i, 0))
     row_full = pl.BlockSpec((1, 1, sq, 1), lambda bb, h, i: (bb, h, 0, 0))
+    dq_call, dkv_call, windowed = {}, {}, {}
+    if window is not None:
+        windowed = {"window": window}
+        dq_call["name"] = "flash_window_bwd_dq"
+        dkv_call["name"] = "flash_window_bwd_dkv"
+    # what sits whole in VMEM, double buffered: K and V for dQ; q, dO and the
+    # two float32 columns (a lane-padded tile a row: 512 bytes) for dK/dV
+    kv_vmem = 2 * skv * 2 * d * k.dtype.itemsize
+    if kv_vmem > KV_VMEM_DEFAULT_BYTES:
+        dq_call["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=kv_vmem + 16 * 2 ** 20)
+    rows_vmem = 2 * sq * (2 * d * q.dtype.itemsize + 2 * 128 * 4)
+    if rows_vmem > ROWS_VMEM_DEFAULT_BYTES:
+        dkv_call["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=rows_vmem + 16 * 2 ** 20)
 
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-            seq_kv=skv, causal=causal, scale=scale, offset=offset,
+            seq_kv=skv, causal=causal, scale=scale, offset=offset, **windowed,
         ),
         grid=(b, hq, sq // block_q),
         in_specs=[q_spec, kv_full, kv_full, q_spec, row_blk, row_blk],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         interpret=interpret,
+        **dq_call,
     )(qt, kt, vt, dot, lse, delta)
 
     dk_h, dv_h = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-            seq_q=sq, causal=causal, scale=scale, offset=offset,
+            seq_q=sq, causal=causal, scale=scale, offset=offset, **windowed,
         ),
         grid=(b, hq, skv // block_k),
         in_specs=[q_full, kv_blk, kv_blk, q_full, row_full, row_full],
@@ -413,6 +457,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret)
             jax.ShapeDtypeStruct((b, hq, skv, d), jnp.float32),
         ],
         interpret=interpret,
+        **dkv_call,
     )(qt, kt, vt, dot, lse, delta)
 
     # GQA reduction: q-head-granular dk/dv sum onto their kv head
@@ -425,15 +470,18 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret)
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret,
+                     window=None):
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                        with_lse=False)
+                        with_lse=False, window=window)
     return out
 
 
-def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                         window=None):
+    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                          window=window)
     # Tag the kernel outputs so a `save_only_these_names` remat policy can
     # pin EXACTLY these as residuals: the surrounding layer then recomputes
     # the cheap projections for q/k/v while the flash kernel itself is never
@@ -445,9 +493,11 @@ def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_attention_bwd(causal, scale, block_q, block_k, interpret, residuals, g):
+def _flash_attention_bwd(causal, scale, block_q, block_k, interpret, window,
+                         residuals, g):
     q, k, v, out, lse = residuals
-    return _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret)
+    return _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
+                      interpret, window)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
@@ -470,9 +520,10 @@ def flash_attention(
     q: [B, Sq, Hq, D]; k: [B, Skv, Hkv, D] with Hq % Hkv == 0; v: [B, Skv,
     Hkv, Dv], Dv = D or a width of its own (forward only: the unabsorbed
     latent attention's 192 / 128). ``window``: causal sliding window (a query
-    sees its ``window`` newest keys, itself among them); forward only, no
-    gradient is defined. ``lengths``: int32 [B], rows that are real (causal,
-    forward only): q blocks past them are zeros and cost nothing.
+    sees its ``window`` newest keys, itself among them); it differentiates,
+    through kernels that visit only the blocks the window admits.
+    ``lengths``: int32 [B], rows that are real (causal, forward only): q
+    blocks past them are zeros and cost nothing.
     """
     if window is not None and not causal:
         raise ValueError("a sliding window is causal")
@@ -513,8 +564,9 @@ def flash_attention(
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    if window is None and v.shape[-1] == d and lengths is None:
-        out = _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret)
+    if v.shape[-1] == d and lengths is None:
+        out = _flash_attention(q, k, v, causal, scale, block_q, block_k,
+                               interpret, window)
     else:
         out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                             with_lse=False, window=window, lengths=lengths)
@@ -530,7 +582,7 @@ def _round_up(x: int, m: int) -> int:
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, impl: str = "auto",
               window: Optional[int] = None, lengths=None):
     """Dispatch. impl: "flash" | "flash_interpret" | "reference" | "auto".
-    ``window``: a causal sliding window (forward only). ``lengths``: the
+    ``window``: a causal sliding window (forward and backward). ``lengths``: the
     rows of each batch row that are real; the kernel skips the blocks past
     them, the reference computes them (nobody reads them).
 

@@ -21,7 +21,19 @@ Two ways to do the products, chosen by the caller from its token count:
   is gathered, multiplied, weighted and added to its tokens, and not the
   ``T * top_k`` rows of which the share holds an eighth. Still dropless: a
   routing that leans on this share runs the same body over the next block
-  until every held assignment is covered.
+  until every held assignment is covered. REVERSE MODE: the block loop is a
+  ``lax.while_loop`` whose trip count follows the routing, which JAX cannot
+  differentiate, so the compacted product is a ``custom_vjp``
+  (``_compacted`` / ``_compacted_bwd``): the reverse pass walks the same
+  blocks, as many as the held assignments need (dropless in both
+  directions), keeps nothing of the forward but the layer's inputs,
+  multiplies each block's rows by W_up (and W_gate) again, and gives the
+  cotangent to the tokens, to the held experts' matrices (a grouped product
+  whose contracted dimension is the ragged one) and to the routing weights,
+  so to the router through the chosen weights and their normalisation, and
+  not through the choice. Where the block would hold every assignment (a
+  share of a half or more) the product runs uncompacted and JAX's own rules
+  for ``ragged_dot`` differentiate it.
 - ``"dense"``: every held expert over every token, the unchosen ones weighted
   zero. A decode batch of a hundred rows touches nearly every held expert
   anyway, so the weights are read once either way and the extra
@@ -42,6 +54,7 @@ capacity); only its tests and ``__graft_entry__.py`` use it.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -117,7 +130,8 @@ def routed_experts(x, router: Dict[str, Any], experts: Dict[str, Any], *,
     assert experts["w_up"].shape[0] == n, (experts["w_up"].shape, held)
     if form not in ("relu2", "swiglu"):
         raise ValueError(f"unknown expert form {form!r}")
-    chosen, weights = route(x, router, top_k, scale, scoring)
+    with jax.named_scope("router"):
+        chosen, weights = route(x, router, top_k, scale, scoring)
     here = (chosen >= lo) & (chosen < hi)
     local = jnp.where(here, chosen - lo, n)          # n: "not held here"
     if impl == "dense":
@@ -188,10 +202,10 @@ def _ragged(x, experts, local, weights, n: int, form: str, width: int):
     compacted product ran beyond its first; zeros where the block would hold
     every assignment and the product runs over all of them at once)."""
     t, k = local.shape
-    flat = local.reshape(-1)
-    order = jnp.argsort(flat, stable=True)       # held first, by expert
     cap = _capacity(t * k, n, width)
     if cap >= t * k:
+        flat = local.reshape(-1)
+        order = jnp.argsort(flat, stable=True)   # held first, by expert
         sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
         down = _grouped(x[order // k], experts, sizes, form)     # [T*k, h]
         valid = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
@@ -200,20 +214,55 @@ def _ragged(x, experts, local, weights, n: int, form: str, width: int):
             jnp.arange(t * k, dtype=jnp.int32))
         return jnp.sum(down[back].reshape(t, k, -1), axis=1).astype(x.dtype), \
             jnp.zeros((2,), jnp.int32)
+    # the matrices in the order the product takes them (a dict would reach
+    # the compiled program sorted by name: another text for the same work)
+    return _compacted(x, tuple(experts[name] for name in _names(form)),
+                      weights, local, n, form, cap)
+
+
+def _names(form: str):
+    return ("w_up", "w_gate", "w_down") if form == "swiglu" \
+        else ("w_up", "w_down")
+
+
+def _block_plan(local, n: int):
+    """The sorted order of the assignments (held first, by expert) and each
+    held expert's first and last sorted row."""
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
     # a count by comparison: bincount's scatter of 32,768 ones took 0.29 ms
     ends = jnp.cumsum(jnp.sum(flat[:, None] == jnp.arange(n), axis=0,
                               dtype=jnp.int32))
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    return order, starts, ends
+
+
+def _block_rows(b, order, starts, ends, cap: int, k: int):
+    """Sorted rows [lo, lo + cap) of block ``b``: their position, the
+    assignment and the token each is, and each expert's group clipped to
+    them."""
+    lo = b * cap
+    at = lo + jnp.arange(cap, dtype=jnp.int32)
+    which = order[jnp.minimum(at, order.shape[0] - 1)]
+    token = which // k
+    sizes = jnp.clip(ends, lo, lo + cap) - jnp.clip(starts, lo, lo + cap)
+    return at, which, token, sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _compacted(x, matrices, weights, local, n: int, form: str, cap: int):
+    """The compacted product: blocks of ``cap`` sorted rows until every held
+    assignment is covered -> (the weighted sum [T, h], int32 [2]: 1 and the
+    blocks beyond the first). ``matrices``: the experts' in ``_names``'
+    order."""
+    experts = dict(zip(_names(form), matrices))
+    t, k = local.shape
+    order, starts, ends = _block_plan(local, n)
     flat_weights = weights.reshape(-1)
 
     def block(carry):
-        """Sorted rows [lo, lo + cap): each expert's group clipped to them."""
         b, acc = carry
-        lo = b * cap
-        at = lo + jnp.arange(cap, dtype=jnp.int32)
-        which = order[jnp.minimum(at, t * k - 1)]
-        token = which // k
-        sizes = jnp.clip(ends, lo, lo + cap) - jnp.clip(starts, lo, lo + cap)
+        at, which, token, sizes = _block_rows(b, order, starts, ends, cap, k)
         down = _grouped(x[token], experts, sizes, form)          # [cap, h]
         down = down * flat_weights[which][:, None]
         # a segment sum by token; rows past the held ones go nowhere
@@ -224,3 +273,112 @@ def _ragged(x, experts, local, weights, n: int, form: str, width: int):
         lambda carry: carry[0] * cap < ends[-1], block,
         (jnp.int32(0), jnp.zeros((t, x.shape[1]), jnp.float32)))
     return out.astype(x.dtype), jnp.stack([1, jnp.maximum(blocks - 1, 0)])
+
+
+def _compacted_fwd(x, matrices, weights, local, n, form, cap):
+    # what the reverse pass keeps: the layer's inputs. It multiplies each
+    # block's rows by W_up (and W_gate) again, which under a layer that is
+    # rematerialised anyway costs less than keeping them: the second forward
+    # of this product is then dead code (PERF.md 6, PR 46)
+    return _compacted(x, matrices, weights, local, n, form, cap), \
+        (x, matrices, weights, local)
+
+
+def _grouped_t(rows, w, sizes):
+    """``rows`` [cap, b] sorted by expert x ``w`` [E, a, b] over b -> [cap,
+    a]: a cotangent back through a grouped product to its rows. The matrices
+    are transposed first (66 MB a stack at Mellum's widths): XLA:TPU has its
+    grouped kernel for ``ragged_dot``'s own dimension numbers, and lowers a
+    contraction over the matrices' LAST dimension to every expert over every
+    row (a [E, cap, a] convolution: 9.7 GB at 16 x 65,536 x 2,304; read from
+    the compiled program, PR 46)."""
+    return jax.lax.ragged_dot(rows, jnp.swapaxes(w, 1, 2), sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _grouped_outer(rows, cot, sizes):
+    """``rows`` [cap, a], ``cot`` [cap, b], both sorted by expert -> [E, a,
+    b]: each expert's matrix gets the product over ITS rows (the contracted
+    dimension is the ragged one); rows past ``sum(sizes)`` belong to none."""
+    return jax.lax.ragged_dot_general(
+        rows, cot, sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=(0,), rhs_group_dimensions=()),
+        preferred_element_type=jnp.float32)
+
+
+def _compacted_bwd(n, form, cap, kept, cotangent):
+    """The reverse of ``_compacted``, dropless as it is: the same blocks in
+    the same order, as many as the held assignments need. A block gathers its
+    rows, multiplies them by W_up (and W_gate) again, and takes the cotangent
+    of the result's rows back through the three grouped products: to the
+    rows (scattered onto their tokens), to each held expert's matrices (a
+    product over the ragged dimension) and to the routing weights (``<act,
+    g W_down^T>``: the unweighted output is never formed). Nothing flows to
+    ``local``: the choice is no function of the scores in reverse mode."""
+    x, matrices, weights, local = kept
+    names = _names(form)
+    experts = dict(zip(names, matrices))
+    g, _ = cotangent
+    t, k = local.shape
+    order, starts, ends = _block_plan(local, n)
+    flat_weights = weights.reshape(-1)
+    dt = x.dtype
+
+    def reverse(b):
+        """Block ``b`` -> (the tokens its rows are and what flows to them,
+        the assignments they are and what flows to their weights, what flows
+        to the experts' matrices); rows past the held ones go nowhere."""
+        at, which, token, sizes = _block_rows(b, order, starts, ends, cap, k)
+        valid = (at < ends[-1])[:, None]
+        rows = x[token]
+        up = jax.lax.ragged_dot(rows, experts["w_up"], sizes,
+                                preferred_element_type=jnp.float32)
+        gate = jax.lax.ragged_dot(rows, experts["w_gate"], sizes,
+                                  preferred_element_type=jnp.float32) \
+            if form == "swiglu" else None
+        act, act_vjp = jax.vjp(lambda u, v: _act(u, v, form), up, gate)
+        w = flat_weights[which][:, None]
+        g_rows = jnp.where(valid, g[token], 0).astype(dt)
+        back = _grouped_t(g_rows, experts["w_down"], sizes)      # [cap, f]
+        d_w = jnp.sum(act * back, axis=-1)
+        d_up, d_gate = act_vjp(back * w)
+        # rows past the held ones hold whatever the products left there
+        clean = lambda a: jnp.where(valid, a, 0).astype(dt)  # noqa: E731
+        d_up = clean(d_up)
+        grads = {"w_down": _grouped_outer(clean(act * w), g_rows, sizes),
+                 "w_up": _grouped_outer(rows, d_up, sizes)}
+        d_rows = _grouped_t(d_up, experts["w_up"], sizes)
+        if form == "swiglu":
+            d_gate = clean(d_gate)
+            grads["w_gate"] = _grouped_outer(rows, d_gate, sizes)
+            d_rows = d_rows + _grouped_t(d_gate, experts["w_gate"], sizes)
+        return (jnp.where(valid[:, 0], token, t), d_rows,
+                jnp.where(valid[:, 0], which, t * k), d_w, grads)
+
+    def block(carry):
+        b, dx, d_weights, d_experts = carry
+        token, d_rows, which, d_w, grads = reverse(b)
+        return (b + 1, dx.at[token].add(d_rows, mode="drop"),
+                d_weights.at[which].set(d_w, mode="drop"),
+                {name: d_experts[name] + grads[name] for name in names})
+
+    # the first block outside the loop: a share that fits one block (every
+    # call but a routing that leans on this share) then adds nothing to three
+    # float32 stacks of zeros, which cost a layer's chunk as much as a
+    # grouped product (PERF.md 6, PR 46); with no held assignment at all its
+    # groups are empty and its rows go nowhere
+    token, d_rows, which, d_w, grads = reverse(jnp.int32(0))
+    _, dx, d_weights, d_experts = jax.lax.while_loop(
+        lambda carry: carry[0] * cap < ends[-1], block,
+        (jnp.int32(1),
+         jnp.zeros(x.shape, jnp.float32).at[token].add(d_rows, mode="drop"),
+         jnp.zeros((t * k,), jnp.float32).at[which].set(d_w, mode="drop"),
+         grads))
+    d_matrices = tuple(d_experts[name].astype(experts[name].dtype)
+                       for name in names)
+    return (dx.astype(dt), d_matrices,
+            d_weights.reshape(t, k).astype(weights.dtype), None)
+
+
+_compacted.defvjp(_compacted_fwd, _compacted_bwd)

@@ -1,4 +1,5 @@
-"""Sharded train/eval step construction.
+"""Sharded train/eval step construction, for whichever model family the
+caller's configuration belongs to.
 
 The compiled-step analogue of the reference's Train worker loop (reference:
 python/ray/train/_internal/session.py — but there the step is torch eager +
@@ -6,18 +7,27 @@ NCCL allreduce; here the WHOLE step, gradients + optimizer + collectives, is
 one pjit-compiled XLA program over the mesh: gradients reduce over (dp, fsdp)
 via XLA's sharding propagation, parameters/optimizer state stay sharded per
 the logical rules).
+
+Whose loss it steps: this module names no family. ``make_train_step``,
+``make_train_state_factory`` and ``state_logical_axes`` ask the module that
+holds the configuration's class (``_family``, the way ``serve/llm.py``
+``_model_of`` asks a served model's module) for its ``loss(params, tokens,
+targets, config, mesh=, rules=)``, ``init_params(config, key)`` and
+``logical_axes(config)``: every module under ``models/`` that is trained
+holds the three. A family whose loss also counts (``loss_and_counters`` ->
+``(loss, {name: array})``) has what it counted beside ``loss`` and
+``grad_norm`` in the step's output.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+import sys
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 
-from ray_tpu.models.llama import LlamaConfig, cross_entropy_loss, llama_forward, llama_init, llama_logical_axes, llama_loss
 from ray_tpu.parallel.sharding import (
     DEFAULT_LLM_RULES,
     ShardingRules,
@@ -52,12 +62,21 @@ def default_optimizer(
     )
 
 
-def state_logical_axes(config: LlamaConfig, optimizer, sample_params=None) -> Any:
+def _family(config):
+    """The module that holds ``config``'s class, and beside it the family's
+    ``init_params``, ``logical_axes`` and ``loss``: whoever made ``config``
+    has imported it."""
+    return sys.modules[type(config).__module__]
+
+
+def state_logical_axes(config, optimizer, sample_params=None) -> Any:
     """Logical axes for the full TrainState: optimizer moments mirror the
     param axes; scalars (step, counts) carry no axes."""
-    param_axes = llama_logical_axes(config)
+    family = _family(config)
+    param_axes = family.logical_axes(config)
     if sample_params is None:
-        sample_params = jax.eval_shape(lambda k: llama_init(config, k), jax.random.key(0))
+        sample_params = jax.eval_shape(
+            lambda k: family.init_params(config, k), jax.random.key(0))
     opt_shape = jax.eval_shape(optimizer.init, sample_params)
 
     # Optimizer moments mirror the params pytree nested somewhere inside the
@@ -106,7 +125,7 @@ def _state_shardings(axes_tree, mesh, rules):
 
 
 def make_train_state_factory(
-    config: LlamaConfig,
+    config,
     optimizer,
     mesh=None,
     rules: ShardingRules = DEFAULT_LLM_RULES,
@@ -115,9 +134,10 @@ def make_train_state_factory(
     jitted with sharded out_shardings so parameters are created directly in
     their shards (no host-side full materialization)."""
     enable_compile_cache()
+    init_params = _family(config).init_params
 
     def init(key) -> TrainState:
-        params = llama_init(config, key)
+        params = init_params(config, key)
         opt_state = optimizer.init(params)
         return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=opt_state)
 
@@ -128,26 +148,42 @@ def make_train_state_factory(
     return jax.jit(init, out_shardings=out_shardings)
 
 
+def _loss_of(config, mesh, rules):
+    """The family's loss as ``(params, tokens, targets) -> loss`` or, where
+    its module has ``loss_and_counters``, ``-> (loss, counters)``."""
+    family = _family(config)
+    counted = getattr(family, "loss_and_counters", None)
+    fn = counted or family.loss
+    return (lambda params, tokens, targets: fn(
+        params, tokens, targets, config, mesh=mesh, rules=rules)), \
+        counted is not None
+
+
 def make_train_step(
-    config: LlamaConfig,
+    config,
     optimizer,
     mesh=None,
     rules: ShardingRules = DEFAULT_LLM_RULES,
     donate: bool = True,
 ):
-    """(state, tokens, targets) -> (state, metrics). tokens/targets: [B, S]."""
+    """(state, tokens, targets) -> (state, metrics). tokens/targets: [B, S].
+    What the family's loss counted (``_loss_of``) goes into the metrics."""
     enable_compile_cache()
+    loss, has_counters = _loss_of(config, mesh, rules)
 
     def step_fn(state: TrainState, tokens, targets) -> Tuple[TrainState, Dict[str, jax.Array]]:
         def loss_fn(params):
-            return llama_loss(params, tokens, targets, config, mesh=mesh, rules=rules)
+            return loss(params, tokens, targets)
 
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        out, grads = jax.value_and_grad(loss_fn, has_aux=has_counters)(state.params)
+        value, counters = out if has_counters else (out, {})
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt)
-        return new_state, {"loss": loss, "grad_norm": gnorm, "step": new_state.step}
+        return new_state, {"loss": value, "grad_norm": gnorm,
+                           "step": new_state.step, **counters}
 
     donate_argnums = (0,) if donate else ()
     if mesh is None:
@@ -155,8 +191,7 @@ def make_train_step(
     from ray_tpu.parallel.mesh import batch_sharding_spec
 
     batch_sh = jax.sharding.NamedSharding(mesh, batch_sharding_spec())
-    axes = state_logical_axes(config, optimizer)
-    state_sh = _state_shardings(axes, mesh, rules)
+    state_sh = _state_shardings(state_logical_axes(config, optimizer), mesh, rules)
     return jax.jit(
         step_fn,
         in_shardings=(state_sh, batch_sh, batch_sh),
@@ -165,9 +200,12 @@ def make_train_step(
     )
 
 
-def make_eval_step(config: LlamaConfig, mesh=None, rules: ShardingRules = DEFAULT_LLM_RULES):
+def make_eval_step(config, mesh=None, rules: ShardingRules = DEFAULT_LLM_RULES):
+    """(params, tokens, targets) -> the loss alone, no gradient."""
+    loss, has_counters = _loss_of(config, mesh, rules)
+
     def eval_fn(params, tokens, targets):
-        logits = llama_forward(params, tokens, config, mesh=mesh, rules=rules)
-        return cross_entropy_loss(logits, targets)
+        out = loss(params, tokens, targets)
+        return out[0] if has_counters else out
 
     return jax.jit(eval_fn)

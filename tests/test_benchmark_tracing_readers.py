@@ -11,6 +11,7 @@ from benchmarks.readers import (
 from benchmarks.tests.test_kimi_k2_family import *  # noqa: F401,F403
 from benchmarks.tests.test_laguna_family import *  # noqa: F401,F403
 from benchmarks.tests.test_manifest import *  # noqa: F401,F403
+from benchmarks.tests.test_mellum_family import *  # noqa: F401,F403
 from benchmarks.tests.test_nemotron_h_family import *  # noqa: F401,F403
 from benchmarks.tests.test_phi4flash_family import *  # noqa: F401,F403
 from benchmarks.tests.test_rates import *  # noqa: F401,F403

@@ -430,7 +430,42 @@ ACCEPTED_PROGRAMS_SHA = {
         "373b08f3cc3b766fdc496f79d802db901e990b84f58b47925d5908068f7daca8",
     "laguna_prefill":
         "3e289e0cde0ba08fe7ac7fa176851ce1125cd6f0259b31ee1a7bef23c84592e7",
+    # taken on the parent commit of PR 46 (4d75b23) and equal on its tree:
+    # the other two served families that call ``ops/moe.py`` or the flash
+    # forward (a window, ``lengths``, a v width of its own), and ``train_4k``'s
+    # step through the seam of ``train/step.py`` (Mistral-7B-v0.3, 4 layers,
+    # 4 x 4096, ``save_attn``, the benchmark's optimizer)
+    "phi4flash_decode":
+        "66943e95e9f195e64e3b2540d6cd0ba7b2b1fb8979479c6958074c1180a02811",
+    "phi4flash_prefill":
+        "0b697671ae9327ec76b3b5f0ce640f90d2da713260a796e00cf39ddcfcca239b",
+    "kimi_k2_decode":
+        "e2f027d18fa901b132225c84a6c94ceab87d85f9b128477801a75be1e7ee7b51",
+    "kimi_k2_prefill":
+        "b7f872f96286a46d66093e5ada82ae7e7fab520ab104a9138ca1cddfe0ea87b4",
+    "train_4k_step":
+        "93be954fac40c8e84e755380ac97bd779def24c763d3786568e814f9cc165399",
 }
+
+
+def _train_step_lowered(v5e, config, rows, seq):
+    """``train/step.py``'s step for ``config`` (its family's loss, found from
+    the configuration's module) under the benchmark's optimizer, one chip,
+    lowered for ``rows`` x ``seq`` tokens."""
+    from ray_tpu.train.step import (
+        TrainState, _family, default_optimizer, make_train_step)
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    opt = default_optimizer(warmup_steps=10, total_steps=1000)
+
+    def init(key):
+        params = _family(config).init_params(config, key)
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=opt.init(params))
+
+    state = _on(one, jax.eval_shape(init, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one)
+    return make_train_step(config, opt).lower(state, tokens, tokens)
 
 
 def _accepted_program(v5e, name):
@@ -443,6 +478,15 @@ def _accepted_program(v5e, name):
     if name.startswith("laguna"):
         return _laguna_lowered(
             v5e, bucket=8192 if name == "laguna_prefill" else None)[0]
+    if name.startswith("phi4flash"):
+        return _phi4flash_lowered(
+            v5e, bucket=4096 if name == "phi4flash_prefill" else None)[0]
+    if name.startswith("kimi_k2"):
+        return _kimi_k2_lowered(
+            v5e, bucket=8192 if name == "kimi_k2_prefill" else None)[0]
+    if name == "train_4k_step":
+        return _train_step_lowered(
+            v5e, dataclasses.replace(_mistral_7b(4), remat="save_attn"), 4, 4096)
     if name == "llama_decode":
         config, args = _decode_shapes(v5e, config=_mistral_7b(2))
         return pd.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(*args)
@@ -483,6 +527,11 @@ def test_accepted_programs_lower_to_the_parents_text(v5e, name):
     share's assignments (PR 37: a half share's block holds every assignment);
     ``ops/ssm.py`` gained the selective scan beside the Mamba-2 pair and
     ``models/paged_decode.py`` the ring arithmetic that was Laguna's (PR 38).
+``ops/attention.py``'s
+    ``custom_vjp`` took the window, its backward kernels a window's loop
+    bounds, ``ops/moe.py``'s compacted product became a ``custom_vjp`` with a
+    reverse pass, and ``train/step.py`` asks the configuration's module for
+    the loss it steps (PR 46).
     Called as the accepted families call
     them, they trace to what they were: the decode and prefill programs of
     the Llama-shaped and the hybrid family, and the flash forward and
@@ -583,12 +632,12 @@ def test_laguna_prefill_of_the_longest_bucket_compiles(v5e):
 # --------------------------------------------------------------------------- #
 # PR 38: the fourth family, a selective scan and one layer's pages for eight
 # --------------------------------------------------------------------------- #
-def _phi4flash(v5e, slots=24, bucket=None):
+def _phi4flash_lowered(v5e, slots=24, bucket=None):
     """The fourth family at Phi-4-mini-flash-reasoning's published widths and
     EIGHT of its 32 layers, one of every kind in the published order (scan,
     window, scan, window, scan, full, memory unit, cross), a slice of the
     vocabulary: its decode program over ``slots`` slots, or its one-row
-    prefill program of ``bucket``, compiled; and its cache."""
+    prefill program of ``bucket``, lowered; and its cache."""
     from ray_tpu.models import phi4flash as pf
 
     one = SingleDeviceSharding(v5e.devices[0])
@@ -612,6 +661,11 @@ def _phi4flash(v5e, slots=24, bucket=None):
             params, cache, ints, ints, shape((slots,), jnp.bool_),
             shape((slots, pages), jnp.int32),
             _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered, cache
+
+
+def _phi4flash(v5e, slots=24, bucket=None):
+    lowered, cache = _phi4flash_lowered(v5e, slots, bucket)
     return lowered.compile(), cache
 
 
@@ -656,12 +710,12 @@ def test_phi4flash_prefill_of_the_longest_bucket_has_no_attention_over_the_promp
 # --------------------------------------------------------------------------- #
 # PR 44: the fifth family, a latent page pool and two attention paths over it
 # --------------------------------------------------------------------------- #
-def _kimi_k2(v5e, bucket=None):
+def _kimi_k2_lowered(v5e, bucket=None):
     """The fifth family at Kimi-K2.6's published widths, the leading dense
     layer and TWO of its expert layers (the scan's body is compiled once
     whatever their number), 12 of the router's 384 experts and a slice of the
     vocabulary, 16 slots of 25,088 as the benchmark's cell: its decode
-    program, or its one-row prefill program of ``bucket``, compiled; and its
+    program, or its one-row prefill program of ``bucket``, lowered; and its
     cache."""
     from ray_tpu.models import kimi_k2 as km
 
@@ -686,6 +740,11 @@ def _kimi_k2(v5e, bucket=None):
             params, cache, ints, ints, shape((slots,), jnp.bool_),
             shape((slots, pages), jnp.int32),
             _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered, cache
+
+
+def _kimi_k2(v5e, bucket=None):
+    lowered, cache = _kimi_k2_lowered(v5e, bucket)
     return lowered.compile(), cache
 
 
@@ -731,3 +790,38 @@ def test_kimi_k2_prefill_of_the_longest_bucket_is_unabsorbed_and_fits(v5e):
     assert re.search(r"f32\[2048,18432\]", text)
     assert not re.search(r"f32\[24576,18432\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+
+
+# --------------------------------------------------------------------------- #
+# PR 46: the second trained family
+# --------------------------------------------------------------------------- #
+def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
+    """Mellum2-12B-A2.5B's published widths, 8 of 28 layers (two periods), 16
+    of the router's 64 experts and a quarter of the vocabulary, 2 x 8,192
+    tokens, the benchmark's optimizer: Mosaic takes the flash backward under
+    a window (``flash_window_bwd_dq`` / ``flash_window_bwd_dkv``, whose K, V,
+    q and dO of one head sit whole in VMEM: 24 MB at 8,192 rows, over the
+    default scoped limit) beside the full one; every grouped product of the
+    expert layer, forward and reverse, is XLA's grouped kernel (three forward
+    and eight in reverse a layer, the eight written twice: the first block
+    and the loop over any further ones; none is lowered to every expert over
+    every row); the scanned
+    period holds ONE layer of each kind; and the program fits the chip beside
+    6.47 GB of state."""
+    from ray_tpu.models import mellum as ml
+
+    config = ml.MellumConfig(
+        vocab_size=24576, layer_types=ml.PERIOD * 2,
+        mlp_layer_types=("sparse",) * 8, num_experts=16,
+        held_experts=(0, 16), attention_impl="flash", moe_tokens=4096)
+    compiled = _train_step_lowered(v5e, config, 2, 8192).compile()
+    text = compiled.as_text()
+    calls = [c.split(".")[0] for c in _mosaic_calls(text)]
+    assert {"flash_window_fwd", "flash_window_bwd_dq", "flash_window_bwd_dkv",
+            "attn_full"} <= set(calls), sorted(set(calls))
+    assert calls.count("flash_window_bwd_dq") == 1  # one sliding layer's body
+    assert calls.count("ragged-dot-none") == 2 * (3 + 2 * 8), calls
+    assert not re.search(r"= f32\[16,\d+,(2304|896)\]\S* convolution", text)
+    mem = compiled.memory_analysis()
+    state = mem.argument_size_in_bytes
+    assert 6.4e9 < state < 6.6e9 and mem.alias_size_in_bytes > 6.4e9

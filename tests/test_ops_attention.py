@@ -173,3 +173,47 @@ def test_flash_gradient_gqa_causal():
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+
+
+# (seq, window, block_q, block_k): one block; a window that is no multiple of
+# a block and spans several; blocks of unequal size (the dK/dV loop's last q
+# block and the dQ loop's first k block are both inside the sequence); S <
+# window (the window never bites: the full backward's answer); a sequence the
+# pad path grows
+WINDOW_GRAD_CASES = [(64, 24, 64, 64), (256, 100, 64, 64), (512, 130, 64, 128),
+                     (256, 96, 128, 64), (128, 1024, 64, 64), (200, 70, 64, 64)]
+
+
+@pytest.mark.parametrize("s,window,bq,bk", WINDOW_GRAD_CASES)
+def test_flash_window_gradient_matches_reference(s, window, bq, bk):
+    """The backward under a sliding window (``flash_window_bwd_dq`` /
+    ``flash_window_bwd_dkv``, whose loops start and stop at the blocks the
+    window admits) against ``reference_attention``'s gradient, GQA 4 / 2.
+    Tolerance 5e-5: float32 throughout with ``highest`` products, so what is
+    left is the order of the sums (the same bound as the full backward's
+    test above); a block wrongly skipped or a mask off by one moves an entry
+    by 1e-2 or more (the planted fault below)."""
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.standard_normal((2, s, 4, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, s, 2, 32)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, s, 2, 32)), jnp.float32)
+
+    def loss(fn, window):
+        def f(q, k, v):
+            out = fn(q, k, v, window)
+            return (out * out).sum()
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        flash = lambda q, k, v, w: flash_attention(  # noqa: E731
+            q, k, v, causal=True, interpret=True, block_q=bq, block_k=bk,
+            window=w)
+        ref = lambda q, k, v, w: reference_attention(  # noqa: E731
+            q, k, v, causal=True, window=w)
+        got, want = loss(flash, window), loss(ref, window)
+        off_by_one = loss(flash, window + 1)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+    if window < s:
+        worst = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(off_by_one, want))
+        assert worst > 1e-2, worst

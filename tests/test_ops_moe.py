@@ -122,3 +122,94 @@ def test_compacted_at_the_real_tile_under_jit():
     want, _ = layer("dense")
     np.testing.assert_allclose(got, want, atol=1e-5)
     assert blocks.tolist() == [[1, 0], [1, 0]]
+
+
+# --------------------------------------------------------------------------- #
+# PR 46: reverse mode through the compacted product
+# --------------------------------------------------------------------------- #
+def _grads(x, router, experts, n, impl, form, scoring, lo=0):
+    """d(sum of out * a fixed random tensor) / d(x, router, experts)."""
+    probe = jax.random.normal(jax.random.key(77), x.shape, jnp.float32)
+
+    def f(x, router, experts):
+        out = _run(x, router, experts, n, impl, form, scoring, counted=False,
+                   lo=lo)
+        return jnp.sum(out * probe)
+
+    return jax.grad(f, argnums=(0, 1, 2))(x, router, experts)
+
+
+def _assert_trees_close(got, want, atol):
+    flat_got, tree = jax.tree.flatten_with_path(got)
+    flat_want, tree_want = jax.tree.flatten_with_path(want)
+    assert tree == tree_want
+    for (path, a), (_, b) in zip(flat_got, flat_want):
+        # a gradient that says something; the correction bias moves the
+        # choice alone, and the choice has no gradient
+        assert float(jnp.abs(b).max()) > 1e-3 or "bias" in str(path), path
+        np.testing.assert_allclose(a, b, atol=atol, err_msg=str(path))
+
+
+@pytest.mark.parametrize("n,lo", [(4, 0), (2, 6), (4, 12)],
+                         ids=["quarter", "eighth", "last-quarter"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid_bias"])
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_compacted_gradient_equals_the_dense_forms(small_tiles, form, scoring,
+                                                   n, lo):
+    """``jax.grad`` through the compacted path (a ``custom_vjp`` over the
+    block loop) against the gradient JAX derives of ``_dense``: to x, to the
+    router (through the chosen weights and their normalisation) and to each
+    held expert's matrices. Float32 on both sides; 2e-5 absolute is the order
+    of the sums (the gradients' entries reach 0.1 to 10)."""
+    router, experts, x = _layer(form, scoring, n)
+    assert moe._capacity(T * K, n, R) < T * K
+    got = _grads(x, router, experts, n, "ragged", form, scoring, lo)
+    want = _grads(x, router, experts, n, "dense", form, scoring, lo)
+    _assert_trees_close(got, want, 2e-5)
+
+
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_gradient_is_dropless_under_a_routing_that_overflows_a_block(
+        small_tiles, form):
+    """Every token's every choice on the four held experts: two blocks
+    forward, two in reverse; a reverse pass that stopped after one block
+    would leave half of the tokens' and experts' gradient out."""
+    router, experts, x = _layer(form, "softmax", 4)
+    x = x.at[:, 0].set(1.0)
+    router = {"w": router["w"].at[0, :4].set(40.0).at[0, 4:].set(-40.0)}
+    assert moe._capacity(T * K, 4, R) * 2 == T * K
+    got = _grads(x, router, experts, 4, "ragged", form, "softmax")
+    want = _grads(x, router, experts, 4, "dense", form, "softmax")
+    _assert_trees_close(got, want, 2e-5)
+
+
+def test_compacted_gradient_at_the_real_tile_under_jit():
+    """2,048 tokens' 16,384 choices over 4 of 16 at the real tile: one block
+    of 8,192 rows, the reverse pass compiled (``lax.while_loop`` in both
+    directions)."""
+    router, experts, _ = _layer("swiglu", "softmax", 4, key=9)
+    x = jax.random.normal(jax.random.key(10), (2048, H), jnp.float32)
+    assert moe._capacity(2048 * 8, 4, R) == 8192 < 2048 * 8
+
+    def grads(impl):
+        def f(x, router, experts):
+            out = moe.routed_experts(
+                x, router, experts, held=(0, 4), top_k=8, scale=1.0, impl=impl,
+                scoring="softmax", form="swiglu")
+            return jnp.sum(out * out)
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2)))(x, router, experts)
+
+    _assert_trees_close(grads("ragged"), grads("dense"), 1e-4)
+
+
+def test_gradient_of_a_share_no_token_chose_is_zero_and_finite(small_tiles):
+    """A router that has walked away from this share (what training on data
+    with nothing to learn does to one chip's share within 25 steps, PERF.md 6,
+    PR 46): the reverse pass runs its first block over no group at all, and
+    what flows back is zeros, not what the products left in unowned rows."""
+    router, experts, x = _layer("swiglu", "softmax", 2)
+    x = x.at[:, 0].set(1.0)
+    router = {"w": router["w"].at[0, :2].set(-40.0).at[0, 2:].set(40.0)}
+    got = _grads(x, router, experts, 2, "ragged", "swiglu", "softmax")
+    for leaf in jax.tree.leaves(got):
+        assert not np.any(np.asarray(leaf)), leaf

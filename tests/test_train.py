@@ -152,3 +152,66 @@ def test_train_tiny_llama_e2e(trainer_env):
     assert result.error is None
     losses = [m["loss"] for m in result.metrics_history]
     assert len(losses) == 3 and losses[-1] < losses[0]
+
+
+def test_the_dense_family_through_the_seam_of_the_train_step():
+    """``train/step.py`` names no family: it asks the module that holds the
+    configuration's class for ``loss``, ``init_params`` and ``logical_axes``.
+    The dense family steps through that seam; a module whose
+    ``loss_and_counters`` wraps the dense loss steps loss for loss the same,
+    with what it counted in the step's output; the state's logical axes are
+    the family's."""
+    import dataclasses
+    import sys
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.sharding import axes_is_leaf
+    from ray_tpu.train import step as ts
+
+    assert not any(name in open(ts.__file__).read()
+                   for name in ("llama", "mellum", "Llama"))
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32, remat=None,
+                                 attention_impl="reference")
+    opt = ts.default_optimizer(lr=1e-2, warmup_steps=1, total_steps=20)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 32)), jnp.int32)
+    targets = jnp.roll(tokens, -1, axis=1)
+
+    def run(config):
+        state = ts.make_train_state_factory(config, opt)(jax.random.key(0))
+        step = ts.make_train_step(config, opt, donate=False)
+        losses = []
+        for _ in range(3):
+            state, out = step(state, tokens, targets)
+            losses.append(float(out["loss"]))
+        return losses, out
+
+    dense, out = run(cfg)
+    assert set(out) == {"loss", "grad_norm", "step"} and dense[-1] < dense[0]
+
+    # a family of its own module: the dense one's three, and a loss that counts
+    counting = types.ModuleType("a_family_that_counts")
+    counting.init_params, counting.logical_axes = llama.init_params, llama.logical_axes
+    counting.loss_and_counters = lambda params, tokens, targets, config, **kw: (
+        llama.loss(params, tokens, targets, config, **kw),
+        {"tokens_seen": jnp.int32(tokens.size)})
+    counting.Config = type("Config", (llama.LlamaConfig,),
+                           {"__module__": counting.__name__})
+    sys.modules[counting.__name__] = counting
+    try:
+        counted_cfg = counting.Config(**dataclasses.asdict(cfg))
+        counted, out = run(counted_cfg)
+        assert float(ts.make_eval_step(counted_cfg)(
+            llama.init_params(cfg, jax.random.key(0)), tokens, targets)) > 0
+    finally:
+        del sys.modules[counting.__name__]
+    assert counted == dense and int(out["tokens_seen"]) == 64
+
+    state_axes = ts.state_logical_axes(cfg, opt)
+    assert state_axes.params == llama.logical_axes(cfg)
+    moments = jax.tree.leaves(state_axes.opt_state, is_leaf=axes_is_leaf)
+    assert ("layers", "embed", "heads") in moments
