@@ -26,7 +26,13 @@ one scanned body: the layers' parameters are stacked ``[L, ...]``, read as
 compiles once. Each layer of the body is rematerialised on its own, under
 one policy (``_remat``): the flash kernel's output and log-sum-exp are kept,
 the rest is computed again; the expert product's reverse pass multiplies by
-W_up and W_gate again by itself (``ops/moe.py`` ``_compacted_bwd``).
+W_up and W_gate again by itself (``ops/moe.py`` ``_compacted_bwd``). Its
+eleven grouped products a chunk (three forward, eight in reverse: the
+cotangent back through W_down, W_up and W_gate over the stacks' LAST
+dimension, and each held expert's three matrix gradients from its own rows)
+are one Pallas kernel (``ops/grouped_matmul.py``) that reads the stacks as
+they lie: no stack is transposed, copied or re-laid for a product, and the
+rows of a block that hold no assignment are not multiplied.
 
 ``num_experts`` is the experts HELD here, ``held_experts`` which of the
 router's ``n_router_outputs`` they are; ``vocab_size`` the rows of the

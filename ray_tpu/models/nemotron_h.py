@@ -263,8 +263,10 @@ def _moe(config: NemotronHConfig, lp, y, impl: str, counted=None):
     """y: [T, h] normed. Routed experts held here + the shared expert.
     ``impl``: prefill sorts its thousands of tokens by expert ("ragged");
     decode multiplies its 128 rows with every held expert ("dense": 18.3 ms
-    a step against 71.6 for the grouped product, which re-lays every expert
-    matrix a call; my chip run, PR 29)."""
+    a step against 71.6 for XLA's grouped product of the time, which re-laid
+    every expert matrix a call; my chip run, PR 29. The grouped product is
+    since PR 47 ``ops/grouped_matmul.py``'s kernel, which re-lays nothing;
+    decode through it: not measured)."""
     routed = routed_experts(
         y, lp["router"], lp["experts"], held=config.held_experts,
         top_k=config.num_experts_per_tok, scale=config.routed_scaling_factor,

@@ -13,8 +13,9 @@ With ``held = (0, n_router_outputs)`` it is the whole layer.
 Two ways to do the products, chosen by the caller from its token count:
 
 - ``"ragged"``: assignments sorted by expert, one grouped product a side
-  (``lax.ragged_dot``, which XLA:TPU lowers to its grouped-matmul kernel and
-  which costs what the assignments need), unsorted and summed. For prefill.
+  (``ops/grouped_matmul.py``: one Pallas kernel whose visits follow the
+  experts' sizes, so it costs what the assignments need and nothing for the
+  rows of a block that hold none), unsorted and summed. For prefill.
   On a small share the sorted assignments are COMPACTED first: the held ones
   sort first, so a block of ``_capacity`` rows (a static size from the
   call's shapes: the expected held count with ``COMPACT_SLACK`` to spare)
@@ -32,8 +33,9 @@ Two ways to do the products, chosen by the caller from its token count:
   whose contracted dimension is the ragged one) and to the routing weights,
   so to the router through the chosen weights and their normalisation, and
   not through the choice. Where the block would hold every assignment (a
-  share of a half or more) the product runs uncompacted and JAX's own rules
-  for ``ragged_dot`` differentiate it.
+  share of a half or more) the product runs uncompacted and the grouped
+  product's own ``custom_vjp`` (its other two entry points) differentiates
+  it.
 - ``"dense"``: every held expert over every token, the unchosen ones weighted
   zero. A decode batch of a hundred rows touches nearly every held expert
   anyway, so the weights are read once either way and the extra
@@ -61,9 +63,15 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-# rows of a tile of XLA:TPU's grouped product: ``ragged-dot-metadata`` plans
-# ``rows / 512 + groups - 1`` tiles (read from the compiled program, v5e)
-RAGGED_TILE = 512
+from ray_tpu.ops.grouped_matmul import ROW_TILE, grouped_dot, grouped_outer
+
+# what ``_capacity`` rounds a block to: the rows one visit of the grouped
+# product fetches and writes (``ops/grouped_matmul.py``; a tile is fetched
+# only if an expert holds a row of it, so a block's unheld tail costs nothing
+# there, only in the passes around the product). It was the tile of XLA:TPU's
+# ``ragged-dot``, which planned ``rows / 512 + groups - 1`` of them whatever
+# the sizes
+RAGGED_TILE = ROW_TILE
 # the compacted product's block over the EXPECTED held count, T * top_k *
 # held / router outputs. A Laguna prefill chunk (32,768 assignments, 32 of
 # 256 held, 4,096 +- 60 expected under a seeded router) took 2.26 ms a layer
@@ -185,16 +193,14 @@ def _capacity(assignments: int, n: int, width: int) -> int:
 
 def _grouped(rows, experts, sizes, form: str):
     """rows sorted by expert, ``sizes`` rows each -> the experts' outputs in
-    float32, unweighted. Rows past ``sum(sizes)`` belong to no group:
-    whatever the grouped product left there is not to be read."""
-    up = jax.lax.ragged_dot(
-        rows, experts["w_up"], sizes, preferred_element_type=jnp.float32)
-    gate = jax.lax.ragged_dot(
-        rows, experts["w_gate"], sizes, preferred_element_type=jnp.float32) \
+    float32, unweighted. Rows past ``sum(sizes)`` belong to no group: the
+    grouped product writes nothing there, and what the memory held (not
+    zeros, maybe not numbers) is not to be read."""
+    up = grouped_dot(rows, experts["w_up"], sizes)
+    gate = grouped_dot(rows, experts["w_gate"], sizes) \
         if form == "swiglu" else None
-    return jax.lax.ragged_dot(_act(up, gate, form).astype(rows.dtype),
-                              experts["w_down"], sizes,
-                              preferred_element_type=jnp.float32)
+    return grouped_dot(_act(up, gate, form).astype(rows.dtype),
+                       experts["w_down"], sizes)
 
 
 def _ragged(x, experts, local, weights, n: int, form: str, width: int):
@@ -208,8 +214,11 @@ def _ragged(x, experts, local, weights, n: int, form: str, width: int):
         order = jnp.argsort(flat, stable=True)   # held first, by expert
         sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
         down = _grouped(x[order // k], experts, sizes, form)     # [T*k, h]
+        # the rows past the held ones are masked BEFORE they meet the
+        # weights: what the product left there may be no number, and a
+        # product's cotangent would hand it on to the weights times zero
         valid = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
-        down = jnp.where(valid, down * weights.reshape(-1)[order][:, None], 0.0)
+        down = jnp.where(valid, down, 0.0) * weights.reshape(-1)[order][:, None]
         back = jnp.zeros((t * k,), jnp.int32).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32))
         return jnp.sum(down[back].reshape(t, k, -1), axis=1).astype(x.dtype), \
@@ -284,38 +293,20 @@ def _compacted_fwd(x, matrices, weights, local, n, form, cap):
         (x, matrices, weights, local)
 
 
-def _grouped_t(rows, w, sizes):
-    """``rows`` [cap, b] sorted by expert x ``w`` [E, a, b] over b -> [cap,
-    a]: a cotangent back through a grouped product to its rows. The matrices
-    are transposed first (66 MB a stack at Mellum's widths): XLA:TPU has its
-    grouped kernel for ``ragged_dot``'s own dimension numbers, and lowers a
-    contraction over the matrices' LAST dimension to every expert over every
-    row (a [E, cap, a] convolution: 9.7 GB at 16 x 65,536 x 2,304; read from
-    the compiled program, PR 46)."""
-    return jax.lax.ragged_dot(rows, jnp.swapaxes(w, 1, 2), sizes,
-                              preferred_element_type=jnp.float32)
-
-
-def _grouped_outer(rows, cot, sizes):
-    """``rows`` [cap, a], ``cot`` [cap, b], both sorted by expert -> [E, a,
-    b]: each expert's matrix gets the product over ITS rows (the contracted
-    dimension is the ragged one); rows past ``sum(sizes)`` belong to none."""
-    return jax.lax.ragged_dot_general(
-        rows, cot, sizes, jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=(0,), rhs_group_dimensions=()),
-        preferred_element_type=jnp.float32)
-
-
 def _compacted_bwd(n, form, cap, kept, cotangent):
     """The reverse of ``_compacted``, dropless as it is: the same blocks in
     the same order, as many as the held assignments need. A block gathers its
     rows, multiplies them by W_up (and W_gate) again, and takes the cotangent
     of the result's rows back through the three grouped products: to the
-    rows (scattered onto their tokens), to each held expert's matrices (a
-    product over the ragged dimension) and to the routing weights (``<act,
+    rows (the product over the matrices' LAST dimension, the stacks read as
+    they lie; scattered onto their tokens), to each held expert's matrices
+    (the outer product over ITS rows) and to the routing weights (``<act,
     g W_down^T>``: the unweighted output is never formed). Nothing flows to
-    ``local``: the choice is no function of the scores in reverse mode."""
+    ``local``: the choice is no function of the scores in reverse mode.
+    Rows past the held ones hold whatever the products left there, through
+    every elementwise pass: nothing zeroes them, because the outer product
+    reads no row that is not its expert's and everything else a row feeds
+    is that row's own, which the scatters drop."""
     x, matrices, weights, local = kept
     names = _names(form)
     experts = dict(zip(names, matrices))
@@ -330,31 +321,29 @@ def _compacted_bwd(n, form, cap, kept, cotangent):
         the assignments they are and what flows to their weights, what flows
         to the experts' matrices); rows past the held ones go nowhere."""
         at, which, token, sizes = _block_rows(b, order, starts, ends, cap, k)
-        valid = (at < ends[-1])[:, None]
+        valid = at < ends[-1]
         rows = x[token]
-        up = jax.lax.ragged_dot(rows, experts["w_up"], sizes,
-                                preferred_element_type=jnp.float32)
-        gate = jax.lax.ragged_dot(rows, experts["w_gate"], sizes,
-                                  preferred_element_type=jnp.float32) \
+        up = grouped_dot(rows, experts["w_up"], sizes)
+        gate = grouped_dot(rows, experts["w_gate"], sizes) \
             if form == "swiglu" else None
         act, act_vjp = jax.vjp(lambda u, v: _act(u, v, form), up, gate)
         w = flat_weights[which][:, None]
-        g_rows = jnp.where(valid, g[token], 0).astype(dt)
-        back = _grouped_t(g_rows, experts["w_down"], sizes)      # [cap, f]
+        g_rows = g[token].astype(dt)
+        back = grouped_dot(g_rows, experts["w_down"], sizes,
+                           transposed=True)                  # [cap, f]
         d_w = jnp.sum(act * back, axis=-1)
         d_up, d_gate = act_vjp(back * w)
-        # rows past the held ones hold whatever the products left there
-        clean = lambda a: jnp.where(valid, a, 0).astype(dt)  # noqa: E731
-        d_up = clean(d_up)
-        grads = {"w_down": _grouped_outer(clean(act * w), g_rows, sizes),
-                 "w_up": _grouped_outer(rows, d_up, sizes)}
-        d_rows = _grouped_t(d_up, experts["w_up"], sizes)
+        d_up = d_up.astype(dt)
+        grads = {"w_down": grouped_outer((act * w).astype(dt), g_rows, sizes),
+                 "w_up": grouped_outer(rows, d_up, sizes)}
+        d_rows = grouped_dot(d_up, experts["w_up"], sizes, transposed=True)
         if form == "swiglu":
-            d_gate = clean(d_gate)
-            grads["w_gate"] = _grouped_outer(rows, d_gate, sizes)
-            d_rows = d_rows + _grouped_t(d_gate, experts["w_gate"], sizes)
-        return (jnp.where(valid[:, 0], token, t), d_rows,
-                jnp.where(valid[:, 0], which, t * k), d_w, grads)
+            d_gate = d_gate.astype(dt)
+            grads["w_gate"] = grouped_outer(rows, d_gate, sizes)
+            d_rows = d_rows + grouped_dot(d_gate, experts["w_gate"], sizes,
+                                          transposed=True)
+        return (jnp.where(valid, token, t), d_rows,
+                jnp.where(valid, which, t * k), d_w, grads)
 
     def block(carry):
         b, dx, d_weights, d_experts = carry

@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from ray_tpu.models import paged_decode as pd
 from ray_tpu.models.llama import LlamaConfig, llama_init
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.attention import flash_attention
 
 B, S, HQ, HKV, D = 8, 2048, 16, 4, 128
@@ -46,7 +47,12 @@ def v5e():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # the grouped expert product as a TPU compiles it, not interpreted as
+    # this host's default backend would have it
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(grouped_matmul, "INTERPRET", False)
     yield topo
+    monkeypatch.undo()
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
 
@@ -419,17 +425,26 @@ ACCEPTED_PROGRAMS_SHA = {
         "56b64beacc9154f146f8b1a8da0aa5e62f924fd7ae92fae43f0858d9b65dbdaf",
     "hybrid_decode": 
         "f10679f1eeeb5ec1bfe2bc568c804d4679e7872e9bb464c87d2a1e95c255fedc",
-    "hybrid_prefill": 
-        "7aa2520cbdeae4f4b4180246a934686474e3e9e6e42299a5f5bf1400c3b313c7",
+    # the four programs that run a grouped expert product were taken again on
+    # PR 47's tree, which means to change them: the product is
+    # ``ops/grouped_matmul.py``'s Pallas kernel and no longer ``lax.ragged_dot``
+    # (the hashes until then: hybrid_prefill 7aa2520cbdeae4f4b4180246a934686474e3e9e6e42299a5f5bf1400c3b313c7,
+    # laguna_decode 373b08f3cc3b766fdc496f79d802db901e990b84f58b47925d5908068f7daca8,
+    # laguna_prefill 3e289e0cde0ba08fe7ac7fa176851ce1125cd6f0259b31ee1a7bef23c84592e7,
+    # kimi_k2_prefill b7f872f96286a46d66093e5ada82ae7e7fab520ab104a9138ca1cddfe0ea87b4).
+    # The decode programs that multiply every held expert (``_dense``) and
+    # everything without experts stand as they were
+    "hybrid_prefill":
+        "2db757e59e520551b8312ed20a85fef6376b7ce87e999ac39870e0045691a163",
     "flash_fwd_bwd": 
         "f7e7ab589c6105498b819b990dbda5cda3c09b4307791981cd966bc05680969c",
     # the window family, taken on the parent commit of PR 38 (e8140a7), whose
     # ring arithmetic PR 38 moved into models/paged_decode.py for the fourth
     # family to share
     "laguna_decode":
-        "373b08f3cc3b766fdc496f79d802db901e990b84f58b47925d5908068f7daca8",
+        "17cf42f26931dfe7f6026da714cbeb46ac8eb1314c9260d1e0921801a0e6efba",
     "laguna_prefill":
-        "3e289e0cde0ba08fe7ac7fa176851ce1125cd6f0259b31ee1a7bef23c84592e7",
+        "4a8333ce8d08809ce98370b6027536d0ac8ff3857b423b6b5eed91bd8703cd94",
     # taken on the parent commit of PR 46 (4d75b23) and equal on its tree:
     # the other two served families that call ``ops/moe.py`` or the flash
     # forward (a window, ``lengths``, a v width of its own), and ``train_4k``'s
@@ -442,7 +457,7 @@ ACCEPTED_PROGRAMS_SHA = {
     "kimi_k2_decode":
         "e2f027d18fa901b132225c84a6c94ceab87d85f9b128477801a75be1e7ee7b51",
     "kimi_k2_prefill":
-        "b7f872f96286a46d66093e5ada82ae7e7fab520ab104a9138ca1cddfe0ea87b4",
+        "be933e12300872caca90f81967c21985f71455d5df3d4c93eedb6099a1a49dfc",
     "train_4k_step":
         "93be954fac40c8e84e755380ac97bd779def24c763d3786568e814f9cc165399",
 }
@@ -531,7 +546,9 @@ def test_accepted_programs_lower_to_the_parents_text(v5e, name):
     ``custom_vjp`` took the window, its backward kernels a window's loop
     bounds, ``ops/moe.py``'s compacted product became a ``custom_vjp`` with a
     reverse pass, and ``train/step.py`` asks the configuration's module for
-    the loss it steps (PR 46).
+    the loss it steps (PR 46). ``ops/moe.py``'s grouped products became
+    ``ops/grouped_matmul.py``'s kernel (PR 47), which MEANS to change the four
+    programs that run one; their hashes were taken again there.
     Called as the accepted families call
     them, they trace to what they were: the decode and prefill programs of
     the Llama-shaped and the hybrid family, and the flash forward and
@@ -593,7 +610,7 @@ def test_laguna_decode_holds_both_kernels_and_moves_no_pool(v5e):
     donated cache that the program aliases and never copies."""
     compiled, cache = _laguna(v5e)
     calls = [c for c in _mosaic_calls(compiled.as_text())
-             if "ragged" not in c]  # the grouped expert products are XLA's
+             if "ragged" not in c]  # the grouped expert products' kernel
     assert sorted(c.split(".")[0] for c in calls) == [
         "paged_attention", "paged_attention_window"], calls
     assert compiled.memory_analysis().alias_size_in_bytes == _pool_bytes(cache)
@@ -624,7 +641,10 @@ def test_laguna_prefill_of_the_longest_bucket_compiles(v5e):
     choices = lg.MOE_PREFILL_TOKENS * 8
     block = moe._capacity(choices, 32, 256)
     assert block == 8192
-    assert re.search(rf"ragged-dot\S* = f32\[{block},2048\]", text)
+    # since PR 47 ``ops/grouped_matmul.py``'s kernel, under the name the
+    # benchmark's metrics read
+    assert re.search(rf"ragged-dot-rows\S* = f32\[{block},2048\]", text)
+    assert "ragged-dot-none" not in text
     assert not re.search(rf"f32\[{choices},\d+\]", text)
     assert not re.search(rf"bf16\[{choices},\d+\]", text)
 
@@ -795,6 +815,70 @@ def test_kimi_k2_prefill_of_the_longest_bucket_is_unabsorbed_and_fits(v5e):
 # --------------------------------------------------------------------------- #
 # PR 46: the second trained family
 # --------------------------------------------------------------------------- #
+# an expert stack of Mellum's copied or transposed (the parent's reverse pass
+# transposed one before each product over the matrices' last dimension, and
+# XLA's grouped kernel had some re-laid)
+STACK_COPY = r"= bf16\[16,(896,2304|2304,896)\]\S* (copy|transpose)\("
+
+
+def test_mellum_expert_chunk_compiles_with_the_grouped_kernel(v5e):
+    """One chunk of Mellum's expert layer at the real widths (4,096 tokens,
+    16 of the router's 64 experts, 2304 x 896, bfloat16: a block of 16,384
+    sorted rows), forward and ``jax.value_and_grad``: Mosaic takes the grouped
+    product's three entry points inside its scoped VMEM (an expert's matrix
+    whole, double buffered; the outer product's float32 result block, 8.3
+    MB); each reaches the compiled program as ``ragged-dot-... = f32[``, one
+    float32 result, which is what ``experts_grouped_roofline``,
+    ``experts_train_share`` and ``experts_glue_train_share`` key on; the
+    reverse pass is the first block and the loop over further ones, 2 + 3 + 3
+    products each; and the program holds neither a [16, rows, width]
+    convolution (XLA's lowering of a contraction over the stacks' last
+    dimension) nor a copy of a transposed stack."""
+    from ray_tpu.ops import moe
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    tokens, h, f, held, width, k = 4096, 2304, 896, 16, 64, 8
+    block = moe._capacity(tokens * k, held, width)
+    assert block == 16384
+    args = (shape((tokens, h), jnp.bfloat16),
+            {"w": shape((h, width), jnp.bfloat16)},
+            {"w_up": shape((held, h, f), jnp.bfloat16),
+             "w_gate": shape((held, h, f), jnp.bfloat16),
+             "w_down": shape((held, f, h), jnp.bfloat16)})
+
+    def layer(x, router, experts):
+        return moe.routed_experts(
+            x, router, experts, held=(0, held), top_k=k, scale=1.0,
+            impl="ragged", scoring="softmax", form="swiglu")
+
+    def names(text):
+        return sorted(c.split(".")[0] for c in _mosaic_calls(text))
+
+    forward = _compiled_text(layer, *args)
+    assert names(forward) == ["ragged-dot-rows"] * 3
+    text = _compiled_text(jax.value_and_grad(
+        lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        *args)
+    # outside a scanned, rematerialised layer the first block's calls carry
+    # the transformation in front of their name (``transpose_jvp_ragged-...``);
+    # inside one, as the train step has them, they do not
+    # (``test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192``)
+    kind = r"ragged-dot-(?:rows-t|rows|outer)"
+    assert sorted(re.search(kind, c).group() for c in names(text)) == \
+        ["ragged-dot-outer"] * 6 + ["ragged-dot-rows"] * 7 \
+        + ["ragged-dot-rows-t"] * 6
+    results = re.findall(rf"%?[\w\-]*{kind}[\w.]* = (\S+?)\{{", forward + text)
+    assert len(results) == 3 + 19 and set(results) == {
+        f"f32[{block},{f}]", f"f32[{block},{h}]", f"f32[{held},{h},{f}]",
+        f"f32[{held},{f},{h}]"}
+    for program in (forward, text):
+        assert "ragged-dot-none" not in program
+        assert not re.search(r"= f32\[16,\d+,(2304|896)\]\S* convolution",
+                             program)
+        assert not re.search(STACK_COPY, program)
+
+
 def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     """Mellum2-12B-A2.5B's published widths, 8 of 28 layers (two periods), 16
     of the router's 64 experts and a quarter of the vocabulary, 2 x 8,192
@@ -802,10 +886,12 @@ def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     a window (``flash_window_bwd_dq`` / ``flash_window_bwd_dkv``, whose K, V,
     q and dO of one head sit whole in VMEM: 24 MB at 8,192 rows, over the
     default scoped limit) beside the full one; every grouped product of the
-    expert layer, forward and reverse, is XLA's grouped kernel (three forward
-    and eight in reverse a layer, the eight written twice: the first block
-    and the loop over any further ones; none is lowered to every expert over
-    every row); the scanned
+    expert layer, forward and reverse, is ``ops/grouped_matmul.py``'s kernel
+    under a name that starts ``ragged-dot`` (three forward and eight in
+    reverse a layer, the eight written twice: the first block and the loop
+    over any further ones; since PR 47 none is XLA's, none is lowered to
+    every expert over every row, and no expert stack is copied, transposed
+    or re-laid for one); the scanned
     period holds ONE layer of each kind; and the program fits the chip beside
     6.47 GB of state."""
     from ray_tpu.models import mellum as ml
@@ -820,8 +906,13 @@ def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     assert {"flash_window_fwd", "flash_window_bwd_dq", "flash_window_bwd_dkv",
             "attn_full"} <= set(calls), sorted(set(calls))
     assert calls.count("flash_window_bwd_dq") == 1  # one sliding layer's body
-    assert calls.count("ragged-dot-none") == 2 * (3 + 2 * 8), calls
+    grouped = [c for c in calls if c.startswith("ragged-dot")]
+    assert len(grouped) == 2 * (3 + 2 * 8), calls
+    assert {c: grouped.count(c) for c in set(grouped)} == {
+        "ragged-dot-rows": 2 * (3 + 2 * 2), "ragged-dot-rows-t": 2 * 2 * 3,
+        "ragged-dot-outer": 2 * 2 * 3}
     assert not re.search(r"= f32\[16,\d+,(2304|896)\]\S* convolution", text)
+    assert not re.search(STACK_COPY, text)
     mem = compiled.memory_analysis()
     state = mem.argument_size_in_bytes
     assert 6.4e9 < state < 6.6e9 and mem.alias_size_in_bytes > 6.4e9

@@ -3,12 +3,18 @@ assignments are compacted to a block of ``_capacity`` rows before anything
 as wide as the model is touched, and a routing that holds more than a block
 runs the block again (PR 37). Float32 on the CPU, against ``_dense`` (every
 held expert over every token) and against the uncompacted product, which is
-what ``_ragged`` emits where the block would hold every assignment."""
+what ``_ragged`` emits where the block would hold every assignment.
+
+Since PR 47 every grouped product is ``ops/grouped_matmul.py``'s Pallas
+kernel, here in interpret mode (which hands out NaN for what a kernel never
+wrote): the tests above it run over it unchanged, and its three entry points
+are held to ``lax.ragged_dot`` / ``ragged_dot_general`` on their own."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import grouped_matmul as gm
 from ray_tpu.ops import moe
 
 T, K, H, F, R = 96, 4, 16, 8, 16
@@ -35,8 +41,13 @@ def _run(x, router, experts, n, impl, form, scoring, counted=True, lo=0):
 
 @pytest.fixture
 def small_tiles(monkeypatch):
-    """Blocks of whole 8-row tiles, so that 384 assignments are several."""
+    """Blocks of whole 8-row tiles, so that 384 assignments are several; the
+    grouped product's row tiles 32 rows and its pieces 16, so that a block is
+    several tiles and an expert's rows several pieces."""
     monkeypatch.setattr(moe, "RAGGED_TILE", 8)
+    monkeypatch.setattr(gm, "ROW_TILE", 32)
+    monkeypatch.setattr(gm, "DOT_PIECE", 16)
+    monkeypatch.setattr(gm, "OUTER_PIECE", 16)
 
 
 def test_capacity_is_the_expected_share_with_slack_in_whole_tiles():
@@ -213,3 +224,155 @@ def test_gradient_of_a_share_no_token_chose_is_zero_and_finite(small_tiles):
     got = _grads(x, router, experts, 2, "ragged", "swiglu", "softmax")
     for leaf in jax.tree.leaves(got):
         assert not np.any(np.asarray(leaf)), leaf
+
+
+@pytest.mark.parametrize("n", [8, 16], ids=["half", "whole"])
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_uncompacted_gradient_equals_the_dense_forms(small_tiles, form, n):
+    """A share of a half or more runs uncompacted, and the grouped product's
+    own ``custom_vjp`` differentiates it. The rows past the held ones hold no
+    number in interpret mode: nothing of them reaches a gradient."""
+    router, experts, x = _layer(form, "softmax", n)
+    assert moe._capacity(T * K, n, R) >= T * K
+    got = _grads(x, router, experts, n, "ragged", form, "softmax")
+    want = _grads(x, router, experts, n, "dense", form, "softmax")
+    _assert_trees_close(got, want, 2e-5)
+
+
+# --------------------------------------------------------------------------- #
+# PR 47: the grouped product is one Pallas kernel with three entry points
+# --------------------------------------------------------------------------- #
+ROWS, A, B = 96, 24, 40
+SIZES = {
+    # 32-row tiles: the first group spans three, the last three share one
+    "spans-tiles-and-shares-one": [75, 3, 2, 4],
+    "sums-to-less-than-the-rows": [10, 5, 23, 5],
+    "an-empty-group-in-the-middle": [12, 0, 0, 30, 7],
+    "no-held-row-at-all": [0, 0, 0],
+    "every-row-held": [32, 16, 48],
+    "one-row-each": [1, 1, 1, 1, 1, 1],
+}
+
+
+def _outer_reference(rows, cot, sizes):
+    return jax.lax.ragged_dot_general(
+        rows, cot, sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=(0,), rhs_group_dimensions=()),
+        preferred_element_type=jnp.float32)
+
+
+def _operands(sizes, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.key(len(sizes)), 3)
+    return (jax.random.normal(keys[0], (ROWS, A), dtype),
+            jax.random.normal(keys[1], (ROWS, B), dtype),
+            jax.random.normal(keys[2], (len(sizes), A, B), dtype),
+            jnp.asarray(sizes, jnp.int32))
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_grouped_dot_is_ragged_dot_on_the_rows_of_the_groups(small_tiles, case,
+                                                             dtype, atol):
+    """Rows x matrices, and the same over the matrices' LAST dimension with
+    no transposed copy, against ``lax.ragged_dot``: float32 results whatever
+    the operands. What callers rely on past ``sum(sizes)``: NOTHING is
+    written there (the interpreter's NaN stands), so they mask by ``where``;
+    and a row that holds no number spoils no other row."""
+    rows, cot, w, sizes = _operands(SIZES[case], dtype)
+    held = int(sizes.sum())
+    for got, want in [
+            (gm.grouped_dot(rows, w, sizes),
+             jax.lax.ragged_dot(rows, w, sizes,
+                                preferred_element_type=jnp.float32)),
+            (gm.grouped_dot(cot, w, sizes, True),
+             jax.lax.ragged_dot(cot, jnp.swapaxes(w, 1, 2), sizes,
+                                preferred_element_type=jnp.float32))]:
+        assert got.dtype == jnp.float32 and got.shape == want.shape
+        np.testing.assert_allclose(got[:held], want[:held], atol=atol)
+        assert np.all(np.isnan(np.asarray(got[held:])))
+    spoiled = rows.at[held:].set(jnp.nan)
+    got = gm.grouped_dot(spoiled, w, sizes)
+    assert np.all(np.isfinite(np.asarray(got[:held])))
+
+
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_grouped_outer_reads_only_its_experts_rows(small_tiles, case):
+    """Each expert's matrix from ITS rows against ``ragged_dot_general`` over
+    the ragged dimension (whose operands must be zeroed past the sum by
+    hand): the kernel reads nothing there, on either operand, so what is not
+    a number there reaches no result; an expert with no row gets zeros."""
+    rows, cot, _, sizes = _operands(SIZES[case])
+    valid = (jnp.arange(ROWS) < sizes.sum())[:, None]
+    want = _outer_reference(jnp.where(valid, rows, 0), jnp.where(valid, cot, 0),
+                            sizes)
+    for r, c in [(jnp.where(valid, rows, jnp.nan), cot),
+                 (rows, jnp.where(valid, cot, jnp.nan))]:
+        got = gm.grouped_outer(r, c, sizes)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(got, want, atol=1e-5)
+    empty = np.asarray(sizes) == 0
+    assert not np.any(np.asarray(got)[empty])
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["plain", "last-dim"])
+def test_grouped_dot_differentiates_through_its_other_entry_points(
+        small_tiles, transposed):
+    rows, cot, w, sizes = _operands(SIZES["sums-to-less-than-the-rows"])
+    valid = (jnp.arange(ROWS) < sizes.sum())[:, None]
+    x = cot if transposed else rows
+
+    def loss(product):
+        return lambda x, w: jnp.sum(jnp.where(valid, product(x, w), 0.0) ** 2)
+
+    got = jax.grad(loss(lambda x, w: gm.grouped_dot(x, w, sizes, transposed)),
+                   argnums=(0, 1))(x, w)
+    want = jax.grad(loss(lambda x, w: jax.lax.ragged_dot(
+        x, jnp.swapaxes(w, 1, 2) if transposed else w, sizes)),
+        argnums=(0, 1))(x, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+
+
+def test_rows_that_are_no_multiple_of_a_tile_and_one_short_tile(monkeypatch):
+    """50 rows under 32-row tiles are padded to 64; 40 rows under the real
+    512-row tile are one tile of 48 (a decode tick's call)."""
+    rows, _, w, _ = _operands([0] * 4)
+    sizes = jnp.asarray([7, 0, 20, 11], jnp.int32)
+    want = jax.lax.ragged_dot(rows[:50], w, sizes)
+    got = gm.grouped_dot(rows[:40], w, jnp.asarray([7, 0, 20, 11], jnp.int32))
+    np.testing.assert_allclose(got[:38], want[:38], atol=1e-5)
+    assert got.shape == (40, B)
+    monkeypatch.setattr(gm, "ROW_TILE", 32)
+    got = gm.grouped_dot(rows[:50], w, sizes)
+    np.testing.assert_allclose(got[:38], want[:38], atol=1e-5)
+    assert got.shape == (50, B)
+    outer = gm.grouped_outer(rows[:50], want.at[38:].set(jnp.nan), sizes)
+    np.testing.assert_allclose(
+        outer, _outer_reference(rows[:50], want.at[38:].set(0.0), sizes),
+        atol=1e-4)
+
+
+def test_the_plan_visits_the_tiles_that_hold_rows_and_no_other():
+    """16,384 rows holding 8,500 under 512-row tiles: 17 tiles hold a row,
+    and the visits are those plus one for each expert boundary inside a
+    tile; the steps past the plan's end repeat its last."""
+    sizes = jnp.asarray([500] * 15 + [1000], jnp.int32)
+    group, tile, starts, ends, total = gm._visits(sizes, 16384, 512, False)
+    assert group.shape == tile.shape == (32 + 16 - 1,)
+    n = int(total[0])
+    assert int(tile[n - 1]) == 16 and int(tile.max()) == 16  # 8,500 rows
+    pairs = {(int(g), int(t)) for g, t in zip(group[:n], tile[:n])}
+    want = {(g, t) for g in range(16)
+            for t in range(int(starts[g]) // 512, (int(ends[g]) - 1) // 512 + 1)}
+    assert pairs == want and len(pairs) == n == 17 + 15
+    assert group[n:].tolist() == [15] * (47 - n)
+    assert np.all(np.diff(np.asarray(tile)) >= 0)
+    # an expert with no row: not visited by the products over rows, visited
+    # once by the outer product (which has zeros to write there)
+    sizes = jnp.asarray([600, 0, 0, 425], jnp.int32)
+    assert int(gm._visits(sizes, 2048, 512, False)[4][0]) == 2 + 2
+    group, _, _, _, total = gm._visits(sizes, 2048, 512, True)
+    assert int(total[0]) == 6 and group[:6].tolist() == [0, 0, 1, 2, 3, 3]
