@@ -19,8 +19,11 @@ Two ways to do the products, chosen by the caller from its token count:
   On a small share the sorted assignments are COMPACTED first: the held ones
   sort first, so a block of ``_capacity`` rows (a static size from the
   call's shapes: the expected held count with ``COMPACT_SLACK`` to spare)
-  is gathered, multiplied, weighted and added to its tokens, and not the
-  ``T * top_k`` rows of which the share holds an eighth. Still dropless: a
+  is gathered, multiplied, and its rows, weighted, are summed onto their
+  tokens (``ops/rows_to_tokens.py``: one Pallas kernel that walks the
+  block's rows and keeps the result in VMEM, where XLA's row scatter-add ran
+  at a ninth of the memory's bandwidth), and not the ``T * top_k`` rows of
+  which the share holds an eighth. Still dropless: a
   routing that leans on this share runs the same body over the next block
   until every held assignment is covered. REVERSE MODE: the block loop is a
   ``lax.while_loop`` whose trip count follows the routing, which JAX cannot
@@ -29,7 +32,8 @@ Two ways to do the products, chosen by the caller from its token count:
   blocks, as many as the held assignments need (dropless in both
   directions), keeps nothing of the forward but the layer's inputs,
   multiplies each block's rows by W_up (and W_gate) again, and gives the
-  cotangent to the tokens, to the held experts' matrices (a grouped product
+  cotangent to the tokens (the same kernel over the block's row
+  cotangents), to the held experts' matrices (a grouped product
   whose contracted dimension is the ragged one) and to the routing weights,
   so to the router through the chosen weights and their normalisation, and
   not through the choice. Where the block would hold every assignment (a
@@ -64,6 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.grouped_matmul import ROW_TILE, grouped_dot, grouped_outer
+from ray_tpu.ops.rows_to_tokens import rows_to_tokens
 
 # what ``_capacity`` rounds a block to: the rows one visit of the grouped
 # product fetches and writes (``ops/grouped_matmul.py``; a tile is fetched
@@ -273,15 +278,24 @@ def _compacted(x, matrices, weights, local, n: int, form: str, cap: int):
         b, acc = carry
         at, which, token, sizes = _block_rows(b, order, starts, ends, cap, k)
         down = _grouped(x[token], experts, sizes, form)          # [cap, h]
-        down = down * flat_weights[which][:, None]
-        # a segment sum by token; rows past the held ones go nowhere
-        token = jnp.where(at < ends[-1], token, t)
-        return b + 1, acc.at[token].add(down, mode="drop")
+        # a segment sum by token onto the carry, whose memory the result
+        # takes; rows past the held ones go nowhere, and what they hold is
+        # never read (``ops/rows_to_tokens.py``). What the carry holds
+        # counts from the second block on: the first starts from zeros
+        # without reading it
+        return b + 1, rows_to_tokens(
+            down, jnp.where(at < ends[-1], token, t), t, flat_weights[which],
+            onto=(acc, b > 0))
 
+    # ONE block body in the program, run at least once (with no held
+    # assignment at all its groups are empty, its rows go nowhere and the
+    # result is zeros): a first block written out beside the loop, as the
+    # reverse pass has it, made a warm start of Laguna's five prefill
+    # programs 1.4 s longer each (PERF.md 6, PR 48)
     blocks, out = jax.lax.while_loop(
-        lambda carry: carry[0] * cap < ends[-1], block,
+        lambda carry: (carry[0] == 0) | (carry[0] * cap < ends[-1]), block,
         (jnp.int32(0), jnp.zeros((t, x.shape[1]), jnp.float32)))
-    return out.astype(x.dtype), jnp.stack([1, jnp.maximum(blocks - 1, 0)])
+    return out.astype(x.dtype), jnp.stack([1, blocks - 1])
 
 
 def _compacted_fwd(x, matrices, weights, local, n, form, cap):
@@ -299,14 +313,16 @@ def _compacted_bwd(n, form, cap, kept, cotangent):
     rows, multiplies them by W_up (and W_gate) again, and takes the cotangent
     of the result's rows back through the three grouped products: to the
     rows (the product over the matrices' LAST dimension, the stacks read as
-    they lie; scattered onto their tokens), to each held expert's matrices
+    they lie; summed onto their tokens by ``rows_to_tokens``, as the forward
+    sums its outputs), to each held expert's matrices
     (the outer product over ITS rows) and to the routing weights (``<act,
     g W_down^T>``: the unweighted output is never formed). Nothing flows to
     ``local``: the choice is no function of the scores in reverse mode.
     Rows past the held ones hold whatever the products left there, through
     every elementwise pass: nothing zeroes them, because the outer product
     reads no row that is not its expert's and everything else a row feeds
-    is that row's own, which the scatters drop."""
+    is that row's own, which goes nowhere: ``rows_to_tokens`` selects such a
+    row away and the weights' scatter drops it."""
     x, matrices, weights, local = kept
     names = _names(form)
     experts = dict(zip(names, matrices))
@@ -348,7 +364,7 @@ def _compacted_bwd(n, form, cap, kept, cotangent):
     def block(carry):
         b, dx, d_weights, d_experts = carry
         token, d_rows, which, d_w, grads = reverse(b)
-        return (b + 1, dx.at[token].add(d_rows, mode="drop"),
+        return (b + 1, dx + rows_to_tokens(d_rows, token, t),
                 d_weights.at[which].set(d_w, mode="drop"),
                 {name: d_experts[name] + grads[name] for name in names})
 
@@ -361,7 +377,7 @@ def _compacted_bwd(n, form, cap, kept, cotangent):
     _, dx, d_weights, d_experts = jax.lax.while_loop(
         lambda carry: carry[0] * cap < ends[-1], block,
         (jnp.int32(1),
-         jnp.zeros(x.shape, jnp.float32).at[token].add(d_rows, mode="drop"),
+         rows_to_tokens(d_rows, token, t),
          jnp.zeros((t * k,), jnp.float32).at[which].set(d_w, mode="drop"),
          grads))
     d_matrices = tuple(d_experts[name].astype(experts[name].dtype)

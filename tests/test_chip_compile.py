@@ -141,6 +141,20 @@ def _mosaic_calls(text):
         r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
 
 
+def _without_combines(calls):
+    """(the calls that are not ``ops/rows_to_tokens.py``'s kernel, those that
+    are): it keeps its name, ``rows-to-tokens``, in a compiled program."""
+    combines = [c for c in calls if c.startswith("rows-to-tokens")]
+    return [c for c in calls if c not in combines], combines
+
+
+def _row_scatters(text, h):
+    """The scatters of a compiled program whose operand is float32 rows of
+    ``h``: XLA's row scatter-add of an expert block onto its tokens, which
+    ``rows-to-tokens`` replaced (PR 48)."""
+    return re.findall(rf"= f32\[\d+,{h}\]\S* scatter\(.*", text)
+
+
 @pytest.mark.parametrize("family", ["llama", "hybrid"])
 def test_decode_attention_kernel_compiles_and_keeps_its_name(v5e, family):
     """The repo's kernel (``ops/paged_attention.py``) inside the decode
@@ -443,8 +457,18 @@ ACCEPTED_PROGRAMS_SHA = {
     # family to share
     "laguna_decode":
         "17cf42f26931dfe7f6026da714cbeb46ac8eb1314c9260d1e0921801a0e6efba",
+    # the two served programs that run the COMPACTED product (a share under
+    # a half: ``_compacted``) were taken again on PR 48's tree, which means
+    # to change them: a block's rows reach their tokens through
+    # ``ops/rows_to_tokens.py``'s kernel and no longer through XLA's row
+    # scatter-add, and the forward's loop runs its body at least once (the hashes
+    # until then: laguna_prefill 4a8333ce8d08809ce98370b6027536d0ac8ff3857b423b6b5eed91bd8703cd94,
+    # kimi_k2_prefill be933e12300872caca90f81967c21985f71455d5df3d4c93eedb6099a1a49dfc).
+    # ``hybrid_prefill`` (a half share) and ``laguna_decode`` (192 choices
+    # under one block) run uncompacted and stand as they were, as does
+    # everything else
     "laguna_prefill":
-        "4a8333ce8d08809ce98370b6027536d0ac8ff3857b423b6b5eed91bd8703cd94",
+        "2a61cf13059123931f5c4a544a16545afbdd24d8954b11a76a6b302629b50a39",
     # taken on the parent commit of PR 46 (4d75b23) and equal on its tree:
     # the other two served families that call ``ops/moe.py`` or the flash
     # forward (a window, ``lengths``, a v width of its own), and ``train_4k``'s
@@ -457,7 +481,7 @@ ACCEPTED_PROGRAMS_SHA = {
     "kimi_k2_decode":
         "e2f027d18fa901b132225c84a6c94ceab87d85f9b128477801a75be1e7ee7b51",
     "kimi_k2_prefill":
-        "be933e12300872caca90f81967c21985f71455d5df3d4c93eedb6099a1a49dfc",
+        "ca8fb536ba4b158f31a25d6746ec298545da3e50b5cb41a57cd46be15b5e2ee8",
     "train_4k_step":
         "93be954fac40c8e84e755380ac97bd779def24c763d3786568e814f9cc165399",
 }
@@ -548,7 +572,10 @@ def test_accepted_programs_lower_to_the_parents_text(v5e, name):
     reverse pass, and ``train/step.py`` asks the configuration's module for
     the loss it steps (PR 46). ``ops/moe.py``'s grouped products became
     ``ops/grouped_matmul.py``'s kernel (PR 47), which MEANS to change the four
-    programs that run one; their hashes were taken again there.
+    programs that run one; their hashes were taken again there. The compacted
+    product's sums of rows onto tokens became ``ops/rows_to_tokens.py``'s
+    kernel (PR 48), which means to change the two served programs that run
+    the compacted product (Laguna's and Kimi's prefill) and no other.
     Called as the accepted families call
     them, they trace to what they were: the decode and prefill programs of
     the Llama-shaped and the hybrid family, and the flash forward and
@@ -611,6 +638,7 @@ def test_laguna_decode_holds_both_kernels_and_moves_no_pool(v5e):
     compiled, cache = _laguna(v5e)
     calls = [c for c in _mosaic_calls(compiled.as_text())
              if "ragged" not in c]  # the grouped expert products' kernel
+    # (192 choices under one block run uncompacted: no ``rows-to-tokens``)
     assert sorted(c.split(".")[0] for c in calls) == [
         "paged_attention", "paged_attention_window"], calls
     assert compiled.memory_analysis().alias_size_in_bytes == _pool_bytes(cache)
@@ -636,8 +664,14 @@ def test_laguna_prefill_of_the_longest_bucket_compiles(v5e):
     compiled, _ = _laguna(v5e, bucket=24576)
     text = compiled.as_text()
     calls = [c for c in _mosaic_calls(text) if "ragged" not in c]
+    calls, combines = _without_combines(calls)
     assert any(c.startswith("flash_window_fwd") for c in calls), calls
     assert len(calls) == 2
+    # since PR 48 a block's rows reach their tokens through
+    # ``ops/rows_to_tokens.py``'s kernel (ONE in the sparse layer's loop
+    # over its blocks), and no row scatter is left
+    assert len(combines) == 1, combines
+    assert not _row_scatters(text, 2048)
     choices = lg.MOE_PREFILL_TOKENS * 8
     block = moe._capacity(choices, 32, 256)
     assert block == 8192
@@ -804,7 +838,11 @@ def test_kimi_k2_prefill_of_the_longest_bucket_is_unabsorbed_and_fits(v5e):
     compiled, _ = _kimi_k2(v5e, bucket=24576)
     text = compiled.as_text()
     calls = [c for c in _mosaic_calls(text) if "ragged" not in c]
+    calls, combines = _without_combines(calls)
     assert calls and all(c.startswith("flash_mla_fwd") for c in calls), calls
+    # ONE in the scanned expert layer's loop over its blocks
+    assert len(combines) == 1, combines
+    assert not _row_scatters(text, 7168)
     assert re.search(r"flash_mla_fwd\S* = bf16\[1,16,24576,128\]", text)
     assert km.PREFILL_ROWS == 2048
     assert re.search(r"f32\[2048,18432\]", text)
@@ -855,8 +893,10 @@ def test_mellum_expert_chunk_compiles_with_the_grouped_kernel(v5e):
     def names(text):
         return sorted(c.split(".")[0] for c in _mosaic_calls(text))
 
+    # ONE block body in the forward's loop: three products and (PR 48) one
+    # sum of the rows onto their tokens, which takes the loop's carry
     forward = _compiled_text(layer, *args)
-    assert names(forward) == ["ragged-dot-rows"] * 3
+    assert names(forward) == ["ragged-dot-rows"] * 3 + ["rows-to-tokens"]
     text = _compiled_text(jax.value_and_grad(
         lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
         *args)
@@ -864,19 +904,60 @@ def test_mellum_expert_chunk_compiles_with_the_grouped_kernel(v5e):
     # the transformation in front of their name (``transpose_jvp_ragged-...``);
     # inside one, as the train step has them, they do not
     # (``test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192``)
-    kind = r"ragged-dot-(?:rows-t|rows|outer)"
+    kind = r"ragged-dot-(?:rows-t|rows|outer)|rows-to-tokens"
     assert sorted(re.search(kind, c).group() for c in names(text)) == \
         ["ragged-dot-outer"] * 6 + ["ragged-dot-rows"] * 7 \
-        + ["ragged-dot-rows-t"] * 6
+        + ["ragged-dot-rows-t"] * 6 + ["rows-to-tokens"] * 3
+    kind = r"ragged-dot-(?:rows-t|rows|outer)"
     results = re.findall(rf"%?[\w\-]*{kind}[\w.]* = (\S+?)\{{", forward + text)
     assert len(results) == 3 + 19 and set(results) == {
         f"f32[{block},{f}]", f"f32[{block},{h}]", f"f32[{held},{h},{f}]",
         f"f32[{held},{f},{h}]"}
+    # the rows' sums onto the tokens, forward and reverse: the kernel under
+    # its own name (what ``experts_glue_train_share`` counts it under: an
+    # operand of [16384,2304]), and no row scatter-add left
+    combines = re.findall(r"%?[\w\-]*rows-to-tokens[\w.]* = (\S+?)\{", forward + text)
+    assert combines == [f"f32[{tokens},{h}]"] * (1 + 3)
     for program in (forward, text):
+        assert not _row_scatters(program, h)
         assert "ragged-dot-none" not in program
         assert not re.search(r"= f32\[16,\d+,(2304|896)\]\S* convolution",
                              program)
         assert not re.search(STACK_COPY, program)
+
+
+@pytest.mark.parametrize("cell,cap,h,t,blocks", [
+    ("train_moe_8k", 16384, 2304, 4096, 2),
+    ("serve_mla_longdoc", 1024, 7168, 2048, 4),
+    ("serve_window_longctx", 8192, 2048, 4096, 2)])
+def test_rows_to_tokens_compiles_at_the_cells_shapes(v5e, cell, cap, h, t,
+                                                     blocks):
+    """``ops/rows_to_tokens.py`` at the three cells that run the compacted
+    product (Mellum's chunk, Kimi's piece, Laguna's chunk), weighted and
+    onto a loop's carry as the forward calls it (a block of the carry's
+    columns by one DMA from HBM into the result's block, whose memory the
+    result takes) and plain as the reverse does: Mosaic takes a load, an
+    add and a store at a dynamic sublane of a result block that stays in
+    VMEM (18.9, 14.7 and 16.8 MB, double buffered), ``token`` and the factor
+    in scalar memory (64 KB each at 16,384 rows); the program keeps the
+    kernel's name, which no reader of ``^ragged-dot`` matches."""
+    from ray_tpu.ops import rows_to_tokens as rt
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    assert h // rt._tiling(cap, t, h, rt.ROW_TILE, rt.ROWS_A_TRIP,
+                           rt.RESULT_BLOCK_BYTES)[1] == blocks
+    rows, token = shape((cap, h), jnp.float32), shape((cap,), jnp.int32)
+    carry = (shape((t, h), jnp.float32), shape((), jnp.bool_))
+    for factor, onto in ((shape((cap,), jnp.float32), carry), (None, None)):
+        text = _compiled_text(
+            lambda rows, token, factor, onto: rt.rows_to_tokens(
+                rows, token, t, factor, onto),
+            rows, token, factor, onto)
+        assert [c.split(".")[0] for c in _mosaic_calls(text)] == [
+            "rows-to-tokens"], cell
+        assert re.search(rf"rows-to-tokens\S* = f32\[{t},{h}\]", text)
+        assert not _row_scatters(text, h)
 
 
 def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
@@ -911,6 +992,11 @@ def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     assert {c: grouped.count(c) for c in set(grouped)} == {
         "ragged-dot-rows": 2 * (3 + 2 * 2), "ragged-dot-rows-t": 2 * 2 * 3,
         "ragged-dot-outer": 2 * 2 * 3}
+    # since PR 48 the rows reach their tokens through ``rows-to-tokens``,
+    # forward (one block body in its loop) and reverse (the first block and
+    # the loop), and no row scatter-add of float32 [*, 2304] is left
+    assert calls.count("rows-to-tokens") == 2 * (1 + 2)
+    assert not _row_scatters(text, 2304)
     assert not re.search(r"= f32\[16,\d+,(2304|896)\]\S* convolution", text)
     assert not re.search(STACK_COPY, text)
     mem = compiled.memory_analysis()

@@ -8,7 +8,12 @@ what ``_ragged`` emits where the block would hold every assignment.
 Since PR 47 every grouped product is ``ops/grouped_matmul.py``'s Pallas
 kernel, here in interpret mode (which hands out NaN for what a kernel never
 wrote): the tests above it run over it unchanged, and its three entry points
-are held to ``lax.ragged_dot`` / ``ragged_dot_general`` on their own."""
+are held to ``lax.ragged_dot`` / ``ragged_dot_general`` on their own.
+
+Since PR 48 a block's rows reach their tokens through
+``ops/rows_to_tokens.py``'s kernel, forward and reverse, interpreted here
+too: the tests above run over it unchanged, and the kernel alone is held to
+the row scatter-add it replaced."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +21,7 @@ import pytest
 
 from ray_tpu.ops import grouped_matmul as gm
 from ray_tpu.ops import moe
+from ray_tpu.ops import rows_to_tokens as rt
 
 T, K, H, F, R = 96, 4, 16, 8, 16
 
@@ -43,8 +49,12 @@ def _run(x, router, experts, n, impl, form, scoring, counted=True, lo=0):
 def small_tiles(monkeypatch):
     """Blocks of whole 8-row tiles, so that 384 assignments are several; the
     grouped product's row tiles 32 rows and its pieces 16, so that a block is
-    several tiles and an expert's rows several pieces."""
+    several tiles and an expert's rows several pieces; the combine's row
+    tiles 32 rows too, and its result in blocks of 128 columns of up to 96
+    tokens."""
     monkeypatch.setattr(moe, "RAGGED_TILE", 8)
+    monkeypatch.setattr(rt, "ROW_TILE", 32)
+    monkeypatch.setattr(rt, "RESULT_BLOCK_BYTES", 96 * 128 * 4)
     monkeypatch.setattr(gm, "ROW_TILE", 32)
     monkeypatch.setattr(gm, "DOT_PIECE", 16)
     monkeypatch.setattr(gm, "OUTER_PIECE", 16)
@@ -376,3 +386,90 @@ def test_the_plan_visits_the_tiles_that_hold_rows_and_no_other():
     assert int(gm._visits(sizes, 2048, 512, False)[4][0]) == 2 + 2
     group, _, _, _, total = gm._visits(sizes, 2048, 512, True)
     assert int(total[0]) == 6 and group[:6].tolist() == [0, 0, 1, 2, 3, 3]
+
+
+# --------------------------------------------------------------------------- #
+# PR 48: a block's rows onto their tokens (``ops/rows_to_tokens.py``)
+# --------------------------------------------------------------------------- #
+def _tokens_of(case, cap, t, rng):
+    """int32 [cap]: the token each row of the block goes to, ``t`` nowhere."""
+    held = cap * 5 // 8
+    token = rng.integers(0, t, cap)
+    if case == "top-k-rows-and-none":
+        # token 3 holds K rows (one an expert, far apart in the block),
+        # token 5 none
+        token = np.where(np.isin(token, (3, 5)), 7, token)
+        token[np.arange(K) * (held // K)] = 3
+    if case == "no-held-row":
+        held = 0
+    if case == "nowhere-in-the-middle":
+        token[::3] = t
+    token[held:] = t
+    return token.astype(np.int32)
+
+
+# cap, t, h -> (rows of a tile, columns of a block of the result): 32 rows
+# under the file's small tiles, 512 at the real tile
+BLOCKS = {
+    "nan-to-nowhere": (64, 24, 128, (32, 128)),
+    "top-k-rows-and-none": (128, 24, 128, (32, 128)),
+    "no-held-row": (64, 24, 128, (32, 128)),
+    "nowhere-in-the-middle": (96, 40, 128, (32, 128)),
+    "no-multiple-of-a-tile": (75, 21, 128, (32, 128)),      # 3 tiles, 21 padded
+    "three-blocks-of-columns": (64, 96, 384, (32, 128)),
+    "a-width-of-no-whole-lanes": (64, 24, 200, (32, 200)),  # one block, whole
+    "the-real-tile-under-jit": (1200, 300, 256, (512, 256)),  # 3 tiles
+}
+
+
+@pytest.mark.parametrize("weighted,onto", [
+    (False, None), (True, None), (True, "kept"), (False, "not-kept")],
+    ids=["plain", "weighted", "onto-a-carry", "onto-a-carry-not-kept"])
+@pytest.mark.parametrize("case", sorted(BLOCKS))
+def test_rows_to_tokens_is_the_row_scatter_add(request, case, weighted, onto):
+    """Against ``zeros.at[token].add(rows, mode="drop")``: every row to
+    nowhere holds NaN, as the grouped product may leave it, and no NaN
+    reaches a result; a token with ``K`` rows, a token with none, a block
+    with no held row at all (zeros), ``cap`` and ``t`` that are no multiple
+    of a tile, a result walked in blocks of columns, a width that is no
+    multiple of 128 (one block as wide as it is). Onto a carry: what it
+    holds is added where it is kept, and is never read (NaN here) where it
+    is not."""
+    cap, t, h, tiling = BLOCKS[case]
+    small = tiling[0] == 32
+    if small:
+        request.getfixturevalue("small_tiles")
+    assert rt._tiling(cap, t, h, rt.ROW_TILE, rt.ROWS_A_TRIP,
+                      rt.RESULT_BLOCK_BYTES) == tiling
+    rng = np.random.default_rng(sorted(BLOCKS).index(case))
+    token = _tokens_of(case, cap, t, rng)
+    rows = rng.standard_normal((cap, h)).astype(np.float32)
+    rows[token == t] = np.nan
+    factor = rng.standard_normal(cap).astype(np.float32) if weighted else None
+    weighed = rows * factor[:, None] if weighted else rows
+    want = jnp.zeros((t, h), jnp.float32).at[token].add(weighed, mode="drop")
+    carry = None
+    if onto == "kept":
+        sums = rng.standard_normal((t, h)).astype(np.float32)
+        carry, want = (jnp.asarray(sums), jnp.asarray(True)), want + sums
+    elif onto:
+        carry = (jnp.full((t, h), jnp.nan, jnp.float32), jnp.asarray(False))
+    run = rt.rows_to_tokens if small else jax.jit(
+        rt.rows_to_tokens, static_argnums=2)
+    got = run(jnp.asarray(rows), jnp.asarray(token), t,
+              None if factor is None else jnp.asarray(factor), carry)
+    assert got.shape == (t, h) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    counts = np.bincount(token, minlength=t + 1)[:t]
+    if onto != "kept":
+        assert not np.any(np.asarray(got)[counts == 0])  # exact zeros
+    if case == "top-k-rows-and-none":
+        assert counts[3] == K and counts[5] == 0
+    if case == "no-held-row":
+        assert not counts.any()
+
+
+def test_rows_to_tokens_sums_float32_rows_only():
+    with pytest.raises(ValueError, match="float32 rows"):
+        rt.rows_to_tokens(jnp.zeros((8, 128), jnp.bfloat16),
+                          jnp.zeros((8,), jnp.int32), 4)
