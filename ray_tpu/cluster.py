@@ -62,6 +62,7 @@ class Cluster:
         self._gcs_persist_dir = (os.path.join(self.session_dir, "gcs_state")
                                  if gcs_persist else None)
         self.nodes: List[NodeHandle] = []
+        self._removed: List[NodeHandle] = []  # killed by remove_node
         self._start_gcs()
         if initialize_head:
             self.add_node(is_head=True, **(head_node_args or {}))
@@ -162,6 +163,7 @@ class Cluster:
         node.kill()
         if node in self.nodes:
             self.nodes.remove(node)
+            self._removed.append(node)
 
     def wait_for_nodes(self, count: Optional[int] = None, timeout: float = 30.0) -> None:
         from ray_tpu.core.rpc import SyncRpcClient
@@ -217,6 +219,29 @@ class Cluster:
                     except OSError:
                         pass
         except OSError:
+            pass
+        # An agent the GCS could not name (it was dead by now, or too slow
+        # to answer in 2 s) keeps its arena. Every agent of ours is dead:
+        # reap them (a zombie's pid still counts as alive) and take the
+        # arenas whose pidfile names one of them, and no one else's.
+        agents = set()
+        for node in self.nodes + self._removed:
+            agents.add(node.proc.pid)
+            try:
+                node.proc.wait(timeout=2.0)
+            except Exception:  # noqa: BLE001 - unkillable: its arena stays
+                pass
+        try:
+            from ray_tpu.core.shm_store import arena_owner, find_orphan_arenas
+
+            for path in find_orphan_arenas():
+                if arena_owner(path) in agents:
+                    for p in (path, path + ".pid"):
+                        try:
+                            os.unlink(p)
+                        except OSError:
+                            pass
+        except Exception:  # noqa: BLE001 - best-effort, as at startup
             pass
 
     def __enter__(self) -> "Cluster":

@@ -19,8 +19,7 @@ import cloudpickle
 
 from ray_tpu import exceptions as exc
 from ray_tpu.core import serialization
-from ray_tpu.core.config import (columnar_exchange_enabled, config,
-                                 gcs_recovery_enabled)
+from ray_tpu.core.config import columnar_exchange_enabled, config
 from ray_tpu.core.ids import ActorID, JobID, NodeID, ObjectID, PlacementGroupID
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.resources import (
@@ -102,7 +101,6 @@ class ClusterRuntime(CoreRuntime):
         self._workdir_hashes: Dict[str, str] = {}
         self._actor_clients: Dict[str, SyncRpcClient] = {}
         self._actor_cache: Dict[str, Dict[str, Any]] = {}
-        self._dispatchers: Dict[str, Any] = {}
         self._agent_clients: Dict[str, SyncRpcClient] = {agent_address: self.agent}
         self._lock = threading.Lock()
         self._bg = concurrent.futures.ThreadPoolExecutor(max_workers=16,
@@ -119,16 +117,14 @@ class ClusterRuntime(CoreRuntime):
         self._submit_lock = threading.Lock()  # user threads may race get()/remote()
         self._shutting_down = False
         # ---- pipelined control plane (ISSUE r06) ----
-        from ray_tpu.core.config import inline_max_bytes, pipeline_enabled
+        from ray_tpu.core.config import inline_max_bytes
 
-        self.pipelined = pipeline_enabled()
         self._inline_max = inline_max_bytes()
         # submission coalescing: specs buffer here and flush as ONE
         # submit_task_batch RPC by size or a ~1 ms window
         self._submit_buf: List[Dict[str, Any]] = []
         self._submit_buf_bytes = 0
         self._submit_event = threading.Event()
-        self._submit_flusher: Optional[threading.Thread] = None
         self.submit_batches_sent = 0   # observability + tests
         self.tasks_submitted = 0
         # inline completion cache: results that NEVER touched the arena
@@ -157,11 +153,9 @@ class ClusterRuntime(CoreRuntime):
         self._caller_streams: Dict[str, "_CallerStream"] = {}
         # batched actor-call ref pins/unpins: one FIFO thread preserves
         # pin-before-unpin order per task while coalescing into pin_tasks/
-        # unpin_tasks RPCs (the lockstep path pays one GCS round trip per
-        # call for each)
+        # unpin_tasks RPCs
         self._refop_buf: List[Tuple[str, Dict[str, Any]]] = []
         self._refop_event = threading.Event()
-        self._refop_thread: Optional[threading.Thread] = None
         # GCS crash-restart recovery (core/recovery/envelope.py): epoch
         # observation rides the holder-heartbeat ack; the reconnect hook
         # fires the catch-up (sealed-channel poll + ref re-assertion) the
@@ -170,24 +164,20 @@ class ClusterRuntime(CoreRuntime):
 
         self._envelope = RetryEnvelope()
         self._recovery_lock = threading.Lock()
-        if gcs_recovery_enabled():
-            self.gcs.add_reconnect_hook(
-                lambda: self._spawn_gcs_recovery("gcs client reconnected"))
-        if self.pipelined:
-            self._submit_flusher = threading.Thread(
-                target=self._submit_flush_loop, daemon=True,
-                name=f"submit-flush-{self.client_id[2:10]}")
-            self._submit_flusher.start()
-            self._refop_thread = threading.Thread(
-                target=self._refop_flush_loop, daemon=True,
-                name=f"refop-flush-{self.client_id[2:10]}")
-            self._refop_thread.start()
-            try:
-                self.gcs.subscribe(f"sealed:{self.client_id}",
-                                   self._on_sealed_event)
-            except Exception:  # noqa: BLE001 - pushes are an optimization;
-                # get()/wait() fall back to the polling paths without them
-                logger.warning("sealed-event subscription failed", exc_info=True)
+        self.gcs.add_reconnect_hook(
+            lambda: self._spawn_gcs_recovery("gcs client reconnected"))
+        threading.Thread(
+            target=self._submit_flush_loop, daemon=True,
+            name=f"submit-flush-{self.client_id[2:10]}").start()
+        threading.Thread(
+            target=self._refop_flush_loop, daemon=True,
+            name=f"refop-flush-{self.client_id[2:10]}").start()
+        try:
+            self.gcs.subscribe(f"sealed:{self.client_id}",
+                               self._on_sealed_event)
+        except Exception:  # noqa: BLE001 - pushes are an optimization;
+            # get()/wait() fall back to the polling paths without them
+            logger.warning("sealed-event subscription failed", exc_info=True)
 
     # ------------------------------------------------------------- objects
     def _store_admission_call(self, method: str, **params):
@@ -215,7 +205,7 @@ class ClusterRuntime(CoreRuntime):
         w = global_worker()
         oid = w.next_put_id()
         payload, refs = serialization.pack(value)
-        if self.pipelined and refs:
+        if refs:
             # refs nested inside the stored value escape this process with
             # the container: materialize any inline-only values first
             self._promote_inline([r.id.hex() for r in refs])
@@ -226,7 +216,7 @@ class ClusterRuntime(CoreRuntime):
                 "put_object", object_id=oid.hex(), payload=bytes(payload),
                 contained=[r.id.hex() for r in refs] or None,
             )
-            if self.pipelined and len(payload) <= self._inline_max:
+            if len(payload) <= self._inline_max:
                 # the putter already HAS the bytes: cache them so a local
                 # get() is a dict lookup, no RPC and no arena read. Marked
                 # promoted — the value is sealed in the arena already.
@@ -264,31 +254,13 @@ class ClusterRuntime(CoreRuntime):
 
     def _put_via_rpc(self, oid: ObjectID, payload,
                      contained: Optional[List[str]]) -> None:
-        """Stream a large put into the agent store. Raw plane: chunk
-        payloads ride raw frames (memoryview straight to the socket, no
-        per-chunk bytes() copy or msgpack encode) with a window of sends in
-        flight instead of one serial round trip per chunk; the agent's
-        cached-writer ingest seals + registers once every byte lands.
-        RTPU_RAW_TRANSFER=0 restores the serial in-band path."""
-        from ray_tpu.core.config import raw_transfer_enabled
-
+        """Stream a large put into the agent store: chunk payloads ride raw
+        frames (memoryview straight to the socket, no per-chunk bytes() copy
+        or msgpack encode) with a window of sends in flight; the agent's
+        cached-writer ingest seals + registers once every byte lands."""
         size = len(payload)
         view = memoryview(payload)
         chunk = config.fetch_chunk_bytes
-        if not raw_transfer_enabled():
-            sent = 0
-            while True:
-                n = min(chunk, size - sent)
-                last = sent + n >= size
-                self.agent.call(
-                    "receive_chunk", object_id=oid.hex(), total_size=size,
-                    offset=sent, data=bytes(view[sent:sent + n]),
-                    contained=contained if last else None,
-                    timeout=120.0,
-                )
-                sent += n
-                if last:
-                    return
         from collections import deque
 
         window = max(1, int(config.transfer_window_chunks))
@@ -341,28 +313,6 @@ class ClusterRuntime(CoreRuntime):
         except Exception:  # noqa: BLE001 - log mirroring is best-effort
             logger.warning("worker-log stream unavailable", exc_info=True)
 
-    def _read_via_rpc(self, oid: ObjectID, size: int) -> bytes:
-        from ray_tpu.core.config import raw_transfer_enabled
-
-        if raw_transfer_enabled():
-            return self._read_via_raw(oid, size)
-        data = bytearray()
-        chunk = config.fetch_chunk_bytes
-        while len(data) < size:
-            try:
-                data += self.agent.call(
-                    "read_chunk", object_id=oid.hex(), offset=len(data),
-                    length=min(chunk, size - len(data)), timeout=120.0,
-                )
-            except RpcError as e:
-                if e.remote_type == "KeyError":
-                    # evicted between the metadata reply and this chunk:
-                    # surface as the same transient condition the shm path
-                    # raises so get()'s re-ensure retry loop handles it
-                    raise FileNotFoundError(str(e)) from e
-                raise
-        return bytes(data)
-
     def _read_via_raw(self, oid: ObjectID, size: int) -> bytes:
         """Client-mode chunked read over raw frames: payload bytes land
         straight in the destination buffer (no msgpack decode, no per-chunk
@@ -395,6 +345,9 @@ class ClusterRuntime(CoreRuntime):
                     res = fut.result()
                 except RpcError as e:
                     if e.remote_type == "KeyError":
+                        # evicted between the metadata reply and this chunk:
+                        # the same transient condition the shm path raises,
+                        # so get()'s re-ensure retry loop handles it
                         raise FileNotFoundError(str(e)) from e
                     raise
                 except TimeoutError:
@@ -412,7 +365,7 @@ class ClusterRuntime(CoreRuntime):
     def _read_local(self, oid: ObjectID, size: int, is_error: bool,
                     offset: Optional[int] = None) -> Any:
         if self.remote_data_plane:
-            value = serialization.unpack(self._read_via_rpc(oid, size),
+            value = serialization.unpack(self._read_via_raw(oid, size),
                                          zero_copy=True)
         else:
             reader = ShmReader(oid, size, self.node_hex, offset=offset)
@@ -634,7 +587,7 @@ class ClusterRuntime(CoreRuntime):
             if h in seen:
                 continue
             seen.add(h)
-            if not (self.pipelined and self._resolve_cached(h, resolved)):
+            if not self._resolve_cached(h, resolved):
                 todo.append(h)
         if not todo:
             # everything was in this process already (inline results, a
@@ -642,10 +595,9 @@ class ClusterRuntime(CoreRuntime):
             return [resolved[h] for h in ids]
         blocked = self._notify_blocked(True)
         try:
-            if self.pipelined:
-                # push phase: completions stream in over the sealed-event
-                # channel (and actor-call replies); zero RPCs while they flow
-                todo = self._await_pushed(todo, deadline, resolved)
+            # push phase: completions stream in over the sealed-event
+            # channel (and actor-call replies); zero RPCs while they flow
+            todo = self._await_pushed(todo, deadline, resolved)
             if todo:
                 self._get_via_ensure(todo, deadline, resolved)
             return [resolved[h] for h in ids]
@@ -717,8 +669,11 @@ class ClusterRuntime(CoreRuntime):
                 break
         return [h for h in todo if h in pending]
 
-    def _get_via_ensure(self, ids: List[str], deadline: Optional[float],
-                        resolved: Dict[str, Any]) -> None:
+    def _ensure_batch(self, ids: List[str], deadline: Optional[float],
+                      resolved: Dict[str, Any]
+                      ) -> Tuple[List[str], List[Dict[str, Any]]]:
+        """Make ``ids`` local: the ids the cache did not serve meanwhile, and
+        the agent's record of each."""
         # One batched RPC: the agent pulls every object concurrently
         # (reference: plasma batched Get, src/ray/core_worker/
         # store_provider/plasma_store_provider.cc). Issued in bounded
@@ -727,13 +682,12 @@ class ClusterRuntime(CoreRuntime):
         # and a timeout=None get still survives connection hiccups.
         store_full_retries = 0
         while True:
-            if self.pipelined:
-                # a pushed completion may land while we poll — and an
-                # inline-only actor result NEVER appears in the store, so
-                # this re-check is what ultimately serves it here
-                ids = [h for h in ids if not self._resolve_cached(h, resolved)]
-                if not ids:
-                    return
+            # a pushed completion may land while we poll — and an
+            # inline-only actor result NEVER appears in the store, so
+            # this re-check is what ultimately serves it here
+            ids = [h for h in ids if not self._resolve_cached(h, resolved)]
+            if not ids:
+                return [], []
             remaining = None if deadline is None else deadline - time.monotonic()
             if remaining is not None and remaining <= 0:
                 raise exc.GetTimeoutError(
@@ -774,6 +728,11 @@ class ClusterRuntime(CoreRuntime):
                 if info.get("error_type") == "ObjectStoreFullError":
                     raise exc.ObjectStoreFullError(info["error"])
                 raise exc.ObjectLostError(h, info["error"])
+        return ids, infos
+
+    def _get_via_ensure(self, ids: List[str], deadline: Optional[float],
+                        resolved: Dict[str, Any]) -> None:
+        for h, info in zip(*self._ensure_batch(ids, deadline, resolved)):
             oid = ObjectID.from_hex(h)
             for attempt in range(4):
                 try:
@@ -784,14 +743,16 @@ class ClusterRuntime(CoreRuntime):
                 except FileNotFoundError:
                     # arena slot evicted between the metadata reply and
                     # the copy (or mid-copy): the object may still live
-                    # in spill — re-ensure and retry with fresh metadata
+                    # in spill — re-ensure and retry with fresh metadata.
+                    # Through the same loop as the first ensure: a restore
+                    # into a full arena is as transient here as there.
                     if attempt == 3:
                         raise exc.ObjectLostError(
                             h, "evicted repeatedly during read")
-                    info = self.agent.call(
-                        "ensure_local", object_id=h,
-                        timeout_s=10.0, timeout=15.0,
-                    )
+                    again = self._ensure_batch([h], deadline, resolved)[1]
+                    if not again:
+                        break  # a pushed payload served it meanwhile
+                    info = again[0]
 
     def _notify_blocked(self, blocked: bool) -> bool:
         """Within a worker: tell the agent this worker is blocked in get()
@@ -813,10 +774,7 @@ class ClusterRuntime(CoreRuntime):
         self._barrier_submit_acks()
         ids = [r.id.hex() for r in refs]
         deadline = None if timeout is None else time.monotonic() + timeout
-        if self.pipelined:
-            ready_set = self._wait_pushed(ids, num_returns, deadline)
-        else:
-            ready_set = self._wait_via_rpc(ids, num_returns, deadline)
+        ready_set = self._wait_pushed(ids, num_returns, deadline)
         ready, not_ready = [], []
         for r in refs:
             if r.id.hex() in ready_set and len(ready) < num_returns:
@@ -874,31 +832,6 @@ class ClusterRuntime(CoreRuntime):
                 return ready
             if remaining is not None and remaining <= attempt_s:
                 return ready
-
-    def _wait_via_rpc(self, ids: List[str], num_returns: int,
-                      deadline: Optional[float]) -> set:
-        # bounded chunks, like get(): one infinite RPC would hang forever if
-        # its response frame is lost (agent restart, connection blip) — a
-        # re-sent wait is idempotent
-        while True:
-            remaining = None if deadline is None else deadline - time.monotonic()
-            attempt_s = 10.0 if remaining is None else max(0.0, min(remaining, 10.0))
-            try:
-                ready_ids = self.agent.call(
-                    "wait_objects", object_ids=ids, num_returns=num_returns,
-                    timeout=attempt_s + 10.0, timeout_s=attempt_s,
-                )
-            except TimeoutError:
-                if remaining is not None and remaining <= attempt_s:
-                    ready_ids = []
-                    break
-                continue
-            if len(ready_ids) >= min(num_returns, len(ids)):
-                break
-            if remaining is not None and remaining <= attempt_s:
-                break
-        return (set(ready_ids[:num_returns]) if len(ready_ids) > num_returns
-                else set(ready_ids))
 
     def free(self, refs: Sequence[ObjectRef]) -> None:
         for r in refs:
@@ -1075,8 +1008,7 @@ class ClusterRuntime(CoreRuntime):
                     ack = self.gcs.call("holder_heartbeat",
                                         holder=self.client_id)
                     epoch = ack.get("epoch") if isinstance(ack, dict) else None
-                    if self._envelope.observe_epoch(epoch) \
-                            and gcs_recovery_enabled():
+                    if self._envelope.observe_epoch(epoch):
                         self._spawn_gcs_recovery(
                             f"gcs epoch bumped to {epoch}")
             except Exception:  # noqa: BLE001 - sync is advisory; retry next tick
@@ -1218,13 +1150,12 @@ class ClusterRuntime(CoreRuntime):
 
     def _spec_dict(self, spec: TaskSpec, args: tuple, kwargs: dict) -> Dict[str, Any]:
         payload, _refs = serialization.pack((args, kwargs))
-        if self.pipelined:
-            # any argument ref whose value lives only in this process's
-            # inline cache must be materialized in the cluster before anyone
-            # else tries to resolve it (top-level deps AND nested refs)
-            self._promote_inline(
-                [d.hex() for d in spec.dependencies()]
-                + [r.id.hex() for r in _refs])
+        # any argument ref whose value lives only in this process's
+        # inline cache must be materialized in the cluster before anyone
+        # else tries to resolve it (top-level deps AND nested refs)
+        self._promote_inline(
+            [d.hex() for d in spec.dependencies()]
+            + [r.id.hex() for r in _refs])
         sd = {
             "runtime_env": self._prepare_runtime_env(spec.runtime_env),
             "task_id": spec.task_id.binary().hex(),
@@ -1250,22 +1181,18 @@ class ClusterRuntime(CoreRuntime):
         # a task holder) BEFORE accepting — see agent.rpc_submit_task
         sd["holder"] = self.client_id
         self.tasks_submitted += 1
-        if self.pipelined:
-            if not spec.generator:
-                # expected pushed completions: get() stays on the channel
-                # for these instead of polling the agent
-                with self._seal_cond:
-                    for r in sd["returns"]:
-                        self._pending_task_returns[r] = True
-                    while len(self._pending_task_returns) > 200000:
-                        self._pending_task_returns.pop(
-                            next(iter(self._pending_task_returns)))
-            # coalescing buffer: specs flush as ONE submit_task_batch RPC by
-            # size or the ~1 ms window (the flusher thread)
-            self._enqueue_submit(sd)
-        else:
-            with self._submit_lock:
-                self._submit_acks.append(self.agent.call_async("submit_task", spec=sd))
+        if not spec.generator:
+            # expected pushed completions: get() stays on the channel
+            # for these instead of polling the agent
+            with self._seal_cond:
+                for r in sd["returns"]:
+                    self._pending_task_returns[r] = True
+                while len(self._pending_task_returns) > 200000:
+                    self._pending_task_returns.pop(
+                        next(iter(self._pending_task_returns)))
+        # coalescing buffer: specs flush as ONE submit_task_batch RPC by
+        # size or the ~1 ms window (the flusher thread)
+        self._enqueue_submit(sd)
         self._reap_submit_acks()
         if spec.generator:
             # dynamic returns: item holders are registered at stream_put time;
@@ -1333,8 +1260,7 @@ class ClusterRuntime(CoreRuntime):
         """Wait for every in-flight submit to be accepted (and its deps
         pinned). Called before get()/wait() so a dropped submit surfaces as
         an exception instead of a hang."""
-        if self.pipelined:
-            self._flush_submits()  # buffered specs must join the barrier
+        self._flush_submits()  # buffered specs must join the barrier
         while True:
             fut = self._pop_ack(only_done=False)
             if fut is None:
@@ -1433,38 +1359,25 @@ class ClusterRuntime(CoreRuntime):
                 except Exception:  # noqa: BLE001
                     rec = {}
             self._actor_cache[actor_id.hex()] = rec
-        if self.pipelined:
-            # windowed pipelining: the pin rides the batched refop channel
-            # (FIFO — the completion's unpin is enqueued after it and can
-            # never overtake it), results at most the inline threshold ride
-            # back IN the completion reply, and many calls stay in flight
-            # per actor (seq-ordered on the worker side).
-            sd["inline_max"] = self._inline_max
-            if spec.generator:
-                # the stream's directory is in the actor's worker, and this
-                # process reads it there over the pipeline's connection
-                self._caller_streams[sd["task_id"]] = _CallerStream(
-                    self._actor_pipeline(actor_id.hex()))
-            else:
-                with self._seal_cond:
-                    self._pending_actor_returns.update(sd["returns"])
-            self._queue_refop("pin", pin_kwargs)
-            self._actor_pipeline(actor_id.hex()).submit(
-                sd, spec.max_task_retries,
-                ordered=rec.get("max_concurrency", 1) <= 1)
-            return refs
-        try:
-            self.gcs.call("pin_task", **pin_kwargs)
-        except Exception:  # noqa: BLE001 - advisory bookkeeping
-            logger.exception("actor-task ref pinning failed")
-        if rec.get("max_concurrency", 1) > 1:
-            # threaded/async actors: unordered concurrent pushes (reference
-            # semantics: ordering is only guaranteed for max_concurrency=1)
-            self._bg.submit(self._push_actor_task, actor_id.hex(), sd, spec.max_task_retries)
+        # windowed pipelining: the pin rides the batched refop channel
+        # (FIFO — the completion's unpin is enqueued after it and can
+        # never overtake it), results at most the inline threshold ride
+        # back IN the completion reply, and many calls stay in flight
+        # per actor (seq-ordered on the worker side; threaded/async actors
+        # are unordered, as in the reference).
+        sd["inline_max"] = self._inline_max
+        if spec.generator:
+            # the stream's directory is in the actor's worker, and this
+            # process reads it there over the pipeline's connection
+            self._caller_streams[sd["task_id"]] = _CallerStream(
+                self._actor_pipeline(actor_id.hex()))
         else:
-            # ordered: one dispatcher thread per actor preserves submission
-            # order end-to-end (ActorSchedulingQueue equivalent)
-            self._actor_dispatcher(actor_id.hex()).put((sd, spec.max_task_retries))
+            with self._seal_cond:
+                self._pending_actor_returns.update(sd["returns"])
+        self._queue_refop("pin", pin_kwargs)
+        self._actor_pipeline(actor_id.hex()).submit(
+            sd, spec.max_task_retries,
+            ordered=rec.get("max_concurrency", 1) <= 1)
         return refs
 
     def _actor_pipeline(self, actor_hex: str) -> "_ActorPipeline":
@@ -1476,90 +1389,6 @@ class ClusterRuntime(CoreRuntime):
                 p = _ActorPipeline(self, actor_hex)
                 self._actor_pipelines[actor_hex] = p
             return p
-
-    def _actor_dispatcher(self, actor_hex: str):
-        import queue as _q
-
-        with self._lock:
-            disp = self._dispatchers.get(actor_hex)
-            if disp is None:
-                disp = _q.Queue()
-                self._dispatchers[actor_hex] = disp
-
-                def loop() -> None:
-                    while True:
-                        item = disp.get()
-                        if item is None:
-                            return
-                        sd, retries = item
-                        try:
-                            self._push_actor_task(actor_hex, sd, retries)
-                        except Exception:  # noqa: BLE001
-                            logger.exception("actor dispatch failed")
-
-                threading.Thread(
-                    target=loop, daemon=True, name=f"actor-dispatch-{actor_hex[:8]}"
-                ).start()
-            return disp
-
-    def _push_actor_task(self, actor_hex: str, sd: Dict[str, Any], max_task_retries: int) -> None:
-        try:
-            self._push_actor_task_inner(actor_hex, sd, max_task_retries)
-        finally:
-            holder = sd.get("task_holder")
-            if holder:
-                try:
-                    self.gcs.call(
-                        "remove_object_refs",
-                        object_ids=(sd.get("deps") or []) + (sd.get("returns") or []),
-                        holder=holder,
-                    )
-                except Exception:  # noqa: BLE001
-                    pass
-
-    def _push_actor_task_inner(self, actor_hex: str, sd: Dict[str, Any], max_task_retries: int) -> None:
-        attempts = 0
-        while True:
-            try:
-                rec = self._resolve_actor(actor_hex)
-                client = self._actor_client(rec["address"])
-                while True:
-                    try:
-                        client.call("run_actor_task", spec=sd,
-                                    caller=self.client_id,
-                                    timeout=config.actor_call_deadline_s)
-                        return
-                    except TimeoutError:
-                        # Deadline expired: never wedge this dispatcher on a
-                        # hung worker (the old timeout=None did exactly that).
-                        # Probe liveness — an alive worker means the call is
-                        # merely long-running: re-attach (the worker dedupes
-                        # by task_id and piggybacks the running execution). A
-                        # dead worker fails the ping, which lands in the
-                        # retry handler below.
-                        client.call("ping", timeout=5.0)
-                        logger.warning(
-                            "actor call %s exceeded %.0fs; worker alive, "
-                            "re-attaching", sd.get("name"),
-                            config.actor_call_deadline_s)
-            except (exc.ActorDiedError, exc.ActorUnavailableError) as e:
-                self._store_error_objects(sd, str(e), "ActorDiedError")
-                return
-            except (ConnectionError, RpcError, TimeoutError) as e:
-                # worker died mid-call or address stale
-                attempts += 1
-                if isinstance(e, RpcError) and e.remote_type not in (
-                    "ConnectionError", "RpcConnectionError", "ActorDiedError",
-                ):
-                    # handler-level error: results already stored as errors
-                    return
-                if attempts > max(max_task_retries, 0):
-                    self._store_error_objects(
-                        sd, f"actor call failed after {attempts} attempts: {e}",
-                        "ActorDiedError" if isinstance(e, RpcError) else "ActorUnavailableError",
-                    )
-                    return
-                time.sleep(0.1 * attempts)
 
     def _fail_caller_stream(self, sd: Dict[str, Any], message: str,
                             error_type: str) -> bool:

@@ -119,20 +119,13 @@ _DEFINITIONS = [
      "Chunk size for node-to-node object transfer."),
     ("object_transfer_retries", 5, int,
      "Pull retries (exponential backoff) before an object fetch errors."),
-    # --- zero-copy pipelined transfer plane ---
-    ("raw_transfer_enabled", True, bool,
-     "Data plane for object bytes: raw binary frames (small msgpack header "
-     "+ payload written/received as memoryviews, socket<->arena with no "
-     "msgpack encode of the payload) with windowed pipelined chunk "
-     "requests, striped multi-source pulls and mid-object failover. "
-     "Escape hatch: env RTPU_RAW_TRANSFER=0 restores the serial in-band "
-     "msgpack chunk path."),
+    # --- zero-copy pipelined transfer plane (raw binary frames) ---
     ("pull_stripe_enabled", True, bool,
      "Striped pulls: spread chunk ranges of one object across every "
      "GCS-known holder instead of draining a single source."),
     ("transfer_window_chunks", 8, int,
      "In-flight chunk requests per transfer source (the pull/push "
-     "pipelining window; 1 = lockstep await-per-chunk)."),
+     "pipelining window; 1 = one chunk awaited at a time)."),
     ("transfer_max_sources", 4, int,
      "Max holders one striped pull spreads its chunk ranges across."),
     ("transfer_inflight_max_bytes", 256 * 1024 * 1024, int,
@@ -154,8 +147,6 @@ _DEFINITIONS = [
      "(crashed driver/worker cleanup); task pins are dropped with their node."),
     ("max_object_reconstructions", 3, int,
      "Per-object cap on lineage-reconstruction attempts after all copies are lost."),
-    ("max_lineage_bytes", 8 * 1024 * 1024, int,
-     "Task specs above this size are not retained for lineage reconstruction."),
     # --- scheduling ---
     ("gcs_snapshot_interval_s", 1.0, float,
      "Interval between GCS state snapshots when --persist-dir is set."),
@@ -200,13 +191,6 @@ _DEFINITIONS = [
     ("prestart_workers", True, bool,
      "Start workers ahead of demand based on queue backlog."),
     # --- fault tolerance ---
-    ("gcs_recovery_enabled", True, bool,
-     "GCS crash-restart recovery subsystem (core/recovery/): a restarted "
-     "GCS stamps a new gcs_epoch, restores snapshot state, and rebuilds "
-     "the object directory from agent re-registration inside a bounded "
-     "reconstruction window; agents and drivers park-and-retry across the "
-     "outage instead of failing. Escape hatch: env RTPU_GCS_RECOVERY=0 "
-     "restores fail-fast behavior for A/B."),
     ("gcs_reconstruction_window_s", 5.0, float,
      "Upper bound on the post-restart reconstruction window: snapshot-"
      "restored object locations stay provisional until the holder node "
@@ -226,7 +210,10 @@ _DEFINITIONS = [
     ("actor_max_restarts_default", 0, int,
      "Default actor restarts."),
     ("max_lineage_bytes", 512 * 1024 * 1024, int,
-     "Budget of task-spec lineage kept for object reconstruction."),
+     "Per-task limit: the agent hands a task's spec to the GCS with its ref "
+     "pin (lineage for reconstructing lost returns) only when the spec's "
+     "args payload is at most this many bytes; a larger spec is not "
+     "retained and its returns cannot be re-executed."),
     ("log_monitor_interval_s", 0.5, float,
      "How often each agent checks worker logs for growth."),
     ("health_check_period_ms", 1000, int,
@@ -246,10 +233,6 @@ _DEFINITIONS = [
      "Absolute free-memory floor that also triggers the OOM killer when "
      "crossed (-1 = derive from memory_usage_threshold only)."),
     # --- pipelined control plane ---
-    ("pipeline_enabled", True, bool,
-     "Pipelined control plane: batched task submission, windowed actor-call "
-     "dispatch, pushed completions and inline small results. Escape hatch: "
-     "env RTPU_PIPELINE=0 restores the lockstep request/response paths."),
     ("inline_max_bytes", 8192, int,
      "Task/actor-call results whose serialized payload is at most this many "
      "bytes ride inline in the completion message (actor replies and pushed "
@@ -349,27 +332,6 @@ _DEFINITIONS = [
 config = Config()
 
 
-def pipeline_enabled() -> bool:
-    """Pipelined control plane on/off. The RTPU_PIPELINE env var is the
-    operator escape hatch (tools/ray_perf.py --no-pipeline sets it) and wins
-    over the config entry so one process tree can be flipped wholesale."""
-    raw = os.environ.get("RTPU_PIPELINE")
-    if raw is not None:
-        return raw.strip().lower() not in ("0", "false", "no", "off")
-    return config.pipeline_enabled
-
-
-def raw_transfer_enabled() -> bool:
-    """Raw-frame data plane on/off. The RTPU_RAW_TRANSFER env var is the
-    operator escape hatch (tools/ray_perf.py --no-raw-transfer sets it) and
-    wins over the config entry so one process tree can be flipped wholesale
-    for A/B measurement against the msgpack in-band path."""
-    raw = os.environ.get("RTPU_RAW_TRANSFER")
-    if raw is not None:
-        return raw.strip().lower() not in ("0", "false", "no", "off")
-    return config.raw_transfer_enabled
-
-
 def streaming_shuffle_enabled() -> bool:
     """Streaming shuffle subsystem on/off. The RTPU_STREAMING_SHUFFLE env
     var is the operator escape hatch (tools/bench_shuffle.py --no-streaming
@@ -393,18 +355,6 @@ def columnar_exchange_enabled() -> bool:
     if raw is not None:
         return raw.strip().lower() not in ("0", "false", "no", "off")
     return config.columnar_exchange_enabled
-
-
-def gcs_recovery_enabled() -> bool:
-    """GCS crash-restart recovery on/off. The RTPU_GCS_RECOVERY env var is
-    the operator escape hatch (tests and tools/bench_chaos.py set it) and
-    wins over the config entry so one process tree can be flipped wholesale:
-    with it off, a dead GCS fails agents and drivers fast (the pre-recovery
-    behavior) instead of parking-and-retrying through the outage."""
-    raw = os.environ.get("RTPU_GCS_RECOVERY")
-    if raw is not None:
-        return raw.strip().lower() not in ("0", "false", "no", "off")
-    return config.gcs_recovery_enabled
 
 
 def inline_max_bytes() -> int:

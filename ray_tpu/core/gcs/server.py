@@ -31,7 +31,7 @@ import random
 import time
 from typing import Any, Dict, List, Optional, Set, Tuple
 
-from ray_tpu.core.config import config, gcs_recovery_enabled
+from ray_tpu.core.config import config
 from ray_tpu.core.recovery.window import ReconstructionWindow
 from ray_tpu.core.rpc import RpcServer, loop_lag_watchdog, spawn
 from ray_tpu.utils.logging import get_logger
@@ -1144,9 +1144,11 @@ class GcsServer:
         submitter: str = "",
         spec: Optional[Dict[str, Any]] = None,
     ) -> bool:
-        """One-shot task-submission bookkeeping (single RPC on the submit hot
-        path): pin deps+returns under the task holder, register the
-        submitter's holder on the returns, retain the spec as lineage."""
+        """One task's submission bookkeeping: pin deps+returns under the task
+        holder, register the submitter's holder on the returns, retain the
+        spec as lineage. Called once per actor call by the C++ client
+        (cpp/ray_tpu_client.cc); Python callers batch through rpc_pin_tasks,
+        which applies this to each pin."""
         await self.rpc_add_object_refs(deps + returns, task_holder)
         if submitter:
             await self.rpc_add_object_refs(returns, submitter)
@@ -1501,10 +1503,9 @@ class GcsServer:
         # new incarnation: every epoch observer (agent heartbeats, driver
         # holder_heartbeat acks) sees the bump and triggers its resync
         self.gcs_epoch = s.get("gcs_epoch", 0) + 1
-        if gcs_recovery_enabled():
-            # restored directory/node state is authoritative-but-stale until
-            # agents re-report it; the window bounds how long we wait
-            self.recovery_window = ReconstructionWindow(self.objects, self.nodes)
+        # restored directory/node state is authoritative-but-stale until
+        # agents re-report it; the window bounds how long we wait
+        self.recovery_window = ReconstructionWindow(self.objects, self.nodes)
         # nodes must prove liveness again: stamp now so the health loop gives
         # them a full window to heartbeat before declaring them dead
         now = time.monotonic()

@@ -28,12 +28,13 @@ import sys
 import time
 from typing import Any, Dict, List, Optional, Set, Tuple
 
-from ray_tpu.core.config import config, gcs_recovery_enabled, raw_transfer_enabled
+from ray_tpu.core.config import config
 from ray_tpu.core.ids import NodeID, ObjectID
 from ray_tpu.core.node.transfer import TransferManager
 from ray_tpu.core.rpc import (RawResult, RpcClient, RpcConnectionError,
                               RpcError, RpcServer, loop_lag_watchdog, spawn)
-from ray_tpu.core.shm_store import ShmObjectStore, ShmReader, ShmWriter
+from ray_tpu.core.shm_store import (FRAGMENTED, ShmObjectStore, ShmReader,
+                                    ShmWriter)
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("node_agent")
@@ -438,20 +439,10 @@ class NodeAgent:
                     # restarted GCS with no (or a pre-us) snapshot: it lost
                     # this node entirely — full re-registration, not just
                     # register_node (our objects/actors/pins are gone too)
-                    if gcs_recovery_enabled():
-                        from ray_tpu.core.recovery import trigger_resync
+                    from ray_tpu.core.recovery import trigger_resync
 
-                        trigger_resync(self, "heartbeat rejected: GCS lost "
-                                             "this node")
-                    else:
-                        await self.gcs.call(
-                            "register_node",
-                            node_id=self.hex,
-                            address=self.rpc.address,
-                            resources=self.total_resources,
-                            labels=self.labels,
-                            is_head=self.is_head,
-                        )
+                    trigger_resync(self, "heartbeat rejected: GCS lost "
+                                         "this node")
                     self._hb_full_pending = True  # fresh GCS: resend view
                 elif isinstance(ok, dict) and ok.get("resync"):
                     self._hb_full_pending = True  # GCS lost our version
@@ -459,7 +450,7 @@ class NodeAgent:
                     self._hb_full_pending = False
                 if isinstance(ok, dict):
                     epoch = ok.get("epoch")
-                    if (epoch is not None and gcs_recovery_enabled()
+                    if (epoch is not None
                             and self._last_gcs_epoch is not None
                             and epoch != self._last_gcs_epoch):
                         from ray_tpu.core.recovery import trigger_resync
@@ -963,10 +954,6 @@ class NodeAgent:
                     # wait instead of failing their tasks. register_objects
                     # is idempotent on the GCS side, so a duplicate re-send
                     # after an ambiguous timeout is harmless.
-                    if not gcs_recovery_enabled():
-                        self._fail_reg_batch(batch, e)
-                        await asyncio.sleep(0.2)
-                        break
                     now = time.monotonic()
                     if parked_until is None:
                         parked_until = now + config.recovery_park_timeout_s
@@ -1107,6 +1094,8 @@ class NodeAgent:
                 "offset": self.store.offset(oid)}
 
     async def rpc_read_chunk(self, object_id: str, offset: int, length: int) -> bytes:
+        """In-band (msgpack) chunk read for a client that speaks no raw
+        frames: the C++ client's Get (cpp/ray_tpu_client.cc)."""
         oid = ObjectID.from_hex(object_id)
         size = self.store.ensure_local(oid)
         if size is None:
@@ -1239,8 +1228,9 @@ class NodeAgent:
                                 "offset": self.store.offset(oid)}
                     remotes = [n for n in rec["locations"] if n != self.hex]
                     if remotes:
-                        meta = await self._pull(oid, rec["size"], remotes,
-                                                owner_hint=rec.get("owner", ""))
+                        meta = await self.transfer.pull(
+                            oid, rec["size"], remotes,
+                            owner_hint=rec.get("owner", ""))
                         if meta is not None:
                             if meta.get("is_error") or \
                                     rec.get("owner", "").endswith(":error"):
@@ -1369,6 +1359,15 @@ class NodeAgent:
             rec = await self.gcs.call("lookup_object", object_id=object_id)
             if rec and rec["locations"]:
                 return
+            freed = await self._freed_argument(spec)
+            if freed is not None:
+                raise exc.ObjectLostError(
+                    object_id,
+                    f"object {object_id[:16]} was lost and the task that "
+                    f"made it ({spec.get('name')}) cannot run again: its "
+                    f"argument {freed[:16]} was freed after the first run "
+                    "and has no lineage left",
+                )
             self._recon_attempts[task_key] = self._recon_attempts.get(task_key, 0) + 1
             logger.info(
                 "reconstructing %s (attempt %d): re-running task %s",
@@ -1392,6 +1391,24 @@ class NodeAgent:
                 pass
             await self._submit_with_retries(spec)
 
+    async def _freed_argument(self, spec: Dict[str, Any]) -> Optional[str]:
+        """The first argument of a retained spec that can never come back,
+        or None. The task ran once, so each argument existed; one the
+        directory no longer knows and that has no lineage was freed (the GCS
+        drops an object's lineage with the object, object_ref_grace_s after
+        its last holder), and a re-run would wait for it for ever. Refusing
+        the re-run is a STOPGAP: the repair is lineage kept while a retained
+        spec names it (ROADMAP Design 10 (b))."""
+        deps = spec.get("deps") or []
+        if not deps:
+            return None
+        known = await self.gcs.call("lookup_objects", object_ids=deps)
+        for dep, rec in zip(deps, known):
+            if rec is None and await self.gcs.call(
+                    "get_lineage", object_id=dep) is None:
+                return dep
+        return None
+
     # ------------------------------------------------------- object broadcast
     async def _upload_object_to(self, client: "RpcClient", oid: ObjectID,
                                 object_id: str, size: int) -> bool:
@@ -1400,13 +1417,9 @@ class NodeAgent:
         the first chunk — no wasted re-upload). A size-0 object still sends
         one empty chunk so the receiver can reserve+seal.
 
-        Raw plane: chunk payloads are arena memoryviews written straight to
-        the socket (object pinned for the duration — no bytes() copy, no
-        msgpack encode) with ``transfer_window_chunks`` sends in flight;
-        RTPU_RAW_TRANSFER=0 restores the serial in-band path."""
-        if not raw_transfer_enabled():
-            return await self._upload_object_to_legacy(client, oid,
-                                                       object_id, size)
+        Chunk payloads are arena memoryviews written straight to the socket
+        as raw frames (object pinned for the duration — no bytes() copy, no
+        msgpack encode) with ``transfer_window_chunks`` sends in flight."""
         reader = ShmReader(oid, size, self.hex, offset=self.store.offset(oid))
         self.store.pin(oid)
         try:
@@ -1451,33 +1464,6 @@ class NodeAgent:
             return True
         finally:
             self.store.unpin(oid)
-            reader.close()
-
-    async def _upload_object_to_legacy(self, client: "RpcClient",
-                                       oid: ObjectID, object_id: str,
-                                       size: int) -> bool:
-        """Serial in-band msgpack chunk upload (pre-raw-plane baseline)."""
-        reader = ShmReader(oid, size, self.hex, offset=self.store.offset(oid))
-        try:
-            sent = 0
-            chunk = config.fetch_chunk_bytes
-            while True:
-                n = min(chunk, size - sent)
-                data = bytes(reader.buffer[sent : sent + n])
-                if not reader.revalidate():
-                    raise KeyError(f"object {object_id[:16]} evicted mid-push")
-                resp = await client.call(
-                    "receive_chunk", object_id=object_id, total_size=size,
-                    offset=sent, data=data,
-                    is_error=object_id in self.error_objects,
-                    timeout=60.0,
-                )
-                if isinstance(resp, dict) and resp.get("existing") == "sealed":
-                    return sent > 0  # already had it iff detected up front
-                sent += n
-                if sent >= size:
-                    return True
-        finally:
             reader.close()
 
     async def rpc_push_object(self, object_id: str,
@@ -1560,11 +1546,10 @@ class NodeAgent:
                                 offset: int, data: bytes,
                                 is_error: bool = False, owner: str = "",
                                 contained: Optional[List[str]] = None) -> Dict[str, Any]:
-        """In-band (msgpack) chunk ingest — compat path and the
-        RTPU_RAW_TRANSFER=0 A/B baseline. Shares the per-object cached
-        ShmWriter ingest table with the raw plane instead of constructing a
-        fresh writer (attach + validate) for every chunk; seals + registers
-        with the GCS once every byte has landed."""
+        """In-band (msgpack) chunk ingest for a client that speaks no raw
+        frames: the C++ client's Put (cpp/ray_tpu_client.cc). Shares the
+        per-object cached ShmWriter ingest table with the raw plane; seals +
+        registers with the GCS once every byte has landed."""
         sink, finish = await self.transfer.open_ingest(
             payload_len=len(data), object_id=object_id,
             total_size=total_size, offset=offset, is_error=is_error,
@@ -1572,69 +1557,6 @@ class NodeAgent:
         if sink is not None and data:
             sink[: len(data)] = data
         return await finish(len(data))
-
-    async def _pull(self, oid: ObjectID, size: int, locations: List[str],
-                    owner_hint: str = "") -> Optional[Dict[str, Any]]:
-        """Materialize a remote object locally. Raw plane: striped windowed
-        pull with mid-object failover/resume (TransferManager); returns the
-        piggybacked metadata dict on success, None on failure.
-        RTPU_RAW_TRANSFER=0 restores the serial single-source msgpack path."""
-        if raw_transfer_enabled():
-            return await self.transfer.pull(oid, size, locations,
-                                            owner_hint=owner_hint)
-        ok = await self._pull_legacy(oid, size, locations)
-        return {} if ok else None
-
-    async def _pull_legacy(self, oid: ObjectID, size: int,
-                           locations: List[str]) -> bool:
-        """Serial chunked pull from one peer agent (pre-raw-plane baseline;
-        reference: PullManager/PushManager 64MB chunks)."""
-        object_id = oid.hex()
-        for node_id in locations:
-            try:
-                client = await self._peer(node_id)
-                if client is None:
-                    continue
-                arena_off = self.store.reserve(oid, size)
-                writer = ShmWriter(oid, size, self.hex, offset=arena_off)
-                seal_failed = False
-                try:
-                    offset = 0
-                    chunk = config.fetch_chunk_bytes
-                    while offset < size:
-                        data = await client.call(
-                            "read_chunk", object_id=object_id, offset=offset,
-                            length=min(chunk, size - offset),
-                        )
-                        writer.buffer[offset : offset + len(data)] = data
-                        offset += len(data)
-                finally:
-                    try:
-                        writer.seal()
-                    except FileNotFoundError:
-                        # reservation aborted while pulling: don't let the
-                        # seal error mask the chunk error / skip cleanup
-                        seal_failed = True
-                if seal_failed:
-                    raise KeyError(
-                        f"reservation for {object_id[:16]} aborted mid-pull")
-                self.store.seal(oid)
-                # peer knows error-ness
-                info = await client.call("object_info", object_id=object_id)
-                if info and info.get("is_error"):
-                    self.error_objects.add(object_id)
-                await self.gcs.call(
-                    "register_object", object_id=object_id, size=size, node_id=self.hex
-                )
-                return True
-            except (RpcConnectionError, RpcError, TimeoutError, KeyError) as e:
-                logger.warning("pull of %s from %s failed: %s", object_id[:16], node_id[:8], e)
-                try:
-                    self.store.abort(oid)
-                except Exception:  # noqa: BLE001
-                    pass
-                continue
-        return False
 
     async def _peer(self, node_id: str) -> Optional[RpcClient]:
         client = self._peer_clients.get(node_id)
@@ -1726,8 +1648,10 @@ class NodeAgent:
 
     # ------------------------------------------------------------ scheduling
     async def rpc_submit_task(self, spec: Dict[str, Any]) -> Dict[str, Any]:
-        """Entry from drivers/workers on this node. Returns {accepted: bool}.
-        Completion is observed through the object plane.
+        """Single-spec submission: the C++ client's (cpp/ray_tpu_client.cc;
+        Python drivers and workers batch through rpc_submit_task_batch).
+        Returns {accepted: bool}. Completion is observed through the object
+        plane.
 
         Before accepting, the task's deps + returns are PINNED at the GCS
         under a task holder (so distributed GC can't free an argument while
@@ -2117,10 +2041,23 @@ class NodeAgent:
                                 "error": f"deps unavailable: {e}"}
                     self.store.pin(oid)
                     pinned_deps.append(oid)
-            return await self._dispatch_execute(spec, tid)
+            result = await self._dispatch_execute(spec, tid)
         finally:
             for oid in pinned_deps:
                 self.store.unpin(oid)
+        if result.get("returns_fragmented"):
+            # The returns had the bytes and no contiguous room while the deps
+            # sat pinned where an earlier restore had put them: a dep in the
+            # middle of a small arena splits the free bytes into holes that
+            # are each too small, nothing evictable is left to merge them,
+            # and the requeued run would pin the same deps in the same places
+            # and fail the same way for ever. Send them to spill: the next
+            # dispatch restores them first-fit from the arena's low end. (A
+            # store that is plainly full is the transient pressure above:
+            # the deps stay where they are for the retry.)
+            for oid in pinned_deps:
+                self.store.spill(oid)
+        return result
 
     async def _dispatch_execute(self, spec: Dict[str, Any],
                                 tid: str) -> Dict[str, Any]:
@@ -2248,10 +2185,12 @@ class NodeAgent:
             # (at-least-once; already-sealed returns dedupe on re-store)
             # instead of surfacing an internal error
             return {"ok": False, "retryable": True, "reason": "busy",
+                    "returns_fragmented": FRAGMENTED in str(e),
                     "error": f"store full for returns: {e}"}
         if (result or {}).get("state") == "retry_store_full":
             # worker-side big-return store failed the same way: requeue
             return {"ok": False, "retryable": True, "reason": "busy",
+                    "returns_fragmented": bool(result.get("fragmented")),
                     "error": "store full for returns (worker)"}
         return {"ok": True, **(result or {})}
 

@@ -172,11 +172,6 @@ class _RegistrationBatcher:
                     # against the new incarnation instead of failing every
                     # waiter's pull/ingest (register_objects is idempotent,
                     # so an ambiguous timeout re-send is harmless)
-                    from ray_tpu.core.config import gcs_recovery_enabled
-
-                    if not gcs_recovery_enabled():
-                        self._fail_waiters(waiters, e)
-                        break
                     now = time.monotonic()
                     if parked_until is None:
                         parked_until = now + config.recovery_park_timeout_s

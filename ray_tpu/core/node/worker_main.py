@@ -31,7 +31,7 @@ from ray_tpu.core.config import config
 from ray_tpu.core.ids import ActorID, JobID, NodeID, ObjectID, TaskID, WorkerID
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.rpc import RpcClient, RpcServer, SyncRpcClient, spawn
-from ray_tpu.core.shm_store import ShmWriter
+from ray_tpu.core.shm_store import FRAGMENTED, ShmWriter
 from ray_tpu.core.streaming import WorkerStream, stream_item_id
 from ray_tpu.utils.logging import get_logger, setup_component_logging
 
@@ -326,9 +326,10 @@ class WorkerProcess:
     def _streams_to_caller(spec: Dict[str, Any]) -> bool:
         """A streaming ACTOR call whose caller takes small results over the
         call's connection (``inline_max``, as for a plain call's returns)
-        reads the stream from this worker. A task's caller has no connection
-        to the worker, and a lockstep caller asks for nothing inline: their
-        streams go through the store and the GCS."""
+        reads the stream from this worker. A Python caller always sends
+        ``inline_max``; a spec without it is the C++ client's. Its stream, and
+        a task's (whose caller has no connection to the worker), go through
+        the store and the GCS."""
         return bool(spec.get("streaming") and spec.get("actor_id")
                     and int(spec.get("inline_max") or 0) > 0)
 
@@ -545,6 +546,7 @@ class WorkerProcess:
                         # store right now: ask the agent to requeue (GC/spill
                         # frees space; already-sealed returns dedupe)
                         return {"state": "retry_store_full",
+                                "fragmented": FRAGMENTED in repr(store_err),
                                 "inline_returns": inline}
                     raise
                 return {"state": "ok", "inline_returns": inline}
@@ -605,6 +607,10 @@ class WorkerProcess:
     async def rpc_run_actor_task(self, spec: Dict[str, Any],
                                  seq: Optional[int] = None,
                                  caller: str = "") -> Dict[str, Any]:
+        """One actor call. A Python caller sends ``seq`` (an ordered actor's
+        turn) and ``inline_max`` in the spec; the C++ client
+        (cpp/ray_tpu_client.cc) sends neither: its call runs in arrival order
+        and every result goes through the store."""
         if self.actor_instance is None:
             raise exc.ActorDiedError(self.actor_id or "", "actor not constructed")
         if spec.get("actor_id") != self.actor_id:

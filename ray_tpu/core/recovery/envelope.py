@@ -10,8 +10,7 @@ flusher's lease renewal — already periodic, already cheap). The envelope:
   newer than the restored snapshot);
 - wraps non-retry-safe control RPCs in park-and-retry: during an outage a
   call sleeps with backoff and re-sends instead of raising, bounded by
-  ``recovery_park_timeout_s``. With recovery disabled (RTPU_GCS_RECOVERY=0)
-  the wrapper is a plain pass-through call — the fail-fast A/B baseline.
+  ``recovery_park_timeout_s``.
 """
 
 from __future__ import annotations
@@ -20,7 +19,7 @@ import threading
 import time
 from typing import Any, Optional
 
-from ray_tpu.core.config import config, gcs_recovery_enabled
+from ray_tpu.core.config import config
 from ray_tpu.core.rpc import RpcConnectionError
 from ray_tpu.utils.logging import get_logger
 
@@ -45,7 +44,7 @@ class RetryEnvelope:
                 self.epoch_bumps += 1
         return bumped
 
-    def send(self, client, method: str, timeout: Any = None, **params) -> Any:
+    def send(self, client, method: str, **params) -> Any:
         """``client.call`` (SyncRpcClient) with park-and-retry across a GCS
         outage. Connection loss and per-call timeouts re-send with backoff
         until ``recovery_park_timeout_s``; anything else (an actual remote
@@ -53,10 +52,6 @@ class RetryEnvelope:
 
         Named ``send`` (not ``call``) so rtpu-lint's rpc-drift pass sees it
         as a dispatch forwarder rather than shadowing the client method."""
-        if not gcs_recovery_enabled():
-            if timeout is None:
-                return client.call(method, **params)
-            return client.call(method, timeout=timeout, **params)
         deadline = time.monotonic() + config.recovery_park_timeout_s
         delay = 0.05
         while True:

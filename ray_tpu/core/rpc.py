@@ -144,8 +144,8 @@ class _Chaos:
     the pipelined control-plane frames: pushed completion events
     (``should_drop_push``, consulted by RpcServer.publish) and inline result
     payloads (``should_drop_inline``, consulted by the GCS before attaching
-    a payload to a sealed event) — so retry/fallback coverage tracks the
-    pipelined protocol instead of silently shrinking to the lockstep one."""
+    a payload to a sealed event) — so retry/fallback coverage reaches the
+    frames that carry completions, not only requests and responses."""
 
     def __init__(self, enabled: bool = True,
                  methods: Optional[frozenset] = None) -> None:

@@ -39,6 +39,10 @@ from ray_tpu.utils.logging import get_logger
 logger = get_logger("shm_store")
 
 _SHM_DIR = "/dev/shm"
+# How an ObjectStoreFullError starts when the budget would do and no one hole
+# does (what is left in the arena is pinned or unsealed); the agent's busy
+# requeue tells this from a store that is plainly full by it.
+FRAGMENTED = "arena fragmented"
 
 
 def segment_name(oid: ObjectID, node_suffix: str) -> str:
@@ -77,16 +81,21 @@ def write_arena_pidfile(path: str, pid: Optional[int] = None) -> None:
         pass  # /dev/shm unwritable: the arena create will fail loudly anyway
 
 
+def arena_owner(path: str) -> Optional[int]:
+    """The pid an arena's pidfile names; None if it is missing or corrupt."""
+    try:
+        return int(open(_arena_pid_path(path)).read().strip())
+    except (OSError, ValueError):
+        return None
+
+
 def arena_owner_alive(path: str) -> bool:
     """True unless the pidfile names a provably-dead process. A missing or
     corrupt pidfile counts as DEAD: every arena creator in this codebase
     writes the pidfile first, so an arena without one is a pre-pidfile
     orphan (or lost its owner before finishing startup)."""
-    try:
-        pid = int(open(_arena_pid_path(path)).read().strip())
-    except (OSError, ValueError):
-        return False
-    return _pid_alive(pid)
+    pid = arena_owner(path)
+    return pid is not None and _pid_alive(pid)
 
 
 def sweep_dead_arenas() -> List[str]:
@@ -427,7 +436,7 @@ class ShmObjectStore:
                     not (self._reclaim_quarantine_locked()
                          or self._evict_one_locked()):
                 raise ObjectStoreFullError(
-                    f"arena fragmented: need {size} contiguous, largest free "
+                    f"{FRAGMENTED}: need {size} contiguous, largest free "
                     f"{self._arena.largest_free()} "
                     f"({self._arena.num_free_blocks()} free blocks)"
                 )
@@ -510,6 +519,19 @@ class ShmObjectStore:
             e = self._entries.get(oid)
             if e is not None and e.pinned > 0:
                 e.pinned -= 1
+
+    def spill(self, oid: ObjectID) -> bool:
+        """Move THIS object out of the arena into the spill directory, as
+        eviction does to its LRU victim; False (and nothing done) unless it
+        is sealed, resident, unpinned and spilling is on."""
+        with self._lock:
+            e = self._entries.get(oid)
+            if (e is None or not e.sealed or e.pinned
+                    or e.spilled_path is not None or self.spill_dir is None
+                    or not config.object_spilling_enabled):
+                return False
+            self._spill_locked(oid, e)
+            return True
 
     def delete(self, oid: ObjectID) -> None:
         with self._lock:

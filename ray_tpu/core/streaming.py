@@ -24,8 +24,8 @@ per consumed item. Where the directory lives depends on who can reach whom:
   the watermark passes it); the worker's record carries its id in place of a
   payload.
 - A TASK's stream (the caller has no connection to the worker a task lands
-  on), and the stream of a caller without the pipelined control plane: beside
-  the GCS's object directory, as before. Every item is a normal object (sealed
+  on), and the stream of a caller whose spec carries no ``inline_max`` (the
+  C++ client): beside the GCS's object directory, as before. Every item is a normal object (sealed
   and location-registered through the existing paths) plus one
   stream-directory append at the GCS.
 """

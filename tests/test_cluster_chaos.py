@@ -94,6 +94,7 @@ def test_streaming_generator_survives_chaos(chaos_cluster, producer):
     assert [x["i"] for x in items] == list(range(n))
 
 
+@pytest.mark.timeout_s(180)  # 10 s alone; a lost creation frame is 120 s
 def test_actor_restart_under_chaos(chaos_cluster):
     """Worker death + GCS-driven restart while the control plane drops 5%
     of frames (reference: test_actor_failures under rpc chaos)."""

@@ -283,3 +283,68 @@ def test_reconstruction_with_lost_dependency_chain(cluster):
 
     # b reconstructs, which requires re-running base() for the lost dep too
     assert ray_tpu.get(b, timeout=90) == 20
+
+
+def _lose_a_result_whose_argument_was_freed(cluster):
+    """b = twice(base()) on a second node; base()'s result dropped by its
+    only holder and freed by the GCS, lineage and all; then the node goes.
+    Returns b, of which no copy is left."""
+    node = cluster.add_node(num_cpus=2)
+    cluster.wait_for_nodes(2)
+    strat = NodeAffinitySchedulingStrategy(
+        node_id=_node_id_of(cluster, node), soft=False)
+
+    @ray_tpu.remote
+    def base():
+        return bytes(20_000)  # over inline_max_bytes: it lives in the store
+
+    @ray_tpu.remote
+    def twice(x):
+        return x + x
+
+    a = base.options(scheduling_strategy=strat).remote()
+    b = twice.options(scheduling_strategy=strat).remote(a)
+    _wait_sealed(cluster, b.id.hex())
+    a_hex = a.id.hex()
+    del a
+    gcs = SyncRpcClient(cluster.gcs_address)
+    try:
+        deadline = time.monotonic() + 30
+        while gcs.call("lookup_object", object_id=a_hex) is not None:
+            assert time.monotonic() < deadline, "the argument was never freed"
+            time.sleep(0.1)
+    finally:
+        gcs.close()
+
+    cluster.remove_node(node)
+    deadline = time.monotonic() + 30
+    while _object_exists(cluster, b.id.hex()) and time.monotonic() < deadline:
+        time.sleep(0.1)
+    return b
+
+
+@pytest.mark.timeout_s(150)
+def test_reconstruction_refuses_when_an_argument_was_freed(cluster):
+    """Lineage is one level deep unless the arguments are held: the GCS
+    frees an unheld object with its lineage. A lost object whose producing
+    task's argument went that way is reported lost, with the reason, where
+    the re-run would wait for the argument for ever. The error is a STOPGAP
+    for the recovery the next test asks for, and goes when that one
+    passes."""
+    b = _lose_a_result_whose_argument_was_freed(cluster)
+    with pytest.raises(exceptions.ObjectLostError, match="was freed"):
+        ray_tpu.get(b, timeout=60)
+
+
+@pytest.mark.timeout_s(150)
+@pytest.mark.xfail(strict=True, raises=exceptions.ObjectLostError,
+                   reason="owed (ROADMAP Design 10 (b)): the GCS drops an "
+                          "unheld object's lineage while a retained spec "
+                          "still names it as an argument")
+def test_reconstruction_reaches_an_argument_that_was_freed(cluster):
+    """What a user's shuffle with unheld sources needs when it loses a node
+    (test_data_shuffle.py's kill test holds its sources to get by): a
+    retained spec keeps its arguments' lineage, so the lost object comes
+    back by running base() and then twice() again."""
+    b = _lose_a_result_whose_argument_was_freed(cluster)
+    assert ray_tpu.get(b, timeout=60) == bytes(40_000)

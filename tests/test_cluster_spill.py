@@ -66,3 +66,48 @@ def test_shuffle_larger_than_store_spills(small_store_cluster):
     # ~4MB of tensor rows across 8 blocks >> 2MB store
     ds = rd.range_tensor(4096, shape=(128,), parallelism=8).random_shuffle(seed=3)
     assert ds.count() == 4096
+
+
+@pytest.mark.timeout_s(120)  # 6 s alone; without the spill it never ends
+def test_return_blocked_by_its_own_pinned_dep_requeues_spills_and_fits():
+    """The agent's busy requeue, end to end: a task's return has the bytes
+    and no contiguous room because the task's OWN argument sits pinned in
+    the middle of the arena. The store says so (`arena fragmented`), the
+    agent sends the argument to spill before the requeue, the next dispatch
+    restores it first-fit at the low end, and the same return fits. A
+    requeue that left the argument where it was pinned it in the same place
+    and failed the same way for ever."""
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    mb = 1024 * 1024
+    c = Cluster(initialize_head=True,
+                head_node_args={"num_cpus": 1, "object_store_memory": 2 * mb})
+    ray_tpu.init(address=c.gcs_address)
+    agent = SyncRpcClient(c.nodes[0].address)
+    try:
+        if agent.call("node_info")["store"]["backend"] != "arena":
+            pytest.skip("segments backend: no arena to fragment")
+        e = 2 * mb // 8
+        low = ray_tpu.put(bytes(3 * e - 8192))
+        dep = ray_tpu.put(bytes(e - 8192))
+        low_hex = low.id.hex()
+        del low  # [hole 3/8][dep 1/8][hole 4/8] once the GCS has freed it
+        deadline = time.monotonic() + 30
+        while agent.call("object_info", object_id=low_hex) is not None:
+            assert time.monotonic() < deadline, "the first put was never freed"
+            time.sleep(0.1)
+        before = agent.call("node_info")["store"]
+
+        @ray_tpu.remote
+        def grow(x):
+            return bytes(5 * len(x))  # 5/8 of the arena: neither hole holds it
+
+        out = ray_tpu.get(grow.remote(dep), timeout=90)
+        assert len(out) == 5 * (e - 8192)
+        after = agent.call("node_info")["store"]
+        assert after["spill_count"] > before["spill_count"], (before, after)
+        assert after["restored_bytes"] > before["restored_bytes"], (before, after)
+    finally:
+        agent.close()
+        ray_tpu.shutdown()
+        c.shutdown()

@@ -40,6 +40,7 @@ def test_cpp_client_demo_roundtrip():
         c.shutdown()
 
 
+@pytest.mark.timeout_s(150)  # 1 s beside 12 CPU burners; the demo has 90 s
 @pytest.mark.skipif(shutil.which("g++") is None, reason="no C++ toolchain")
 def test_cpp_put_python_get_interop():
     """An object stored by the C++ client is a first-class object: Python

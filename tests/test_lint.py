@@ -372,6 +372,19 @@ def test_every_core_call_site_resolves():
     assert not unresolved, unresolved
 
 
+def test_every_setting_is_declared_once():
+    """The registry is built into a dict: of two entries with one name the
+    later silently wins, and the earlier's documented default never applies
+    (``max_lineage_bytes`` was declared at 8 MiB and at 512 MiB)."""
+    from collections import Counter
+
+    from ray_tpu.core.config import _DEFINITIONS
+
+    twice = [n for n, k in Counter(d[0] for d in _DEFINITIONS).items()
+             if k > 1]
+    assert not twice, twice
+
+
 def test_pass_registry_complete():
     from tools.rtpulint.passes import ALL_PASSES
 

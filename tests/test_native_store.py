@@ -158,6 +158,31 @@ class TestArenaStore:
         r = ShmReader(oid, size, store.node_suffix, offset=off)
         assert bytes(r.buffer) == payload
 
+    def test_spilling_a_dep_undoes_the_hole_it_split(self, store):
+        """What the agent's busy requeue leans on: an object pinned in the
+        middle of the arena leaves free bytes enough, in holes that are each
+        too small; nothing evictable merges them. Once unpinned and sent to
+        spill BY NAME it comes back first-fit at the low end, and the same
+        reservation fits."""
+        from ray_tpu.core.ids import ObjectID
+        from ray_tpu.core.shm_store import ObjectStoreFullError
+
+        e = (1 << 20) // 8
+        a, dep = ObjectID.from_random(), ObjectID.from_random()
+        self._write(store, a, b"a" * (3 * e - 4096))
+        self._write(store, dep, b"d" * (e - 4096))
+        store.pin(dep)
+        store.delete(a)  # [hole 3/8][dep, pinned 1/8][hole 4/8]
+        out = ObjectID.from_random()
+        with pytest.raises(ObjectStoreFullError, match="fragmented"):
+            store.reserve(out, 5 * e)
+        assert not store.spill(dep), "a pinned object must stay"
+        store.unpin(dep)
+        assert store.spill(dep) and store.offset(dep) is None
+        assert store.ensure_local(dep) == e - 4096
+        store.pin(dep)  # [dep 1/8][hole 7/8]
+        assert store.reserve(out, 5 * e) > store.offset(dep)
+
     def test_delete_frees_arena_space(self, store):
         from ray_tpu.core.ids import ObjectID
 

@@ -1,6 +1,6 @@
-"""Pipelined control plane (ISSUE r06): batched submission, windowed actor
-calls, pushed completions, inline small results — plus the RTPU_PIPELINE=0
-lockstep escape hatch and the ray_perf smoke invocation."""
+"""The control plane (ISSUE r06): batched submission, windowed actor calls,
+pushed completions, inline small results — and the ray_perf smoke
+invocation."""
 
 import json
 import os
@@ -40,7 +40,6 @@ def test_batch_flush_on_size(pipe_cluster):
         return i
 
     rt = _runtime()
-    assert rt.pipelined
     before_batches = rt.submit_batches_sent
     before_tasks = rt.tasks_submitted
     n = 200
@@ -199,58 +198,6 @@ def test_get_resolves_remote_task_via_push(pipe_cluster):
 
     refs = [tiny.remote(i) for i in range(8)]
     assert ray_tpu.get(refs, timeout=120) == [{"i": i} for i in range(8)]
-
-
-# ------------------------------------------------------------ escape hatch
-_LOCKSTEP_SCRIPT = """
-import ray_tpu
-from ray_tpu.cluster import Cluster
-from ray_tpu.core.worker import global_worker
-
-c = Cluster(initialize_head=True, head_node_args={"num_cpus": 2})
-ray_tpu.init(address=c.gcs_address)
-
-@ray_tpu.remote
-def add(a, b):
-    return a + b
-
-@ray_tpu.remote
-class Counter:
-    def __init__(self):
-        self.n = 0
-    def inc(self):
-        self.n += 1
-        return self.n
-
-rt = global_worker().runtime
-assert rt.pipelined is False, "RTPU_PIPELINE=0 must force lockstep"
-assert ray_tpu.get([add.remote(i, 1) for i in range(20)],
-                   timeout=120) == [i + 1 for i in range(20)]
-assert rt.submit_batches_sent == 0, "lockstep must not batch submissions"
-a = Counter.remote()
-assert ray_tpu.get([a.inc.remote() for _ in range(10)],
-                   timeout=120) == list(range(1, 11))
-ready, _ = ray_tpu.wait([a.inc.remote()], timeout=30)
-assert len(ready) == 1
-ray_tpu.shutdown()
-c.shutdown()
-print("LOCKSTEP-OK")
-"""
-
-
-def test_lockstep_mode_end_to_end():
-    """RTPU_PIPELINE=0 restores the lockstep paths (no batches, blocking
-    actor pushes) and everything still works. Subprocess: the flag is read
-    at runtime init, and this pytest process already runs a pipelined
-    driver."""
-    proc = subprocess.run(
-        [sys.executable, "-c", _LOCKSTEP_SCRIPT],
-        capture_output=True, text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "RTPU_PIPELINE": "0"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "LOCKSTEP-OK" in proc.stdout
 
 
 # ----------------------------------------------------------------- tooling

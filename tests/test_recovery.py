@@ -142,31 +142,6 @@ def test_gcs_restart_mid_registration_drain(tmp_path, monkeypatch):
     asyncio.run(scenario())
 
 
-@pytest.mark.chaos
-def test_recovery_disabled_restores_fail_fast(tmp_path, monkeypatch):
-    """RTPU_GCS_RECOVERY=0 (the A/B escape hatch): the same mid-drain
-    restart must fail the waiter promptly instead of parking."""
-    from ray_tpu.core.node.transfer import _RegistrationBatcher
-
-    monkeypatch.setenv("RTPU_GCS_RECOVERY", "0")
-    monkeypatch.setattr(config, "rpc_call_timeout_s", 1.0)
-    monkeypatch.setattr(config, "rpc_retry_attempt_timeout_s", 0.3)
-
-    async def scenario():
-        gcs = GcsServer("127.0.0.1", 0, persist_dir=str(tmp_path))
-        host, port = await gcs.start()
-        client = await RpcClient(f"{host}:{port}").connect()
-        batcher = _RegistrationBatcher(SimpleNamespace(gcs=client))
-        await gcs.stop()
-        with pytest.raises(Exception):
-            await asyncio.wait_for(
-                batcher.register(object_id=OID_A, size=3, node_id=NODE_1),
-                timeout=10)
-        await client.close()
-
-    asyncio.run(scenario())
-
-
 # --------------------------------------------------------------------------- #
 # reconstruction window: stale snapshot locations vs agent re-reports
 # --------------------------------------------------------------------------- #

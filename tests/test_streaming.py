@@ -209,6 +209,7 @@ def stream_cluster():
     c.shutdown()
 
 
+@pytest.mark.timeout_s(120)  # 4 s beside 12 CPU burners
 def test_cluster_streaming_1000_items_flat_memory(stream_cluster):
     """VERDICT done-criterion: 1,000 items consumed incrementally with flat
     memory — the 64 MB store moves 1000 × 128 KB = 125 MB of stream data only

@@ -350,41 +350,135 @@ def test_raw_frames_survive_chaos_truncation_and_drops():
             os.environ.pop(k, None)
 
 
-def test_legacy_msgpack_path_still_works():
-    """RTPU_RAW_TRANSFER=0 (the A/B escape hatch) restores the serial
-    in-band path end to end: pull, broadcast and streamed puts."""
-    env = {
-        "RTPU_RAW_TRANSFER": "0",
-        "RAY_TPU_FETCH_CHUNK_BYTES": str(256 * 1024),
+# ------------------------------------------------- the in-band protocol
+# A client that speaks no raw frames and batches nothing: what
+# cpp/ray_tpu_client.cc sends, from Python (same methods, same fields).
+def _xlang_spec(task_id, function, args, client_id):
+    from ray_tpu.core import serialization
+
+    return {
+        "task_id": task_id, "name": function, "function_id": function,
+        "args_payload": serialization.xlang_pack([args, {}]),
+        "deps": [], "returns": [task_id + "00000001"],
+        "resources": {"CPU": 1.0}, "strategy": {"kind": "default"},
+        "max_retries": 0, "retry_exceptions": False,
+        "holder": client_id, "xlang": True,
     }
-    os.environ.update(env)
-    if ray_tpu.is_initialized():
-        ray_tpu.shutdown()
+
+
+def _in_band_get(agent, object_id, chunk=CHUNK):
+    """Client::GetObject: ensure_local, then read_chunk to the size."""
+    meta = agent.call("ensure_local", object_id=object_id, timeout_s=60.0,
+                      timeout=65.0)
+    assert not meta["is_error"], meta
+    out = b""
+    while len(out) < meta["size"]:
+        out += agent.call("read_chunk", object_id=object_id, offset=len(out),
+                          length=min(chunk, meta["size"] - len(out)))
+    return out
+
+
+def _holders(gcs, object_id):
+    return next(o["holders"] for o in gcs.call("list_objects", limit=100000)
+                if o["object_id"] == object_id)
+
+
+def test_in_band_protocol_serves_a_client_without_raw_frames(xfer_cluster):
+    """Client::PutObject on one node, Client::GetObject on another: the
+    object goes in by receive_chunk, crosses by ensure_local and comes out
+    by read_chunk, byte for byte."""
+    c, n2, _ = xfer_cluster
+    payload = _put_bytes(3 * CHUNK + 1000, seed=11).tobytes()
+    oid = os.urandom(24).hex()
+    head, a2 = _agent(c.nodes[0]), _agent(n2)
     try:
-        c = Cluster(initialize_head=True, head_node_args={"num_cpus": 2})
-        n2 = c.add_node(num_cpus=1)
-        c.wait_for_nodes(2, timeout=60)
-        ray_tpu.init(address=c.gcs_address)
-        from ray_tpu.experimental.broadcast import broadcast
-
-        payload = _put_bytes(2 << 20, seed=7)
-        ref = ray_tpu.put(payload)
-        assert broadcast(ref, timeout=120.0) == 1
-
-        @ray_tpu.remote(num_cpus=1)
-        def total(x):
-            return int(x.sum())
-
-        assert ray_tpu.get(total.remote(ref), timeout=120) == \
-            int(payload.sum())
-        a2 = _agent(n2)
-        try:
-            stats = a2.call("transfer_stats")
-        finally:
-            a2.close()
-        assert stats["pulls"] == 0  # the raw pull manager stayed out of it
-        ray_tpu.shutdown()
-        c.shutdown()
+        for off in range(0, len(payload), CHUNK):
+            head.call("receive_chunk", object_id=oid, total_size=len(payload),
+                      offset=off, data=payload[off:off + CHUNK])
+        assert _in_band_get(a2, oid) == payload
+        assert a2.call("object_info", object_id=oid)["sealed"]
     finally:
-        for k in env:
-            os.environ.pop(k, None)
+        head.close()
+        a2.close()
+
+
+def test_in_band_single_spec_submit_and_pin(xfer_cluster):
+    """Session::SubmitTask: one spec by submit_task (no batch), its return
+    pinned by pin_task at the GCS, its value read back in band."""
+    from ray_tpu.core import serialization
+
+    c, _, _ = xfer_cluster
+    client_id = "w:inband" + os.urandom(6).hex()
+    task_id = os.urandom(8).hex() + "0" * 16 + "00000001"
+    head, gcs = _agent(c.nodes[0]), SyncRpcClient(c.gcs_address)
+    try:
+        spec = _xlang_spec(task_id, "xlang:operator:add", [40, 2], client_id)
+        assert head.call("submit_task", spec=spec)["accepted"]
+        rid = spec["returns"][0]
+        assert serialization.unpack(_in_band_get(head, rid)) == 42
+        before = _holders(gcs, rid)
+        pin = f"task:{task_id}@{client_id}"
+        assert gcs.call("pin_task", task_holder=pin, deps=[], returns=[rid],
+                        submitter=client_id, spec=None)
+        assert _holders(gcs, rid) == before + 1  # submitter held it already
+        gcs.call("remove_object_refs", object_ids=[rid], holder=pin)
+        assert _holders(gcs, rid) == before
+        gcs.call("drop_holder", holder=client_id)
+    finally:
+        head.close()
+        gcs.close()
+
+
+def test_in_band_actor_call_without_seq_or_inline_max(xfer_cluster):
+    """Session::ActorCall: run_actor_task with neither ``seq`` nor
+    ``inline_max``. The calls run in arrival order, the reply carries no
+    inline result, and the value is read from the store."""
+    from ray_tpu.core import serialization
+
+    c, _, _ = xfer_cluster
+    client_id = "w:inband" + os.urandom(6).hex()
+    actor_id = os.urandom(8).hex() + "00000001"
+    head, gcs = _agent(c.nodes[0]), SyncRpcClient(c.gcs_address)
+    worker = None
+    try:
+        spec = _xlang_spec("0" * 16 + actor_id, "xlang:collections:Counter",
+                           [], client_id)
+        spec.update(actor_id=actor_id, max_concurrency=1, max_restarts=0)
+        gcs.call("create_actor", spec=spec,
+                 class_name="xlang:collections:Counter", name="",
+                 namespace="default", max_restarts=0)
+        deadline = time.monotonic() + 60
+        while True:
+            rec = gcs.call("get_actor", actor_id=actor_id)
+            if rec["state"] == "ALIVE":
+                break
+            assert rec["state"] != "DEAD" and time.monotonic() < deadline, rec
+            time.sleep(0.02)
+        worker = SyncRpcClient(rec["address"])
+
+        def call(method, args):
+            task_id = os.urandom(8).hex() + actor_id
+            rid = task_id + "00000001"
+            pin = f"task:{task_id}@{client_id}"
+            gcs.call("pin_task", task_holder=pin, deps=[], returns=[rid],
+                     submitter=client_id, spec=None)
+            reply = worker.call("run_actor_task", spec={
+                "task_id": task_id, "actor_id": actor_id, "method": method,
+                "name": method, "deps": [], "returns": [rid], "xlang": True,
+                "args_payload": serialization.xlang_pack([args, {}]),
+            })
+            assert not (reply or {}).get("inline_returns"), reply
+            gcs.call("remove_object_refs", object_ids=[rid], holder=pin)
+            return rid
+
+        call("update", [["a", "b", "a"]])
+        call("update", [["a"]])
+        rid = call("total", [])
+        assert serialization.unpack(_in_band_get(head, rid)) == 4
+        gcs.call("kill_actor", actor_id=actor_id, no_restart=True)
+        gcs.call("drop_holder", holder=client_id)
+    finally:
+        if worker is not None:
+            worker.close()
+        head.close()
+        gcs.close()

@@ -4,21 +4,15 @@
 Usage:
     python tools/ray_perf.py                 # in-process local runtime
     python tools/ray_perf.py --cluster       # real multi-process cluster (1 node)
-    python tools/ray_perf.py --cluster --no-pipeline   # lockstep control plane
     python tools/ray_perf.py --cluster --smoke         # fast CI smoke preset
     python tools/ray_perf.py --cluster --transfer      # + data-plane MB/s
-    python tools/ray_perf.py --cluster --transfer --no-raw-transfer  # A/B
     python tools/ray_perf.py --cluster --transfer --no-stripe        # A/B
     python tools/ray_perf.py --cluster --stream        # + actor-stream items/s
     python tools/ray_perf.py --cluster --out results.json
 
-Prints one JSON line per metric. --no-pipeline sets RTPU_PIPELINE=0 before
-the cluster starts (inherited by every agent/worker), so regressions are
-attributable to the pipelined control plane vs the lockstep one. The same
-pattern covers the DATA plane: --no-raw-transfer sets RTPU_RAW_TRANSFER=0
-(serial in-band msgpack chunks) and --no-stripe disables multi-source
-striping, so `cluster_transfer_mbps_*` deltas are attributable to the raw
-transfer plane / striping specifically.
+Prints one JSON line per metric. --no-stripe disables multi-source striping
+before the cluster starts (inherited by every agent/worker), so
+`cluster_transfer_mbps_*` deltas are attributable to striping.
 """
 
 import argparse
@@ -200,9 +194,6 @@ def main() -> None:
                         help="run against a real multi-process cluster")
     parser.add_argument("--scale", type=float, default=1.0,
                         help="multiply iteration counts")
-    parser.add_argument("--no-pipeline", action="store_true",
-                        help="lockstep control plane (sets RTPU_PIPELINE=0 "
-                             "for this process tree)")
     parser.add_argument("--transfer", action="store_true",
                         help="also measure data-plane transfer throughput "
                              "(pull/broadcast/striped pull; needs --cluster)")
@@ -211,9 +202,6 @@ def main() -> None:
                              "streaming call: items/s, ms and CPU ms an item "
                              "of 1, 8 and 32 concurrent streams (needs "
                              "--cluster)")
-    parser.add_argument("--no-raw-transfer", action="store_true",
-                        help="serial in-band msgpack data plane (sets "
-                             "RTPU_RAW_TRANSFER=0 for this process tree)")
     parser.add_argument("--no-stripe", action="store_true",
                         help="single-source pulls (disables multi-source "
                              "striping for this process tree)")
@@ -223,10 +211,6 @@ def main() -> None:
                         help="also append a JSON summary line to this file")
     args = parser.parse_args()
 
-    if args.no_pipeline:
-        os.environ["RTPU_PIPELINE"] = "0"
-    if args.no_raw_transfer:
-        os.environ["RTPU_RAW_TRANSFER"] = "0"
     if args.no_stripe:
         os.environ["RAY_TPU_PULL_STRIPE_ENABLED"] = "0"
     if args.smoke:
@@ -286,13 +270,9 @@ def main() -> None:
         transfer_benchmarks(cluster, results, smoke=args.smoke)
 
     if args.out:
-        from ray_tpu.core.config import pipeline_enabled, raw_transfer_enabled
-
         with open(args.out, "a") as f:
             f.write(json.dumps({
                 "mode": mode,
-                "pipeline": pipeline_enabled(),
-                "raw_transfer": raw_transfer_enabled(),
                 "stripe": not args.no_stripe,
                 "scale": s,
                 "results": results,

@@ -1,7 +1,8 @@
 """env-flag: the RTPU_* operator-flag surface must stay registered.
 
-``RTPU_*`` env vars are the operator escape hatches (RTPU_PIPELINE,
-RTPU_RAW_TRANSFER, RTPU_STREAMING_SHUFFLE, ...). Each one must be:
+``RTPU_*`` env vars are the operator escape hatches
+(RTPU_STREAMING_SHUFFLE, RTPU_COLUMNAR_EXCHANGE, RTPU_INLINE_MAX_BYTES).
+Each one must be:
 
 - read ONLY through ``ray_tpu/core/config.py`` (a module-level helper next
   to the matching config entry), never ad hoc at a call site — scattered
