@@ -30,6 +30,14 @@ every kind
     ``serve_programs(config, deployment)``: for ``tools/size_memory.py``, the
     abstract weights, the abstract state a replica keeps beside them, and each
     program the engine runs with its abstract arguments.
+    ``DECODE_MODULE``, ``PREFILL_MODULE``: regular expressions over the names
+    its two programs have in a profile, and ``PREFILL_ROWS_FROM``: where a
+    prefill call holds several rows, the operation that tells how many
+    (``readers/module_time.py`` ``rows_from``), else None. All three are
+    REQUIRED: ``decode_device_per_step`` and ``prefill_device_per_call`` are
+    one entry each for every family and name these
+    (``manifest.resolve_params`` raises for a name a family lacks): a new
+    family states its own and adds its cell to their ``workloads``.
 
 ``"kind": "train"``
     ``loss(params, tokens, targets, config)``: the program's loss, whose

@@ -27,6 +27,12 @@ from benchmarks.families.kimi_k2_reference import (  # noqa: F401 - the surface
     make_gap_fn, make_greedy_fn, reference_logits)
 from benchmarks.harness.weights import seed_key
 
+# the programs' names in a profile (``families/__init__.py``, the serve surface)
+DECODE_MODULE = "^jit_kimi_k2_decode"
+PREFILL_MODULE = "^jit_kimi_k2_prefill"
+# a prefill call holds one row: the time is a call's
+PREFILL_ROWS_FROM = None
+
 
 class _NoProgram:
     """The engine of a commit whose program lacks this family: the replica

@@ -21,7 +21,14 @@ from typing import Any, Dict
 
 from benchmarks.families.nemotron_h_reference import (  # noqa: F401 - the surface
     make_gap_fn, make_greedy_fn, reference_logits)
+from benchmarks.harness.trace_reduce import FLASH_CALL_ROWS
 from benchmarks.harness.weights import seed_key
+
+# the programs' names in a profile (``families/__init__.py``, the serve surface)
+DECODE_MODULE = "^jit_nemotron_h_decode"
+PREFILL_MODULE = "^jit_nemotron_h_prefill"
+# a prefill call holds several rows: the flash call's batch says how many
+PREFILL_ROWS_FROM = FLASH_CALL_ROWS
 
 
 class _NoProgram:

@@ -23,6 +23,12 @@ from benchmarks.families.phi4flash_reference import (  # noqa: F401 - the surfac
     kind_of, make_gap_fn, make_greedy_fn, reference_logits, sizes)
 from benchmarks.harness.weights import seed_key
 
+# the programs' names in a profile (``families/__init__.py``, the serve surface)
+DECODE_MODULE = "^jit_phi4flash_decode"
+PREFILL_MODULE = "^jit_phi4flash_prefill"
+# a prefill call holds one row: the time is a call's
+PREFILL_ROWS_FROM = None
+
 
 class _NoProgram(_NoLagunaProgram):
     """The engine of a commit whose program lacks this family: it answers

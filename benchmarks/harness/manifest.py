@@ -7,7 +7,7 @@ import importlib
 import json
 import os
 import re
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -78,15 +78,70 @@ def family_of(cfg: Dict[str, Any]):
     return load_plugin("families", cfg["family"])
 
 
+def resolve_params(params: Dict[str, Any],
+                   cfg: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    """One entry a MEANING, whatever the family: a parameter a family or a
+    configuration decides is not written into the metric's file but named
+    there, and a cell of a new family brings the value in its own new files.
+
+        {"family": "NAME"}       the attribute NAME of the cell's family module
+                                 (a program's name in the trace, the operation
+                                 that tells a call's rows). The family HAS to
+                                 state it (``AttributeError`` otherwise: a
+                                 forgotten name may not read another
+                                 quantity in silence); stated as None it
+                                 leaves the parameter out
+        {"config_span": "key"}   last minus first of the pair the cell's
+                                 configuration file holds under that key
+                                 (``held_experts`` [0, 64]: 64 experts held)
+    """
+    out = {}
+    for key, value in params.items():
+        if isinstance(value, dict) and len(value) == 1:
+            (how, name), = value.items()
+            if how in ("family", "config_span") and cfg is None:
+                raise KeyError(f"parameter {key!r} is decided by the cell's "
+                               f"configuration, and the caller gave none")
+            if how == "family":
+                family = family_of(cfg)
+                if not hasattr(family, name):
+                    raise AttributeError(
+                        f"{family.__name__} states no {name} (parameter {key!r}; "
+                        f"families/__init__.py lists what a family states)")
+                value = getattr(family, name)
+                if value is None:
+                    continue
+            elif how == "config_span":
+                value = cfg[name][-1] - cfg[name][0]
+        out[key] = value
+    return out
+
+
+def metric_params(name: str, cfg: Dict[str, Any], root: str = ROOT,
+                  manifest=None) -> Dict[str, Any]:
+    """The parameters of the metric's file, resolved for the cell's family
+    and configuration: what its reader is given, and what a runner asks of
+    to know which operations a trace summary has to keep."""
+    return resolve_params(metric_file(name, root, manifest).get("params", {}), cfg)
+
+
+def read_metric(name: str, ctx: Dict[str, Any], root: str = ROOT, manifest=None):
+    """The metric's reader over the run's observations, its parameters
+    resolved by ``ctx["cfg"]``, the cell's configuration (a ``KeyError`` where
+    a parameter needs it and the caller left it out). None where the reader
+    finds nothing to read."""
+    spec = metric_file(name, root, manifest)
+    params = resolve_params(spec.get("params", {}), ctx.get("cfg"))
+    return load_plugin("readers", spec["reader"]).read(ctx, params)
+
+
 def read_metrics(manifest, workload: str, group: str, ctx: Dict[str, Any],
                  root: str = ROOT) -> Dict[str, Dict[str, Any]]:
     """Run each metric's reader over the run's observations. A reader that
     finds nothing to read returns None and the metric is left out."""
     out = {}
     for m in metrics_for(manifest, workload, group):
-        spec = metric_file(m["name"], root, manifest)
-        reader = load_plugin("readers", spec["reader"])
-        value = reader.read(ctx, spec.get("params", {}))
+        value = read_metric(m["name"], ctx, root, manifest)
         if value is None:
             continue
         out[m["name"]] = {"value": value, "unit": m["unit"]}

@@ -4,6 +4,7 @@ events, percentiles with failures counted as missing."""
 from __future__ import annotations
 
 import math
+import statistics
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 
@@ -22,6 +23,25 @@ def between_events_rate(events: Iterable[Tuple[float, float]],
         return None
     amount = sum(a for t, a in inside if t > first)
     return amount / (last - first)
+
+
+def median_interval_rate(events: Iterable[Tuple[float, float]],
+                         t0: float, t1: float,
+                         least: int = 3) -> Optional[float]:
+    """The median, over every two consecutive instants with t0 <= instant
+    <= t1, of the later one's amount over the time between them. One stall
+    of the host inside a window of five intervals moves the first-to-last
+    rate by the stall's share of the window and this not at all; a stall in
+    EVERY interval moves both alike, and one in every other interval only
+    the first-to-last rate, which therefore stays printed beside it. With
+    fewer than ``least`` intervals a median has nothing to outvote: then
+    ``between_events_rate``."""
+    inside = sorted((t, a) for t, a in events if t0 <= t <= t1)
+    rates = [a / (t - before) for (before, _), (t, a)
+             in zip(inside, inside[1:]) if t > before]
+    if len(rates) < least:
+        return between_events_rate(inside, t0, t1)
+    return statistics.median(rates)
 
 
 def fixed_window_rate(events: Iterable[Tuple[float, float]],

@@ -17,6 +17,10 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 MIN_GAP_NS = 10_000  # shorter pauses between two ops are not idle gaps worth listing
+# the flash-attention forward call in an op's HLO text (``op_key``): output and
+# first operand ``bf16[rows, heads, seq, head_dim]``; the group is the rows
+FLASH_CALL_ROWS = (r"= bf16\[(\d+),\d+,\d+,\d+\]\S* "
+                   r"custom-call\(bf16\[\1,\d+,\d+,\d+\]")
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:

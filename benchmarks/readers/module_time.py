@@ -13,7 +13,10 @@ operation that runs inside it. params:
         pattern matches): the group of the heaviest match is the rows a call
         of that module holds, and the time is then a ROW's, so that calls of
         1 and 4 rows are one quantity. A matched module without such an
-        operation has no known rows: nothing is read
+        operation has no known rows: nothing is read (a module of which
+        the profile holds no whole call is left out before that: the part
+        of a call it saw may end before the operation, and its time is not
+        read anyway)
     "cut_at_edges": false where the runner starts and stops the profile
         between calls (training); else the calls that touch the profile's
         first or last instant are left out (``trace_reduce._whole``): the
@@ -53,6 +56,10 @@ def read(ctx, params):
     rows_from = re.compile(params["rows_from"]) if params.get("rows_from") else None
     total, units = 0.0, 0
     for name in names:
+        if not count.get(name):
+            # every call of it touches the profile's edge: no time is read
+            # from it, so the part the profile saw need not name its rows
+            continue
         rows = 1
         if rows_from is not None:
             rows = _rows(trace.get("module_ops", {}).get(name, {}), rows_from)

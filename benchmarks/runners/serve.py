@@ -165,10 +165,9 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
     if args.trace:
         # the operations a metric of this cell knows a program's rows by
         manifest = mf.load_manifest()
-        specs = [mf.metric_file(m["name"], manifest=manifest)
-                 for m in mf.metrics_for(manifest, args.workload, "per_layer")]
-        keep = sorted({s["params"]["rows_from"] for s in specs
-                       if "rows_from" in s.get("params", {})})
+        params = [mf.metric_params(m["name"], cfg, manifest=manifest)
+                  for m in mf.metrics_for(manifest, args.workload, "per_layer")]
+        keep = sorted({p["rows_from"] for p in params if "rows_from" in p})
         summary = handle.trace_summary.remote(keep).result(timeout=600)
     return {
         "cfg": cfg, "plan_offered": plan["offered"],

@@ -14,6 +14,7 @@ import time
 from typing import Any, Dict
 
 from benchmarks.harness.manifest import load_plugin
+from benchmarks.readers import train_token_rate
 
 
 def init_state(family, config, optimizer, key):
@@ -241,10 +242,10 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
     inside = [t for t in reports if w_open <= t <= w_close]
     finite = all(math.isfinite(x) for x in final["losses"])
     print("report gaps s: " + " ".join(
-        f"{b - a:.2f}" for a, b in zip(reports, reports[1:]))
+        f"{b - a:.4f}" for a, b in zip(reports, reports[1:]))
         + f"; longest input wait {max(final['input_waits']):.3f} s"
         + f", longest report wait {max(final['report_waits']):.3f} s", file=sys.stderr)
-    return {
+    ctx = {
         "cfg": cfg, "chips": chips,
         "plan_offered": data["offered"],
         "reports": reports, "report_tokens": amounts,
@@ -265,3 +266,11 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
         "check": {**final["check"], "all_losses_finite": finite},
         "check_limits": traffic.get("check", {}).get("limits", {}),
     }
+    # the metric is the first-to-last rate: every token and every second of
+    # the window. The median of the intervals' rates beside it leaves one
+    # stall out, so the two apart say "a stall", the two alike "a slower step"
+    rates = train_token_rate.both(ctx)
+    if rates:
+        print(f"train rate tokens/s/chip: first to last {rates[0]:.3f}, "
+              f"median of intervals {rates[1]:.3f}", file=sys.stderr)
+    return ctx

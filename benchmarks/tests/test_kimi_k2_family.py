@@ -18,6 +18,7 @@ from benchmarks.families import kimi_k2 as family
 from benchmarks.harness import manifest as mf
 from benchmarks.harness import reference as ref
 from benchmarks.harness.weights import load_config_file
+from benchmarks.readers import poll_level
 
 KIMI_FILE = os.path.join(mf.ROOT, "benchmarks", "configs",
                          "kimi-k2.6-serve.json")
@@ -240,15 +241,14 @@ def kimi_k2_ctx():
 
 
 def _kimi_k2_read(ctx, name):
-    spec = mf.metric_file(name)
-    return mf.load_plugin("readers", spec["reader"]).read(ctx, spec["params"])
+    return mf.read_metric(name, ctx)
 
 
 def test_kimi_k2_readers_on_a_hand_made_context(kimi_k2_ctx):
     cfg = kimi_k2_ctx["cfg"]
-    assert _kimi_k2_read(kimi_k2_ctx, "decode_device_per_step.mla") \
+    assert _kimi_k2_read(kimi_k2_ctx, "decode_device_per_step") \
         == pytest.approx(1e3 * 0.2 / 16)
-    assert _kimi_k2_read(kimi_k2_ctx, "prefill_device_per_call.mla") \
+    assert _kimi_k2_read(kimi_k2_ctx, "prefill_device_per_call") \
         == pytest.approx(1e3 * 1.6 / 2)
     assert _kimi_k2_read(kimi_k2_ctx, "latent_attn_decode_share") \
         == pytest.approx(100 * 0.06 / 0.2)
@@ -268,13 +268,15 @@ def test_kimi_k2_readers_on_a_hand_made_context(kimi_k2_ctx):
     assert _kimi_k2_read(kimi_k2_ctx, "flash_mla_fwd_roofline") \
         == pytest.approx(100 * flops / 197e12 / 0.7)
     # counters and levels
-    assert _kimi_k2_read(kimi_k2_ctx, "latent_bytes_per_token") \
-        == pytest.approx(1280.0)
-    assert _kimi_k2_read(kimi_k2_ctx, "kv_pool_fill.mla") == pytest.approx(
+    # (latent_bytes_per_token, a constant of the layout, was retired in PR 51;
+    # the level stays in stats() and reads as before)
+    assert poll_level.read(kimi_k2_ctx, {
+        "key": "kv_bytes_per_token", "scale": 1 / 6}) == pytest.approx(1280.0)
+    assert _kimi_k2_read(kimi_k2_ctx, "kv_pool_fill") == pytest.approx(
         100 * (2400 + 2800 + 3600 + 4000 + 4400) / 5 / 6272)
-    assert _kimi_k2_read(kimi_k2_ctx, "held_assignment_share.mla") \
+    assert _kimi_k2_read(kimi_k2_ctx, "held_assignment_share") \
         == pytest.approx(100 * 20 / 640)
-    assert _kimi_k2_read(kimi_k2_ctx, "expert_load_max_over_mean.mla") \
+    assert _kimi_k2_read(kimi_k2_ctx, "expert_load_max_over_mean") \
         == pytest.approx(12 * 5 / 20)
 
 
@@ -284,21 +286,23 @@ def test_kimi_k2_cell_reports_what_the_manifest_says():
     own = {m["name"] for m in manifest["per_layer"]
            if m.get("workloads") == [KIMI_CELL]}
     assert own >= {
-        "decode_device_per_step.mla", "prefill_device_per_call.mla",
         "latent_attn_decode_share", "mla_prefill_attn_share",
         "moe_decode_share.mla", "latent_attn_decode_roofline",
-        "flash_mla_fwd_roofline", "latent_bytes_per_token", "kv_pool_fill.mla",
-        "held_assignment_share.mla", "expert_load_max_over_mean.mla",
-        "ttft_mean.longdoc", "ttft_p90.longdoc", "tpot_p50.longdoc"}
-    assert own | {"peak_hbm.serve", "device_idle_share.serve",
+        "flash_mla_fwd_roofline", "tpot_p50.longdoc"}
+    # one entry a meaning since PR 51: what other families report too is read
+    # under the name they read it under
+    assert own | {"decode_device_per_step", "prefill_device_per_call",
+                  "kv_pool_fill", "held_assignment_share",
+                  "expert_load_max_over_mean", "ttft_mean", "ttft_p90",
+                  "peak_hbm.serve", "device_idle_share.serve",
                   "compiles_in_window", "ingress_overhead_p50",
                   "client_to_engine_p50", "first_token_return_p50",
                   "admit_burst_p90", "engine_step_wall",
                   "gc_pause_in_window"} <= per_layer
     # another family's counts would charge rows this one does not read
     assert not {"paged_attn_roofline", "full_attn_decode_roofline",
-                "decode_device_per_step.chat", "decode_device_per_step",
-                "decode_device_per_step.phi"} & per_layer
+                "decode_device_per_step.chat", "window_attn_decode_roofline",
+                "shared_kv_decode_roofline"} & per_layer
     # tpot_p50 spread over half its bound in six runs (two and a half rounds
     # of 16 prefills a window): it is a per-layer metric here, as ISSUE 44 says
     assert {m["name"] for m in mf.metrics_for(manifest, KIMI_CELL, "end_to_end")} \
@@ -334,7 +338,11 @@ def test_kimi_k2_readers_read_nothing_from_a_program_without_the_family(
     names = [m["name"] for m in manifest["per_layer"]
              if m.get("workloads") == [KIMI_CELL]
              and not m["name"].startswith(("ttft_", "tpot_"))]
-    assert len(names) == 11
+    # and what this family reads under a name it shares since PR 51
+    names += ["decode_device_per_step", "prefill_device_per_call",
+              "kv_pool_fill", "held_assignment_share",
+              "expert_load_max_over_mean"]
+    assert len(names) == 10
     for name in names:
         assert _kimi_k2_read(bare, name) is None, name
-        assert _kimi_k2_read({}, name) is None, name
+        assert _kimi_k2_read({"cfg": bare["cfg"]}, name) is None, name

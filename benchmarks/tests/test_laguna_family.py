@@ -271,15 +271,14 @@ def laguna_ctx():
 
 
 def _laguna_read(ctx, name):
-    spec = mf.metric_file(name)
-    return mf.load_plugin("readers", spec["reader"]).read(ctx, spec["params"])
+    return mf.read_metric(name, ctx)
 
 
 def test_laguna_readers_on_a_hand_made_context(laguna_ctx):
     cfg, peak = laguna_ctx["cfg"], 819e9
-    assert _laguna_read(laguna_ctx, "decode_device_per_step.laguna") \
+    assert _laguna_read(laguna_ctx, "decode_device_per_step") \
         == pytest.approx(1e3 * 0.4 / 16)
-    assert _laguna_read(laguna_ctx, "prefill_device_per_call.laguna") \
+    assert _laguna_read(laguna_ctx, "prefill_device_per_call") \
         == pytest.approx(1e3 * 0.6 / 2)
     # both kernels are attention; the share tells neither from the other
     assert _laguna_read(laguna_ctx, "attn_decode_share") \
@@ -311,9 +310,9 @@ def test_laguna_readers_on_a_hand_made_context(laguna_ctx):
     # counters and levels
     assert _laguna_read(laguna_ctx, "kv_pool_fill") == pytest.approx(
         100 * (4800 + 5600 + 7200 + 8000 + 8800) / 5 / 9600)
-    assert _laguna_read(laguna_ctx, "held_assignment_share.laguna") \
+    assert _laguna_read(laguna_ctx, "held_assignment_share") \
         == pytest.approx(12.5)
-    assert _laguna_read(laguna_ctx, "expert_load_max_over_mean.laguna") \
+    assert _laguna_read(laguna_ctx, "expert_load_max_over_mean") \
         == pytest.approx(32 * 2 / 24)
 
 
@@ -324,12 +323,14 @@ def test_laguna_cell_reports_what_the_manifest_says():
             "flash_window_fwd_roofline", "attn_decode_share", "kv_pool_fill",
             "peak_hbm.serve", "device_idle_share.serve",
             # the way to the first token: proxy, router, replica, admission
-            "ttft_p90.longctx", "ttft_mean.longctx", "compiles_in_window",
+            "ttft_p90", "ttft_mean", "compiles_in_window",
+            "decode_device_per_step", "prefill_device_per_call",
             "ingress_overhead_p50", "client_to_engine_p50",
             "first_token_return_p50", "admit_burst_p90"} <= per_layer
     # stale counts that would charge a window layer for rows it does not read
-    assert not {"paged_attn_roofline", "decode_device_per_step",
-                "prefill_device_per_call"} & per_layer
+    assert not {"paged_attn_roofline", "decode_device_per_step.chat",
+                "prefill_device_per_call.chat",
+                "shared_kv_decode_roofline"} & per_layer
     assert {m["name"] for m in mf.metrics_for(manifest, CELL, "end_to_end")} \
         == {"serve_tokens_per_s", "tpot_p50", "setup_s"}
     with open(os.path.join(mf.ROOT, "benchmarks", "traffic",
@@ -356,8 +357,10 @@ def test_laguna_readers_read_nothing_from_a_program_without_the_family(laguna_ct
              if "laguna" in n] + [
         "full_attn_decode_roofline.json", "window_attn_decode_roofline.json",
         "flash_window_fwd_roofline.json", "attn_decode_share.json",
-        "kv_pool_fill.json"]
+        "kv_pool_fill.json", "decode_device_per_step.json",
+        "prefill_device_per_call.json", "held_assignment_share.json",
+        "expert_load_max_over_mean.json"]
     assert len(names) == 10
     for name in names:
         assert _laguna_read(bare, name[:-5]) is None, name
-        assert _laguna_read({}, name[:-5]) is None, name
+        assert _laguna_read({"cfg": bare["cfg"]}, name[:-5]) is None, name

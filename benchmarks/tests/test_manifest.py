@@ -123,6 +123,27 @@ def test_every_moves_names_an_end_to_end_metric_of_the_same_cells(manifest):
             assert "moves" not in spec, fname
 
 
+def test_one_entry_a_meaning(manifest):
+    """``per_layer`` was full at 128 of 128 (PR 48) because a family brought
+    its own copy of a quantity another family already read. Every entry lists
+    at least one cell that exists, and no two entries share reader, parameters
+    and ``moves``: a twin is one entry with both cells in its ``workloads``;
+    what a family decides (a program's name, the experts held) is named in the
+    parameters and stated in the family's or the configuration's own file
+    (``manifest.resolve_params``)."""
+    cells = {w["name"] for w in manifest["workloads"]}
+    seen = {}
+    for m in manifest["per_layer"]:
+        assert m.get("workloads"), m["name"]
+        assert set(m["workloads"]) & cells, m["name"]
+        assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
+        spec = mf.metric_file(m["name"])
+        key = (spec["reader"], json.dumps(spec.get("params", {}), sort_keys=True),
+               m["moves"])
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
+
+
 def test_configs_keep_the_published_widths(manifest):
     """EVERY configuration names the file that holds its source's published
     shape (``benchmarks/published/<name>.json``), and differs from it in
@@ -220,6 +241,7 @@ contract.test_top_level_and_limits(m)
 contract.test_names_units_and_keys(m)
 contract.test_every_cell_resolves_and_reports(m)
 contract.test_every_moves_names_an_end_to_end_metric_of_the_same_cells(m)
+contract.test_one_entry_a_meaning(m)
 contract.test_configs_keep_the_published_widths(m)
 # every family of the copy: a configuration, weights at tiny widths, one
 # call of its reference

@@ -150,17 +150,16 @@ def hybrid_ctx():
 
 
 def _hybrid_read(ctx, name):
-    spec = mf.metric_file(name)
-    return mf.load_plugin("readers", spec["reader"]).read(ctx, spec["params"])
+    return mf.read_metric(name, ctx)
 
 
 def test_hybrid_readers_on_a_recorded_context(hybrid_ctx):
     trace = hybrid_ctx["trace"]
     decode = trace["module_s"]["jit_nemotron_h_decode_steps(10832319702325659996)"]
     # 6 calls of 8 steps took 0.874 s; 7 prefill calls of 8 rows 1.285 s
-    assert _hybrid_read(hybrid_ctx, "decode_device_per_step.hybrid") \
+    assert _hybrid_read(hybrid_ctx, "decode_device_per_step") \
         == pytest.approx(1e3 * decode / 48)
-    assert _hybrid_read(hybrid_ctx, "prefill_device_per_call.hybrid") \
+    assert _hybrid_read(hybrid_ctx, "prefill_device_per_call") \
         == pytest.approx(1e3 * 1.285057821 / (7 * 8))
     # one fusion a layer and tick holds both expert products: 5 x 48 of them
     moe, n_moe = ops_share.ops_seconds_and_count(
@@ -218,12 +217,12 @@ def test_hybrid_readers_read_nothing_from_a_program_without_the_family(hybrid_ct
             "cfg": hybrid_ctx["cfg"], "device_report": {"kind": "TPU v5 lite"},
             "marks": {"open": 0.0, "close": 9.0, "polls": [
                 (t, {"decode_steps": 10 * t, "iters": t}) for t in (1.0, 2.0, 3.0)]}}
-    for name in ("decode_device_per_step.hybrid", "prefill_device_per_call.hybrid",
+    for name in ("decode_device_per_step", "prefill_device_per_call",
                  "moe_decode_share", "ssm_decode_share", "moe_decode_roofline",
                  "ssm_update_roofline", "expert_load_max_over_mean",
                  "held_assignment_share"):
         assert _hybrid_read(bare, name) is None, name
-        assert _hybrid_read({}, name) is None, name
+        assert _hybrid_read({"cfg": bare["cfg"]}, name) is None, name
     # the operations are there, the counters are not
     hybrid_ctx["marks"]["polls"] = [
         (t, {k: v for k, v in s.items() if not k.startswith("moe_")})

@@ -19,6 +19,7 @@ from benchmarks.harness import manifest as mf
 from benchmarks.harness import reference as ref
 from benchmarks.harness.loadgen import RequestRecord
 from benchmarks.harness.weights import load_config_file
+from benchmarks.readers import engine_counters
 
 PHI_FILE = os.path.join(mf.ROOT, "benchmarks", "configs",
                         "phi-4-mini-flash-reasoning-serve.json")
@@ -289,15 +290,14 @@ def phi4flash_ctx():
 
 
 def _phi4flash_read(ctx, name):
-    spec = mf.metric_file(name)
-    return mf.load_plugin("readers", spec["reader"]).read(ctx, spec["params"])
+    return mf.read_metric(name, ctx)
 
 
 def test_phi4flash_readers_on_a_hand_made_context(phi4flash_ctx):
     cfg, peak = phi4flash_ctx["cfg"], 819e9
-    assert _phi4flash_read(phi4flash_ctx, "decode_device_per_step.phi") \
+    assert _phi4flash_read(phi4flash_ctx, "decode_device_per_step") \
         == pytest.approx(1e3 * 0.4 / 16)
-    assert _phi4flash_read(phi4flash_ctx, "prefill_device_per_call.phi") \
+    assert _phi4flash_read(phi4flash_ctx, "prefill_device_per_call") \
         == pytest.approx(1e3 * 0.9 / 2)
     # the shared reads alone: the window calls are another kernel by name
     assert _phi4flash_read(phi4flash_ctx, "shared_kv_decode_share") \
@@ -314,7 +314,7 @@ def test_phi4flash_readers_on_a_hand_made_context(phi4flash_ctx):
     assert 90 < want < 95
     want = 100 * family.window_attn_decode_bytes(cfg, 128, 24, 8 * 24 * 512) \
         / peak / 0.0128
-    assert _phi4flash_read(phi4flash_ctx, "window_attn_decode_roofline.phi") \
+    assert _phi4flash_read(phi4flash_ctx, "window_attn_decode_roofline") \
         == pytest.approx(want)
     flops = 8 * family.flash_diff_fwd_flops(cfg, 1, 40, 8192, 128) \
         + 8 * family.flash_diff_fwd_flops(cfg, 1, 40, 16384, 128)
@@ -328,28 +328,31 @@ def test_phi4flash_readers_on_a_hand_made_context(phi4flash_ctx):
         == pytest.approx(100 * family.selective_scan_step_bytes(cfg, 144, 24)
                          / peak / 0.0072)
     # counters and levels
-    assert _phi4flash_read(phi4flash_ctx, "cross_rows_in_prefill") \
-        == pytest.approx(100 / 9216)
-    assert _phi4flash_read(phi4flash_ctx, "kv_pool_fill.phi") == pytest.approx(
+    # (cross_rows_in_prefill, a constant of the layout, was retired in PR 51;
+    # its two counters stay in stats() and read as before)
+    assert engine_counters.read(phi4flash_ctx, {
+        "plus": ["prefill_rows_cross"], "over": "prefill_rows_self",
+        "scale": 100}) == pytest.approx(100 / 9216)
+    assert _phi4flash_read(phi4flash_ctx, "kv_pool_fill") == pytest.approx(
         100 * (2400 + 2800 + 3600 + 4000 + 4400) / 5 / 6336)
 
 
 def test_phi4flash_cells_report_what_the_manifest_says():
     manifest = mf.load_manifest()
     per_layer = {m["name"] for m in mf.metrics_for(manifest, PHI_CELL, "per_layer")}
-    assert {"shared_kv_decode_roofline", "window_attn_decode_roofline.phi",
+    assert {"shared_kv_decode_roofline", "window_attn_decode_roofline",
             "selective_scan_fwd_roofline", "selective_scan_step_roofline",
             "flash_diff_fwd_roofline", "shared_kv_decode_share",
-            "scan_prefill_share", "cross_rows_in_prefill", "kv_pool_fill.phi",
-            "decode_device_per_step.phi", "prefill_device_per_call.phi",
-            "ttft_mean.history", "ttft_p90.history", "peak_hbm.serve",
+            "scan_prefill_share", "kv_pool_fill",
+            "decode_device_per_step", "prefill_device_per_call",
+            "ttft_mean", "ttft_p90", "peak_hbm.serve",
             "device_idle_share.serve", "compiles_in_window",
             "ingress_overhead_p50", "client_to_engine_p50",
             "first_token_return_p50", "admit_burst_p90"} <= per_layer
     # another family's counts would charge rows this one does not read
     assert not {"paged_attn_roofline", "full_attn_decode_roofline",
-                "window_attn_decode_roofline", "decode_device_per_step",
-                "decode_device_per_step.laguna"} & per_layer
+                "decode_device_per_step.chat", "latent_attn_decode_roofline",
+                "moe_decode_share.laguna"} & per_layer
     assert {m["name"] for m in mf.metrics_for(manifest, PHI_CELL, "end_to_end")} \
         == {"serve_tokens_per_s", "tpot_p50", "setup_s"}
     with open(os.path.join(mf.ROOT, "benchmarks", "traffic",
@@ -398,7 +401,10 @@ def test_phi4flash_readers_read_nothing_from_a_program_without_the_family(
     names = [m["name"] for m in manifest["per_layer"]
              if m.get("workloads") == [PHI_CELL]
              and not m["name"].startswith("ttft_")]
-    assert len(names) == 11
+    # and what this family reads under a name it shares since PR 51
+    names += ["decode_device_per_step", "prefill_device_per_call",
+              "kv_pool_fill", "window_attn_decode_roofline"]
+    assert len(names) == 10
     for name in names:
         assert _phi4flash_read(bare, name) is None, name
-        assert _phi4flash_read({}, name) is None, name
+        assert _phi4flash_read({"cfg": bare["cfg"]}, name) is None, name
