@@ -69,18 +69,17 @@ from typing import Any, Dict, Mapping, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import cache_rows
 from ray_tpu.models.paged_decode import (
     _live_lengths, _scatter_prompt_rows_full, _scatter_token_rows, _walk,
     counted_decode_steps)
 from ray_tpu.ops.moe import routed_experts, swiglu_mlp
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.paged_attention import paged_attention_latent
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 ROPE_K26 = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
             "mscale": 1, "mscale_all_dim": 1,
             "original_max_position_embeddings": 4096}
-LANES = 128
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -127,7 +126,8 @@ class KimiK2Config:
     def latent_width(self) -> int:
         """A cached row: ``kv_lora_rank + qk_rope_head_dim`` values in whole
         lane tiles."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // LANES) * LANES
+        return cache_rows.latent_width(self.kv_lora_rank,
+                                       self.qk_rope_head_dim)
 
     @property
     def softmax_scale(self) -> float:
@@ -277,13 +277,10 @@ def _latents(config: KimiK2Config, lp, y, rope, positions=None):
     """y: [B, T, h] normed -> (c_q [B, T, r_q] normed, the rows to cache
     [B, T, 1, latent_width] = [rms(c) | rope(k_r) | 0]). The rotated key is
     ONE head."""
-    rkv, dr = config.kv_lora_rank, config.qk_rope_head_dim
     c_q = rms_norm(y @ lp["wq_a"], lp["q_norm"], config.rms_norm_eps)
-    ckv = y @ lp["wkv_a"]
-    c = rms_norm(ckv[..., :rkv], lp["kv_norm"], config.rms_norm_eps)
-    k_r = apply_rope(ckv[..., None, rkv:], *rope, positions)
-    pad = jnp.zeros((*c.shape[:-1], 1, config.latent_width - rkv - dr), c.dtype)
-    return c_q, jnp.concatenate([c[..., None, :], k_r, pad], axis=-1)
+    return c_q, cache_rows.latent_rows(
+        y @ lp["wkv_a"], lp["kv_norm"], config.rms_norm_eps,
+        config.kv_lora_rank, config.latent_width, rope, positions)
 
 
 def _experts(config: KimiK2Config, lp, rows, impl: str, counted):
@@ -414,20 +411,6 @@ def paged_prefill(params, cache: KimiK2Cache, tokens, pages, lengths,
 # --------------------------------------------------------------------------- #
 # Decode: absorbed
 # --------------------------------------------------------------------------- #
-def _latent_attention_reference(q, pool, table, lengths, v_width: int):
-    """Gather-based ``paged_attention_latent`` (CPU tests, widths the kernel
-    does not tile). q: [B, G, W] scaled; pool: [1, P, ps, W]."""
-    b, _, w = q.shape
-    kg = pool[0][table].reshape(b, -1, w)                 # [B, S, W]
-    logits = jnp.einsum("bgw,bsw->bgs", q, kg,
-                        preferred_element_type=jnp.float32)
-    seen = jnp.arange(kg.shape[1])[None, :] < lengths[:, None]
-    probs = jax.nn.softmax(jnp.where(seen[:, None, :], logits, -1e30), axis=-1)
-    out = jnp.einsum("bgs,bsv->bgv", probs.astype(kg.dtype),
-                     kg[..., :v_width], preferred_element_type=jnp.float32)
-    return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
-
-
 def _decode_layer(config: KimiK2Config, lp, x, pool, base, tick,
                   use_kernel: bool):
     """One layer of a decode tick over every slot. x: [B, h] -> (x, pool, the
@@ -442,20 +425,10 @@ def _decode_layer(config: KimiK2Config, lp, x, pool, base, tick,
         c_q, row = _latents(config, lp, y[:, None], rope, safe_pos[:, None])
         pool = _scatter_token_rows(pool, row[:, 0], pages + base, rows)
         q = (c_q[:, 0] @ lp["wq_b"]).reshape(nb, nh, dn + dr)
-        q_r = apply_rope(q[:, None, :, dn:], *rope, safe_pos[:, None])[:, 0]
-        # W_kvb a head: [r_kv, nh, d_n | d_v]; W_uk and W_uv are views of it
-        w_kvb = lp["wkv_b"].reshape(rkv, nh, dn + dv)
-        q_c = jnp.einsum("bhn,chn->bhc", q[..., :dn], w_kvb[..., :dn])
-        pad = jnp.zeros((nb, nh, config.latent_width - rkv - dr), q.dtype)
-        q_lat = jnp.concatenate([q_c, q_r, pad], axis=-1)
-        q_lat = (q_lat * config.softmax_scale).astype(q.dtype)
-        if use_kernel:
-            o = paged_attention_latent(q_lat, pool, lengths, table + base,
-                                       v_width=rkv)
-        else:
-            o = _latent_attention_reference(q_lat, pool, table + base,
-                                            lengths, rkv)
-        a = jnp.einsum("bhc,chv->bhv", o, w_kvb[..., dn:])
+        a = cache_rows.absorbed_attention(
+            q, lp["wkv_b"], pool, table, base, lengths, rope, safe_pos,
+            rank=rkv, nope=dn, scale=config.softmax_scale,
+            use_kernel=use_kernel)
         x = x + a.reshape(nb, nh * dv) @ lp["wo"]
     y = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
     if "mlp" in lp:
@@ -523,7 +496,7 @@ def paged_decode_steps(params, cache: KimiK2Cache, tokens, positions, active,
 def paged_kernel_fits(config: KimiK2Config) -> bool:
     """``paged_attention_latent`` slices the values off the fetched rows at
     a lane tile."""
-    return config.kv_lora_rank % LANES == 0
+    return config.kv_lora_rank % cache_rows.LANES == 0
 
 
 def make_paged_decode_fn(config: KimiK2Config, num_steps: int, page_size: int,

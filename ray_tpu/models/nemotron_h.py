@@ -42,6 +42,8 @@ from typing import Any, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.cache_rows import (
+    init_slot_state, put_prompt_state, put_slot_state, slot_state)
 from ray_tpu.models.paged_decode import (
     _live_lengths, _paged_attention, _scatter_prompt_rows_full,
     _scatter_token_rows, counted_decode_steps)
@@ -144,10 +146,11 @@ def init_cache(config: NemotronHConfig, num_slots: int, total_pages: int,
     lm = config.count("M")
     return HybridCache(
         k=jnp.zeros(pool, config.dtype), v=jnp.zeros(pool, config.dtype),
-        ssm=jnp.zeros((lm, num_slots + 1, config.mamba_num_heads,
-                       config.mamba_head_dim, config.ssm_state_size), jnp.float32),
-        conv=jnp.zeros((lm, num_slots + 1, config.conv_kernel - 1,
-                        config.conv_channels), config.dtype))
+        ssm=init_slot_state(lm, num_slots, (
+            config.mamba_num_heads, config.mamba_head_dim,
+            config.ssm_state_size), jnp.float32),
+        conv=init_slot_state(lm, num_slots, (
+            config.conv_kernel - 1, config.conv_channels), config.dtype))
 
 
 def init_params(config: NemotronHConfig, key) -> Dict[str, Any]:
@@ -325,8 +328,8 @@ def paged_prefill(params, cache: HybridCache, tokens, pages, lengths, slots,
                 xh, _step_size(lp, dt_raw), -jnp.exp(lp["a_log"]), b, c, lp["d"],
                 jnp.zeros((pb,) + cs.shape[2:], jnp.float32), lengths,
                 chunk=config.chunk_size)
-            cs = cs.at[m_idx, slots].set(state.astype(cs.dtype))
-            cc = cc.at[m_idx, slots].set(kept.astype(cc.dtype))
+            cs = put_prompt_state(cs, m_idx, slots, state)
+            cc = put_prompt_state(cc, m_idx, slots, kept)
             m_idx += 1
             x = x + _mamba_out(config, lp, out.reshape(pb, s, -1), z)
         elif kind == "E":
@@ -373,14 +376,16 @@ def paged_decode_one(params, cache: HybridCache, tokens, positions, active,
         if kind == "M":
             z, xbc, dt_raw = _mamba_parts(config, lp, y)
             xbc, kept = ssm.causal_conv_step(
-                xbc, cc[m_idx, :nb], lp["conv_w"], lp["conv_b"])
-            kept = jnp.where(active[:, None, None], kept, cc[m_idx, :nb])
+                xbc, slot_state(cc, m_idx, nb), lp["conv_w"], lp["conv_b"])
+            kept = jnp.where(active[:, None, None], kept,
+                             slot_state(cc, m_idx, nb))
             xh, b, c = _mamba_split(config, jax.nn.silu(xbc))
             dt = jnp.where(active[:, None], _step_size(lp, dt_raw), 0.0)
             out, state = ssm.mamba2_step(
-                xh, dt, -jnp.exp(lp["a_log"]), b, c, lp["d"], cs[m_idx, :nb])
-            cs = cs.at[m_idx, :nb].set(state.astype(cs.dtype))
-            cc = cc.at[m_idx, :nb].set(kept)
+                xh, dt, -jnp.exp(lp["a_log"]), b, c, lp["d"],
+                slot_state(cs, m_idx, nb))
+            cs = put_slot_state(cs, m_idx, state)
+            cc = put_slot_state(cc, m_idx, kept)
             m_idx += 1
             x = x + _mamba_out(config, lp, out.reshape(nb, -1), z)
         elif kind == "E":

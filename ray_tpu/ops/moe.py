@@ -52,7 +52,12 @@ scoring (``scoring``): ``"sigmoid_bias"``, sigmoid scores, the choice by
 score + a correction bias, the weights the scores without it (Nemotron-H);
 or ``"softmax"``, a softmax over all the router's outputs in float32, the
 choice and the weights both by it (Laguna, the Qwen-MoE lineage). Either way
-the chosen weights are normalised over all the chosen and scaled.
+the chosen weights are normalised over all the chosen and scaled. A
+``"sigmoid_bias"`` router may also be GROUP-LIMITED (``groups = (n_group,
+topk_group)``, ``route``): the choice is among the outputs of the best
+``topk_group`` of ``n_group`` groups (``ling-3.0-flash-serve``: the 4 best of
+8 groups of 64; a share of whole groups then holds all or nothing of a
+token's group). Every other configuration has one group and passes none.
 
 ``parallel/expert.py`` is the older capacity-based layer (it drops past a
 capacity); only its tests and ``__graft_entry__.py`` use it.
@@ -62,7 +67,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,14 +105,20 @@ def swiglu_mlp(x, w_gate, w_up, w_down):
 
 
 def route(x, router: Dict[str, Any], top_k: int, scale: float,
-          scoring: str = "sigmoid_bias"):
+          scoring: str = "sigmoid_bias",
+          groups: Optional[Tuple[int, int]] = None):
     """x: [T, h]. Scores in float32 over all the router's outputs.
     ``"sigmoid_bias"``: sigmoid scores; the choice is the top ``top_k`` of
     score + ``bias`` (the published ``e_score_correction_bias``), the weights
     are the scores WITHOUT the bias. ``"softmax"``: a softmax over the
     outputs; choice and weights by it, no bias. The weights are over the sum
-    of the chosen, times ``scale``. Returns (experts [T, k] int32, weights
-    [T, k] float32)."""
+    of the chosen, times ``scale``. ``groups = (n_group, topk_group)`` limits
+    the choice (``"sigmoid_bias"`` only; the published ``noaux_tc``): the
+    outputs lie in ``n_group`` groups of equal size, a group's score is the
+    sum of its two largest score + ``bias``, and only the ``topk_group`` best
+    groups' outputs may be chosen. None or one group: no limit, and the lines
+    every configuration but ``ling-3.0-flash-serve`` (8 groups, 4 kept) runs.
+    Returns (experts [T, k] int32, weights [T, k] float32)."""
     logits = jnp.matmul(x.astype(jnp.float32), router["w"].astype(jnp.float32),
                         precision="highest")
     if scoring == "softmax":
@@ -117,7 +128,10 @@ def route(x, router: Dict[str, Any], top_k: int, scale: float,
         weights, chosen = jax.lax.top_k(scores, top_k)
     elif scoring == "sigmoid_bias":
         scores = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(scores + router["bias"].astype(jnp.float32), top_k)
+        choice = scores + router["bias"].astype(jnp.float32)
+        if groups is not None and groups[0] > 1:
+            choice = _in_the_best_groups(choice, *groups)
+        _, chosen = jax.lax.top_k(choice, top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
@@ -125,10 +139,23 @@ def route(x, router: Dict[str, Any], top_k: int, scale: float,
     return chosen.astype(jnp.int32), weights
 
 
+def _in_the_best_groups(choice, n_group: int, topk_group: int):
+    """choice: [T, R] -> the same with the outputs outside each row's
+    ``topk_group`` best of ``n_group`` groups at -inf. A group's score: the
+    sum of its two largest entries."""
+    t, r = choice.shape
+    grouped = choice.reshape(t, n_group, r // n_group)
+    best_two, _ = jax.lax.top_k(grouped, 2)
+    _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    allowed = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(allowed[:, :, None], grouped, -jnp.inf).reshape(t, r)
+
+
 def routed_experts(x, router: Dict[str, Any], experts: Dict[str, Any], *,
                    held: Tuple[int, int], top_k: int, scale: float,
                    impl: str = "ragged", counted=None,
-                   scoring: str = "sigmoid_bias", form: str = "relu2"):
+                   scoring: str = "sigmoid_bias", form: str = "relu2",
+                   groups: Optional[Tuple[int, int]] = None):
     """x: [T, h]; router: {"w": [h, R], "bias": [R]} (no bias under
     ``"softmax"``); experts: {"w_up": [E, h, f], "w_down": [E, f, h]} and,
     for ``"swiglu"``, "w_gate" like "w_up", with E = hi - lo. Returns the held
@@ -137,14 +164,15 @@ def routed_experts(x, router: Dict[str, Any], experts: Dict[str, Any], *,
     fell on held experts, held experts with at least one, and the fullest
     held expert's count; under ``"ragged"`` int32 [6], with the calls of the
     compacted product (1 or 0) and the blocks it ran beyond its first (0
-    unless the share held more than ``_capacity`` of this call's choices)."""
+    unless the share held more than ``_capacity`` of this call's choices).
+    ``groups``: ``route``'s group limit."""
     lo, hi = held
     n = hi - lo
     assert experts["w_up"].shape[0] == n, (experts["w_up"].shape, held)
     if form not in ("relu2", "swiglu"):
         raise ValueError(f"unknown expert form {form!r}")
     with jax.named_scope("router"):
-        chosen, weights = route(x, router, top_k, scale, scoring)
+        chosen, weights = route(x, router, top_k, scale, scoring, groups)
     here = (chosen >= lo) & (chosen < hi)
     local = jnp.where(here, chosen - lo, n)          # n: "not held here"
     if impl == "dense":

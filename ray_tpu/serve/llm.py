@@ -85,7 +85,8 @@ DEVICE_COUNTERS = MOE_COUNTERS + ("moe_blocks", "moe_blocks_extra",
                                   "prefill_rows_self", "prefill_rows_cross",
                                   "attn_rows_latent", "prefill_rows",
                                   "prefill_attn_pairs",
-                                  "prefill_rows_computed")
+                                  "prefill_rows_computed", "kda_rows",
+                                  "kda_state_updates")
 # the row counts a prefill program is compiled at (those that fit the
 # slots): a group of one bucket takes the smallest that holds it. Two, not
 # the four powers of two up to 8: every count is one more program to bring
@@ -143,6 +144,12 @@ def _kimi_k2_tiny():
     return KimiK2Config.tiny()
 
 
+def _ling_hybrid_tiny():
+    from ray_tpu.models.ling_hybrid import LingHybridConfig
+
+    return LingHybridConfig.tiny()
+
+
 def model_presets() -> Dict[str, Any]:
     """``LLMDeployment``'s preset names."""
     from ray_tpu.models.llama import LlamaConfig
@@ -150,7 +157,8 @@ def model_presets() -> Dict[str, Any]:
     return {"tiny": LlamaConfig.tiny, "llama_1b": LlamaConfig.llama_1b,
             "llama3_8b": LlamaConfig.llama3_8b,
             "nemotron_h_tiny": _nemotron_h_tiny, "laguna_tiny": _laguna_tiny,
-            "phi4flash_tiny": _phi4flash_tiny, "kimi_k2_tiny": _kimi_k2_tiny}
+            "phi4flash_tiny": _phi4flash_tiny, "kimi_k2_tiny": _kimi_k2_tiny,
+            "ling_hybrid_tiny": _ling_hybrid_tiny}
 
 
 def _slots_updated(table, tokens, positions, active, retired, firsts, placed):
@@ -373,6 +381,11 @@ class LLMEngine:
       programs ran, and the causal (query, key) pairs ONE layer attended over
       for them, the sum of n (n + 1) / 2 (every layer attends the same
       pairs; a pad row counts one of each). 0 for the other families.
+    - ``kda_rows``, ``kda_state_updates``: prompt rows the chunked delta-rule
+      kernel took (prompt tokens x KDA layers, counted in the prefill
+      program; a pad row counts one a layer) and delta-rule states its
+      one-token update moved (live slots x KDA layers x ticks, counted in
+      the decode program); models/ling_hybrid.py, 0 for the other families.
     - ``moe_blocks``, ``moe_blocks_extra``: calls of the compacted expert
       product (``ops/moe.py``: a layer of a decode tick, a layer and chunk of
       a prefill call) and the blocks they ran beyond their first, which is 0
@@ -385,7 +398,8 @@ class LLMEngine:
     (``models/paged_decode.py`` for ``LlamaConfig``,
     ``models/nemotron_h.py`` for ``NemotronHConfig``, ``models/laguna.py``
     for ``LagunaConfig``, ``models/phi4flash.py`` for ``Phi4FlashConfig``,
-    ``models/kimi_k2.py`` for ``KimiK2Config``).
+    ``models/kimi_k2.py`` for ``KimiK2Config``, ``models/ling_hybrid.py``
+    for ``LingHybridConfig``).
     The cache is one donated pytree. Where the module
     says ``SLOT_STATE``, the cache also holds state addressed by slot
     (recurrent state, window rings): prefill is told each row's slot (a pad

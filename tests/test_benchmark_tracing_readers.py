@@ -10,6 +10,7 @@ from benchmarks.readers import (
     engine_counters, engine_longest_iter, engine_queue_wait, hops_percentile)
 from benchmarks.tests.test_kimi_k2_family import *  # noqa: F401,F403
 from benchmarks.tests.test_laguna_family import *  # noqa: F401,F403
+from benchmarks.tests.test_ling_family import *  # noqa: F401,F403
 from benchmarks.tests.test_manifest import *  # noqa: F401,F403
 from benchmarks.tests.test_mellum_family import *  # noqa: F401,F403
 from benchmarks.tests.test_nemotron_h_family import *  # noqa: F401,F403
@@ -111,7 +112,7 @@ HOST_TURN = {
 }
 SATURATED = ["serve_longprompt", "serve_hybrid_longreply",
              "serve_window_longctx", "serve_yoco_longctx", "serve_chat_sat",
-             "serve_mla_longdoc"]
+             "serve_mla_longdoc", "serve_kda_longdoc"]
 
 
 @pytest.mark.parametrize("name,unit,expected", [

@@ -851,6 +851,86 @@ def test_kimi_k2_prefill_of_the_longest_bucket_is_unabsorbed_and_fits(v5e):
 
 
 # --------------------------------------------------------------------------- #
+# PR 52: the sixth family, delta-rule state beside latent pages
+# --------------------------------------------------------------------------- #
+def _ling_lowered(v5e, bucket=None):
+    """The sixth family at Ling-3.0-flash's published widths, ONE group of
+    three (a dense KDA layer, a KDA layer with experts, the MLA layer with
+    experts), 128 of the router's 512 experts and a slice of the vocabulary,
+    24 slots of 33,280 as the benchmark's cell: its decode program, or its
+    one-row prefill program of ``bucket``, lowered; and its cache."""
+    from ray_tpu.models import ling_hybrid as lh
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    slots = 24
+    config = lh.LingHybridConfig(
+        vocab_size=8192, num_hidden_layers=3, layer_group_size=3,
+        first_k_dense_replace=1, num_experts=128, held_experts=(0, 128),
+        max_seq_len=33280, attention_impl="flash", kda_impl="pallas")
+    pages = 33280 // PAGE
+    params = _on(one, jax.eval_shape(lambda k: lh.init_params(config, k),
+                                     jax.random.key(0)))
+    cache = _on(one, jax.eval_shape(
+        lambda: lh.init_cache(config, slots, slots * pages + 1, PAGE)))
+    if bucket:
+        lowered = lh.make_paged_prefill_fn(config, PAGE).lower(
+            params, cache, shape((1, bucket), jnp.int32),
+            shape((1, bucket // PAGE), jnp.int32), shape((1,), jnp.int32),
+            shape((1,), jnp.int32))
+    else:
+        ints = shape((slots,), jnp.int32)
+        lowered = lh.make_paged_decode_fn(config, 8, PAGE, use_kernel=True).lower(
+            params, cache, ints, ints, shape((slots,), jnp.bool_),
+            shape((slots, pages), jnp.int32),
+            _on(one, jax.eval_shape(lambda: jax.random.key(0))))
+    return lowered, cache
+
+
+def test_ling_decode_moves_state_in_place_beside_one_latent_pool(v5e):
+    """Mosaic takes the one-token delta-rule kernel (``kda_step``, a call a
+    KDA layer) and the latent paged-attention kernel (a call the MLA layer)
+    in ONE program over ONE donated cache: the float32 state of every slot,
+    the convolutions' rows and the latent pool are all aliased, and nothing
+    the size of a layer's state or of the pool is copied."""
+    lowered, cache = _ling_lowered(v5e)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = sorted(c.split(".")[0] for c in _mosaic_calls(text))
+    assert calls == ["kda_step", "kda_step", "paged_attention_latent"], calls
+    assert cache.k.shape == (1, 24 * 520 + 1, 64, 640)
+    assert cache.kda.shape == (2, 25, 32, 128, 128)
+    held = sum(x.size * x.dtype.itemsize for x in cache)
+    assert compiled.memory_analysis().alias_size_in_bytes == held
+    assert not re.search(r"= f32\[2,25,32,128,128\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[1,12481,64,640\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e8
+
+
+def test_ling_prefill_of_the_longest_bucket_walks_its_pieces_and_fits(v5e):
+    """One row of 32,768 tokens, the ONE prefill program the cell compiles:
+    the chunked delta-rule kernel (``kda_chunk_fwd``) over a piece of 2,048
+    rows of 32 heads inside each KDA layer's loop, the flash forward at q / k
+    of 192 and v of 128 told the row's length in the MLA layer, the grouped
+    expert product; nothing of a KDA layer is as tall as the bucket times its
+    five projections, and the temporaries fit beside 8.8 GB of weights and
+    1.3 GB of pool and state."""
+    from ray_tpu.models import ling_hybrid as lh
+
+    lowered, _ = _ling_lowered(v5e, bucket=32768)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    names = {c.split(".")[0] for c in _mosaic_calls(text)}
+    assert {"kda_chunk_fwd", "flash_mla_fwd"} <= names, names
+    assert re.search(r"kda_chunk_fwd\S* = \(bf16\[1,32,2048,128\]", text)
+    assert re.search(r"flash_mla_fwd\S* = bf16\[1,16,32768,128\]", text)
+    assert lh.PREFILL_ROWS == 2048 and lh._pieces(32768) == 16
+    assert re.search(r"bf16\[1,2048,20480\]", text)
+    assert not re.search(r"\[(1,)?32768,20480\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+# --------------------------------------------------------------------------- #
 # PR 46: the second trained family
 # --------------------------------------------------------------------------- #
 # an expert stack of Mellum's copied or transposed (the parent's reverse pass

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from benchmarks.families import kimi_k2_reference as ref
-from ray_tpu.models import kimi_k2 as km
+from ray_tpu.models import cache_rows, kimi_k2 as km
 from ray_tpu.ops import moe
 from ray_tpu.ops.attention import flash_attention, reference_attention
 from ray_tpu.ops.paged_attention import paged_attention_latent
@@ -77,7 +77,7 @@ def test_latent_kernel_reads_each_row_once_for_scores_and_values():
         want = (p / p.sum(axis=-1, keepdims=True)) @ rows[:, :vw]
         # float32 in, float32 sums in another order (blocks of two pages)
         np.testing.assert_allclose(got[b], want, atol=2e-5)
-    fallback = km._latent_attention_reference(
+    fallback = cache_rows.latent_attention_reference(
         jnp.asarray(q), jnp.asarray(np.nan_to_num(pool)), jnp.asarray(table),
         jnp.asarray(lengths), vw)
     np.testing.assert_allclose(got, fallback, atol=2e-5)
@@ -289,7 +289,7 @@ def test_the_cache_is_one_latent_row_a_token_a_layer(tiny):
 
 
 def _route_with(bias_in_weights=False, normalised=True):
-    def route(x, router, top_k, scale, scoring="sigmoid_bias"):
+    def route(x, router, top_k, scale, scoring="sigmoid_bias", groups=None):
         scores = jax.nn.sigmoid(x.astype(jnp.float32) @ router["w"])
         biased = scores + router["bias"]
         _, chosen = jax.lax.top_k(biased, top_k)
@@ -316,19 +316,19 @@ def _plant(fault, monkeypatch, config, params):
         monkeypatch.setattr(km, "_rope_tables", lambda c, n: tuple(
             t * m for t in tables(c, n)))
     elif fault == "k_r_left_unrotated":
-        rope = km.apply_rope
-        monkeypatch.setattr(km, "apply_rope", lambda x, *a: x
+        rope = cache_rows.apply_rope
+        monkeypatch.setattr(cache_rows, "apply_rope", lambda x, *a: x
                             if x.shape[-2] == 1 else rope(x, *a))
     elif fault == "c_cached_before_its_norm":
-        norm = km.rms_norm
-        monkeypatch.setattr(km, "rms_norm", lambda x, w, eps: x
+        norm = cache_rows.rms_norm
+        monkeypatch.setattr(cache_rows, "rms_norm", lambda x, w, eps: x
                             if x.shape[-1] == config.kv_lora_rank
                             else norm(x, w, eps))
     elif fault == "values_from_the_rotated_columns_too":
         # the values read across the whole stored row: a shifted window of it
-        attend = km._latent_attention_reference
+        attend = cache_rows.latent_attention_reference
         monkeypatch.setattr(
-            km, "_latent_attention_reference",
+            cache_rows, "latent_attention_reference",
             lambda q, pool, table, lengths, vw: attend(
                 q, pool, table, lengths, pool.shape[-1])[
                     ..., config.qk_rope_head_dim:][..., :vw])
