@@ -28,6 +28,9 @@ kernel does not tile. Which of the two a decode program holds is its
 builder's decision (``use_kernel``), made once and visible in the lowered
 text (``tpu_custom_call``); nothing inside the traced function asks the
 backend. Both read the same pool through the same table, offset by ``l*P``.
+This family's own rows follow the same decision (``_write_token_rows``): one
+in-place Pallas call a layer for K and V (``ops/token_rows.py``) beside the
+attention kernel, a scatter a pool beside the gather.
 
 Layout notes:
 - the pool's shape is the kernel's operand shape, so the layer scan hands it
@@ -53,6 +56,7 @@ from ray_tpu.models.llama import LlamaConfig, llama_init as init_params  # noqa:
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.token_rows import fits as _row_writer_fits, token_rows_write
 
 
 class PagedKVCache(NamedTuple):
@@ -143,11 +147,41 @@ def _scatter_token_rows(pool, rows, pages, rownum):
     then wants n_kv next to D in the operand's layout and re-lays the whole
     pool around every scatter (tests/test_chip_compile.py holds the form).
     On a v5e the 512 row writes of 64 slots x 8 heads take 0.043 ms a call
-    whatever the pool's size."""
+    whatever the pool's size.
+
+    This is the write of a decode program that holds no Pallas kernel
+    (``use_kernel`` false: CPU tests, a head width the kernels do not tile),
+    of pools or a batch the row writer does not take and of every family but
+    this module's own, and it is what the kernel of this family's other
+    programs is held to, bit for bit (``_write_token_rows``)."""
     vals = rows.transpose(1, 0, 2)  # [n_kv, B, D]
     heads = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
     return pool.at[heads, pages[None, :], rownum[None, :]].set(
         vals.astype(pool.dtype))
+
+
+def _write_token_rows(pools, rows, pages, rownum, live, use_kernel: bool):
+    """A tick's new rows into their pools: ``pools`` of one shape (K and V),
+    as many ``rows`` [B, n_kv, D], ``pages`` / ``rownum`` as
+    ``_scatter_token_rows``, ``live`` [B] the tick's ``active``. Returns the
+    pools, a tuple.
+
+    Where the decode program runs the Pallas kernels (``use_kernel``) and the
+    kernel takes the pools (``ops/token_rows.py`` ``fits``: bfloat16, pages
+    of whole 16-row tiles, whole lanes, the batch's tiles in VMEM), ONE
+    in-place kernel call writes every pool's rows (``token_rows_write`` in a
+    profile) and skips the slots that are not live; else a scatter a pool,
+    above, which puts those slots' rows on the trash page their zeroed table
+    rows name. Nothing reads that page: on every other page both leave the
+    same bits in the same places.
+
+    This family's decode tick alone calls it (PR 53). The other five serving
+    families call ``_scatter_token_rows`` as they did (PERF.md 6, PR 53
+    (v))."""
+    if use_kernel and _row_writer_fits(pools, rows[0].shape[0]):
+        return token_rows_write(pools, rows, pages, rownum, live)
+    return tuple(_scatter_token_rows(pool, new, pages, rownum)
+                 for pool, new in zip(pools, rows))
 
 
 def _paged_attention_reference(q, k_pool, v_pool, table, lengths, scale,
@@ -414,14 +448,22 @@ def paged_decode_one(params, cache: PagedKVCache, tokens, positions, active,
 
     def body(carry, lp):
         x, ck, cv, layer = carry
-        _, q, k, v = _project_qkv(config, lp, x)
+        y, q, _, v = _project_qkv(config, lp, x)
         q = apply_rope(q, cos, sin, positions=positions[:, None])
-        k = apply_rope(k, cos, sin, positions=positions[:, None])
+        # K's product goes into the rotary in float32 and is rounded ONCE,
+        # after it: what XLA:TPU made of the bfloat16 product while the
+        # rotary was its only reader (it fused the two and never rounded
+        # between them); beside the row writer's custom call it would round
+        # twice, and a reply would part from PR 52's on a near-tie
+        # (_project_qkv's bfloat16 K is not used: dead code to the compiler)
+        k = jnp.matmul(y, lp["wk"], preferred_element_type=jnp.float32)
+        k = apply_rope(k.reshape(v.shape), cos, sin,
+                       positions=positions[:, None]).astype(v.dtype)
         # layer l owns pages [l*P, (l+1)*P) of the one pool: the write and
         # the read both go through the offset, nothing is sliced out
         base = layer * per_layer
-        ck = _scatter_token_rows(ck, k[:, 0], pages + base, rows)
-        cv = _scatter_token_rows(cv, v[:, 0], pages + base, rows)
+        ck, cv = _write_token_rows((ck, cv), (k[:, 0], v[:, 0]),
+                                   pages + base, rows, active, use_kernel)
         o = _paged_attention(q, ck, cv, table + base, lengths, scale,
                              use_kernel)
         b, t, nh, hd = q.shape

@@ -148,6 +148,21 @@ def _without_combines(calls):
     return [c for c in calls if c not in combines], combines
 
 
+def _without_token_writes(calls):
+    """(the calls that are not ``ops/token_rows.py``'s kernel, those that
+    are): it keeps its name, ``token_rows_write``, in a compiled program."""
+    writes = [c for c in calls if c.startswith("token_rows_write")]
+    return [c for c in calls if c not in writes], writes
+
+
+def _pool_scatters(text):
+    """The scatters of a compiled program, inside a fusion or not, whose
+    result (and so whose operand) is a page pool (``[n_kv, pages, 64, D]`` in
+    bfloat16): XLA's token or prompt write, which ``token_rows_write``
+    replaced in the decode programs that run the kernels (PR 53)."""
+    return re.findall(r"= bf16\[\d+,\d+,64,\d+\]\S* scatter\(.*", text)
+
+
 def _row_scatters(text, h):
     """The scatters of a compiled program whose operand is float32 rows of
     ``h``: XLA's row scatter-add of an expert block onto its tokens, which
@@ -169,8 +184,12 @@ def test_decode_attention_kernel_compiles_and_keeps_its_name(v5e, family):
         text = decode.lower(*args).compile().as_text()
     else:
         text = _hybrid_decode(v5e, 128)[1].as_text()
-    calls = _mosaic_calls(text)
+    calls, writes = _without_token_writes(_mosaic_calls(text))
     assert calls and all(c.startswith("paged_attention") for c in calls), calls
+    # the Llama-shaped family writes a tick's rows through the row writer
+    # (PR 53); the others keep their scatters, and their programs' text
+    assert len(writes) == (1 if family == "llama" else 0), writes
+    assert bool(_pool_scatters(text)) == (family != "llama")
 
 
 def test_prefill_bucket_compiles(v5e):
@@ -256,18 +275,28 @@ def test_decode_program_never_moves_a_pool_layer(v5e):
     assert _pool_sized_moves(small.as_text(), small_layer) == []
     assert small.memory_analysis().alias_size_in_bytes == small_pool
     assert big.memory_analysis().alias_size_in_bytes == big_pool
+    # K's and V's rows through ONE call of the row writer in the layer scan
+    assert len(_without_token_writes(_mosaic_calls(small.as_text()))[1]) == 1
+    assert _pool_scatters(small.as_text()) == []
 
 
 def test_token_write_with_a_window_over_heads_relays_the_pool(v5e, monkeypatch):
     """Why ``_scatter_token_rows`` indexes the heads too: the shorter form
     gives the scatter a [n_kv, D] window, XLA:TPU then keeps n_kv next to D
     in the operand's layout, and the whole pool is re-laid around every
-    write for the kernel, which reads row-major."""
+    write for the kernel, which reads row-major. Since PR 53 the scatter is
+    the write only of a pool the row writer does not tile (and of a program
+    without kernels): such a pool is feigned here, beside the attention
+    kernel; the form as it stands moves nothing there."""
 
     def window_over_heads(pool, rows, pages, rownum):
         return pool.at[:, pages, rownum].set(
             rows.transpose(1, 0, 2).astype(pool.dtype))
 
+    monkeypatch.setattr(pd, "_row_writer_fits", lambda pools, slots: False)
+    compiled, _, layer = _compile_decode(v5e, POOL_PAGES)
+    assert _pool_scatters(compiled.as_text())
+    assert _pool_sized_moves(compiled.as_text(), layer) == []
     monkeypatch.setattr(pd, "_scatter_token_rows", window_over_heads)
     compiled, _, layer = _compile_decode(v5e, POOL_PAGES)
     assert _pool_sized_moves(compiled.as_text(), layer)
@@ -326,7 +355,7 @@ def test_fsdp4_train_step_compiles_with_flash(v5e):
 # The second family through the engine (PR 29)
 # --------------------------------------------------------------------------- #
 LLAMA_TINY_DECODE_SHA = \
-    "92f77e847eca75f025ee282bd1c8d85a9051395468b808526443d554492f9549"
+    "3e0c3bbed245a7be87be0ddfd5e9baf18ef9a373654b22d9037bac8ade43f895"
 
 
 def test_llama_decode_program_is_what_it_was_before_the_second_family():
@@ -336,7 +365,12 @@ def test_llama_decode_program_is_what_it_was_before_the_second_family():
     decode and prefill were compared too, equal). It depends on the jax that
     lowers it, so another version skips. PR 34 moved the hash in place: tiny
     widths run the GATHER path, whose lengths are 0 for an inactive slot and
-    whose output is zeros there since then (``_live_lengths``)."""
+    whose output is zeros there since then (``_live_lengths``). PR 53 moved
+    it again (until then 92f77e847eca75f025ee282bd1c8d85a9051395468b808526443d554492f9549):
+    K's and V's rows go to ``_write_token_rows`` in one call, so V's slice
+    is traced before K's scatter and no longer after it, and K's product is
+    float32 into the rotary and rounded once after it (what XLA:TPU made of
+    it before); the same two scatters on the same operands."""
     import hashlib
 
     from ray_tpu.serve.llm import LLMEngine
@@ -429,8 +463,16 @@ def _without_locations(text):
 # cell does (it held 8 of 128, a share ``ops/moe.py`` now compacts), and is
 # equal on PR 37's tree
 ACCEPTED_PROGRAMS_SHA = {
+    # taken again on PR 53's tree, which means to change this program and no
+    # other: the Llama-shaped family's decode tick writes its K and V rows
+    # through ``ops/token_rows.py``'s kernel and no longer through
+    # ``_scatter_token_rows``' two scatters, and K's product reaches the
+    # rotary in float32 (the hash until then:
+    # 4faf3387d777c75cad44fe924f5ee1365a0fbabc2bb83479003e2a9b50fd3725).
+    # Every other family's decode program calls the scatter as it did and
+    # stands as it was
     "llama_decode": 
-        "4faf3387d777c75cad44fe924f5ee1365a0fbabc2bb83479003e2a9b50fd3725",
+        "b4f022d54764f597905375d8ff080be8d043dfba860da3f0aed9980c3d81cfd2",
     # taken again on PR 45's tree, which means to change this program: the
     # row-wise work walks a prompt in pieces and skips those past its length,
     # the head runs over the last row (the hash of PR 35's parent
@@ -1004,6 +1046,31 @@ def test_mellum_expert_chunk_compiles_with_the_grouped_kernel(v5e):
         assert not re.search(r"= f32\[16,\d+,(2304|896)\]\S* convolution",
                              program)
         assert not re.search(STACK_COPY, program)
+
+
+def test_token_rows_write_compiles_at_the_chat_cells_decode_shape(v5e):
+    """``ops/token_rows.py`` alone at the shape the Llama-shaped family's
+    decode program runs it at (K and V of 8 heads of 128 for 64 slots, the
+    chat cell's pool): Mosaic takes the strided tile copies with a semaphore
+    a slot, the select on packed bfloat16 rows and the aliased pools in HBM;
+    the program keeps the kernel's name, aliases both pools and holds nothing
+    else of size."""
+    from ray_tpu.ops.token_rows import token_rows_write
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    held = (shape((8, 8 * 1537, PAGE, 128), jnp.bfloat16),) * 2
+    rows = (shape((64, 8, 128), jnp.bfloat16),) * 2
+    ints = shape((64,), jnp.int32)
+    compiled = jax.jit(token_rows_write, donate_argnums=(0,)).lower(
+        held, rows, ints, ints, shape((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert [c.split(".")[0] for c in _mosaic_calls(text)] == [
+        "token_rows_write"]
+    assert _pool_scatters(text) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(held)
+    assert mem.temp_size_in_bytes < 1e6
 
 
 @pytest.mark.parametrize("cell,cap,h,t,blocks", [
