@@ -43,7 +43,27 @@ What enters the MXU follows the inputs: bfloat16 q / k / v multiply as
 bfloat16 with float32 sums, except the inverse's chain, whose float32
 matrices multiply in three bfloat16 passes (``_dot_split``); float32 inputs
 multiply at ``highest`` everywhere (tests). ``a``, the decays and the state
-are float32 always.
+are float32 always. What a product holds: the MXU takes a row of its left
+operand a cycle whatever the operands' widths, and a [64, 64] float32
+matrix half fills its registers' lanes, so the chunk kernel keeps every [64,
+64] matrix of a PAIR of heads side by side, ``[x_a | x_b]`` [64, 128], and
+multiplies it by ``blockdiag(y_a, y_b)``: one product and whole registers
+where there were two and half-empty ones (an absent partner is a zero block
+of the same product). Products that share a right operand are stacked on the
+rows (``[N^2 ; inv] @ N^2``); rows whose block is zero are not multiplied
+(the first ``i`` sub-chunks of a block ``i`` below the diagonal, of the block
+rows' ``m`` and ``m^2``); the Neumann product runs over the diagonal blocks
+alone, the pair's eight side by side [16, 128]; and a chain product's three
+passes are one product three times as deep. A head and chunk took 40
+passes of the MXU (30 of them the chain's [64, 64] products, each waiting
+for the one before) that streamed 2,880 rows of left operands through it;
+it takes 20 now (a pair 40, of whole 128 x 128 tiles) that stream 1,152.
+And the pairs of a grid step are traced in LOCKSTEP (``_in_lockstep``): the
+scheduler keeps to the order of the trace, and chains traced one after
+another waited on every product. On a v5e, a piece of 2,048 rows x 32 heads
+(PERF.md 6, PR 54): 1.72 ms as it was; the same body in lockstep 0.64; in
+pairs 0.97; both, 0.44; without the zero rows 0.33; sixteen heads a grid
+step 0.26.
 
 The serving engine pads. A row at or past its prompt's ``lengths`` entry
 takes ``a = 0`` and ``beta = 0``: no decay, nothing written, so the state
@@ -62,9 +82,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64
 SUB = 16
-# heads a grid step of either kernel holds: their chains of small products
-# are independent, so the scheduler runs one head's beside another's; and 8
-# rows of [heads, 128] are one float32 tile of the step kernel's tokens
+# heads a grid step of the step kernel holds: 8 rows of [heads, 128] are one
+# float32 tile of its tokens. The chunk kernel takes twice as many where
+# they divide the head count (eight pairs in lockstep; thirty-two heads'
+# blocks are over the kernel's 16 MB of VMEM), else as many, else one
 HEADS = 8
 
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -93,15 +114,22 @@ def _dot(a, b, dims=_NN, dtype=jnp.float32):
 
 
 def _dot_split(a, b):
-    """a @ b for float32 operands in three bfloat16 passes (high x high, high
-    x low, low x high; what is left is 2^-16 of a term): the inverse's chain
+    """a @ b for float32 operands in three bfloat16 passes (high x high, low
+    x high, high x low; what is left is 2^-16 of a term): the inverse's chain
     under bfloat16 inputs. One pass lost 9% of an output where keys repeat
-    and nothing decays (the chain's terms then cancel); ``highest`` is six."""
-    a_hi, b_hi = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
-    a_lo = (a - a_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    b_lo = (b - b_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return sum(jax.lax.dot_general(x, y, _NN, preferred_element_type=jnp.float32)
-               for x, y in ((a_hi, b_hi), (a_hi, b_lo), (a_lo, b_hi)))
+    and nothing decays (the chain's terms then cancel); ``highest`` is six.
+    The three are ONE product three times as deep, ``[a_hi | a_lo | a_hi] @
+    [b_hi ; b_hi ; b_lo]``: each half is rounded to bfloat16 once, where the
+    product takes it (a rounded value read by a product AND by a subtraction
+    is laid out twice), and nothing is added up outside the product."""
+    def halves(x):
+        high = x.astype(jnp.bfloat16).astype(jnp.float32)
+        return high, x - high
+
+    (a_hi, a_lo), (b_hi, b_lo) = halves(a), halves(b)
+    return _dot(jnp.concatenate([a_hi, a_lo, a_hi], axis=1),
+                jnp.concatenate([b_hi, b_hi, b_lo], axis=0), _NN,
+                jnp.bfloat16)
 
 
 # --------------------------------------------------------------------------- #
@@ -140,11 +168,25 @@ def _rows_of_subs(parts, d: int):
         [jnp.broadcast_to(p, (SUB, d)) for p in parts], axis=0)
 
 
-def _chunk_head(q, k, kb, vb, g, state, mxu):
-    """One head, one chunk. q, k: [CHUNK, d]; kb, vb: ``beta * k``, ``beta *
-    v``; g: the sum of ``a`` from each row's SUB-chunk's start through the
-    row, float32; state: [d_v, d_k] float32 (the decay then runs ALONG a
-    row of it). Returns (o [CHUNK, d] float32, the state after the chunk)."""
+def _beside(parts):
+    """[a | b] along the lanes; a pair's absent partner is a zero block."""
+    return jnp.concatenate(
+        list(parts) + [jnp.zeros_like(parts[0])] * (2 - len(parts)), axis=1)
+
+
+def _blockdiag(parts):
+    """[[a, 0], [0, b]]: ``[x_a | x_b] @ blockdiag(y_a, y_b)`` is ``[x_a y_a
+    | x_b y_b]``, both heads' product in one (the zeros are exact); a pair's
+    absent partner is a zero block."""
+    zero = jnp.zeros_like(parts[0])
+    a, b = (list(parts) + [zero])[:2]
+    return jnp.concatenate([_beside([a, zero]), _beside([zero, b])], axis=0)
+
+
+def _head_rows(q, k, kb, vb, g):
+    """What ONE head brings to its chunk's products, each [rows, d] float32.
+    q, k: [CHUNK, d]; kb, vb: ``beta * k``, ``beta * v``; g: the sum of ``a``
+    from each row's SUB-chunk's start through the row, float32."""
     f32 = jnp.float32
     n, d = CHUNK // SUB, q.shape[-1]
     q, k, kb, vb = (t.astype(f32) for t in (q, k, kb, vb))
@@ -155,56 +197,146 @@ def _chunk_head(q, k, kb, vb, g, state, mxu):
         before.append(before[-1] + ends[i])
     g_chunk = g + _rows_of_subs(before, d)        # from the chunk's start
     g_last = before[-1] + ends[-1]                # [1, d]: the whole chunk
-    # the sub-chunk before a row's, and the one before that, whole
-    prev1 = _rows_of_subs([zero] + ends[:-1], d)
-    prev2 = _rows_of_subs([zero, zero] + ends[:-2], d)
     # a diagonal block factors about its sub-chunk's MIDDLE row: e^40 either
     # way, where e^-80 about its start would push a small q or k under the
     # smallest float32 and the e^80 beside it would bring nothing back
     g_mid = g - _rows_of_subs(
         [g[SUB * i + SUB // 2 - 1:SUB * i + SUB // 2] for i in range(n)], d)
-    # any other block's columns: from the column's row to its sub-chunk's end
-    k_end = k * jnp.exp(_rows_of_subs(ends, d) - g)
-    def rows(log_decay):                         # [2 CHUNK, d]: kb over q
-        decay = jnp.exp(log_decay)
-        return jnp.concatenate([kb * decay, q * decay], axis=0)
 
-    same = _dot(rows(g_mid), k * jnp.exp(-g_mid), _NT, mxu)  # [2 C, C]
-    near = _dot(rows(g), k_end, _NT, mxu)
-    far1 = _dot(rows(g + prev1), k_end, _NT, mxu)
-    far2 = _dot(rows(g + prev1 + prev2), k_end, _NT, mxu)
-    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    def rows(log_decay, first=0):       # kb over q, from row ``first`` on
+        decay = jnp.exp(log_decay[first:])
+        return [kb[first:] * decay, q[first:] * decay]
+
+    # a block ``apart`` sub-chunks below the diagonal: the row's decay from
+    # its sub-chunk's start, times the whole sub-chunks between, against the
+    # column's from its row to ITS sub-chunk's end. The first ``apart``
+    # sub-chunks of rows have no such block and are not multiplied
+    below, between = [], jnp.zeros_like(g)
+    for apart in range(1, n):
+        below += rows(g + between, apart * SUB)
+        between = between + _rows_of_subs([zero] * apart + ends[:-apart], d)
+    return dict(
+        same=jnp.concatenate(rows(g_mid), axis=0),
+        same_k=k * jnp.exp(-g_mid),
+        below=jnp.concatenate(below, axis=0),
+        below_k=k * jnp.exp(_rows_of_subs(ends, d) - g),
+        k_in=kb * jnp.exp(g_chunk), vb=vb, q_in=q * jnp.exp(g_chunk),
+        k_out=k * jnp.exp(g_last - g_chunk), decay=jnp.exp(g_last))
+
+
+def _chunk_pair(heads, mxu):
+    """One chunk of a PAIR of heads (or of one: its partner is then a zero
+    block of the same products). ``heads``: a (q, k, kb, vb, g, state) each,
+    as ``_head_rows`` takes them, and state: [d_v, d_k] float32 (the decay
+    then runs ALONG a row of it). A [CHUNK, CHUNK] matrix of the chunk lives
+    as the pair's ``[x_a | x_b]``, [CHUNK, 2 CHUNK]: whole lanes, and one
+    product against a ``_blockdiag`` is both heads'. A generator: it yields
+    between its stages, so that the pairs of a grid step can be taken a
+    stage each in turn (``_in_lockstep``), and RETURNS an (o [CHUNK, d]
+    float32, the state after the chunk) a head."""
+    f32 = jnp.float32
+    n, wide = CHUNK // SUB, 2 * CHUNK
+    states = [head[5] for head in heads]
+    parts = [_head_rows(*head[:5]) for head in heads]
+    d = states[0].shape[-1]
+    yield
+
+    def of(name):
+        return [part[name] for part in parts]
+
+    same = _dot(_beside(of("same")), _blockdiag(of("same_k")), _NT, mxu)
+    below = _dot(_beside(of("below")), _blockdiag(of("below_k")), _NT, mxu)
+    yield
+    # masks from whole iotas: Mosaic aborts on a slice of one and on ``%``
+    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, wide), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, wide), 1)
+    left = lane < CHUNK
+    col = jnp.where(left, lane, lane - CHUNK)
     apart = row // SUB - col // SUB
 
-    def pairs(half, below):
-        part = slice(half * CHUNK, (half + 1) * CHUNK)
-        return jnp.where((apart == 0) & below, same[part], 0.0) \
-            + jnp.where(apart == 1, near[part], 0.0) \
-            + jnp.where(apart == 2, far1[part], 0.0) \
-            + jnp.where(apart == 3, far2[part], 0.0)
+    def pairs(half, under):                      # half 0: kb's rows, 1: q's
+        out = jnp.where((apart == 0) & under, same[half * CHUNK:][:CHUNK], 0.0)
+        first = 0
+        for i in range(1, n):                    # rows i SUB.. of ``below``
+            rows = CHUNK - i * SUB
+            block = jnp.concatenate(
+                [jnp.zeros((i * SUB, wide), f32),
+                 below[first + half * rows:][:rows]], axis=0)
+            out, first = jnp.where(apart == i, block, out), first + 2 * rows
+        return out
 
     lower, a_q = pairs(0, row > col), pairs(1, row >= col)  # Diag(beta) A, Aq
-    # (I + lower)^-1: the diagonal blocks, then the block rows
-    eye = (row == col).astype(f32)
+    chain = _dot if mxu == f32 else _dot_split
+
+    # (I + lower)^-1. First its diagonal blocks, (I - N)(I + N^2)(I + N^4)
+    # (I + N^8) each (N^16 = 0): the pair's 2 n blocks side by side, [SUB, 2
+    # CHUNK], against the blockdiag of as many; a product that shares its
+    # right operand with another is stacked on the other's rows
     diag = jnp.where(apart == 0, lower, 0.0)
-    inv = eye - diag
-    power = diag
-    chain = _dot if mxu == jnp.float32 else _dot_split
-    for _ in range(3):                  # (I + N^2)(I + N^4)(I + N^8)
-        power = chain(power, power)
-        inv = inv + chain(inv, power)
-    m = chain(inv, lower - diag)
-    m2 = chain(m, m)
-    inv = chain(eye - m + m2 - chain(m, m2), inv)
+    small = sum(diag[SUB * i:][:SUB] for i in range(n))
+    own = jax.lax.broadcasted_iota(jnp.int32, (wide, wide), 0) // SUB \
+        == jax.lax.broadcasted_iota(jnp.int32, (wide, wide), 1) // SUB
+
+    def blocks(x, y):
+        return chain(x, jnp.where(
+            own, jnp.concatenate([y] * (2 * n), axis=0), 0.0))
+
+    inv = (jax.lax.broadcasted_iota(jnp.int32, (SUB, wide), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (SUB, wide), 1) & (SUB - 1)
+           ).astype(f32) - small
+    power = blocks(small, small)
+    yield
+    for _ in range(2):
+        both = blocks(jnp.concatenate([power, inv], axis=0), power)
+        power, inv = both[:SUB], inv + both[SUB:]
+        yield
+    inv = inv + blocks(inv, power)
+    inv = jnp.where(apart == 0, jnp.concatenate([inv] * n, axis=0), 0.0)
+    yield
+
+    # then the block rows: (I + m)^-1 = (I + m^2)(I - m), m^4 = 0. m is
+    # strictly block-lower: its first sub-chunk of rows is zero, and m^2's
+    # first two, and are not multiplied
+    def rows_from(first, x, y):        # x[first:] @ blockdiag(y_a, y_b)
+        y = jnp.concatenate([jnp.where(left, y, 0.0),
+                             jnp.where(left, 0.0, y)], axis=0)
+        return jnp.concatenate(
+            [jnp.zeros((first, wide), f32), chain(x[first:], y)], axis=0)
+
+    m = rows_from(SUB, inv, lower - diag)
+    yield
+    inv, m2 = inv - rows_from(SUB, m, inv), rows_from(2 * SUB, m, m)
+    yield
+    inv = inv + rows_from(2 * SUB, m2, inv)
+    yield
     # what the chunk's rows write, and its outputs
-    k_in = kb * jnp.exp(g_chunk)
-    wu = _dot(inv, jnp.concatenate([k_in, vb], axis=1), _NN, mxu)  # [C, 2 d]
-    u = wu[:, d:] - _dot(wu[:, :d], state, _NT, mxu)
-    o = _dot(q * jnp.exp(g_chunk), state, _NT, mxu) + _dot(a_q, u, _NN, mxu)
-    k_out = k * jnp.exp(g_last - g_chunk)
-    state = jnp.exp(g_last) * state + _dot(u, k_out, _TN, mxu)
-    return o, state
+    wu = _dot(inv, _blockdiag([jnp.concatenate([part["k_in"], part["vb"]],
+                                               axis=1) for part in parts]),
+              _NN, mxu)                                      # [C, 2 x 2 d]
+    yield
+    us = [wu[:, (2 * i + 1) * d:(2 * i + 2) * d]
+          - _dot(wu[:, 2 * i * d:(2 * i + 1) * d], state, _NT, mxu)
+          for i, state in enumerate(states)]
+    yield
+    o = _dot(a_q, _blockdiag(us), _NN, mxu)                  # [C, 2 d]
+    return [(_dot(part["q_in"], state, _NT, mxu) + o[:, i * d:(i + 1) * d],
+             part["decay"] * state + _dot(u, part["k_out"], _TN, mxu))
+            for i, (part, state, u) in enumerate(zip(parts, states, us))]
+
+
+def _in_lockstep(bodies):
+    """Take generators of equally many stages a stage each in turn; what
+    they return. The scheduler follows the order a kernel was traced in: of
+    chains traced one after another it runs one after another and waits for
+    each product; traced in turn, one pair's product fills another's wait."""
+    bodies, returned = list(bodies), []
+    while not returned:
+        for body in bodies:
+            try:
+                next(body)
+            except StopIteration as end:
+                returned.append(end.value)
+    return returned
 
 
 def _chunk_kernel(len_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref,
@@ -222,11 +354,15 @@ def _chunk_kernel(len_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref,
 
     @pl.when(live)
     def _():
-        for h in range(heads):
-            o, state = _chunk_head(q_ref[0, h], k_ref[0, h], kb_ref[0, h],
-                                   vb_ref[0, h], g_ref[0, h], s_ref[0, h], mxu)
-            o_ref[0, h] = o.astype(o_ref.dtype)
-            s_ref[0, h] = state
+        pairs = [range(first, min(first + 2, heads))
+                 for first in range(0, heads, 2)]
+        done = _in_lockstep(_chunk_pair(
+            [(q_ref[0, h], k_ref[0, h], kb_ref[0, h], vb_ref[0, h],
+              g_ref[0, h], s_ref[0, h]) for h in pair], mxu) for pair in pairs)
+        for pair, pair_done in zip(pairs, done):
+            for h, (o, state) in zip(pair, pair_done):
+                o_ref[0, h] = o.astype(o_ref.dtype)
+                s_ref[0, h] = state
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -264,7 +400,7 @@ def kda_prefill(q, k, v, a, beta, state0, lengths, *,
     scaled = beta[..., None]
     heads_first = [t.transpose(0, 2, 1, 3) for t in (
         q, k, (scaled * k).astype(k.dtype), (scaled * v).astype(v.dtype), g)]
-    hb = HEADS if h % HEADS == 0 else 1
+    hb = next(n for n in (2 * HEADS, HEADS, 1) if h % n == 0)
     mxu = jnp.float32 if q.dtype == jnp.float32 else jnp.bfloat16
     rows = pl.BlockSpec((1, hb, CHUNK, d), lambda i, j, c, _n: (i, j, c, 0))
     held = pl.BlockSpec((1, hb, d, d), lambda i, j, c, _n: (i, j, 0, 0))

@@ -972,6 +972,29 @@ def test_ling_prefill_of_the_longest_bucket_walks_its_pieces_and_fits(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
+def test_kda_chunk_kernel_alone_compiles_at_the_cells_call(v5e):
+    """The chunk kernel by itself at the call the cell makes of it (one
+    piece of 2,048 rows of 32 heads in bfloat16, ``a`` and the state
+    float32): Mosaic takes its pairs of heads side by side, the stacked rows
+    and the masks (it ABORTS, with no Python error, on a form it refuses: a
+    slice of an iota, an int32 ``%``), sixteen heads a grid step fit the
+    kernel's 16 MB of VMEM, and the call keeps the name and the heads-first
+    result the benchmark's readers find it by."""
+    from ray_tpu.ops import kda
+
+    shape = functools.partial(jax.ShapeDtypeStruct,
+                              sharding=SingleDeviceSharding(v5e.devices[0]))
+    b, s, h, d = 1, 2048, 32, 128
+    rows = shape((b, s, h, d), jnp.bfloat16)
+    text = _compiled_text(
+        functools.partial(kda.kda_prefill, impl="pallas"), rows, rows, rows,
+        shape((b, s, h, d), jnp.float32), shape((b, s, h), jnp.float32),
+        shape((b, h, d, d), jnp.float32), shape((b,), jnp.int32))
+    assert [c.split(".")[0] for c in _mosaic_calls(text)] == ["kda_chunk_fwd"]
+    assert re.search(r"kda_chunk_fwd\S* = \(bf16\[1,32,2048,128\]\S*, "
+                     r"f32\[1,32,128,128\]", text)
+
+
 # --------------------------------------------------------------------------- #
 # PR 46: the second trained family
 # --------------------------------------------------------------------------- #

@@ -58,6 +58,15 @@ CASES = {
                                  high=-5.0),
     # next to no decay: the chunk's triangular system at its fullest
     "a_near_zero": dict(s=128, lengths=(128, 127, 1), low=-1e-3, high=0.0),
+    # a grid step of eight heads: four pairs side by side, taken in lockstep
+    "eight_heads_in_pairs": dict(s=128, lengths=(128, 90, 17), low=-5.0,
+                                 high=0.0, heads=8),
+    # a head count no grid step divides: a head a step, its partner absent
+    "three_heads_unpaired": dict(s=128, lengths=(128, 64, 3), low=-5.0,
+                                 high=0.0, heads=3),
+    # a piece whose last real row lies inside a chunk, and inside a sub-chunk
+    "ends_inside_a_chunk": dict(s=192, lengths=(130, 97, 71), low=-2.0,
+                                high=0.0, heads=8),
 }
 
 
@@ -66,8 +75,9 @@ CASES = {
 def test_kda_prefill_is_the_recurrence(impl, case):
     spec = CASES[case]
     lengths = np.asarray(spec["lengths"], np.int32)
-    q, k, v, a, beta, state0 = _inputs(3, spec["s"], 2, 32, seed=len(case),
-                                       low=spec["low"], high=spec["high"])
+    q, k, v, a, beta, state0 = _inputs(3, spec["s"], spec.get("heads", 2), 32,
+                                       seed=len(case), low=spec["low"],
+                                       high=spec["high"])
     want_o, want_state = _literal(q, k, v, a, beta, state0, lengths)
     o, state = kda.kda_prefill(*(jnp.asarray(x) for x in (
         q, k, v, a, beta, state0, lengths)), impl=impl)
@@ -129,6 +139,43 @@ def test_kda_prefill_in_bfloat16_stays_within_its_rounding(keys):
         < 0.025 * np.abs(want_o).max()
     assert np.abs(np.asarray(state, np.float64) - want_state).max() \
         < 0.025 * np.abs(want_state).max()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kda_prefill_of_a_head_does_not_see_its_partner(dtype):
+    """Two heads share each product of the chunk kernel, side by side
+    against a block-diagonal operand. The zero blocks are exact: the even
+    heads' outputs and states are the same BITS whatever the odd heads hold,
+    also where the partner decays at the bound of -5 a row, so that its
+    factors are the largest a zero block can meet (e^40 in a diagonal
+    block)."""
+    q, k, v, a, beta, state0 = _inputs(2, 192, 8, 128, seed=17)
+    lengths = jnp.asarray([192, 150], jnp.int32)
+    other = list(_inputs(2, 192, 8, 128, seed=19, low=-5.0, high=-5.0))
+    other[:3] = [8.0 * x for x in other[:3]]
+
+    def run(tensors):
+        q, k, v, a, beta, state0 = tensors
+        low = [jnp.asarray(x, dtype) for x in (q, k, v)]
+        return kda.kda_prefill(*low, jnp.asarray(a), jnp.asarray(beta),
+                               jnp.asarray(state0), lengths,
+                               impl="pallas_interpret")
+
+    o, state = run((q, k, v, a, beta, state0))
+    swapped = []
+    for mine, theirs in zip((q, k, v, a, beta, state0), other):
+        mixed = mine.copy()
+        odd = (slice(None), slice(1, None, 2)) if mine is state0 \
+            else (slice(None), slice(None), slice(1, None, 2))
+        mixed[odd] = theirs[odd]
+        swapped.append(mixed)
+    o2, state2 = run(swapped)
+    assert np.array_equal(np.asarray(o[:, :, ::2].astype(jnp.float32)),
+                          np.asarray(o2[:, :, ::2].astype(jnp.float32)))
+    assert np.array_equal(np.asarray(state[:, ::2]), np.asarray(state2[:, ::2]))
+    assert not np.array_equal(np.asarray(state[:, 1::2]),
+                              np.asarray(state2[:, 1::2]))
+    assert np.isfinite(np.asarray(o2.astype(jnp.float32))).all()
 
 
 @pytest.mark.parametrize("impl", IMPLS)
