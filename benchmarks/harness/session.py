@@ -51,6 +51,7 @@ class BenchSession:
     def __exit__(self, *exc):
         import ray_tpu
 
+        known = procs.snapshot(self.token)  # while they can still be found
         try:
             from ray_tpu import serve
 
@@ -66,5 +67,5 @@ class BenchSession:
                 self.cluster.shutdown()
             except Exception:  # noqa: BLE001
                 pass
-        self.left_behind = procs.reap_all(self.token)
+        self.left_behind = procs.reap_all(self.token, known=known)
         return False

@@ -27,25 +27,29 @@ sys.path.insert(0, ROOT)
 
 
 def evaluate_check(check, limits):
-    """Every number compared, beside its limit. Returns (correct, lines)."""
+    """Every number compared, beside its limit. Returns (correct, lines,
+    compared): ``compared`` is {name: [value, limit]} of what was held to a
+    limit, for the result's line."""
     if not check:
-        return False, ["check: nothing was compared"]
-    ok, lines = True, []
+        return False, ["check: nothing was compared"], {}
+    ok, lines, compared = True, [], {}
     for key, limit in sorted(limits.items()):
         value = check.get(key)
         good = value is not None and math.isfinite(value) and value <= limit
         ok = ok and good
+        compared[key] = [value, limit]
         lines.append(f"check {key}={value} limit={limit} {'ok' if good else 'FAIL'}")
     for key, value in sorted(check.items()):
         if isinstance(value, bool):
             ok = ok and value
+            compared[key] = [value, True]
             lines.append(f"check {key}={value} limit=True {'ok' if value else 'FAIL'}")
         elif key not in limits:
             lines.append(f"check {key}={value} (reported, no limit)")
     if not limits:
         ok = False
         lines.append("check: no limits are set for this traffic")
-    return ok, lines
+    return ok, lines, compared
 
 
 def main(argv=None) -> int:
@@ -94,7 +98,8 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 3
 
-    correct, lines = evaluate_check(ctx.get("check"), ctx.get("check_limits", {}))
+    correct, lines, compared = evaluate_check(
+        ctx.get("check"), ctx.get("check_limits", {}))
     for line in lines:
         print(line)
     group = "per_layer" if args.trace else "end_to_end"
@@ -120,6 +125,15 @@ def main(argv=None) -> int:
             "probe_reports")}
     if ctx.get("errors"):
         print(f"errors: {ctx['errors']}", file=sys.stderr)
+    # last in the line and last on stderr: what was compared, beside its
+    # limit, and for a run whose requests never came back where it stood
+    out["check"] = dict(compared)
+    if ctx.get("errors"):
+        out["check"]["errors"] = [str(e)[:200] for e in ctx["errors"][:2]]
+    if ctx.get("diagnosis"):
+        out["check"]["diagnosis"] = ctx["diagnosis"]
+    for line in lines:
+        print(line, file=sys.stderr)
     print(json.dumps(out), flush=True)
     return 0
 

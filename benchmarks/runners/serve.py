@@ -80,7 +80,32 @@ def deploy(args, resolved, cfg, plan):
         if not res.ok(rec):
             raise RuntimeError(f"warm-up request failed: {rec.error}")
         t_probe = t_probe or time.time()
+    # the handle's first call (its router, its connection to the replica) is
+    # made here, with the engine idle: a traced run's first poll at the
+    # window's opening is then a call like every later one (PERF.md 6, PR 56)
+    handle.engine_stats.remote().result(timeout=60)
     return handle, address, t_probe - t_run
+
+
+def diagnose(handle) -> Dict[str, Any]:
+    """For a run whose requests did not come back or whose profile caught no
+    device operation: whether the engine still steps, and where the
+    replica's threads stand. Printed on stderr and carried in the result's
+    line; no metric reads it."""
+    keys = ("iters", "decode_steps", "active", "queued", "compiles")
+    out: Dict[str, Any] = {}
+    try:
+        first = handle.engine_stats.remote().result(timeout=30)
+        time.sleep(2.0)
+        second = handle.engine_stats.remote().result(timeout=30)
+        out["engine"] = {k: [first.get(k), second.get(k)] for k in keys}
+        stacks = handle.thread_stacks.remote().result(timeout=30)
+        out["engine_thread"] = next(
+            (v for k, v in stacks.items() if k.startswith("llm-engine")), None)
+        out["threads"] = len(stacks)
+    except Exception as e:  # noqa: BLE001 - a diagnosis may itself fail
+        out["error"] = repr(e)[:300]
+    return out
 
 
 def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
@@ -158,6 +183,10 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
         sent = plan["requests"][r.index] if plan["mode"] == "open" \
             else plan["next_request"](r.index)
         samples.append({"prompt": sent["tokens"], "tokens": r.tokens})
+    diagnosis = None
+    if len(good) < max(1, len(measured)):
+        diagnosis = diagnose(handle)
+        print(f"diagnosis: {diagnosis}", file=sys.stderr)
     check = handle.check_requests.remote(samples, length).result(timeout=900) \
         if samples else None
     report = handle.bench_report.remote().result(timeout=60)
@@ -169,6 +198,11 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
                   for m in mf.metrics_for(manifest, args.workload, "per_layer")]
         keep = sorted({p["rows_from"] for p in params if "rows_from" in p})
         summary = handle.trace_summary.remote(keep).result(timeout=600)
+        if not summary.get("planes") and diagnosis is None:
+            diagnosis = diagnose(handle)
+            print(f"diagnosis (the profile holds no device operation; "
+                  f"poll errors {marks.get('poll_errors')}): {diagnosis}",
+                  file=sys.stderr)
     return {
         "cfg": cfg, "plan_offered": plan["offered"],
         "records": result.records, "t_open": result.t_open, "t_close": result.t_close,
@@ -178,4 +212,5 @@ def run(args, resolved: Dict[str, Any], cfg: Dict[str, Any],
         "setup_s": marks["open_wall"] - t_process,
         "replica_ready_s": replica_ready_s, "device_report": report, "trace": summary, "marks": marks,
         "check": check, "check_limits": traffic["check"].get("limits", {}),
+        "diagnosis": diagnosis,
     }

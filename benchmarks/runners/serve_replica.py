@@ -71,6 +71,23 @@ class BenchLLM:
                     (s.get("peak_bytes_in_use") or 0) for s in stats),
                 "bytes_limit": max((s.get("bytes_limit") or 0) for s in stats)}
 
+    def thread_stacks(self) -> Dict[str, str]:
+        """Where every thread of this process stands, innermost frames first
+        (``file:line function``): what a run whose requests never came back
+        prints, so that a stall names the call it sits in."""
+        import sys
+        import threading
+        import traceback
+
+        names = {t.ident: t.name for t in threading.enumerate()}
+        out = {}
+        for ident, frame in sys._current_frames().items():
+            stack = traceback.extract_stack(frame)[-5:]
+            out[f"{names.get(ident, '?')}-{ident}"] = " < ".join(
+                f"{os.path.basename(f.filename)}:{f.lineno} {f.name}"
+                for f in reversed(stack))
+        return out
+
     def reseed(self, seed: int) -> bool:
         """New seeded weights in place (tools only; the engine is idle)."""
         import jax

@@ -299,4 +299,3 @@ def test_ling_cell_reports_what_the_manifest_says():
     # exactly its cell, and no PR but a ``benchmark`` one may edit that file
     assert not {"latent_attn_decode_roofline", "flash_mla_fwd_roofline",
                 "tpot_p50.longdoc"} & set(per)
-    assert len(manifest["per_layer"]) == 111

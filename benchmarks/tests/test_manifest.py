@@ -1,9 +1,12 @@
 """BENCHMARK.json holds to the contract, every cell's files resolve by name,
 every configuration keeps its source's published shape but for what it lists
 as reduced, and a cell, a configuration of another family and a metric can be
-ADDED as new files only: the copy that has them passes this whole contract."""
+ADDED as new files only: the copy that has them passes this whole contract,
+and so does every family's test that reads the manifest or ``metrics/``."""
+import ast
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -225,10 +228,39 @@ DUMMY_CTX = {"things": 21, "setup_s": 1.0, "chips": 4,
              "input_waits": [9.0, 0.002, 0.004],
              "reports": [10.0, 20.0, 30.0], "report_tokens": [5.0, 400.0, 400.0],
              "window_open": 10.0, "window_close": 35.0}
+# what a family's test reads the manifest or the ``metrics/`` directory through
+READS_THE_MANIFEST = re.compile(
+    r"load_manifest|metrics_for|metric_file|read_metrics?\b|resolve_cell"
+    r"|resolve_params|BENCHMARK\.json|metrics/|[\"']metrics[\"']")
+
+
+def family_tests_that_read_the_manifest(tests_dir):
+    """Node ids of the tests of every ``test_*_family.py`` under ``tests_dir``
+    whose text reads the manifest or ``metrics/``, or names a helper or a
+    fixture of its module that does. The others (a program against its
+    reference at tiny widths, a control) read neither and cost a minute."""
+    ids = []
+    for path in sorted(tests_dir.glob("test_*_family.py")):
+        src = path.read_text()
+        text = {n.name: ast.get_source_segment(src, n)
+                for n in ast.parse(src).body if isinstance(n, ast.FunctionDef)}
+        reads = {n for n, t in text.items() if READS_THE_MANIFEST.search(t)}
+        while True:
+            more = {n for n, t in text.items() if n not in reads
+                    and any(re.search(r"\b%s\b" % r, t) for r in reads)}
+            if not more:
+                break
+            reads |= more
+        ids += [f"{path}::{n}" for n in text
+                if n in reads and n.startswith("test_")]
+    return ids
+
+
 DROP_IN = '''
 import json, sys
 sys.path.insert(0, %r); sys.path.append(%r)
 import numpy as np
+import pytest
 from benchmarks.harness import manifest as mf
 from benchmarks.harness.weights import load_config_file
 from benchmarks.tests import test_manifest as contract
@@ -255,6 +287,9 @@ for c in m["configs"]:
     gaps = family.make_gap_fn(cfg)(params, tokens, np.argmax(logits, -1))
     assert float(abs(gaps).max()) == 0.0
     shapes[c["name"]] = [family.__name__, list(logits.shape)]
+# every family's tests of the manifest and of metrics/, against THIS copy: one
+# that pins a count or a set fails in the PR that writes it, not in the next
+assert pytest.main(["-q", "-p", "no:cacheprovider", "-p", "no:xdist"] + %r) == 0
 print(json.dumps({"root": mf.ROOT, "chips": r["cell"]["chips"], "shapes": shapes,
   "per": mf.read_metrics(m, "dummy_cell", "per_layer", ctx),
   "e2e": mf.read_metrics(m, "dummy_cell", "end_to_end", ctx)}))
@@ -269,8 +304,12 @@ def test_a_cell_a_configuration_and_a_metric_drop_in_as_new_files(tmp_path, unli
     reader, a runner, and manifest entries (the cell appended to the
     ``workloads`` of the metrics it reports); edit no file that was there. The
     copy then passes the whole contract above, its families answer, and the
-    harness reads the new metric. A value changed without an entry in
-    ``reduced`` fails the same contract."""
+    harness reads the new metric. Two more made-up entries list an ACCEPTED
+    train cell and an ACCEPTED serving cell, as a later PR's new entry would:
+    every family's tests that read the manifest or ``metrics/`` pass against
+    the copy too (PR 56: a count of ``per_layer`` pinned in one of them had
+    shut every later entry out). A value changed without an entry
+    in ``reduced`` fails the same contract."""
     root = tmp_path / "checkout"
     shutil.copytree(os.path.join(mf.ROOT, "benchmarks"), root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -310,16 +349,30 @@ def test_a_cell_a_configuration_and_a_metric_drop_in_as_new_files(tmp_path, unli
         "name": "dummy_metric", "unit": "things", "better": "higher",
         "source": "program_counter", "layer": "Dummy",
         "moves": "train_tokens_per_s_chip", "workloads": ["dummy_cell"]})
+    # what a later PR's entry looks like: a cell that is there, at the END
+    for name, cell, moves, scale in (
+            ("dummy_train_entry", "train_moe_8k", "train_tokens_per_s_chip", 3),
+            ("dummy_serve_entry", "serve_chat", "tpot_p50", 5)):
+        (b / "metrics" / (name + ".json")).write_text(json.dumps({
+            "name": name, "layer": "Dummy", "unit": "things", "moves": moves,
+            "reader": "dummy_reader", "params": {"scale": scale}}))
+        manifest["per_layer"].append({
+            "name": name, "unit": "things", "better": "higher",
+            "source": "program_counter", "layer": "Dummy", "moves": moves,
+            "workloads": [cell]})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     assert all(p.read_bytes() == data for p, data in before.items())
-    p = subprocess.run([sys.executable, "-c", DROP_IN % (str(root), mf.ROOT)],
+    family_tests = family_tests_that_read_the_manifest(b / "tests")
+    assert family_tests
+    p = subprocess.run([sys.executable, "-c",
+                        DROP_IN % (str(root), mf.ROOT, family_tests)],
                        capture_output=True, text=True, cwd=str(root),
                        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     if unlisted:
         assert p.returncode != 0 and "AssertionError" in p.stderr
         assert "'dummy-model', {" in p.stderr and unlisted in p.stderr
         return
-    assert p.returncode == 0, p.stderr[-3000:]
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     got = json.loads(p.stdout.strip().splitlines()[-1])
     assert got["root"] == str(root) and got["chips"] == 4
     assert got["per"] == {

@@ -259,6 +259,72 @@ def test_the_reducer_keeps_the_operation_a_pattern_names(wall_profile):
         "jit_paged_decode_steps(33)": 3}
 
 
+# ---- shares of a program's time, by the operations a pattern names ----
+POOL = "bf16[8,12296,64,128]{3,2,1,0:T(8,128)(2,1)}"
+STACK = "bf16[8,16,2304,896]{3,2,1,0:T(8,128)(2,1)}"
+SHARES = [
+    # the parent's two scatter fusions of a decode tick and the kernel that
+    # took their place; not the prompt's writes (32 page ids, whole pages)
+    ("token_write_decode_share.chat", "mistral-7b-v0.3-serve",
+     "jit_paged_decode_steps(1)", {
+         f"fusion.135 = {POOL} fusion({POOL} %get-tuple-element.1076, "
+         "s32[512]{0:T(512)S(1)} %reshape.333, bf16[512,128]{1,0:T(8,128)(2,1)"
+         "S(1)} %bitcast.185), kind=kCustom": 0.04,
+         f"token_rows_write.9 = ({POOL}, {POOL}) custom-call(s32[64]{{0:T(128)"
+         "S(1)} %broadcast_add_fusion.5, s32[64]{0:T(128)S(1)} %gte.1152)": 0.02},
+     {f"fusion.137 = {POOL} fusion({POOL} %get-tuple-element.77, s32[32]"
+      "{0:T(128)} %pages, bf16[32,8,64,128]{3,2,1,0} %rows)": 0.5,
+      "paged_attention.10 = (f32[64,32,1,128]{3,2,1,0}) custom-call(%q)": 0.3},
+     6.0),
+    # a compacted block's rows onto their tokens, forward and reverse
+    ("experts_combine_train_share", "mellum2-12b-a2.5b-train", "jit_step_fn(1)", {
+        "rows-to-tokens.36 = f32[4096,2304]{1,0:T(8,128)} custom-call(s32[1]"
+        "{0:T(128)} %bitcast.1499, s32[16384]{0:T(1024)S(1)} %copy-done.91)": 0.011,
+        "rows-to-tokens.39 = f32[4096,2304]{1,0:T(8,128)} custom-call(s32[1]"
+        "{0:T(128)} %bitcast.1654, s32[16384]{0:T(1024)S(1)} %copy-done.90)": 0.006},
+     {"ragged-dot-rows-t.58 = f32[16384,2304]{1,0:T(8,128)} custom-call(%a)": 0.2,
+      "add.2635 = f32[16384,2304]{1,0:T(8,128)} add(%b, %c)": 0.1}, 1.7),
+    # AdamW's update of a leaf: parameter, first and second moment, one float
+    # shape; not three index vectors of the expert layer
+    ("optimizer_train_share", "mellum2-12b-a2.5b-train", "jit_step_fn(1)", {
+        f"fusion.642 = ({STACK}, {STACK}, {STACK}) fusion({STACK} %state.1, "
+        f"{STACK} %g, {STACK} %m, {STACK} %n), kind=kLoop": 0.016,
+        "fusion.77 = (bf16[8,2304,4096]{2,1,0}, bf16[8,2304,4096]{2,1,0}, "
+        "bf16[8,2304,4096]{2,1,0}) fusion(%p, %g, %m, %n)": 0.008,
+        "fusion.614 = (f32[8,2304,64]{1,2,0:T(8,128)}, f32[8,2304,64]{1,2,0:"
+        "T(8,128)}, f32[8,2304,64]{1,2,0:T(8,128)}) fusion(%router, %lr)": 0.002},
+     {"subtract_convert_fusion.20 = (bf16[2,8192,32,64]{3,1,2,0:T(8,128)(2,1)}, "
+      "bf16[2,8192,32,64]{3,1,2,0:T(8,128)(2,1)}) fusion(%a, %b)": 0.3,
+      "multiply_reduce_fusion.28 = (f32[16384]{0:T(1024)S(1)}, bf16[16384,896]"
+      "{1,0}, bf16[16384,896]{1,0}, bf16[16384,896]{1,0}) fusion(%a)": 0.2,
+      "compare_select_fusion.124 = (s32[16384,1]{0,1:T(1,128)}, s32[16384,1]"
+      "{0,1:T(1,128)S(1)}, s32[16384,1]{0,1:T(1,128)S(1)}) fusion(%i, %n)": 0.1,
+      "select_add_fusion.16 = bf16[16,2304,896]{2,1,0} fusion(%w, %g)": 0.1},
+     2.6),
+]
+
+
+@pytest.mark.parametrize("name,config,program,named,others,expected", SHARES,
+                         ids=[case[0] for case in SHARES])
+def test_a_share_counts_the_operations_its_pattern_names(
+        name, config, program, named, others, expected):
+    """``ops_share`` entries on operation names as the chip's traces hold
+    them: the named operations' seconds over the program's, the other
+    operations and the other programs left out; nothing where none ran."""
+    import os
+
+    from benchmarks.harness.weights import load_config_file
+
+    cfg = load_config_file(os.path.join(
+        mf.ROOT, "benchmarks", "configs", config + ".json"))
+    modules = {program: 1.0, "jit_paged_prefill(2)": 5.0, "jit__argmax(3)": 0.5}
+    ctx = {"cfg": cfg, "trace": {"op_self_s": {**named, **others},
+                                 "module_s": modules}}
+    assert mf.read_metric(name, ctx) == pytest.approx(expected)
+    ctx["trace"]["op_self_s"] = others
+    assert mf.read_metric(name, ctx) is None
+
+
 # ---- one entry a meaning: what a family or a configuration decides ----
 FAMILY_PROGRAMS = {
     "mistral-7b-v0.3-serve": ("jit_paged_decode_steps", "jit_paged_prefill", None),

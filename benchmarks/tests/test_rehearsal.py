@@ -5,6 +5,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -69,6 +70,31 @@ def test_reaping_finds_marked_processes():
     try:
         assert child.pid in procs.marked_pids(token)
         assert procs.reap_all(token, grace_s=0.2, limit_s=20) == []
+        assert child.poll() is not None
+    finally:
+        child.kill()
+        os.environ.pop(procs.MARKER, None)
+
+
+def test_reaping_waits_for_a_process_whose_first_thread_is_gone():
+    """``ps``: Zl, as a killed chip holder is for the seconds its last
+    thread takes to leave libtpu. No environment finds it then: the snapshot
+    before the teardown does, as a descendant, and it counts as running
+    while ``stat`` counts a second thread."""
+    token = procs.mark_environment()
+    child = subprocess.Popen([sys.executable, "-c", (
+        "import ctypes, threading, time\n"
+        "threading.Thread(target=time.sleep, args=(60,)).start()\n"
+        "ctypes.CDLL(None).pthread_exit(None)\n")], start_new_session=True)
+    try:
+        time.sleep(2.0)  # the first thread has left, the other sleeps on
+        with open(f"/proc/{child.pid}/stat") as f:
+            assert f.read().rsplit(")", 1)[1].split()[0] == "Z"
+        assert child.pid not in procs.marked_pids(token)
+        assert procs.running(child.pid)
+        known = procs.snapshot(token)
+        assert child.pid in known
+        assert procs.reap_all(token, grace_s=0.2, limit_s=20, known=known) == []
         assert child.poll() is not None
     finally:
         child.kill()
