@@ -1172,3 +1172,64 @@ def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     mem = compiled.memory_analysis()
     state = mem.argument_size_in_bytes
     assert 6.4e9 < state < 6.6e9 and mem.alias_size_in_bytes > 6.4e9
+
+
+# --------------------------------------------------------------------------- #
+# PR 57: the third trained family, and the first recurrent layer with a
+# reverse pass
+# --------------------------------------------------------------------------- #
+def test_gdn_chunk_kernels_alone_compile_at_the_cells_call(v5e):
+    """The gated delta rule's chunk kernels by themselves at the call the
+    cell makes of them (one row of 32,768 tokens, 30 heads, key 96 / value
+    192 in bfloat16, ``g`` and ``beta`` float32): Mosaic takes ten heads a
+    grid step in lockstep at 0.75 and 1.5 lane tiles, the rows of ``G`` and
+    ``beta`` turned to columns by a product, the masks from whole iotas; the
+    forward is ONE call under the name the benchmark's readers find it by; the
+    reverse pass is two, a group of ten heads at a time (its chunks' states
+    are a third of the heads': 0.5 GB and not 1.5)."""
+    from ray_tpu.ops import gdn
+
+    shape = functools.partial(jax.ShapeDtypeStruct,
+                              sharding=SingleDeviceSharding(v5e.devices[0]))
+    b, s, h = 1, 32768, 30
+    qk, v = shape((b, s, h, 96), jnp.bfloat16), shape((b, s, h, 192), jnp.bfloat16)
+    gate = shape((b, s, h), jnp.float32)
+    fwd = functools.partial(gdn.gdn_chunk, impl="pallas")
+    text = _compiled_text(fwd, qk, qk, v, gate, gate)
+    assert [c.split(".")[0] for c in _mosaic_calls(text)] == ["gdn_chunk_fwd"]
+    assert re.search(r"gdn_chunk_fwd\S* = bf16\[1,30,32768,192\]", text)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                          qk, qk, v, gate, gate)
+    assert sorted(c.split(".")[0] for c in _mosaic_calls(text)) == [
+        "gdn_chunk_bwd", "gdn_chunk_bwd_states"]
+    assert re.search(r"gdn_chunk_bwd_states\S* = f32\[1,10,512,192,96\]", text)
+    assert not re.search(r"f32\[1,30,512,192,96\]", text)
+
+
+def test_olmo_hybrid_train_step_compiles_and_fits_at_one_row_of_32768(v5e):
+    """Olmo-Hybrid-7B's published widths, one period of its eight (L L L F)
+    and a quarter of the vocabulary, 1 x 32,768 tokens, the benchmark's
+    optimizer: the step holds the chunk kernels forward and reverse beside
+    the full flash forward and backward (whose q, dO and statistics of one
+    head sit whole in VMEM: 117 MB at 32,768 rows of a v5e's 128), the
+    scanned period ONE linear layer's body and one full layer's, and the
+    program fits the chip beside 6.16 GB of state."""
+    from ray_tpu.models import olmo_hybrid as oh
+
+    config = oh.OlmoHybridConfig(
+        vocab_size=25088, layer_types=oh.PERIOD, attention_impl="flash",
+        gdn_impl="pallas", max_seq_len=32768)
+    compiled = _train_step_lowered(v5e, config, 1, 32768).compile()
+    calls = [c.split(".")[0] for c in _mosaic_calls(compiled.as_text())]
+    assert {c: calls.count(c) for c in set(calls)} == {
+        "gdn_chunk_fwd": 1, "gdn_chunk_bwd_states": 1, "gdn_chunk_bwd": 1,
+        "attn_full": 3}, calls
+    mem = compiled.memory_analysis()
+    state = mem.argument_size_in_bytes
+    assert 6.1e9 < state < 6.2e9 and mem.alias_size_in_bytes > 6.1e9
+    # the step's own beside the new state it writes onto the old
+    assert mem.temp_size_in_bytes - mem.alias_size_in_bytes < 7.5e9
