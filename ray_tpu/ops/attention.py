@@ -11,8 +11,7 @@ Design (see /opt/skills/guides/pallas_guide.md):
   sliding ``window`` the loop also STARTS at the first K block a row of the
   q block can see, so a window layer costs S x window and not S^2 / 2, in
   serving's prefill and in training's forward and backward alike; those
-  calls are named ``flash_window_fwd``, ``flash_window_bwd_dq`` and
-  ``flash_window_bwd_dkv`` in a profile.
+  calls are named ``flash_window_fwd`` and ``flash_window_bwd`` in a profile.
 - GQA: q heads map onto kv heads through the BlockSpec index_map
   (h // q_per_kv), so kv tensors are never materialized per-q-head.
 - widths: q and k share one head width, any the compiler tiles (128 as the
@@ -22,14 +21,18 @@ Design (see /opt/skills/guides/pallas_guide.md):
   With ``lengths`` (forward only) a batch row's q blocks past its real rows
   are skipped: one program for every prompt length costs what the prompt
   needs.
-- backward: Pallas kernels with the standard flash-bwd recurrence — the
+- backward: ONE Pallas pass with the standard flash-bwd recurrence — the
   forward also emits the logsumexp per row; bwd recomputes p = exp(qk−lse)
-  blockwise, so S×S never materializes. Two kernels: dq (grid over q blocks)
-  and dk/dv (grid over k blocks, accumulated at q-head granularity then
-  reduced onto kv heads for GQA). Under a window the dq kernel's K loop
-  starts, and the dk/dv kernel's Q loop stops, at the blocks the window
-  admits. Defined where q, k and v share one width and no ``lengths`` are
-  given.
+  blockwise, so S×S never materializes. The grid walks the (q block, k block)
+  pairs the mask admits (a table made from the static shapes, the window
+  among them, read by scalar prefetch), k block by k block: a pair forms the
+  scores, p, dP and dS once and adds to all of dV, dK and dQ. dK and dV of a
+  k block are summed over the q blocks AND the group's q heads in float32
+  scratch and written once in the storage dtype; the group's dQ is a float32
+  block that stays in VMEM for the whole walk (a group whose dQ is over
+  ``BWD_DQ_VMEM_BYTES`` is walked in equal parts, whose float32 dK and dV
+  are summed outside). K, V, q, dO, lse and delta stream a block a pair.
+  Defined where q, k and v share one width and no ``lengths`` are given.
 
 Replaces-the-capability-of: the reference's NCCL-attached attention stacks
 are external (DeepSpeed etc. via train integrations); here attention is a
@@ -43,6 +46,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,7 +56,7 @@ logger = get_logger("ops.attention")
 
 # Block sizes for a v5e: small blocks (128/128) leave the MXU idle between
 # grid steps. What these reach is measured where the kernel is used: the
-# benchmark's flash_fwd_roofline / flash_bwd_roofline in train_4k (PERF.md 3).
+# benchmark's flash_fwd_roofline / flash_bwd_fused_roofline in train_4k (PERF.md 3).
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -176,10 +180,6 @@ def _flash_fwd_ragged_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, *,
 # scoped VMEM (a v5e has 128 MiB). 4 MB at the 4096 rows of the training
 # cell: calls that fit are compiled as they were
 KV_VMEM_DEFAULT_BYTES = 8 * 2 ** 20
-# the same for what the dK/dV kernel keeps whole: q, dO and the two float32
-# columns of one (batch, q head). 12 MB at the 4096 rows of ``train_4k``,
-# which compiles as it did; 24 MB at 8192
-ROWS_VMEM_DEFAULT_BYTES = 12 * 2 ** 20
 
 
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
@@ -274,200 +274,213 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                         *, block_q, block_k, seq_kv, causal, scale, offset,
-                         window=None):
-    """dQ for one (batch, q_head, q_block): stream K/V blocks, recompute
-    p = exp(s - lse), ds = p * (dO·Vᵀ - delta), dq += scale · ds · K. Under a
-    ``window`` the stream starts at the first K block the q block's first
-    row sees, as the forward's does."""
-    qi = pl.program_id(2)
-    q = q_ref[0, 0]  # storage dtype: bf16 dots on the MXU, f32 accumulate
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0]  # [block_q, 1] f32
-    delta = delta_ref[0, 0]  # [block_q, 1] f32
-    d = q.shape[-1]
+# what a step of the backward's table is, beside its two block indices
+_PAIR_FIRST, _PAIR_LAST, _PAIR_EDGE = 1, 2, 4
 
-    q_start = qi * block_q + offset
+
+def _flash_bwd_pairs(nq, nk, block_q, block_k, offset, causal, window):
+    """The (q block, k block) pairs the mask admits, in the order the
+    backward walks them: k block by k block, inside it the q blocks. Two
+    int32 tables, a pair an entry: ``i | j << 16`` and flags that say
+    whether the pair is the first or the last of its k block (dK and dV are
+    zeroed at the one and written at the other) and whether it lies on an
+    edge of the mask (only there is the mask applied). A k block no query
+    sees (keys older than every window, where ``skv > sq``) keeps one pair
+    that is all mask, so its dK and dV are written as zeros."""
+    q_lo = offset + np.arange(nq)[None, :] * block_q
+    k_lo = np.arange(nk)[:, None] * block_k
+    q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+    admitted = np.ones((nk, nq), bool)
+    whole = np.ones((nk, nq), bool)
     if causal:
-        num_k_blocks = jax.lax.div(
-            jnp.minimum(q_start + block_q, seq_kv) + block_k - 1, block_k
-        )
-    else:
-        num_k_blocks = seq_kv // block_k
-    first_k_block = 0
+        admitted &= q_hi >= k_lo
+        whole &= q_lo >= k_hi
     if window is not None:
-        first_k_block = jnp.maximum(q_start - window + 1, 0) // block_k
-
-    def body(j, dq):
-        k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            seen = rows >= cols
-            if window is not None:
-                seen = jnp.logical_and(seen, rows - cols < window)
-            s = jnp.where(seen, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta) * scale).astype(k_blk.dtype)
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    dq = jax.lax.fori_loop(first_k_block, num_k_blocks, body,
-                           jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+        admitted &= q_lo - k_hi < window
+        whole &= q_hi - k_lo < window
+    where, flags = [], []
+    for j in range(nk):
+        i = np.flatnonzero(admitted[j])
+        edge = ~whole[j, i] * _PAIR_EDGE
+        if not i.size:
+            i, edge = np.zeros(1, int), np.full(1, _PAIR_EDGE)
+        edge[0] |= _PAIR_FIRST
+        edge[-1] |= _PAIR_LAST
+        where.append(i | j << 16)
+        flags.append(edge)
+    return (np.concatenate(where).astype(np.int32),
+            np.concatenate(flags).astype(np.int32))
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q, block_k, seq_q, causal,
-                          scale, offset, window=None):
-    """dK/dV for one (batch, q_head, k_block): stream q blocks from the first
-    causally-visible one, and under a ``window`` only as far as the last q
-    block whose first row still sees the k block's last key. Accumulated per
-    Q head; the caller reduces onto kv heads (GQA)."""
-    ki = pl.program_id(2)
-    k_blk = k_ref[0, 0]  # storage dtype (bf16 MXU path)
-    v_blk = v_ref[0, 0]
-    d = k_blk.shape[-1]
-    k_start = ki * block_k
+# The dQ of a KV head's q heads stays in VMEM for the whole walk, float32
+# scratch and the output block twice. Up to this many bytes of it the whole
+# group is walked in one grid step (68 MiB: Mellum's 8 heads x 8,192 rows, one
+# head x 65,536); past it the group goes in equal parts (Mistral's 4 heads x
+# 32,768 rows: two of 2), so the call's ask stops growing with the group and
+# stays under a v5e's 128 MiB with the 16 MB a head's pair takes beside it
+BWD_DQ_VMEM_BYTES = 80 * 2 ** 20
 
-    num_q_blocks = seq_q // block_q
-    if causal:
-        # first q block whose LAST row (abs pos offset + i*bq + bq - 1) can
-        # see this k block: i >= (k_start - offset) / bq
-        first = jax.lax.max(0, jax.lax.div(k_start - offset, block_q))
-    else:
-        first = 0
-    if window is not None:
-        # the newest row that sees the block's LAST key (k_start + bk - 1)
-        # is that key's position + window - 1
-        num_q_blocks = jax.lax.min(num_q_blocks, jax.lax.div(
-            k_start + block_k + window - 2 - offset, block_q) + 1)
 
-    def body(i, carry):
-        dk, dv = carry
-        q_blk = q_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        do_blk = do_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        lse_blk = lse_ref[0, 0, pl.ds(i * block_q, block_q), :]  # [bq, 1]
-        delta_blk = delta_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
+def _flash_bwd_kernel(where_ref, flags_ref, q_ref, do_ref, lse_ref, delta_ref,
+                      k_ref, v_ref, dk_ref, dv_ref, dq_ref, dk_acc, dv_acc,
+                      dq_acc, *, block_q, block_k, causal, scale, offset, window):
+    """ONE (q block, k block) pair a grid step, for (batch, part of a KV
+    head's group of q heads, pair of ``_flash_bwd_pairs``), every q head of
+    the part in turn (the part is the whole group where its dQ fits
+    ``BWD_DQ_VMEM_BYTES``): the
+    scores, p, dP and dS of a head are formed once, keys down the sublanes
+    and queries along the lanes (so that lse and delta ride as lane-dense
+    rows and dV and dK take p^T and dS^T as they stand), and feed all three
+    of dV += p^T dO, dK += dS^T q and dQ += dS k, each summed in float32
+    scratch and written once in the storage dtype: dK and dV of the k block,
+    over the heads and the q blocks, at the block's last pair; dQ of the
+    part's heads, which the pair axis does not move, at the walk's last.
+    ``scale`` multiplies dK and dQ there, once a row and not once a score."""
+    t = pl.program_id(2)
+    where, flags = where_ref[t], flags_ref[t]
+    i, j = where & 0xFFFF, where >> 16
+
+    @pl.when(t == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(flags & _PAIR_FIRST != 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def pair(edge):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        rows_of_i = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        nt = (((1,), (1,)), ((), ()))
+        if edge:
+            keys = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
             rows = offset + i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            seen = rows >= cols
+                jnp.int32, (block_k, block_q), 1)
+            seen = rows >= keys
             if window is not None:
-                seen = jnp.logical_and(seen, rows - cols < window)
-            s = jnp.where(seen, s, NEG_INF)
-        p = jnp.exp(s - lse_blk)
-        p_lo = p.astype(do_blk.dtype)
-        dv = dv + jax.lax.dot_general(
-            p_lo, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta_blk) * scale).astype(q_blk.dtype)
-        dk = dk + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return dk, dv
+                seen = jnp.logical_and(seen, rows - keys < window)
 
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first, num_q_blocks, body, (dk0, dv0))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        def head(h, _):
+            q, do = q_ref[0, h], do_ref[0, h]  # storage dtype: bf16 on the MXU
+            s = jax.lax.dot_general(
+                k, q, nt, preferred_element_type=jnp.float32) * scale  # [bk, bq]
+            if edge:
+                s = jnp.where(seen, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[0, h, 0])  # lse, delta: [1, bq]
+            dp = jax.lax.dot_general(v, do, nt, preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, h, 0])).astype(q.dtype)
+            dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dk_acc[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            dq_acc[h, rows_of_i, :] += jax.lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+        jax.lax.fori_loop(0, q_ref.shape[1], head, None)
+
+    if causal:
+        pl.when(flags & _PAIR_EDGE != 0)(lambda: pair(True))
+        pl.when(flags & _PAIR_EDGE == 0)(lambda: pair(False))
+    else:
+        pair(False)
+
+    @pl.when(flags & _PAIR_LAST != 0)
+    def _():
+        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret,
                window=None):
+    """dQ, dK and dV in ONE pass over the block pairs the mask admits. K, V
+    and a group's q and dO stream a block a pair; what stays in VMEM whole is
+    the dQ of the q heads a grid step walks (float32 while it is summed, the
+    storage dtype on its way out), which is what the call asks Mosaic for: a
+    KV head's whole group where that fits ``BWD_DQ_VMEM_BYTES``, else the
+    largest equal part of it that does, and then each part writes its dK and
+    dV in float32 and they are summed here before the one cast. The q block
+    is twice the forward's where the rows divide: a pair does five products
+    where a forward block does two, and a grid step costs what it costs
+    (PERF.md 6, PR 58). The call under a window is named ``flash_window_bwd``."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
-    q_per_kv = hq // hkv
+    group = hq // hkv
+    if sq % (2 * block_q) == 0:
+        block_q *= 2
+    nq, nk = sq // block_q, skv // block_k
+    where, flags = _flash_bwd_pairs(nq, nk, block_q, block_k, skv - sq, causal,
+                                    window)
+    # a head's dQ, float32 scratch and the output block double buffered, and
+    # its blocks of q and dO, double buffered
+    head_vmem = d * (sq * (4 + 2 * q.dtype.itemsize)
+                     + 4 * block_q * q.dtype.itemsize)
+    heads = max(n for n in range(1, group + 1)
+                if group % n == 0 and (n == 1 or n * head_vmem <= BWD_DQ_VMEM_BYTES))
+    parts = group // heads
     qt = q.transpose(0, 2, 1, 3)
+    dot = g.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    dot = g.transpose(0, 2, 1, 3)
-    # delta_i = sum_d dO_i · O_i  (the softmax-jacobian row correction)
+    # delta_i = sum_d dO_i · O_i  (the softmax-jacobian row correction); it
+    # and lse as rows of a q block, along the lanes: [b, hq, nq, 1, block_q]
     delta = jnp.einsum(
         "bqhd,bqhd->bhq", g.astype(jnp.float32), out.astype(jnp.float32)
-    )[..., None]
-    offset = skv - sq
+    ).reshape(b, hq, nq, 1, block_q)
+    lse = lse.reshape(b, hq, nq, 1, block_q)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bb, h, i: (bb, h, i, 0))
-    q_full = pl.BlockSpec((1, 1, sq, d), lambda bb, h, i: (bb, h, 0, 0))
-    kv_full = pl.BlockSpec((1, 1, skv, d), lambda bb, h, i, _g=q_per_kv: (bb, h // _g, 0, 0))
-    kv_blk = pl.BlockSpec((1, 1, block_k, d), lambda bb, h, j, _g=q_per_kv: (bb, h // _g, j, 0))
-    row_blk = pl.BlockSpec((1, 1, block_q, 1), lambda bb, h, i: (bb, h, i, 0))
-    row_full = pl.BlockSpec((1, 1, sq, 1), lambda bb, h, i: (bb, h, 0, 0))
-    dq_call, dkv_call, windowed = {}, {}, {}
+    q_spec = pl.BlockSpec(
+        (1, heads, block_q, d),
+        lambda bb, part, t, where, flags: (bb, part, where[t] & 0xFFFF, 0))
+    row_spec = pl.BlockSpec(
+        (1, heads, 1, 1, block_q),
+        lambda bb, part, t, where, flags: (bb, part, where[t] & 0xFFFF, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda bb, part, t, where, flags: (bb, part // parts, where[t] >> 16, 0))
+    dkv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda bb, part, t, where, flags: (bb, part, where[t] >> 16, 0))
+    dq_spec = pl.BlockSpec(
+        (1, heads, sq, d), lambda bb, part, t, where, flags: (bb, part, 0, 0))
+    call = {}
     if window is not None:
-        windowed = {"window": window}
-        dq_call["name"] = "flash_window_bwd_dq"
-        dkv_call["name"] = "flash_window_bwd_dkv"
-    # what sits whole in VMEM, double buffered: K and V for dQ; q, dO and the
-    # two float32 columns (a lane-padded tile a row: 512 bytes) for dK/dV
-    kv_vmem = 2 * skv * 2 * d * k.dtype.itemsize
-    if kv_vmem > KV_VMEM_DEFAULT_BYTES:
-        dq_call["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=kv_vmem + 16 * 2 ** 20)
-    rows_vmem = 2 * sq * (2 * d * q.dtype.itemsize + 2 * 128 * 4)
-    if rows_vmem > ROWS_VMEM_DEFAULT_BYTES:
-        dkv_call["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=rows_vmem + 16 * 2 ** 20)
-
-    dq = pl.pallas_call(
+        call["name"] = "flash_window_bwd"
+    dk, dv, dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-            seq_kv=skv, causal=causal, scale=scale, offset=offset, **windowed,
-        ),
-        grid=(b, hq, sq // block_q),
-        in_specs=[q_spec, kv_full, kv_full, q_spec, row_blk, row_blk],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        interpret=interpret,
-        **dq_call,
-    )(qt, kt, vt, dot, lse, delta)
-
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-            seq_q=sq, causal=causal, scale=scale, offset=offset, **windowed,
-        ),
-        grid=(b, hq, skv // block_k),
-        in_specs=[q_full, kv_blk, kv_blk, q_full, row_full, row_full],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda bb, h, j: (bb, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bb, h, j: (bb, h, j, 0)),
-        ],
+            _flash_bwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
+            scale=scale, offset=skv - sq, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, hkv * parts, where.size),
+            in_specs=[q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec],
+            out_specs=[dkv_spec, dkv_spec, dq_spec],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2
+            + [pltpu.VMEM((heads, sq, d), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, skv, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, skv, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv * parts, skv, d),
+                                 k.dtype if parts == 1 else jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv * parts, skv, d),
+                                 v.dtype if parts == 1 else jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         ],
+        # beside the dQ: what a head's pair takes (K, V, dK, dV, the scores
+        # and their kin: 16 MB holds blocks of 512 x 512 and twice that)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=heads * head_vmem + 16 * 2 ** 20),
         interpret=interpret,
-        **dkv_call,
-    )(qt, kt, vt, dot, lse, delta)
-
-    # GQA reduction: q-head-granular dk/dv sum onto their kv head
-    dk = dk_h.reshape(b, hkv, q_per_kv, skv, d).sum(axis=2)
-    dv = dv_h.reshape(b, hkv, q_per_kv, skv, d).sum(axis=2)
-    return (
-        dq.transpose(0, 2, 1, 3),
-        dk.transpose(0, 2, 1, 3).astype(k.dtype),
-        dv.transpose(0, 2, 1, 3).astype(v.dtype),
-    )
+        **call,
+    )(jnp.asarray(where), jnp.asarray(flags), qt, dot, lse, delta, kt, vt)
+    if parts > 1:
+        dk, dv = (x.reshape(b, hkv, parts, skv, d).sum(axis=2).astype(k.dtype)
+                  for x in (dk, dv))
+    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
+            dv.transpose(0, 2, 1, 3))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))

@@ -12,6 +12,7 @@ widths; depth is cut where it only repeats a scanned layer.
 
 import dataclasses
 import functools
+import json
 import math
 import os
 import re
@@ -26,7 +27,8 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from ray_tpu.models import paged_decode as pd
 from ray_tpu.models.llama import LlamaConfig, llama_init
 from ray_tpu.ops import grouped_matmul
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, _flash_bwd,
+                                   flash_attention)
 
 B, S, HQ, HKV, D = 8, 2048, 16, 4, 128
 SLOTS, PAGE, POOL_PAGES, TABLE_PAGES, CHUNK = 64, 64, 64 * 8 + 1, 32, 32
@@ -81,7 +83,7 @@ def _compiled_text(fn, *shapes):
 
 
 @pytest.mark.parametrize("passes,kernels", [("forward", 1),
-                                            ("forward_backward", 3)])
+                                            ("forward_backward", 2)])
 def test_flash_attention_compiles(v5e, passes, kernels):
     one = SingleDeviceSharding(v5e.devices[0])
     q = jax.ShapeDtypeStruct((B, S, HQ, D), jnp.bfloat16, sharding=one)
@@ -91,7 +93,7 @@ def test_flash_attention_compiles(v5e, passes, kernels):
         return flash_attention(q, k, v).astype(jnp.float32).sum()
 
     fn = flash_attention if passes == "forward" \
-        else jax.grad(loss, argnums=(0, 1, 2))  # fwd + dq + dk/dv kernels
+        else jax.grad(loss, argnums=(0, 1, 2))  # the forward + ONE backward pass
     assert _compiled_text(fn, q, kv, kv).count("tpu_custom_call") == kernels
 
 
@@ -139,6 +141,118 @@ def _mosaic_calls(text):
     """Names of a compiled program's Mosaic kernel instructions."""
     return re.findall(
         r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+
+
+def _scoped_vmem_asks(text, name):
+    """What each Mosaic call whose name starts ``name`` asks of VMEM, bytes
+    (its ``vmem_limit_bytes``, as the compiled instruction carries it)."""
+    return [int(size) for size in re.findall(
+        r"%?" + name + r"[\w.\-]* = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*?\"scoped_memory_configs\":\[\{[^}]*\"size\":\"(\d+)\"", text)]
+
+
+# (rows, q heads, KV heads, the q heads a grid step walks): Olmo-Hybrid's
+# context, 30 heads of a group of one; Mistral-7B's own, a group of 4 whose
+# dQ (136 MB) is over ``BWD_DQ_VMEM_BYTES`` and goes in two parts of two;
+# Mellum's group of 8 at the same rows, four parts of two
+CONTEXT_CASES = [(65536, 30, 30, 1), (32768, 32, 8, 2), (32768, 32, 4, 2)]
+
+
+@pytest.mark.parametrize("rows,hq,hkv,heads", CONTEXT_CASES)
+def test_flash_backward_alone_compiles_at_a_models_context(v5e, rows, hq, hkv,
+                                                           heads):
+    """The one backward pass by itself at one row of a model's context. The
+    pair of kernels it replaced (PR 58) could not compile 65,536 rows (q, dO
+    and two lane-padded float32 columns of a head whole in VMEM: 200 MB): K,
+    V, q and dO stream a block a pair, and what is held whole is the dQ of
+    the q heads a grid step walks, float32 scratch and the bfloat16 output
+    block twice. That is the whole group where it fits
+    ``BWD_DQ_VMEM_BYTES`` and an equal part of it where it does not, so the
+    ask does not grow with the group: 80.5 MB at 65,536 rows x 1 head and
+    at 32,768 x 2, of a v5e's 128 MiB, and Mosaic takes it."""
+    shape = functools.partial(jax.ShapeDtypeStruct,
+                              sharding=SingleDeviceSharding(v5e.devices[0]))
+    q = shape((1, rows, hq, 128), jnp.bfloat16)
+    kv = shape((1, rows, hkv, 128), jnp.bfloat16)
+    lse = shape((1, hq, rows, 1), jnp.float32)
+
+    def backward(q, k, v, out, lse, g):
+        return _flash_bwd(q, k, v, out, lse, g, True, 128 ** -0.5,
+                          DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, False)
+
+    text = _compiled_text(backward, q, kv, kv, q, lse, q)
+    assert len(_mosaic_calls(text)) == 1
+    asks = _scoped_vmem_asks(text, "")
+    assert asks == [heads * 128 * (rows * (4 + 2 * 2) + 4 * 512 * 2)
+                    + 16 * 2 ** 20]
+    assert asks[0] < 100 * 2 ** 20
+    # a group in parts: (batch, KV heads x parts, rows, 128) float32 leave
+    # the call and are summed over the parts; a whole group: bfloat16
+    parts = hq // hkv // heads
+    assert (f"f32[1,{hkv * parts},{rows},128]" in text) == (parts > 1)
+
+
+def _metric_params(name):
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics",
+                        name + ".json")
+    with open(path) as f:
+        return json.load(f)["params"]
+
+
+def test_the_fused_backward_is_read_whole_or_not_at_all(v5e):
+    """A profile names an operation by its instruction's text. The readers
+    that found the PAIR of backward kernels count half of the five products
+    a match (``flash_bwd_roofline``, ``flash_full_bwd_roofline.train``,
+    ``flash_window_bwd_roofline``; ``attn_train_share`` names the windowed
+    pair): against the ONE pass they must read nothing, because half the
+    products over the whole pass's time is half of the truth. The first
+    result is bfloat16 (dK), so none of them matches; PR 58's two entries
+    match exactly the backward."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    q = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 4096, 8, 128), jnp.bfloat16, sharding=one)
+
+    def results(window):
+        """(the forward's, the backward's) result text, as a profile has it
+        after the call's name."""
+        def loss(q, k, v):
+            return flash_attention(q, k, v, window=window).astype(jnp.float32).sum()
+        text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        found = [line.split(" = ", 1)[1].split("custom-call(")[0]
+                 for line in text.splitlines() if "tpu_custom_call" in line]
+        assert len(found) == 2, found
+        return found
+
+    # under the names the steps' scopes give the calls (the two families'
+    # step tests below hold those), the operands with their types (the
+    # compiled text leaves those out: ``matches`` tries a first operand of
+    # either kind, an array and a scalar-prefetch table)
+    full, windowed = results(None), results(1024)
+
+    def matches(metric, key, op):
+        hits = {re.search(_metric_params(metric)[key], op + "custom-call(" + first)
+                is not None
+                for first in ("bf16[2,32,4096,128]{3,2,1,0} %x, ", "s32[144]{0} %t, ")}
+        assert len(hits) == 1, (metric, op)
+        return hits.pop()
+
+    forward, backward = "checkpoint.21 = " + full[0], "checkpoint.22 = " + full[1]
+    assert full[1].startswith("(bf16[2,8,4096,128]"), backward
+    assert matches("flash_fwd_roofline", "pattern", forward)
+    assert not matches("flash_fwd_roofline", "pattern", backward)
+    for key in ("pattern", "count_pattern"):
+        assert not matches("flash_bwd_roofline", key, backward)
+        assert matches("flash_bwd_fused_roofline", key, backward)
+        assert not matches("flash_bwd_fused_roofline", key, forward)
+    backward = "attn_full.41 = " + full[1]
+    assert not matches("flash_full_bwd_roofline.train", "pattern", backward)
+    assert matches("attn_train_share", "ops", backward)
+    forward = "flash_window_fwd.9 = " + windowed[0]
+    backward = "flash_window_bwd.9 = " + windowed[1]
+    assert not matches("flash_window_bwd_roofline", "pattern", backward)
+    assert not matches("attn_train_share", "ops", backward)
+    assert matches("flash_window_bwd_fused_train_share", "ops", backward)
+    assert not matches("flash_window_bwd_fused_train_share", "ops", forward)
 
 
 def _without_combines(calls):
@@ -343,7 +457,7 @@ def test_fsdp4_train_step_compiles_with_flash(v5e):
     compiled = make_train_step(config, opt, mesh=mesh).lower(
         state, batch, batch).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2  # the forward, the ONE backward
     assert "all-gather" in text  # fsdp: weights gathered per layer
     # each device holds a quarter of the state, not all of it
     whole = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
@@ -492,8 +606,14 @@ ACCEPTED_PROGRAMS_SHA = {
     # everything without experts stand as they were
     "hybrid_prefill":
         "2db757e59e520551b8312ed20a85fef6376b7ce87e999ac39870e0045691a163",
-    "flash_fwd_bwd": 
-        "f7e7ab589c6105498b819b990dbda5cda3c09b4307791981cd966bc05680969c",
+    # taken again on PR 58's tree, which MEANS to change it and
+    # ``train_4k_step`` below and no other: the flash backward is ONE Pallas
+    # pass (``_flash_bwd_kernel``) and no longer the dQ and the dK/dV kernel
+    # with the float32 per-head dK/dV summed outside; the forward inside
+    # both is the kernel it was, as every served program's is (the hash
+    # until then: f7e7ab589c6105498b819b990dbda5cda3c09b4307791981cd966bc05680969c)
+    "flash_fwd_bwd":
+        "43ac267fc8bd1eead36af6cc5c6be13dbb9f627b917bae31669384afee440013",
     # the window family, taken on the parent commit of PR 38 (e8140a7), whose
     # ring arithmetic PR 38 moved into models/paged_decode.py for the fourth
     # family to share
@@ -524,8 +644,11 @@ ACCEPTED_PROGRAMS_SHA = {
         "e2f027d18fa901b132225c84a6c94ceab87d85f9b128477801a75be1e7ee7b51",
     "kimi_k2_prefill":
         "ca8fb536ba4b158f31a25d6746ec298545da3e50b5cb41a57cd46be15b5e2ee8",
+    # taken again on PR 58's tree with ``flash_fwd_bwd`` above: the step
+    # differentiates through the flash call (the hash of PR 46's parent until
+    # then: 93be954fac40c8e84e755380ac97bd779def24c763d3786568e814f9cc165399)
     "train_4k_step":
-        "93be954fac40c8e84e755380ac97bd779def24c763d3786568e814f9cc165399",
+        "7820e1cf7d10c97fd8a7af2caf0b0cacf0a9579521bc852b6b1fc75657994839",
 }
 
 
@@ -617,7 +740,9 @@ def test_accepted_programs_lower_to_the_parents_text(v5e, name):
     programs that run one; their hashes were taken again there. The compacted
     product's sums of rows onto tokens became ``ops/rows_to_tokens.py``'s
     kernel (PR 48), which means to change the two served programs that run
-    the compacted product (Laguna's and Kimi's prefill) and no other.
+    the compacted product (Laguna's and Kimi's prefill) and no other. The
+    flash backward became ONE pass (PR 58), which means to change the two
+    programs that differentiate, ``flash_fwd_bwd`` and ``train_4k_step``.
     Called as the accepted families call
     them, they trace to what they were: the decode and prefill programs of
     the Llama-shaped and the hybrid family, and the flash forward and
@@ -1134,9 +1259,10 @@ def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     """Mellum2-12B-A2.5B's published widths, 8 of 28 layers (two periods), 16
     of the router's 64 experts and a quarter of the vocabulary, 2 x 8,192
     tokens, the benchmark's optimizer: Mosaic takes the flash backward under
-    a window (``flash_window_bwd_dq`` / ``flash_window_bwd_dkv``, whose K, V,
-    q and dO of one head sit whole in VMEM: 24 MB at 8,192 rows, over the
-    default scoped limit) beside the full one; every grouped product of the
+    a window (``flash_window_bwd``, ONE pass since PR 58, which holds the
+    dQ of a KV head's eight q heads in VMEM, 32 MB of float32 scratch and 2
+    x 16 of bfloat16 at 8,192 rows, over the default scoped limit) beside
+    the full one; every grouped product of the
     expert layer, forward and reverse, is ``ops/grouped_matmul.py``'s kernel
     under a name that starts ``ragged-dot`` (three forward and eight in
     reverse a layer, the eight written twice: the first block and the loop
@@ -1154,9 +1280,9 @@ def test_mellum_train_step_compiles_and_fits_at_two_rows_of_8192(v5e):
     compiled = _train_step_lowered(v5e, config, 2, 8192).compile()
     text = compiled.as_text()
     calls = [c.split(".")[0] for c in _mosaic_calls(text)]
-    assert {"flash_window_fwd", "flash_window_bwd_dq", "flash_window_bwd_dkv",
-            "attn_full"} <= set(calls), sorted(set(calls))
-    assert calls.count("flash_window_bwd_dq") == 1  # one sliding layer's body
+    assert {"flash_window_fwd", "flash_window_bwd", "attn_full"} <= set(calls), \
+        sorted(set(calls))
+    assert calls.count("flash_window_bwd") == 1  # one sliding layer's body
     grouped = [c for c in calls if c.startswith("ragged-dot")]
     assert len(grouped) == 2 * (3 + 2 * 8), calls
     assert {c: grouped.count(c) for c in set(grouped)} == {
@@ -1214,8 +1340,10 @@ def test_olmo_hybrid_train_step_compiles_and_fits_at_one_row_of_32768(v5e):
     """Olmo-Hybrid-7B's published widths, one period of its eight (L L L F)
     and a quarter of the vocabulary, 1 x 32,768 tokens, the benchmark's
     optimizer: the step holds the chunk kernels forward and reverse beside
-    the full flash forward and backward (whose q, dO and statistics of one
-    head sit whole in VMEM: 117 MB at 32,768 rows of a v5e's 128), the
+    the full flash forward and its ONE backward pass (which asks for the dQ
+    of one head, float32 scratch and the bfloat16 block twice, and 16 MB: 48
+    MB at 32,768 rows, where the pair it replaced in PR 58 asked for 100.7 MB
+    + 16), the
     scanned period ONE linear layer's body and one full layer's, and the
     program fits the chip beside 6.16 GB of state."""
     from ray_tpu.models import olmo_hybrid as oh
@@ -1224,12 +1352,28 @@ def test_olmo_hybrid_train_step_compiles_and_fits_at_one_row_of_32768(v5e):
         vocab_size=25088, layer_types=oh.PERIOD, attention_impl="flash",
         gdn_impl="pallas", max_seq_len=32768)
     compiled = _train_step_lowered(v5e, config, 1, 32768).compile()
-    calls = [c.split(".")[0] for c in _mosaic_calls(compiled.as_text())]
+    text = compiled.as_text()
+    calls = [c.split(".")[0] for c in _mosaic_calls(text)]
     assert {c: calls.count(c) for c in set(calls)} == {
         "gdn_chunk_fwd": 1, "gdn_chunk_bwd_states": 1, "gdn_chunk_bwd": 1,
-        "attn_full": 3}, calls
+        "attn_full": 2}, calls
+    asks = _scoped_vmem_asks(text, "attn_full")
+    assert len(asks) == 2 and max(asks) < 116.7e6, asks
     mem = compiled.memory_analysis()
     state = mem.argument_size_in_bytes
     assert 6.1e9 < state < 6.2e9 and mem.alias_size_in_bytes > 6.1e9
-    # the step's own beside the new state it writes onto the old
-    assert mem.temp_size_in_bytes - mem.alias_size_in_bytes < 7.5e9
+    # what the step NEEDS, the compiler's peak of live bytes, arguments and
+    # temporaries at the worst instant (15.80 GB of the 16.91 a v5e has):
+    # the linear layers' reverse pass, which PR 58 did not move by a page
+    assert mem.peak_memory_in_bytes < 15.9e9
+    # the step's own beside the new state it writes onto the old, as
+    # ``temp_size_in_bytes`` has it: 6.82 GB until PR 58, 7.52 since, with the
+    # same peak and 1.5 GB fewer buffers. It rose because the change FREES
+    # the forward's lane-padded lse column (480 MiB for 3.9 MB of numbers)
+    # a hundred program points sooner than the pair, which read it as a
+    # column: with that column held to the backward call and nothing else
+    # changed the figure reads 6.820 again, and no buffer AT the call moves
+    # it by a page (dK/dV in float32, the results aliased onto q, K and V,
+    # 70 to 117 MB of VMEM asked: 7.52490752 GB each). PERF.md 7, PR 58 (3)
+    # has the readings and what a next issue sets this bound on
+    assert mem.temp_size_in_bytes - mem.alias_size_in_bytes < 7.6e9

@@ -52,7 +52,9 @@ def test_flash_matches_reference(b, sq, hq, hkv, d, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_flash_gradient_flows():
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_gradient_flows(causal):
+    """Causal, and not: there every block pair is admitted and none masked."""
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((1, 128, 2, 32)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 128, 2, 32)), jnp.float32)
@@ -61,10 +63,11 @@ def test_flash_gradient_flows():
     with jax.default_matmul_precision("highest"):
 
         def loss_flash(q, k, v):
-            return flash_attention(q, k, v, interpret=True, block_q=64, block_k=64).sum()
+            return flash_attention(q, k, v, causal=causal, interpret=True,
+                                   block_q=64, block_k=64).sum()
 
         def loss_ref(q, k, v):
-            return reference_attention(q, k, v).sum()
+            return reference_attention(q, k, v, causal=causal).sum()
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -150,19 +153,37 @@ def test_flash_causal_requires_kv_longer():
         flash_attention(q, k, k, causal=True, interpret=True)
 
 
-def test_flash_gradient_gqa_causal():
-    """Backward kernels under GQA (Hq=4, Hkv=2): dk/dv reduce over the
-    q-head group; compare against the reference vjp."""
+# (batch, sq, skv, q heads, kv heads, block_q, block_k): Mistral's kind of
+# group; a group of 1 (Olmo-Hybrid's 30 / 30: a k block's pairs are one
+# head's); a group of 8 on ONE KV head (Mellum's: every head's pairs of a k
+# block sum into one scratch); keys that precede the queries (``offset`` =
+# skv - sq moves every pair's mask and the first q block a k block meets); a
+# row count the pad path grows (384 -> 512 at 256 / 512, the default blocks:
+# two q blocks against one k block, the padded rows' dO zero). The backward's
+# q block is twice the one asked for where the rows divide, so the first
+# three walk ONE q block of 128 against two k blocks; the last two walk
+# several: 256 rows in two q blocks of 128 against four k blocks of 64, and
+# 192 rows, which twice 64 does not divide, in three of 64
+GQA_GRAD_CASES = [(2, 128, 128, 4, 2, 64, 64), (1, 128, 128, 3, 3, 64, 64),
+                  (1, 128, 128, 8, 1, 64, 64), (1, 64, 192, 4, 2, 64, 64),
+                  (1, 128, 256, 2, 1, 64, 128), (1, 384, 384, 4, 2, 256, 512),
+                  (1, 256, 256, 4, 2, 64, 64), (1, 192, 192, 2, 2, 64, 64)]
+
+
+@pytest.mark.parametrize("b,sq,skv,hq,hkv,bq,bk", GQA_GRAD_CASES)
+def test_flash_gradient_gqa_causal(b, sq, skv, hq, hkv, bq, bk):
+    """The one backward pass under GQA: dk/dv are summed over the q-head
+    group inside the kernel; compare against the reference vjp."""
     rng = np.random.default_rng(7)
-    q = jnp.asarray(rng.standard_normal((2, 128, 4, 32)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, 128, 2, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 128, 2, 32)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, sq, hq, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, skv, hkv, 32)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, skv, hkv, 32)), jnp.float32)
 
     with jax.default_matmul_precision("highest"):
 
         def loss_flash(q, k, v):
             out = flash_attention(q, k, v, causal=True, interpret=True,
-                                  block_q=64, block_k=64)
+                                  block_q=bq, block_k=bk)
             return (out * out).sum()
 
         def loss_ref(q, k, v):
@@ -175,28 +196,114 @@ def test_flash_gradient_gqa_causal():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
 
 
-# (seq, window, block_q, block_k): one block; a window that is no multiple of
-# a block and spans several; blocks of unequal size (the dK/dV loop's last q
-# block and the dQ loop's first k block are both inside the sequence); S <
-# window (the window never bites: the full backward's answer); a sequence the
-# pad path grows
-WINDOW_GRAD_CASES = [(64, 24, 64, 64), (256, 100, 64, 64), (512, 130, 64, 128),
-                     (256, 96, 128, 64), (128, 1024, 64, 64), (200, 70, 64, 64)]
+@pytest.mark.parametrize("hq,hkv,window", [(8, 2, None), (8, 1, 40), (6, 3, None)])
+def test_flash_dkv_of_a_kv_head_is_the_sum_over_its_groups_heads(hq, hkv, window):
+    """dK and dV of a KV head against the float32 SUM, over the q heads of
+    its group, of the reference's gradients with every q head given a K and V
+    of its own: the kernel sums a group in VMEM and writes once. Every head's
+    output is weighted by its own number, so a head summed onto the wrong KV
+    head moves dK by more than the tolerance."""
+    rng = np.random.default_rng(13)
+    group = hq // hkv
+    q = jnp.asarray(rng.standard_normal((1, 128, hq, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 128, hkv, 32)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 128, hkv, 32)), jnp.float32)
+    weight = jnp.arange(1, hq + 1, dtype=jnp.float32)[None, None, :, None]
+
+    with jax.default_matmul_precision("highest"):
+        dk, dv = jax.grad(
+            lambda k, v: (flash_attention(
+                q, k, v, interpret=True, block_q=64, block_k=64,
+                window=window) * weight).sum(), argnums=(0, 1))(k, v)
+        a_head = lambda x: jnp.repeat(x, group, axis=2)  # noqa: E731
+        dk_h, dv_h = jax.grad(
+            lambda k, v: (reference_attention(q, k, v, window=window)
+                          * weight).sum(), argnums=(0, 1))(a_head(k), a_head(v))
+    for got, per_head in ((dk, dk_h), (dv, dv_h)):
+        want = per_head.reshape(1, 128, hkv, group, 32).sum(axis=3)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-5, rtol=5e-5)
 
 
-@pytest.mark.parametrize("s,window,bq,bk", WINDOW_GRAD_CASES)
-def test_flash_window_gradient_matches_reference(s, window, bq, bk):
-    """The backward under a sliding window (``flash_window_bwd_dq`` /
-    ``flash_window_bwd_dkv``, whose loops start and stop at the blocks the
-    window admits) against ``reference_attention``'s gradient, GQA 4 / 2.
+# (what the group's dQ may take of VMEM, in heads' worth; the parts a group of
+# 4 is then walked in): a head's worth and under it, one head a grid step;
+# two heads' worth, two parts of two; the group's whole, one part
+PARTS_CASES = [(0, 4), (1, 4), (2, 2), (3, 2), (4, 1)]
+
+
+@pytest.mark.parametrize("window", [None, 100])
+@pytest.mark.parametrize("heads_worth,parts", PARTS_CASES)
+def test_flash_backward_walks_a_group_in_parts_that_fit(monkeypatch, heads_worth,
+                                                        parts, window):
+    """A group whose dQ is over ``BWD_DQ_VMEM_BYTES`` is walked in equal
+    parts, each a grid step's heads with a dQ of its own; a part writes its
+    dK and dV in float32 and ``_flash_bwd`` sums the parts before the one
+    cast. Whatever the parts, the gradients are the reference's: GQA 8 / 2,
+    two q blocks against four k blocks, with a window and without."""
+    import importlib
+
+    # ``ray_tpu.ops`` names a function ``attention`` over its module
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    rng = np.random.default_rng(17)
+    s, d = 256, 32
+    q = jnp.asarray(rng.standard_normal((1, s, 8, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, s, 2, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, s, 2, d)), jnp.float32)
+    # what ``_flash_bwd`` counts for a head: dQ in float32 scratch and its
+    # output block twice, and the blocks of q and dO (128 rows) twice
+    head_vmem = d * (s * (4 + 2 * 4) + 4 * 128 * 4)
+    monkeypatch.setattr(attention, "BWD_DQ_VMEM_BYTES", heads_worth * head_vmem)
+    seen = []
+    real = attention.pl.pallas_call
+    monkeypatch.setattr(
+        attention.pl, "pallas_call",
+        lambda *a, **kw: seen.append(kw.get("grid_spec")) or real(*a, **kw))
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: (fn(q, k, v) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got = grads(lambda q, k, v: flash_attention(
+            q, k, v, interpret=True, block_q=64, block_k=64, window=window))
+        want = grads(lambda q, k, v: reference_attention(q, k, v, window=window))
+    # the backward's grid: (batch, KV heads x parts, admitted pairs)
+    # (the forward's call states its grid without a specification)
+    assert seen[0] is None and seen[1].grid[:2] == (1, 2 * parts), seen
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+
+
+# (seq, window, block_q, block_k, q heads, kv heads, keys before the queries).
+# At GQA 4 / 2: one block; a window that is no multiple of a block and spans
+# several; blocks of unequal size (a k block's last q block and a q block's
+# first k block are both inside the sequence); S < window (the window never
+# bites: the full backward's answer); a sequence the pad path grows. Then
+# Mellum's group of 8 under a window that spans blocks; a group of 1; keys
+# that precede the queries, the oldest two k blocks of them older than every
+# query's window (their dK and dV are zeros the kernel still has to write)
+WINDOW_GRAD_CASES = [
+    (64, 24, 64, 64, 4, 2, 0), (256, 100, 64, 64, 4, 2, 0),
+    (512, 130, 64, 128, 4, 2, 0), (256, 96, 128, 64, 4, 2, 0),
+    (128, 1024, 64, 64, 4, 2, 0), (200, 70, 64, 64, 4, 2, 0),
+    (256, 100, 64, 64, 8, 1, 0), (256, 96, 128, 64, 3, 3, 0),
+    (64, 40, 64, 64, 4, 2, 192), (128, 100, 64, 128, 8, 1, 128)]
+
+
+@pytest.mark.parametrize("s,window,bq,bk,hq,hkv,before", WINDOW_GRAD_CASES)
+def test_flash_window_gradient_matches_reference(s, window, bq, bk, hq, hkv,
+                                                 before):
+    """The backward under a sliding window (``flash_window_bwd``, which
+    visits the block pairs the window admits and masks those on its edges)
+    against ``reference_attention``'s gradient.
     Tolerance 5e-5: float32 throughout with ``highest`` products, so what is
     left is the order of the sums (the same bound as the full backward's
     test above); a block wrongly skipped or a mask off by one moves an entry
     by 1e-2 or more (the planted fault below)."""
     rng = np.random.default_rng(11)
-    q = jnp.asarray(rng.standard_normal((2, s, 4, 32)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, s, 2, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, s, 2, 32)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((2, s, hq, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, before + s, hkv, 32)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, before + s, hkv, 32)), jnp.float32)
 
     def loss(fn, window):
         def f(q, k, v):
