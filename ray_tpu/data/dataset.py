@@ -34,7 +34,7 @@ from ray_tpu.data.executor import (
     Stage,
     ZipStage,
 )
-from ray_tpu.profiling import span
+from ray_tpu.profiling import span, step_ring
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("data")
@@ -483,7 +483,9 @@ def _device_prefetch(host_iter: Iterator[Dict[str, np.ndarray]], sharding,
                      dtype) -> Iterator[Dict[str, Any]]:
     """Host batches -> device batches, ``config.device_prefetch_depth`` of
     them transferred ahead of consumption. The wait for the next host batch
-    is the ``data.next_batch`` span of a device trace."""
+    is the ``data.next_batch`` span of a device trace. Each ``yield`` is the
+    take of the consuming loop's ``profiling.StepRing``: leaving the generator
+    ends a step, coming back starts the feed's part of the next."""
     import jax
 
     from ray_tpu.core.config import config
@@ -499,6 +501,7 @@ def _device_prefetch(host_iter: Iterator[Dict[str, np.ndarray]], sharding,
     depth = max(1, config.device_prefetch_depth)
     buf: "deque" = deque()
     host_iter = iter(host_iter)
+    ring = step_ring()  # of the thread that takes the first batch
     while True:
         with span("data.next_batch"):
             batch = next(host_iter, None)
@@ -506,9 +509,13 @@ def _device_prefetch(host_iter: Iterator[Dict[str, np.ndarray]], sharding,
             break
         buf.append(to_device(batch))
         if len(buf) >= depth:
+            ring.take(len(buf))
             yield buf.popleft()
+            ring.back()
     while buf:
+        ring.take(len(buf))
         yield buf.popleft()
+        ring.back()
 
 
 def _batch_iterator(refs: Iterator[ObjectRef], batch_size: int, batch_format: str,

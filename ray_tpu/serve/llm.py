@@ -37,7 +37,6 @@ dicts {"tokens": [...], "max_tokens": N} -> {"tokens": [...], "ttft_s": ...}.
 from __future__ import annotations
 
 import bisect
-import os
 import queue
 import sys
 import threading
@@ -49,7 +48,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from ray_tpu.profiling import host_events, span
+from ray_tpu.profiling import host_events, joined_stall, span, stall_watch
 from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("serve.llm")
@@ -71,6 +70,15 @@ PREFILL, DECODE, BRING_UP = 0, 1, 2
 SLOW_ITER_FLOOR_S = 1.0
 SLOW_ITER_MEDIANS = 5.0
 SLOW_LOG_EVERY_S = 10.0
+# the loop's heartbeat for ``profiling.StallWatch``: a beat at each of the
+# seven boundaries names the phase then entered (after the last: the row and
+# the way back to the next iteration) and one an idle poll. Its limit is the
+# slow-iteration threshold, recomputed from the ring's median every
+# ``LIMIT_EVERY_ITERS`` iterations once the ring holds ``LIMIT_MIN_ITERS``
+BEAT_PHASES = PHASES + ("between", "idle")
+BEAT_IDLE = len(PHASES) + 1
+LIMIT_MIN_ITERS = 8
+LIMIT_EVERY_ITERS = 64
 MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                 "moe_experts_touched", "moe_expert_load_max")
 # every counter a family's programs may count on the device: the decode
@@ -175,17 +183,6 @@ def _slots_updated(table, tokens, positions, active, retired, firsts, placed):
             tokens.at[slots].set(firsts, mode="drop"),
             positions.at[slots].set(placed[:, 1], mode="drop"),
             (active & ~retired).at[slots].set(True, mode="drop"))
-
-
-def _steal_s() -> float:
-    """Seconds since boot in which the hypervisor ran something else on this
-    machine's CPUs (``/proc/stat``, summed over CPUs); 0.0 where it is not
-    told."""
-    try:
-        with open("/proc/stat") as f:
-            return int(f.readline().split()[8]) / os.sysconf("SC_CLK_TCK")
-    except (OSError, ValueError, IndexError):
-        return 0.0
 
 
 @dataclass
@@ -334,9 +331,20 @@ class LLMEngine:
       CPU counters above add up; near zero: all of it waited, on the device
       or the kernel; near the total: a thread ran)
       and ``steal_s`` (seconds, summed over CPUs, the hypervisor gave to
-      others since the engine started), ``queued`` and ``active``; each is
+      others OVER the iteration: since the stall watch's last reading before
+      it began, so at most a second more), ``queued`` and ``active``; each is
       also one warning line in the log (at most one every 10 s;
-      ``slow_iters_unlogged`` counts the rest).
+      ``slow_iters_unlogged`` counts the rest). ``stall``, where the
+      process's ``profiling.StallWatch`` sampled the wait inside the
+      iteration: its whole record (what the threads, the faults, the I/O,
+      the throttling and its own lateness did WHILE the wait lasted, and the
+      ``class`` its fixed rules give), joined by time when ``stats()`` is
+      read.
+    - ``in_flight``: ``{loop, phase, for_s}`` while the loop's last beat (one
+      at each phase boundary, one an idle poll) is older than its limit,
+      max(1 s, 5 x the ring's median) once the ring holds 8 iterations; else
+      None. An iteration that never ends is in no other counter: the rest
+      of ``stats()`` is then what the last finished iteration left.
     - ``kv_bytes_per_token``: bytes of K and V a cached token takes over
       all the layers that KEEP (write) pages: what a token costs the pool.
       A family whose cache has no V pool (models/kimi_k2.py: one latent row
@@ -499,7 +507,7 @@ class LLMEngine:
         self._started = time.perf_counter()
         # always-on counters and the flight recorder (see the class docstring)
         self._host_events = host_events()
-        self._steal0_s = _steal_s()
+        self._heart = stall_watch().heartbeat("llm-engine", BEAT_PHASES)
         self._iters = 0
         self._iter_ns = 0
         self._idle_ns = 0
@@ -537,7 +545,9 @@ class LLMEngine:
         self._queue_wait_counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
         self._ring = np.zeros((RING_ITERS, len(RING_COLUMNS)))
         self._longest_iter_ns = 0
-        self._slow_iters: "deque[Dict[str, Any]]" = deque(maxlen=16)
+        # (record, the iteration's first and last boundary): the watch's
+        # record of the wait inside it is joined when ``stats()`` is read
+        self._slow_iters: "deque[tuple]" = deque(maxlen=16)
         self._slow_logged_at = -SLOW_LOG_EVERY_S
         self._slow_unlogged = 0
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -663,8 +673,10 @@ class LLMEngine:
                      "rows": np.roll(ring, -(n % len(ring)), axis=0).tolist()
                      if n else []},
             "longest_iter_s": self._longest_iter_ns / 1e9,
-            "slow_iters": list(self._slow_iters),
+            "slow_iters": [joined_stall(self._heart.name, *slow)
+                           for slow in self._slow_iters],
             "slow_iters_unlogged": self._slow_unlogged,
+            "in_flight": self._heart.in_flight(),
             **self._cache_stats,
             "kv_pages_in_use": self.total_pages - 1 - self.allocator.free_pages,
             "kv_pages_total": self.total_pages - 1,
@@ -1035,24 +1047,29 @@ class LLMEngine:
         except Exception as e:  # noqa: BLE001 - reported to every caller
             logger.exception("llm engine stopped: step failed")
             self._fail_all(e)
+        finally:
+            self._heart.close()
 
     def _step(self) -> None:
         """One iteration, in six phases that partition it (``PHASES``): each
         is a span on the device trace's clock and, at the end, one integer
         add into ``phase_ns`` and one into ``phase_cpu_ns``: the seven
         boundaries are stamped on the wall clock and on the loop thread's CPU
-        clock."""
+        clock, and each is a beat of the loop's heart."""
         jax = self._jax
-        clock, cpu = time.perf_counter_ns, time.thread_time_ns
+        clock, cpu, beat = time.perf_counter_ns, time.thread_time_ns, \
+            self._heart.beat
         host = self._host_events
         compiles0, gc_ns0 = host.compiles, host.gc_pause_ns
         admitted0, retired0 = self._admitted, self._retired
         started_wall = time.time()
         process_cpu0 = time.process_time_ns()
         t0, c0 = clock(), cpu()
+        beat(0, t0)
         with span("engine.admit"):
             groups = self._admit()
         t1, c1 = clock(), cpu()
+        beat(1, t1)
         firsts = []  # a prefill group's first tokens, on the device
         for chunk, bucket, size in groups:
             if bucket not in self._buckets_up:
@@ -1064,6 +1081,7 @@ class LLMEngine:
                 firsts.append(self._prefill_group(chunk, bucket, size))
         t2, c2 = clock(), cpu()
         if not any(r is not None for r in self._slots):
+            beat(BEAT_IDLE, t2)
             with span("engine.idle"):
                 time.sleep(0.01)  # idle: poll for work (_admit drains FIFO)
             idle = clock() - t0
@@ -1076,6 +1094,7 @@ class LLMEngine:
             return
         if self._ended:  # a busy iteration behind a busy one
             self._between_ns += t0 - self._ended
+        beat(2, t2)
         with span("engine.decode_dispatch"):
             if self._retiring.any():  # no prefill group carried them
                 size = self._prefill_rows[0]
@@ -1092,6 +1111,7 @@ class LLMEngine:
             self._tokens = last
             self._steps += self.decode_chunk
         t3, c3 = clock(), cpu()
+        beat(3, t3)
         with span("engine.device_get"):
             # ONE host sync per chunk: the chunk's tokens and, one array a
             # group, the first tokens of this round's prefills
@@ -1104,6 +1124,7 @@ class LLMEngine:
                 self._prefill_counts += call_counts
             self._prefill_counts_pending = []
         t4, c4 = clock(), cpu()
+        beat(4, t4)
         self._chip_empty(t4)  # the fetch holds the last program's result
         now = t4 / 1e9  # perf_counter's clock, as submitted_at
         now_wall = time.time()
@@ -1111,6 +1132,7 @@ class LLMEngine:
         self._decode_rows_live += active * self.decode_chunk
         t5, c5 = self._emit(host_tokens, groups, host_firsts, now, now_wall)
         t6, c6 = clock(), cpu()
+        beat(6, t6)
         self._ended = t6
         self._record_iter(started_wall, (t0, t1, t2, t3, t4, t5, t6),
                           (c0, c1, c2, c3, c4, c5, c6),
@@ -1148,6 +1170,7 @@ class LLMEngine:
                 if self._finished(req):
                     finished.append(slot)
         boundary = time.perf_counter_ns(), time.thread_time_ns()
+        self._heart.beat(5, boundary[0])
         if finished:
             with span("engine.retire"):
                 self._retire(finished)
@@ -1157,8 +1180,9 @@ class LLMEngine:
                      process_cpu_ns: int, active: int, admitted: int,
                      retired: int, compiles: int, gc_ns: int) -> None:
         """Constant work an iteration: the counters, one ring row, and the
-        slow-iteration check (the ring's median and ``/proc/stat`` are read
-        only for an iteration over the 1 s floor). ``t`` and ``cpu``: the
+        slow-iteration check (the ring's median is read for an iteration over
+        the 1 s floor, and every ``LIMIT_EVERY_ITERS`` iterations for the
+        heart's limit). ``t`` and ``cpu``: the
         seven phase boundaries on the wall clock and on the loop thread's
         CPU clock; ``process_cpu_ns``: the process's over the iteration."""
         total = t[6] - t[0]
@@ -1176,11 +1200,17 @@ class LLMEngine:
         self._iters += 1
         if total > self._longest_iter_ns:
             self._longest_iter_ns = total
-        if total <= SLOW_ITER_FLOOR_S * 1e9:
+        slow = total > SLOW_ITER_FLOOR_S * 1e9
+        due = self._iters == LIMIT_MIN_ITERS \
+            or self._iters % LIMIT_EVERY_ITERS == 0
+        if not (slow or due):
             return
         rows = self._ring[:min(self._iters, RING_ITERS)]
         median_s = float(np.median(rows[:, 1:1 + len(PHASES)].sum(axis=1)))
-        if total <= SLOW_ITER_MEDIANS * median_s * 1e9:
+        if due:
+            self._heart.limit_ns = int(1e9 * max(
+                SLOW_ITER_FLOOR_S, SLOW_ITER_MEDIANS * median_s))
+        if not slow or total <= SLOW_ITER_MEDIANS * median_s * 1e9:
             return
         worst = max(range(len(PHASES)), key=phases.__getitem__)
         record = {
@@ -1188,10 +1218,10 @@ class LLMEngine:
             "phase": PHASES[worst], "phase_s": phases[worst] / 1e9,
             "median_s": median_s, "compiles": compiles, "gc_s": gc_ns / 1e9,
             "cpu_s": process_cpu_ns / 1e9, "loop_cpu_s": loop_cpu_ns / 1e9,
-            "steal_s": _steal_s() - self._steal0_s,
+            "steal_s": stall_watch().steal_since(t[0]),
             "queued": self._queued(), "active": active,
         }
-        self._slow_iters.append(record)
+        self._slow_iters.append((record, t[0], t[6]))
         now = time.perf_counter()
         if now - self._slow_logged_at < SLOW_LOG_EVERY_S:
             self._slow_unlogged += 1
@@ -1200,7 +1230,7 @@ class LLMEngine:
         logger.warning(
             "slow engine iteration: %.3f s at %.3f (median %.3f s); longest "
             "phase %s %.3f s; compiles %d, gc %.3f s; cpu %.3f s (loop thread "
-            "%.3f s), steal %.2f s since start; queued %d, active %d; %d "
+            "%.3f s), steal %.2f s over it; queued %d, active %d; %d "
             "earlier ones not logged", record["total_s"], started_wall,
             median_s, record["phase"], record["phase_s"], compiles,
             record["gc_s"], record["cpu_s"], record["loop_cpu_s"],

@@ -19,7 +19,7 @@ import uuid
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
-from ray_tpu.profiling import span
+from ray_tpu.profiling import StepRing, bind_step_ring, span, step_ring
 
 
 class Checkpoint:
@@ -82,6 +82,8 @@ class _Session:
         self.finished = False
         self.stop_requested = False
         self.error: Optional[BaseException] = None
+        # the loop's flight recorder; its heartbeat starts with the first mark
+        self.step_ring = StepRing()
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None) -> None:
         persisted: Optional[str] = None
@@ -97,10 +99,14 @@ class _Session:
                 os.makedirs(os.path.dirname(step_dir), exist_ok=True)
                 shutil.copytree(checkpoint.path, step_dir, dirs_exist_ok=True)
             persisted = step_dir
+        ring = self.step_ring
+        ring.report_put()
         self.result_queue.put({"metrics": dict(metrics), "checkpoint": persisted, "done": False})
+        ring.report_wait()
         # lockstep with the trainer's collection round
         self.continue_event.wait()
         self.continue_event.clear()
+        ring.reported()
         if self.stop_requested:
             raise SessionStopped()
 
@@ -118,11 +124,15 @@ _session_lock = threading.Lock()
 def _bind_session_to_current_thread(s: _Session) -> None:
     with _session_lock:
         _sessions[threading.get_ident()] = s
+    bind_step_ring(s.step_ring)
 
 
 def _unbind_current_thread() -> None:
     with _session_lock:
-        _sessions.pop(threading.get_ident(), None)
+        s = _sessions.pop(threading.get_ident(), None)
+    bind_step_ring(None)
+    if s is not None:
+        s.step_ring.close()
 
 
 def _get_session() -> Optional[_Session]:
@@ -137,6 +147,15 @@ def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None) -> 
         raise RuntimeError("ray_tpu.train.report() called outside a training session")
     with span("train.report"):
         s.report(metrics, checkpoint)
+
+
+def loop_stats() -> Dict[str, Any]:
+    """The calling loop's ``profiling.StepRing.stats()``: its last 256 steps by
+    part (feed, dispatch, report, the rest), counters, and the slow steps each
+    with the stall watch's record of the wait inside it. The session's ring,
+    or outside a session the process's own; what an operator's own
+    ``train.report`` can carry."""
+    return step_ring().stats()
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

@@ -34,6 +34,7 @@ from ray_tpu.parallel.sharding import (
     axes_is_leaf,
     logical_sharding,
 )
+from ray_tpu.profiling import host_events, step_ring
 from ray_tpu.utils.compile_cache import enable_compile_cache
 
 
@@ -159,6 +160,28 @@ def _loss_of(config, mesh, rules):
         counted is not None
 
 
+class _MarkedStep:
+    """The compiled step as a train loop calls it. The call is the loop's
+    ``dispatch`` mark in its ``profiling.StepRing``: the time inside it is the
+    ENQUEUE, and a long one means the call blocked, on a compile or on a full
+    queue. Everything else (``lower``, ``trace``, ...) is the jitted
+    function's own."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __call__(self, *args, **kwargs):
+        ring = step_ring()
+        ring.dispatch()
+        try:
+            return self._jitted(*args, **kwargs)
+        finally:
+            ring.dispatched()
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 def make_train_step(
     config,
     optimizer,
@@ -167,8 +190,10 @@ def make_train_step(
     donate: bool = True,
 ):
     """(state, tokens, targets) -> (state, metrics). tokens/targets: [B, S].
-    What the family's loss counted (``_loss_of``) goes into the metrics."""
+    What the family's loss counted (``_loss_of``) goes into the metrics. The
+    jitted step comes back inside ``_MarkedStep``."""
     enable_compile_cache()
+    host_events()  # the ring counts compiles and collections a step
     loss, has_counters = _loss_of(config, mesh, rules)
 
     def step_fn(state: TrainState, tokens, targets) -> Tuple[TrainState, Dict[str, jax.Array]]:
@@ -187,17 +212,17 @@ def make_train_step(
 
     donate_argnums = (0,) if donate else ()
     if mesh is None:
-        return jax.jit(step_fn, donate_argnums=donate_argnums)
+        return _MarkedStep(jax.jit(step_fn, donate_argnums=donate_argnums))
     from ray_tpu.parallel.mesh import batch_sharding_spec
 
     batch_sh = jax.sharding.NamedSharding(mesh, batch_sharding_spec())
     state_sh = _state_shardings(state_logical_axes(config, optimizer), mesh, rules)
-    return jax.jit(
+    return _MarkedStep(jax.jit(
         step_fn,
         in_shardings=(state_sh, batch_sh, batch_sh),
         out_shardings=(state_sh, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())),
         donate_argnums=donate_argnums,
-    )
+    ))
 
 
 def make_eval_step(config, mesh=None, rules: ShardingRules = DEFAULT_LLM_RULES):

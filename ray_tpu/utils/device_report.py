@@ -4,7 +4,10 @@ Every result that is a statement about the device names the device: the
 bench scripts put this in their JSON line, ``chip_smoke.py`` collects it from
 inside each dedicated TPU worker, and ``LLMDeployment.runtime_report`` serves
 it from a replica. Calling it initialises the jax backend, so only the
-process that is meant to hold the chip may call it.
+process that is meant to hold the chip may call it. ``"host"`` is the
+process's side of a stall: ``profiling.stall_watch()``'s snapshot (pauses,
+stall records, the lateness ring, what is in flight now) and every watched
+loop by name (the engine's pulse; a train loop's whole ``StepRing``).
 """
 
 from __future__ import annotations
@@ -16,6 +19,7 @@ from typing import Any, Dict
 def device_report() -> Dict[str, Any]:
     import jax
 
+    from ray_tpu.profiling import stall_watch
     from ray_tpu.utils.compile_cache import compile_cache_stats
 
     devices = jax.devices()
@@ -38,4 +42,6 @@ def device_report() -> Dict[str, Any]:
             "dir": jax.config.jax_compilation_cache_dir,
             **compile_cache_stats(),
         },
+        "host": {"watch": stall_watch().snapshot(),
+                 "loops": stall_watch().loops()},
     }

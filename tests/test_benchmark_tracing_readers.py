@@ -20,6 +20,7 @@ from benchmarks.tests.test_rates import *  # noqa: F401,F403
 from benchmarks.tests.test_readers import *  # noqa: F401,F403
 from benchmarks.tests.test_reference import *  # noqa: F401,F403
 from benchmarks.tests.test_roofline import *  # noqa: F401,F403
+from benchmarks.tests.test_stall_readers import *  # noqa: F401,F403
 from benchmarks.tests.test_trace_reduce import *  # noqa: F401,F403
 from benchmarks.tests.test_traffic import *  # noqa: F401,F403
 

@@ -106,6 +106,7 @@ class SlowSplit:
 
 def test_phases_partition_the_iteration(engine):
     submit_together(engine, [request([1, 2, 3], 9) for _ in range(3)])
+    time.sleep(0.05)  # some idle polls behind the last busy iteration
     s = engine.stats()
     assert s["iters"] > 0 and set(s["phase_ns"]) == set(llm.PHASES)
     assert sum(s["phase_ns"].values()) == pytest.approx(s["iter_ns"], rel=0.02)
@@ -242,6 +243,78 @@ def test_a_slow_iteration_leaves_one_record_and_one_warning_line(engine):
     assert after["longest_iter_s"] >= rec["total_s"]
     slow_lines = [m for m in lines if m.startswith("slow engine iteration")]
     assert len(slow_lines) == 1 and rec["phase"] in slow_lines[0]
+
+
+class BlockedGet:
+    """``jax``, whose first ``device_get`` waits for ``release`` (or
+    ``seconds``) before it fetches."""
+
+    def __init__(self, jax, seconds):
+        self._jax, self._seconds = jax, seconds
+        self.release, self.blocked = threading.Event(), False
+
+    def __getattr__(self, name):
+        return getattr(self._jax, name)
+
+    def device_get(self, tree):
+        if not self.blocked:
+            self.blocked = True
+            self.release.wait(self._seconds)
+        return self._jax.device_get(tree)
+
+
+def test_a_blocked_fetch_is_in_flight_while_it_lasts_and_joined_after(
+        engine, monkeypatch):
+    """The iteration that does not end is in no counter; ``in_flight`` shows
+    it DURING the wait, the stall watch samples it, and the slow record has
+    the watch's record under ``stall``. ``steal_s`` is the steal over the
+    iteration: against a steal clock that runs a second a second it reads
+    about the iteration's length, not the engine's age or the machine's."""
+    from ray_tpu import profiling
+
+    monkeypatch.setattr(profiling, "cpu_times",
+                        lambda: (5000.0 + time.perf_counter(), 0.0))
+    # the heart's limit is published once the ring holds eight iterations
+    engine.generate([1, 2, 3], max_tokens=40, timeout=300)
+    time.sleep(1.3)  # the watch's next reading, once a second, is off that clock
+    before = engine.stats()
+    assert before["in_flight"] is None
+    real = engine._jax
+    engine._jax = BlockedGet(real, 2.5)
+    done = []
+    caller = threading.Thread(target=lambda: done.append(
+        engine.generate([4, 5, 6], max_tokens=5, timeout=300)))
+    caller.start()
+    try:
+        deadline, seen = time.time() + 30, None
+        while seen is None and time.time() < deadline:
+            seen = engine.stats()["in_flight"]
+            time.sleep(0.02)
+        during = engine.stats()
+    finally:
+        time.sleep(0.5)  # some samples of the wait
+        engine._jax.release.set()
+        caller.join(timeout=60)
+        engine._jax = real
+    assert done and seen["phase"] == "device_get"
+    assert seen["loop"].startswith("llm-engine") and seen["for_s"] >= llm.SLOW_ITER_FLOOR_S
+    # the counters stood still: what they show is the last finished iteration
+    assert during["iters"] == before["iters"] and during["in_flight"] is not None
+    deadline = time.time() + 10
+    while time.time() < deadline:
+        after = engine.stats()
+        new = after["slow_iters"][len(before["slow_iters"]):]
+        if new and "stall" in new[0]:
+            break
+        time.sleep(0.05)
+    assert len(new) == 1 and after["in_flight"] is None
+    rec = new[0]
+    stall = rec["stall"]
+    assert rec["phase"] == stall["phase"] == "device_get"
+    assert stall["loop"] == seen["loop"] and stall["class"] == "all_asleep", stall
+    assert stall["waited_s"] >= llm.SLOW_ITER_FLOOR_S and stall["samples"] >= 5
+    assert stall["limit_s"] >= llm.SLOW_ITER_FLOOR_S
+    assert rec["total_s"] - 0.2 <= rec["steal_s"] <= rec["total_s"] + 1.5
 
 
 def test_a_new_shape_raises_compiles_by_one(engine):
